@@ -62,13 +62,6 @@ def _check_one_model(name: str, args) -> Report:
 
 
 def main(argv=None) -> int:
-    # BEFORE any jax touch: honor a user-pinned JAX_PLATFORMS even when
-    # an externally-registered PJRT plugin tries to override it (same
-    # guard as models/cli.py)
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()
-
     p = argparse.ArgumentParser(
         prog="bigdl_tpu.analysis",
         description="static graph checker + tracer-leak linter")

@@ -17,8 +17,10 @@ reference's runtime:
   (``dataset/image/MTLabeledBGRImgToBatch.scala``).
 
 The shared library is compiled from ``src/*.cc`` with ``make`` on first use
-and bound via ctypes; every entry point has a pure-NumPy fallback so the
-package works without a toolchain.
+(and again whenever a source or the Makefile is newer than the binary) and
+bound via ctypes; every entry point has a pure-NumPy fallback so the
+package works without a toolchain — :func:`is_native_loaded` says which
+one a process ended up with.
 """
 
 from __future__ import annotations
@@ -70,13 +72,17 @@ def _try_load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            try:
-                subprocess.run(["make", "-s"], cwd=_DIR, check=True,
-                               capture_output=True, timeout=120)
-            except Exception:
+        # make decides every time (once per process): a binary older
+        # than src/*.cc or the Makefile is rebuilt, so a stale .so left
+        # on disk — git ignores it — never outlives its sources
+        try:
+            subprocess.run(["make", "-s"], cwd=_DIR, check=True,
+                           capture_output=True, timeout=120)
+        except (OSError, subprocess.SubprocessError):
+            if not os.path.exists(_LIB_PATH):
                 _build_failed = True
                 return None
+            # no toolchain here, but a binary someone built: use it
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError:

@@ -10,7 +10,8 @@ decides which leg runs.
 Knob: ``BIGDL_KERNELS`` (read at trace time):
 
 - ``auto`` (default) — Pallas on TPU hardware when the op's support
-  predicate admits the shape/dtype; XLA everywhere else.  CPU runs keep
+  predicate admits the shape/dtype and the step is not partitioned over
+  a multi-device mesh; XLA everywhere else.  CPU runs keep
   their fused-XLA paths, so enabling telemetry or running the tier-1
   suite never silently drops onto the (slow) Pallas interpreter.
 - ``pallas`` — Pallas whenever the shape is structurally supported;
@@ -30,6 +31,8 @@ env with fresh shapes (or eagerly) for exactly this reason.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 from collections import deque
 from typing import Callable, Deque, List, Tuple
@@ -37,12 +40,34 @@ from typing import Callable, Deque, List, Tuple
 from bigdl_tpu import telemetry
 
 __all__ = ["kernel_mode", "choose_backend", "dispatch", "use_interpret",
-           "decisions", "clear_decisions", "MODES"]
+           "spmd_partitioned", "auto_pallas", "decisions",
+           "clear_decisions", "MODES"]
 
 MODES = ("auto", "pallas", "xla")
 
 #: last N (op, backend, reason) decisions, trace-time order
 _DECISIONS: Deque[Tuple[str, str, str]] = deque(maxlen=256)
+
+#: True while the current thread traces a step that XLA will partition
+#: over a multi-device mesh (see :func:`spmd_partitioned`)
+_SPMD = contextvars.ContextVar("bigdl_spmd_partitioned", default=False)
+
+
+@contextlib.contextmanager
+def spmd_partitioned(mesh):
+    """Trace-time scope for a step whose jit XLA partitions over
+    ``mesh`` (``TrainStep``/``EvalStep`` enter it around the model
+    call).  The TPU compiler refuses a Mosaic kernel there — "Mosaic
+    kernels cannot be automatically partitioned.  Please wrap the call
+    in a shard_map" — so inside the scope, on more than one device,
+    ``auto`` takes the XLA leg and records why.  Code already under a
+    ``shard_map`` (local-SGD islands, ring attention) is per-device and
+    does not enter it."""
+    token = _SPMD.set(mesh is not None and mesh.size > 1)
+    try:
+        yield
+    finally:
+        _SPMD.reset(token)
 
 
 def kernel_mode() -> str:
@@ -60,8 +85,7 @@ def kernel_mode() -> str:
 
 
 def use_interpret() -> bool:
-    """Pallas interpret mode off-TPU (device check, not backend name —
-    the round-4 proxied-PJRT gating bug)."""
+    """Pallas interpret mode off-TPU."""
     from bigdl_tpu.ops.attention import is_tpu_device
 
     return not is_tpu_device()
@@ -76,11 +100,21 @@ def choose_backend(op: str, supported: bool) -> Tuple[str, str]:
         return "xla", "unsupported-shape"
     if mode == "pallas":
         return "pallas", "forced:BIGDL_KERNELS=pallas"
+    ok, reason = auto_pallas()
+    return ("pallas" if ok else "xla"), reason
+
+
+def auto_pallas() -> Tuple[bool, str]:
+    """The ``auto`` mode's rule, shared with the attention router: a
+    Pallas kernel on TPU hardware, unless XLA is about to partition the
+    program over a mesh (:func:`spmd_partitioned`)."""
     from bigdl_tpu.ops.attention import is_tpu_device
 
-    if is_tpu_device():
-        return "pallas", "auto:tpu"
-    return "xla", "auto:off-tpu"
+    if not is_tpu_device():
+        return False, "auto:off-tpu"
+    if _SPMD.get():
+        return False, "auto:spmd-partitioned"
+    return True, "auto:tpu"
 
 
 def note(op: str, backend: str, reason: str) -> None:

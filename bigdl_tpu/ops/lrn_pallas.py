@@ -87,16 +87,16 @@ def cross_map_lrn_supported(x, size: int, layout: str = "NCHW") -> bool:
 
 
 def _pick_tile(f_pad: int, cp: int, esz: int):
-    """Largest HW-tile (divisor of f_pad) whose fwd/bwd block stack fits
-    the VMEM budget; None when even the smallest tile does not fit."""
-    t = f_pad
-    while t > 0:
+    """Largest HW-tile whose fwd/bwd block stack fits the VMEM budget.
+    Mosaic wants a block's last dimension to be a multiple of 128 (or
+    the whole extent), so the candidates are the multiples of 128 that
+    divide ``f_pad`` (itself a multiple of 128); None when even one
+    128-lane tile does not fit."""
+    lanes = f_pad // 128
+    for k in range(lanes, 0, -1):
         # ~5 live [Cp, T] planes: x, sq, running band sum, den, y
-        if 5 * cp * t * esz <= _VMEM_BUDGET:
-            return t
-        if t % 2:
-            return None
-        t //= 2
+        if lanes % k == 0 and 5 * cp * 128 * k * esz <= _VMEM_BUDGET:
+            return 128 * k
     return None
 
 

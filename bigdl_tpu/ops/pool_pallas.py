@@ -71,7 +71,8 @@ def _axis_geom(n: int, k: int, s: int, lo: int, hi: int):
 
 def pool_plane_supported(x, dims, strides) -> bool:
     """Pallas-leg gate: 4-D with the window on the trailing (H, W)
-    axes, bounded taps; Mosaic dtype + VMEM fit on real TPU."""
+    axes, bounded taps; on real TPU also a Mosaic dtype, a stride-1
+    window and a VMEM fit."""
     if x.ndim != 4 or dims[0] != 1 or dims[1] != 1:
         return False
     if strides[0] != 1 or strides[1] != 1:
@@ -82,6 +83,11 @@ def pool_plane_supported(x, dims, strides) -> bool:
         return False
     if not _dispatch.use_interpret():
         if x.dtype not in _TPU_DTYPES:
+            return False
+        if strides[2] != 1 or strides[3] != 1:
+            # Mosaic has no strided vector slice ("expected strides to
+            # be confined to [1, 2)"), which _taps and the residue
+            # gather need for a strided window: XLA leg on the chip
             return False
         esz = jnp.dtype(x.dtype).itemsize
         # ~10 live planes: padded input, padded y/gy, tie count, weight,

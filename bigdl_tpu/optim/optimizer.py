@@ -1436,7 +1436,17 @@ class Optimizer:
                     self.state["epoch"] += 1
                     # expose the epoch to compiled schedules
                     step.opt_state = dict(step.opt_state)
-                    step.opt_state["epoch"] = jax.numpy.asarray(self.state["epoch"], jax.numpy.int32)
+                    epoch = jax.numpy.asarray(self.state["epoch"],
+                                              jax.numpy.int32)
+                    prev = step.opt_state["epoch"]
+                    if mesh is not None and prev.ndim == 0:
+                        # on a mesh, placed like the scalar it replaces:
+                        # a plain asarray lands on the first device only,
+                        # and the changed input sharding recompiles the
+                        # step (local mode restacks its island axis, and
+                        # off a mesh the state stays uncommitted)
+                        epoch = jax.device_put(epoch, prev.sharding)
+                    step.opt_state["epoch"] = epoch
                     records_this_epoch = 0
                     self.state["records"] = 0
                     self.state["_epoch_boundary"] = True

@@ -102,12 +102,6 @@ def make_pipeline_fn(block_fn: Callable, mesh, n_microbatches: int,
     Returns the [B, ...] outputs, replicated (psum of the last stage's
     emissions).
     """
-    try:  # jax >= 0.6 exports shard_map at top level (check_vma kwarg)
-        from jax import shard_map
-        _sm_checked = partial(shard_map, check_vma=False)
-    except ImportError:  # this jaxlib (0.4.x): experimental, check_rep
-        from jax.experimental.shard_map import shard_map
-        _sm_checked = partial(shard_map, check_rep=False)
     from jax.sharding import PartitionSpec as P
 
     s = mesh.shape[axis_name]
@@ -121,8 +115,8 @@ def make_pipeline_fn(block_fn: Callable, mesh, n_microbatches: int,
 
         p_specs = jax.tree.map(lambda _: P(axis_name), stacked_params)
 
-        @partial(_sm_checked, mesh=mesh,
-                 in_specs=(p_specs, P()), out_specs=P())
+        @partial(jax.shard_map, mesh=mesh, in_specs=(p_specs, P()),
+                 out_specs=P(), check_vma=False)
         def run(params, xmb):
             outs = pipeline_apply(block_fn, params, xmb, axis_name)
             # only the last stage holds real outputs; psum replicates

@@ -128,10 +128,7 @@ def make_sequence_parallel_attention(mesh, strategy: str = "ring",
     batch dim shards over ``batch_axis`` while each data-row runs its own
     k/v ring over ``axis_name`` (ppermute is scoped per axis, so the
     rings never cross data rows)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis_name]
@@ -146,9 +143,5 @@ def make_sequence_parallel_attention(mesh, strategy: str = "ring",
     else:
         raise ValueError(f"unknown strategy {strategy!r}")
 
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)
-    except TypeError:  # older shard_map API
-        return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_rep=False)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)

@@ -36,6 +36,7 @@ import numpy as np
 from bigdl_tpu import telemetry as _telemetry
 from bigdl_tpu.analysis import hooks as _hooks
 from bigdl_tpu.nn.module import Module, functional_call, state_dict, _resolve
+from bigdl_tpu.ops import dispatch as _kernel_dispatch
 from bigdl_tpu.parallel.mesh import (DATA_AXIS, data_sharding,
                                      mesh_process_count, replicated,
                                      shard_local_batch)
@@ -501,6 +502,15 @@ class TrainStep:
             loss_fn = jax.checkpoint(loss_fn, static_argnums=())
 
         def step(params, opt_state, buffers, x, y, key, grad_scale=None):
+            # the WHOLE step: custom-VJP backward rules are traced after
+            # the forward returns, and their kernels dispatch too.  (The
+            # argument names label the HLO parameters that the memory
+            # and comms walkers sort into categories.)
+            with _kernel_dispatch.spmd_partitioned(mesh):
+                return _step(params, opt_state, buffers, x, y, key,
+                             grad_scale)
+
+        def _step(params, opt_state, buffers, x, y, key, grad_scale=None):
             if mesh is not None:
                 from jax.sharding import PartitionSpec as P
 
@@ -744,12 +754,7 @@ class TrainStep:
 
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        try:  # jax >= 0.6 exports shard_map at top level (check_vma)
-            from jax import shard_map as _sm
-            smap = partial(_sm, check_vma=False)
-        except ImportError:  # this jaxlib (0.4.x): experimental
-            from jax.experimental.shard_map import shard_map as _sm
-            smap = partial(_sm, check_rep=False)
+        smap = partial(jax.shard_map, check_vma=False)
         rep = NamedSharding(mesh, P())
 
         def spec_of(kind, path, arr):
@@ -813,7 +818,8 @@ class TrainStep:
         def islands(params, opt_state, buffers, xs, ys, keys, *rest):
             # leading axis = the islands of THIS shard (all of them
             # when mesh-free); the fault scalar broadcasts to each
-            if rest:
+            # rest is a Python tuple (the arity), not a traced value
+            if rest:  # noqa: lint/tracer-branch
                 one = lambda p, o, b, xi, yi, k: inner(p, o, b, xi, yi,
                                                        k, rest[0])
             else:
@@ -839,18 +845,12 @@ class TrainStep:
                 args += (grad_scale,)
             if mesh is None:
                 return islands(*args)
-            try:  # jax >= 0.6 exports shard_map at top level
-                from jax import shard_map as _sm
-                smap = partial(_sm, check_vma=False)
-            except ImportError:  # this jaxlib (0.4.x): experimental
-                from jax.experimental.shard_map import shard_map as _sm
-                smap = partial(_sm, check_rep=False)
             isl = P(self._zero_axis())
             in_specs = (isl,) * 6
             if grad_scale is not None:
                 in_specs += (P(),)  # the fault scalar is replicated
-            return smap(islands, mesh=mesh, in_specs=in_specs,
-                        out_specs=isl)(*args)
+            return jax.shard_map(islands, mesh=mesh, in_specs=in_specs,
+                                 out_specs=isl, check_vma=False)(*args)
 
         return many
 
@@ -863,8 +863,7 @@ class TrainStep:
 
     def _build_scan(self, n: int, stacked: bool):
         """n train iterations inside ONE compiled call via ``lax.scan`` —
-        amortizes per-dispatch latency (remote/tunneled devices pay a full
-        round-trip per dispatch) and lets XLA overlap steps.  ``stacked``:
+        amortizes per-dispatch latency and lets XLA overlap steps.  ``stacked``:
         x/y carry a leading iteration axis (one minibatch per step);
         otherwise the same batch repeats (the perf-harness protocol).
         In local mode the body is the vmapped island step, so the scan's
@@ -923,6 +922,12 @@ class TrainStep:
                                       {"x": x, "y": y, "key": key})
         self._dispatch_observed = None
         if self._compiled is None:
+            # the per-step program is what `cli train` compiles: a
+            # restart should load it from the same managed cache as
+            # aot_scan and the serving warmup (docs/compile.md)
+            from bigdl_tpu.utils.engine import enable_compile_cache
+
+            enable_compile_cache()
             self._compiled = self._build()
         if self.parameter_sync == "local":
             # the driver may insert UNSTACKED scalars into opt_state
@@ -1217,11 +1222,11 @@ class TrainStep:
         contract either way."""
         # AOT is the path restarts/preemption-resumes pay repeatedly —
         # a warm restart should LOAD this executable, not rebuild it
-        # (docs/compile.md; implicit: accelerator-only unless
-        # BIGDL_COMPILE_CACHE opts plain CPU in, =0 opts out)
+        # (docs/compile.md: accelerator-only unless BIGDL_COMPILE_CACHE
+        # opts plain CPU in, =0 opts out)
         from bigdl_tpu.utils.engine import enable_compile_cache
 
-        enable_compile_cache(implicit=True)
+        enable_compile_cache()
         x, y = self._shard_batch(x, y, stacked)
         tracer = _telemetry.get()
         t0 = time.perf_counter()
@@ -1332,7 +1337,8 @@ class EvalStep:
             if cdt is not None:
                 state = {k: (v.astype(cdt) if jnp.issubdtype(v.dtype, jnp.floating) else v)
                          for k, v in state.items()}
-            out, _ = functional_call(model, state, x, training=False)
+            with _kernel_dispatch.spmd_partitioned(self.mesh):
+                out, _ = functional_call(model, state, x, training=False)
             if cdt is not None:
                 out = jax.tree.map(
                     lambda a: a.astype(jnp.float32) if jnp.issubdtype(a.dtype, jnp.floating) else a,

@@ -264,12 +264,12 @@ class BucketedExecutor:
         wall seconds spent; idempotent per bucket."""
         # a warm RESTART'S warmup should load every bucket executable
         # from the persistent cache instead of recompiling the whole
-        # set before the ready line (docs/compile.md; implicit:
-        # accelerator-only unless BIGDL_COMPILE_CACHE opts plain CPU
-        # in, =0 opts out) — the same managed cache aot_scan uses
+        # set before the ready line (docs/compile.md: accelerator-only
+        # unless BIGDL_COMPILE_CACHE opts plain CPU in, =0 opts out) —
+        # the same managed cache the train step uses
         from bigdl_tpu.utils.engine import enable_compile_cache
 
-        enable_compile_cache(implicit=True)
+        enable_compile_cache()
         t0 = time.perf_counter()
         self.refresh_state()
         with self._lock, _telemetry.span(

@@ -94,7 +94,10 @@ def goodput() -> Optional[Dict[str, Any]]:
     return ledger.event_fields() if ledger is not None else None
 
 
-def _default_meta() -> Dict[str, Any]:
+def _default_meta(device_facts: bool = True) -> Dict[str, Any]:
+    """Schema + incarnation + (unless ``device_facts=False``) what jax
+    says of the devices — which INITIALIZES the backend, i.e. claims
+    the chip: a process that only launches workers must not ask."""
     meta: Dict[str, Any] = {"schema": SCHEMA_VERSION}
     inc = os.environ.get("BIGDL_SUPERVISOR_INCARNATION")
     if inc is not None:
@@ -102,6 +105,8 @@ def _default_meta() -> Dict[str, Any]:
             meta["incarnation"] = int(inc)
         except ValueError:
             pass
+    if not device_facts:
+        return meta
     try:  # device facts are best-effort: telemetry must work sans jax
         import jax
 
@@ -117,19 +122,22 @@ def _default_meta() -> Dict[str, Any]:
 
 def start_run(path_or_dir: Optional[str] = None,
               meta: Optional[Dict[str, Any]] = None,
-              sinks=None) -> Tracer:
+              sinks=None, device_facts: bool = True) -> Tracer:
     """Install the process-wide tracer.  ``path_or_dir``: a ``.jsonl``
     path is used as-is; a directory gets a fresh
     ``run-<stamp>-<pid>.jsonl``; None writes to no file (pass ``sinks``,
     e.g. a MemorySink, instead).  Raises if a run is already active —
-    nested runs would interleave two schedules into one file."""
+    nested runs would interleave two schedules into one file.
+    ``device_facts=False`` keeps the run's meta (and so this process)
+    off the jax backend — the cluster supervisor's setting: its workers
+    need the chip."""
     global _active, _last_run_path, _metrics_server, _flight, _fleet, \
         _ledger
     with _lifecycle_lock:
         if _active is not None:
             raise RuntimeError("a telemetry run is already active; "
                                "end_run() it first")
-        full_meta = _default_meta()
+        full_meta = _default_meta(device_facts)
         full_meta.update(meta or {})
         all_sinks = list(sinks or [])
         try:  # the run-level goodput ledger rides as one more sink
@@ -273,7 +281,8 @@ def run(path_or_dir: Optional[str] = None,
 
 
 @contextmanager
-def maybe_run(meta: Optional[Dict[str, Any]] = None):
+def maybe_run(meta: Optional[Dict[str, Any]] = None,
+              device_facts: bool = True):
     """Config-gated run ownership for entry points (bench.py,
     profile_bench, models/cli perf): start a JSONL run when
     ``BIGDL_TELEMETRY`` names a directory and no run is active yet.
@@ -287,7 +296,8 @@ def maybe_run(meta: Optional[Dict[str, Any]] = None):
     if not get_config().telemetry_dir or enabled():
         yield None
         return
-    start_run(get_config().telemetry_dir, meta=meta)
+    start_run(get_config().telemetry_dir, meta=meta,
+              device_facts=device_facts)
     try:
         yield _last_run_path
     finally:

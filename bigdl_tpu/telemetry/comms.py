@@ -72,10 +72,11 @@ _HLO_DTYPE_BYTES = {
 }
 
 _OPS_ALT = "|".join(COLLECTIVE_OPS)
-#: one collective op line of optimized HLO text; group(1) = opcode
-#: (base or -start form), group(2) = the operand list inside the parens
+#: one collective op line of optimized HLO text; group(1) = the result
+#: type, group(2) = opcode, group(3) = "-start" on the async form,
+#: group(4) = the operand list inside the parens
 _COLL_RE = re.compile(
-    rf"=\s*(?:\([^=]*?\)|\S+)\s+({_OPS_ALT})(-start)?\((.*?)\)(?:,|\s*$)")
+    rf"=\s*(\([^=]*?\)|\S+)\s+({_OPS_ALT})(-start)?\((.*?)\)(?:,|\s*$)")
 #: typed operand, e.g. ``f32[100,192]{{1,0}} %dot.5``
 _SHAPE_RE = re.compile(r"\b(" + "|".join(_HLO_DTYPE_BYTES) +
                        r")\[([0-9,]*)\]")
@@ -87,6 +88,7 @@ _GROUPS_IOTA_RE = re.compile(
 _PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)+)\}")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _CHANNEL_RE = re.compile(r"channel_id=(\d+)")
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 
 
 class Collective:
@@ -207,16 +209,31 @@ def parse_hlo_collectives(hlo_text: str,
     """All collective ops of one optimized-HLO module text."""
     out: List[Collective] = []
     for line in hlo_text.splitlines():
+        if "/*" in line:  # long tuples carry /*index=5*/ markers
+            line = _COMMENT_RE.sub("", line)
         m = _COLL_RE.search(line)
         if m is None:
             continue
-        opcode = m.group(1)
-        payload = _operand_bytes(m.group(3))
-        if payload == 0:
-            continue
+        opcode = m.group(2)
         groups = _parse_groups(line)
         group_size = max((len(g) for g in groups), default=1) \
             if groups else 1
+        payload = _operand_bytes(m.group(4))
+        if payload == 0 and not (m.group(3) and opcode != "all-reduce"):
+            # the XLA under jax 0.9.0 prints operands by name only
+            # (``all-reduce(%fusion.3)``): size the operands from the
+            # RESULT type.  Not for an async -start form whose result
+            # tuple mixes operands, results and context (all-reduce's
+            # is its operands' shapes alone)
+            result = _operand_bytes(m.group(1))
+            if opcode == "all-gather":
+                payload = result // max(group_size, 1)
+            elif opcode == "reduce-scatter":
+                payload = result * group_size
+            else:
+                payload = result
+        if payload == 0:
+            continue
         if opcode == "all-gather":
             nbytes = payload + payload * group_size
         elif opcode == "reduce-scatter":

@@ -25,95 +25,77 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, Optional
 
-__all__ = ["peak_flops_per_device", "peak_bw_per_device",
+__all__ = ["CHIP_PEAKS", "peak_flops_per_device",
+           "peak_int8_ops_per_device", "peak_bw_per_device",
            "hbm_per_device", "normalize_cost_analysis",
            "cost_facts", "memory_facts", "live_memory_facts",
            "donated_bytes", "collect_device_facts", "mfu_estimate"]
 
-#: per-chip dense bf16 peak FLOP/s by device_kind prefix (the bench.py
-#: table's sibling — shared convention: BIGDL_PEAK_FLOPS overrides).
-_PEAK_FLOPS = {
-    "TPU v2": 45e12,
-    "TPU v3": 123e12,
-    "TPU v4 lite": 137e12,
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v5": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
+#: THE per-chip peak table, keyed by ``device_kind`` prefix (longest
+#: prefix wins: "TPU v5 lite" over "TPU v5").  Columns: dense bf16
+#: FLOP/s, int8 OP/s, aggregate chip-to-chip interconnect (ICI)
+#: bytes/s, HBM GiB.  Source: Google Cloud TPU documentation, the
+#: "System architecture" page of each version (e.g. "TPU v5e": 197
+#: TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM, 1,600 Gbit/s ICI); chips
+#: with no separate int8 rate list their bf16 rate.  bench.py and the
+#: telemetry stream both read this table and no other.
+CHIP_PEAKS = {
+    "TPU v2": (45e12, 45e12, 1.0e11, 8),
+    "TPU v3": (123e12, 123e12, 1.4e11, 16),
+    "TPU v4 lite": (137e12, 137e12, 3.0e11, 8),
+    "TPU v4": (275e12, 275e12, 3.0e11, 32),
+    "TPU v5 lite": (197e12, 393e12, 2.0e11, 16),
+    "TPU v5e": (197e12, 393e12, 2.0e11, 16),
+    "TPU v5p": (459e12, 918e12, 6.0e11, 95),
+    "TPU v5": (459e12, 918e12, 6.0e11, 95),
+    "TPU v6 lite": (918e12, 1836e12, 3.6e11, 32),
+    "TPU v6e": (918e12, 1836e12, 3.6e11, 32),
 }
+_BF16, _INT8, _ICI, _HBM = range(4)
+
+
+def _chip_peak(device_kind: str, column: int, required: bool = False):
+    kind = (device_kind or "").lower()
+    names = [n for n in CHIP_PEAKS if kind.startswith(n.lower())]
+    if names:
+        return CHIP_PEAKS[max(names, key=len)][column]
+    if required and kind != "cpu":
+        # a utilization against a guessed peak is a wrong number, and a
+        # silently missing field hides that nothing was measured
+        raise ValueError(
+            f"unknown accelerator device_kind {device_kind!r}: add it "
+            f"to telemetry.device.CHIP_PEAKS with its source")
+    return None
 
 
 def peak_flops_per_device(device_kind: str) -> Optional[float]:
-    """Dense bf16 peak FLOP/s for one device, or None when unknown (CPU
-    has no meaningful MFU denominator).  ``BIGDL_PEAK_FLOPS`` (FLOP/s)
-    overrides the table — also the escape hatch for new TPU kinds."""
+    """Dense bf16 peak FLOP/s for one device.  None for the CPU (no
+    meaningful MFU denominator); an accelerator that is not in
+    :data:`CHIP_PEAKS` raises.  ``BIGDL_PEAK_FLOPS`` (FLOP/s) overrides
+    the table."""
     env = os.environ.get("BIGDL_PEAK_FLOPS")
     if env:
         return float(env)
-    kind = (device_kind or "").lower()
-    best = None
-    for name, peak in _PEAK_FLOPS.items():
-        if kind.startswith(name.lower()):
-            # longest prefix wins ("TPU v5 lite" over "TPU v5")
-            if best is None or len(name) > best[0]:
-                best = (len(name), peak)
-    return best[1] if best else None
+    return _chip_peak(device_kind, _BF16, required=True)
 
 
-#: per-chip aggregate interconnect (ICI) bandwidth in bytes/s by
-#: device_kind prefix — the comms-attribution denominator
-#: (telemetry/comms.py), sibling of the peak-FLOPs table above.  These
-#: are approximate public aggregate figures; ``BIGDL_PEAK_BW`` overrides
-#: (and is the only way to describe a DCN-spanning slice, whose
-#: cross-slice links are far slower than ICI).
-_PEAK_BW = {
-    "TPU v2": 1.0e11,
-    "TPU v3": 1.4e11,
-    "TPU v4": 3.0e11,
-    "TPU v5 lite": 2.0e11,
-    "TPU v5e": 2.0e11,
-    "TPU v5p": 6.0e11,
-    "TPU v5": 6.0e11,
-    "TPU v6 lite": 3.6e11,
-    "TPU v6e": 3.6e11,
-}
+def peak_int8_ops_per_device(device_kind: str) -> Optional[float]:
+    """int8 peak OP/s for one device — the int8 inference leg's
+    utilization denominator; same unknown-device contract as
+    :func:`peak_flops_per_device`."""
+    return _chip_peak(device_kind, _INT8, required=True)
 
 
 def peak_bw_per_device(device_kind: str) -> Optional[float]:
     """Aggregate interconnect bytes/s for one device, or None when
     unknown (CPU collectives have no meaningful peak).  ``BIGDL_PEAK_BW``
-    (bytes/s) overrides the table — also the DCN escape hatch."""
+    (bytes/s) overrides the table — also the only way to describe a
+    DCN-spanning slice, whose cross-slice links are far slower than
+    ICI."""
     env = os.environ.get("BIGDL_PEAK_BW")
     if env:
         return float(env)
-    kind = (device_kind or "").lower()
-    best = None
-    for name, peak in _PEAK_BW.items():
-        if kind.startswith(name.lower()):
-            if best is None or len(name) > best[0]:
-                best = (len(name), peak)
-    return best[1] if best else None
-
-
-#: per-chip HBM bytes by device_kind prefix (public spec sheets) — the
-#: fit estimator's budget denominator (telemetry/memory.py);
-#: ``BIGDL_HBM_GB`` overrides (and is the only way to describe a
-#: host-capped or MIG-style fractional allocation).
-_HBM_GB = {
-    "TPU v2": 8,
-    "TPU v3": 16,
-    "TPU v4 lite": 8,
-    "TPU v4": 32,
-    "TPU v5 lite": 16,
-    "TPU v5e": 16,
-    "TPU v5p": 95,
-    "TPU v5": 95,
-    "TPU v6 lite": 32,
-    "TPU v6e": 32,
-}
+    return _chip_peak(device_kind, _ICI)
 
 
 def hbm_per_device(device_kind: str) -> Optional[int]:
@@ -121,13 +103,8 @@ def hbm_per_device(device_kind: str) -> Optional[int]:
     unknown (CPU has no fixed budget; ``BIGDL_HBM_GB`` is resolved by
     the caller, ``memory.hbm_limit_bytes``, so this stays a pure table
     lookup)."""
-    kind = (device_kind or "").lower()
-    best = None
-    for name, gb in _HBM_GB.items():
-        if kind.startswith(name.lower()):
-            if best is None or len(name) > best[0]:
-                best = (len(name), gb)
-    return best[1] * (1 << 30) if best else None
+    gb = _chip_peak(device_kind, _HBM)
+    return gb * (1 << 30) if gb else None
 
 
 def normalize_cost_analysis(cost) -> Dict[str, Any]:

@@ -84,14 +84,8 @@ class ProfilerControl:
                 import jax
 
                 os.makedirs(self.trace_dir, exist_ok=True)
-                if self.perfetto:
-                    try:
-                        jax.profiler.start_trace(
-                            self.trace_dir, create_perfetto_trace=True)
-                    except TypeError:  # older jax: no perfetto kwarg
-                        jax.profiler.start_trace(self.trace_dir)
-                else:
-                    jax.profiler.start_trace(self.trace_dir)
+                jax.profiler.start_trace(
+                    self.trace_dir, create_perfetto_trace=self.perfetto)
                 self.state = CAPTURING
             except Exception as e:  # noqa: BLE001 - observer, never fatal
                 self.last_error = f"{type(e).__name__}: {e}"

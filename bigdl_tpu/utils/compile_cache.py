@@ -32,7 +32,9 @@ from typing import Any, Dict, Optional
 __all__ = ["CompileCacheMonitor", "monitor", "cache_key_ingredients",
            "initialized_platform"]
 
-#: jax.monitoring keys this build observes (probed on jax 0.4.37)
+#: jax.monitoring keys this build observes — private strings of
+#: jax._src.compilation_cache / jax._src.dispatch, present on jax 0.9.0
+#: (tests/test_compile_cache.py fails if a hit stops being counted)
 _HIT_KEY = "/jax/compilation_cache/cache_hits"
 _MISS_KEY = "/jax/compilation_cache/cache_misses"
 _REQUEST_KEY = "/jax/compilation_cache/compile_requests_use_cache"
@@ -66,14 +68,10 @@ class CompileCacheMonitor:
         with self._lock:
             if self._installed:
                 return True
-            try:
-                from jax._src import monitoring as _mon
+            import jax.monitoring as _mon  # public since jax 0.4.x
 
-                _mon.register_event_listener(self._on_event)
-                _mon.register_event_duration_secs_listener(
-                    self._on_duration)
-            except Exception:  # noqa: BLE001 - advisory: no counts, ever
-                return False
+            _mon.register_event_listener(self._on_event)
+            _mon.register_event_duration_secs_listener(self._on_duration)
             self._installed = True
             return True
 
@@ -160,12 +158,13 @@ def monitor() -> CompileCacheMonitor:
 
 def initialized_platform() -> Optional[str]:
     """Platform of an ALREADY-initialized jax backend, else None —
-    without initializing one (a status scrape or an import-time check
-    must never be the first device touch; ``Engine.probe_backend`` owns
-    that, with its wedge/singleton guards).  The one home of the
-    private ``xla_bridge._backends`` probe, shared by
-    ``enable_compile_cache``'s implicit gate and
-    :func:`cache_key_ingredients`."""
+    without initializing one (a status scrape, an import-time check or
+    a supervisor parent must never claim the chip).  The one home of
+    the private probe, shared by ``enable_compile_cache``'s CPU gate
+    and :func:`cache_key_ingredients`.  Needs (checked on jax 0.9.0):
+    ``jax._src.xla_bridge._backends``, the dict that ``backends()``
+    fills on first use and that stays empty until then — jax has no
+    public "is a backend up" query."""
     try:
         import jax
         from jax._src import xla_bridge as _xb

@@ -68,14 +68,12 @@ time inside jitted-program construction):
 | BIGDL_FLASH_BLOCK_Q/K | ops.attention flash block sizes (default 1024/512 — round-5 hardware sweep) |
 | BIGDL_FLASH_MIN_SEQ   | ops.attention auto-backend threshold (default 512; dense below) |
 | BIGDL_POOL_KERNEL     | ops.pooling_pallas argmax-index pool (off/auto/on/interpret; auto=off — see BASELINE.md postmortem) |
-| BIGDL_COMPILE_CACHE   | Engine.enable_compile_cache persistent XLA executable cache dir |
+| BIGDL_COMPILE_CACHE   | Engine.enable_compile_cache persistent XLA executable cache dir (0 = off; JAX_COMPILATION_CACHE_DIR, when set, wins; default <checkout>/.jax_cache) |
 | BIGDL_COMPILE_CACHE_MIN_S | Engine.enable_compile_cache min compile seconds for an entry to persist (default 0.1) |
-| BIGDL_SINGLETON_WAIT  | Engine.check_singleton bounded wait (s) for a lock holder |
 | BIGDL_COORDINATOR_TIMEOUT | Engine._init_distributed bounded jax.distributed join (s, default 300; 0 = unbounded) |
 | BIGDL_PEAK_FLOPS      | telemetry.device MFU denominator override (FLOP/s per device) |
 | BIGDL_PEAK_BW         | telemetry.device comms-bandwidth denominator override (interconnect bytes/s per device) |
 | BIGDL_HBM_GB          | telemetry.memory per-device HBM budget override in GiB (fit estimator + OOM forensics; default: the per-chip table, else the live allocator limit) |
-| JAX_PLATFORMS         | honored over externally-registered PJRT plugins via honor_platform_request |
 """
 
 from __future__ import annotations

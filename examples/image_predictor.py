@@ -82,10 +82,6 @@ def main(argv=None):
     p.add_argument("--showNum", type=int, default=100)
     args = p.parse_args(argv)
 
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()
-
     model = load_model(args.modelType, args.modelPath, args.caffeDefPath,
                        args.tfInput, args.tfOutput)
     rows = load_image_features(args.folder, args.imageSize)

@@ -45,10 +45,6 @@ def make_pretrained_caffe(tmp):
 
 
 def main():
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()  # a user-pinned JAX_PLATFORMS must beat the plugin
-
     import jax.numpy as jnp
 
     import bigdl_tpu.nn as nn

@@ -43,10 +43,6 @@ def main(argv=None):
                    help="cap on rows (keeps the example fast)")
     args = p.parse_args(argv)
 
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()
-
     # the reference example's first two lines: log redirection on
     from bigdl_tpu.utils.logging import redirect_thirdparty_logs
 

@@ -123,10 +123,6 @@ def main(argv=None):
                    help="evaluate the int8-quantized model (bigquant)")
     args = p.parse_args(argv)
 
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()
-
     model = load_model(args.modelType, args.modelPath, args.caffeDefPath,
                        args.tfInput, args.tfOutput)
     if args.quantize:

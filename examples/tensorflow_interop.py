@@ -28,10 +28,6 @@ def main(argv=None):
                    help="where to write the GraphDef (tempfile default)")
     args = p.parse_args(argv)
 
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()
-
     import bigdl_tpu.nn as nn
     import bigdl_tpu.optim as optim
     from bigdl_tpu.dataset.sample import Sample

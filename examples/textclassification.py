@@ -90,10 +90,6 @@ def build_model(class_num, embed_dim, seq_len):
 
 
 def main(argv=None):
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()  # a user-pinned JAX_PLATFORMS must beat the plugin
-
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--data-dir", help="20-newsgroups directory "
                    "(one subdir per group); synthetic when absent")

@@ -105,10 +105,6 @@ def main(argv=None):
     p.add_argument("--dropout", type=float, default=0.2)
     args = p.parse_args(argv)
 
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()
-
     from bigdl_tpu.utils.logging import redirect_thirdparty_logs
 
     redirect_thirdparty_logs()

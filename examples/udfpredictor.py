@@ -49,10 +49,6 @@ def query(rows, text_col, udf, keep_classes):
 
 
 def main():
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()  # a user-pinned JAX_PLATFORMS must beat the plugin
-
     from examples.textclassification import main as train_main
 
     model, word_index, table, _ = train_main(

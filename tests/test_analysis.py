@@ -42,10 +42,9 @@ def test_seeded_f64_promotion():
         def update_output(self, input):
             return jnp.asarray(input, jnp.float64)
 
-    from jax.experimental import enable_x64
 
     m = nn.Sequential(nn.Linear(4, 4), PromoteF64(), nn.Linear(4, 2))
-    with enable_x64():
+    with jax.enable_x64():
         res = check_shapes(m, jax.ShapeDtypeStruct((2, 4), jnp.float32))
     assert "shape/f64" in res.report.rules_fired()
     # only the promoting layer is flagged, not every downstream consumer

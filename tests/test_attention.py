@@ -95,8 +95,10 @@ def test_data_x_seq_ring_matches_dense():
     out_ref = dot_product_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
-    g = jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
-                 argnums=(0, 1, 2))(q, k, v)
+    # under jit, as TrainStep differentiates it: an eager shard_map
+    # dispatches every primitive of the ring as its own 8-device program
+    g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
+                         argnums=(0, 1, 2)))(q, k, v)
     g_ref = jax.grad(
         lambda q, k, v: jnp.sum(
             dot_product_attention(q, k, v, causal=True) ** 2),
@@ -117,7 +119,9 @@ def test_ring_attention_differentiable(seq_mesh):
     def loss_ref(q, k, v):
         return jnp.sum(dot_product_attention(q, k, v, causal=True) ** 2)
 
-    g = jax.grad(loss_sp, argnums=(0, 1, 2))(q, k, v)
+    # jitted (the form the train step uses; eager shard_map runs each
+    # primitive of the ring as its own 8-device program)
+    g = jax.jit(jax.grad(loss_sp, argnums=(0, 1, 2)))(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

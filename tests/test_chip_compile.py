@@ -1,0 +1,172 @@
+"""The kernels of the main path, compiled for a described TPU v5e.
+
+No chip is attached here: the installed TPU compiler lowers for a
+``v5e:2x2`` topology that is described, not present, and raises what
+the chip's compiler would raise (a block shape off the (8, 128) tiling,
+a strided vector slice, too much VMEM).  Interpret mode — what every
+other kernel test runs — sees none of that.  Nothing executes, so these
+cases say nothing about values or times; parity lives in
+``test_kernels.py`` and the chip run in ``chip_smoke.py``.
+
+The topology is described inside a module-scoped fixture and only
+there: loading libtpu at import (or in a ``skipif``/``parametrize``
+argument, or in ``conftest.py``) would make every pytest worker fight
+over the library's lock.  ``is_tpu_device()`` still sees the CPU here,
+so each case steers it with ``monkeypatch``.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from bigdl_tpu.ops import attention, dispatch
+from bigdl_tpu.ops.lrn_pallas import cross_map_lrn, within_channel_lrn
+from bigdl_tpu.ops.pool_pallas import avg_pool, maxpool_tie_split
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep it off here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Dispatch as it decides on a TPU, in the default kernel mode."""
+    monkeypatch.setattr(attention, "is_tpu_device", lambda: True)
+    monkeypatch.delenv("BIGDL_KERNELS", raising=False)
+    dispatch.clear_decisions()
+
+
+def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1):
+    """Compiled text of ``op``'s value and VJP.  The cotangent is an
+    ARGUMENT placed on the described device: a backward whose inputs do
+    not depend on such an argument is lowered for the CPU instead."""
+    def fwd_bwd(*args):
+        *xs, g = args
+        y, vjp = jax.vjp(op, *xs)
+        return y, vjp(g)
+
+    xs = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * n_in
+    g = jax.ShapeDtypeStruct(jax.eval_shape(op, *xs).shape, dtype,
+                             sharding=sharding)
+    return jax.jit(fwd_bwd).lower(*xs, g).compile().as_text()
+
+
+def _backends(op_prefix):
+    return {(op, b) for op, b, _ in dispatch.decisions()
+            if op.startswith(op_prefix)}
+
+
+@pytest.mark.parametrize("shape", [(8, 8, 512, 64), (2, 8, 4096, 64)])
+def test_flash_attention_compiles(shape, one_chip, as_tpu):
+    text = _fwd_bwd_text(
+        lambda q, k, v: attention.flash_attention(q, k, v, causal=True),
+        shape, jnp.bfloat16, one_chip, n_in=3)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape", [(32, 64, 56, 56), (32, 192, 56, 56)])
+def test_cross_map_lrn_compiles(shape, dtype, one_chip, as_tpu):
+    """The two LRN sites of Inception-v1 (models/inception.py)."""
+    text = _fwd_bwd_text(lambda x: cross_map_lrn(x, 5, 1e-4, 0.75, 1.0),
+                         shape, dtype, one_chip)
+    assert _backends("lrn_cross_map") == {
+        ("lrn_cross_map.fwd", "pallas"), ("lrn_cross_map.bwd", "pallas")}
+    assert "tpu_custom_call" in text
+
+
+def test_within_channel_lrn_compiles(one_chip, as_tpu):
+    text = _fwd_bwd_text(lambda x: within_channel_lrn(x, 5, 1e-4, 0.75),
+                         (8, 32, 32, 32), jnp.float32, one_chip)
+    assert "tpu_custom_call" in text
+
+
+def _pool_window(k, s, pad=((0, 0), (0, 0))):
+    return (1, 1, k, k), (1, 1, s, s), ((0, 0), (0, 0)) + tuple(pad)
+
+
+def test_avg_pool_stride1_compiles(one_chip, as_tpu):
+    """Inception-v1's 7x7/s1 head pool stays a Pallas kernel."""
+    dims, strides, pads = _pool_window(7, 1)
+    text = _fwd_bwd_text(
+        lambda x: avg_pool(x, dims, strides, pads, pads, True, True),
+        (32, 1024, 7, 7), jnp.bfloat16, one_chip)
+    assert _backends("pool_avg") == {
+        ("pool_avg.fwd", "pallas"), ("pool_avg.bwd", "pallas")}
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("name,shape,make_op", [
+    # the aux heads' 5x5/s3 ceil-mode pool (14 -> 4: one overflow row)
+    ("pool_avg", (32, 512, 14, 14), lambda: (
+        lambda x: avg_pool(x, *_pool_window(5, 3, ((0, 1), (0, 1))),
+                           ((0, 0),) * 4, True, True))),
+    # the stem's 3x3/s2 ceil-mode max pool under split_ties()
+    ("pool_tie_split", (32, 64, 112, 112), lambda: (
+        lambda x: maxpool_tie_split(
+            x, *_pool_window(3, 2, ((0, 1), (0, 1)))))),
+])
+def test_strided_pool_compiles_or_takes_xla(name, shape, make_op,
+                                            one_chip, as_tpu):
+    """Mosaic refuses a strided vector slice, so on a TPU ``auto`` must
+    either compile the strided pool or route it to the XLA leg with the
+    decision recorded — never hand the chip a kernel it rejects."""
+    text = _fwd_bwd_text(make_op(), shape, jnp.bfloat16, one_chip)
+    took = {b for _, b in _backends(name)}
+    assert took, "no dispatch decision recorded"
+    if "pallas" in took:
+        assert "tpu_custom_call" in text
+    else:
+        reasons = {r for op, _, r in dispatch.decisions()
+                   if op.startswith(name)}
+        assert reasons == {"unsupported-shape"}
+
+
+def test_partitioned_step_takes_xla_leg(topo, as_tpu):
+    """On the four-chip data mesh XLA partitions the step, and the TPU
+    compiler refuses a Mosaic kernel there ("cannot be automatically
+    partitioned"): inside ``spmd_partitioned`` — the scope TrainStep and
+    EvalStep trace under — ``auto`` must take the XLA leg and say so."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    batch = NamedSharding(mesh, P("data"))
+
+    def fwd_bwd(x, g):
+        with dispatch.spmd_partitioned(mesh):
+            y, vjp = jax.vjp(lambda a: cross_map_lrn(a, 5, 1e-4, 0.75, 1.0),
+                             x)
+            return y, vjp(g)
+
+    x = jax.ShapeDtypeStruct((32, 64, 56, 56), jnp.bfloat16, sharding=batch)
+    text = jax.jit(fwd_bwd).lower(x, x).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert {(b, r) for _, b, r in dispatch.decisions()} == {
+        ("xla", "auto:spmd-partitioned")}

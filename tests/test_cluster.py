@@ -293,6 +293,37 @@ def test_supervisor_restarts_until_clean_and_reports_history(tmp_path,
     assert sup.exit_history[1] == [0, 0]
 
 
+def test_supervise_parent_never_initializes_a_backend(tmp_path):
+    """A chip belongs to one process at a time: with BIGDL_TELEMETRY
+    set, the ``supervise`` parent opens its run log, launches a worker
+    and reaps it WITHOUT ever initializing a jax backend — the run
+    meta's device facts used to claim the chip before the workers
+    started.  Asserted in a child: this process has a backend already."""
+    code = (
+        "import sys\n"
+        "from bigdl_tpu.models import cli\n"
+        "try:\n"
+        "    cli.main(['supervise', '-n', '1', '--', sys.executable, '-c',"
+        " 'pass'])\n"
+        "except SystemExit as e:\n"
+        "    assert e.code == 0, e.code\n"
+        "from bigdl_tpu import telemetry\n"
+        "from bigdl_tpu.utils.compile_cache import initialized_platform\n"
+        "assert telemetry.last_run_path(), 'no supervisor run log'\n"
+        "assert initialized_platform() is None, initialized_platform()\n"
+        "print('PARENT_OFF_BACKEND')\n")
+    env = dict(os.environ, BIGDL_TELEMETRY=str(tmp_path / "tele"),
+               PYTHONPATH=os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120,
+                          cwd=str(tmp_path))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "PARENT_OFF_BACKEND" in proc.stdout
+    logs = list((tmp_path / "tele").glob("*.jsonl"))
+    assert logs and "device_kind" not in logs[0].read_text().splitlines()[0]
+
+
 def test_supervisor_restart_budget_exhausts(tmp_path, monkeypatch):
     monkeypatch.setenv("BIGDL_RETRY_BACKOFF", "0.01")
     sup = cluster.Supervisor(2, _toy_worker("import sys; sys.exit(5)"),

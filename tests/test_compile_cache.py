@@ -64,7 +64,7 @@ print(json.dumps({"warmup_s": warm_s, "buckets": len(ex.warm_buckets()),
 def _run_child(code, cache_dir, tmp_path, *args):
     """Fresh interpreter, single CPU device (the persistent cache's
     supported CPU shape — the tier-1 rig's forced 8-device host
-    platform is exactly what the implicit gate keeps away from it),
+    platform is exactly what the CPU gate keeps away from it),
     explicit cache opt-in."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # single device in the child
@@ -205,17 +205,62 @@ def test_cache_key_ingredients_name_the_key():
     assert "cache_dir" in ing and "min_compile_s" in ing
 
 
-def test_implicit_enable_stays_off_cpu(monkeypatch, tmp_path):
-    """The hot-path spelling must not flip the cache on for plain-CPU
-    processes (tier-1's forced 8-device host platform is unsafe to
-    serialize on this jaxlib) — only an explicit BIGDL_COMPILE_CACHE
-    opts CPU in."""
+def test_enable_stays_off_cpu(monkeypatch):
+    """No call site may flip the cache on for plain-CPU processes
+    (tier-1's forced 8-device host platform is unsafe to serialize on
+    this jaxlib) — only an explicit BIGDL_COMPILE_CACHE opts CPU in."""
     import jax
 
     from bigdl_tpu.utils.engine import enable_compile_cache
 
     monkeypatch.delenv("BIGDL_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     if jax.config.jax_compilation_cache_dir:
         pytest.skip("cache already configured process-wide")
-    assert enable_compile_cache(implicit=True) == ""
+    assert enable_compile_cache() == ""
     assert not jax.config.jax_compilation_cache_dir
+
+
+#: (JAX_COMPILATION_CACHE_DIR, BIGDL_COMPILE_CACHE) -> (returned dir,
+#: dir this code sets); "EXT"/"OURS" stand for two tmp directories
+_PLACEMENT = {
+    # a harness placed the cache from outside: used as it stands, and
+    # no directory is set from code even when ours is named too
+    "external-wins": (("EXT", "OURS"), ("EXT", None)),
+    "external-alone": (("EXT", None), ("EXT", None)),
+    "ours": ((None, "OURS"), ("OURS", "OURS")),
+    "default-in-checkout": ((None, None), ("DEFAULT", "DEFAULT")),
+    "off": (("EXT", "0"), ("", None)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLACEMENT))
+def test_cache_placement_order(case, monkeypatch, tmp_path):
+    """docs/compile.md "Where the cache lives": BIGDL_COMPILE_CACHE=0,
+    then JAX_COMPILATION_CACHE_DIR (no jax.config.update of the dir),
+    then BIGDL_COMPILE_CACHE=<dir>, then <checkout>/.jax_cache — never
+    a path from the home directory, tempfile, a pid or the clock."""
+    import jax
+
+    from bigdl_tpu.utils import compile_cache as cc
+    from bigdl_tpu.utils import engine
+
+    names = {"EXT": str(tmp_path / "ext"), "OURS": str(tmp_path / "ours"),
+             "DEFAULT": os.path.join(REPO, ".jax_cache"), None: None}
+    (ext, ours), (want, want_set) = _PLACEMENT[case]
+    for var, val in (("JAX_COMPILATION_CACHE_DIR", ext),
+                     ("BIGDL_COMPILE_CACHE", ours)):
+        if val is None:
+            monkeypatch.delenv(var, raising=False)
+        else:
+            monkeypatch.setenv(var, names.get(val, val))
+    # as on the chip — and the cache itself must stay off in THIS
+    # process, so config updates are recorded, not applied
+    monkeypatch.setattr(cc, "initialized_platform", lambda: "tpu")
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+
+    assert engine.enable_compile_cache() == names.get(want, want)
+    assert updates.get("jax_compilation_cache_dir") == names[want_set]
+    assert engine.DEFAULT_COMPILE_CACHE == names["DEFAULT"]

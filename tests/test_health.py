@@ -450,7 +450,6 @@ def test_bench_diff_against_flag(tmp_path, monkeypatch, capsys):
     monkeypatch.setenv("BENCH_CONFIGS", "lenet_mnist")
     monkeypatch.setenv("BENCH_ITERS", "2")
     monkeypatch.setenv("BENCH_INFER", "0")
-    monkeypatch.setenv("BENCH_WEDGE_TIMEOUT", "0")
     with pytest.raises(SystemExit) as exc:
         bench.main(["--diff-against", str(baseline)])
     assert exc.value.code == 4

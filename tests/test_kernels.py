@@ -59,11 +59,65 @@ def _both_legs(fn, x, seed=1, rtol=1e-5, atol=1e-6, monkeypatch=None):
     ((2, 7, 5, 5), 5),      # band wider than half the channels
     ((1, 3, 4, 4), 3),      # tiny channel count
     ((2, 16, 7, 9), 5),     # non-square odd spatial
+    # Inception-v1's conv2/norm2 plane: 56*56 pads to 3200 lanes and the
+    # block does not fit VMEM whole, so the HW axis is TILED (5 x 640)
+    ((1, 192, 56, 56), 5),
 ])
 def test_cross_map_lrn_parity(shape, size, monkeypatch):
     x = jnp.asarray(_rng().randn(*shape).astype(np.float32))
     _both_legs(lambda a: cross_map_lrn(a, size, 1e-4, 0.75, 1.0), x,
                monkeypatch=monkeypatch)
+
+
+@pytest.mark.parametrize("f_pad,cp,esz", [
+    (3200, 196, 4),     # used to halve 3200 -> 1600 -> 800: not x128
+    (3200, 196, 2), (3200, 68, 4), (12544, 68, 4), (128, 1024, 4)])
+def test_lrn_tile_is_a_lane_multiple_that_divides_the_plane(f_pad, cp, esz):
+    """Mosaic refuses a block whose last dimension is neither a multiple
+    of 128 nor the whole extent (tests/test_chip_compile.py asks the
+    real compiler; this pins the chooser's arithmetic)."""
+    from bigdl_tpu.ops.lrn_pallas import _VMEM_BUDGET, _pick_tile
+
+    t = _pick_tile(f_pad, cp, esz)
+    assert t % 128 == 0 and f_pad % t == 0
+    assert 5 * cp * t * esz <= _VMEM_BUDGET
+    # and it is the LARGEST such tile
+    assert not any(f_pad % u == 0 and 5 * cp * u * esz <= _VMEM_BUDGET
+                   for u in range(t + 128, f_pad + 1, 128))
+
+
+def test_strided_pool_leaves_pallas_on_tpu_only(monkeypatch):
+    """Mosaic has no strided vector slice: on a TPU the plane-pool gate
+    turns a strided window to the XLA leg; the interpreter keeps it."""
+    from bigdl_tpu.ops import attention
+    from bigdl_tpu.ops.pool_pallas import pool_plane_supported
+
+    x = jax.ShapeDtypeStruct((2, 4, 14, 14), jnp.float32)
+    s1, s3 = (1, 1, 1, 1), (1, 1, 3, 3)
+    assert pool_plane_supported(x, (1, 1, 5, 5), s3)
+    monkeypatch.setattr(attention, "is_tpu_device", lambda: True)
+    assert not pool_plane_supported(x, (1, 1, 5, 5), s3)
+    assert pool_plane_supported(x, (1, 1, 5, 5), s1)
+
+
+def test_partitioned_step_takes_xla_leg_on_tpu(monkeypatch):
+    """Inside ``spmd_partitioned`` over >1 device ``auto`` never picks a
+    Mosaic kernel (it cannot be partitioned); a 1-device mesh, or no
+    mesh, keeps it."""
+    from bigdl_tpu.ops import attention
+    from bigdl_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(attention, "is_tpu_device", lambda: True)
+    monkeypatch.delenv("BIGDL_KERNELS", raising=False)
+    assert dispatch.choose_backend("op", True) == ("pallas", "auto:tpu")
+    with dispatch.spmd_partitioned(make_mesh((2,), devices=jax.devices()[:2])):
+        assert dispatch.choose_backend("op", True) \
+            == ("xla", "auto:spmd-partitioned")
+        assert attention.select_attention_backend(1024, 1024) \
+            == ("dense", "auto:spmd-partitioned")
+    for mesh in (None, make_mesh((1,), devices=jax.devices()[:1])):
+        with dispatch.spmd_partitioned(mesh):
+            assert dispatch.choose_backend("op", True)[0] == "pallas"
 
 
 def test_cross_map_lrn_general_beta_and_k(monkeypatch):

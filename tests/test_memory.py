@@ -505,7 +505,7 @@ def test_bench_memory_budget_exits_4_on_injected_regression(tmp_path):
         {"configs": {"lenet_mnist": {"peak_hbm_bytes": 1.0}}}))
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                BENCH_CONFIGS="lenet_mnist", BENCH_ITERS="2",
-               BENCH_INFER="0", BIGDL_SINGLETON_WAIT="1")
+               BENCH_INFER="0")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"),

@@ -18,10 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.test_util import check_grads
 
-try:  # this jaxlib keeps the scoped x64 switch in jax.experimental
-    from jax.experimental import enable_x64 as _enable_x64
-except ImportError:  # newer jax promoted it to the public namespace
-    _enable_x64 = jax.enable_x64
+_enable_x64 = jax.enable_x64
 
 import bigdl_tpu.nn as nn
 from bigdl_tpu.utils.rng import RNG

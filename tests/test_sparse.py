@@ -22,10 +22,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-try:  # this jaxlib keeps the scoped x64 switch in jax.experimental
-    from jax.experimental import enable_x64 as _enable_x64
-except ImportError:
-    _enable_x64 = jax.enable_x64
+_enable_x64 = jax.enable_x64
 
 import bigdl_tpu.nn as nn
 import bigdl_tpu.optim as optim

@@ -72,6 +72,12 @@ def test_distri_optimizer_on_8dev_mesh_matches_local():
     for k in p1:
         np.testing.assert_allclose(np.asarray(p1[k]), np.asarray(p2[k]),
                                    rtol=1e-4, atol=1e-5)
+    # 12 iterations cross three epoch boundaries, where the driver
+    # writes the epoch scalar into the step's state: placed anywhere but
+    # where the old one lived (on a mesh: the first device only), it
+    # recompiles the whole step
+    for o in (o1, o2):
+        assert o.last_train_step._compiled._cache_size() == 1
 
 
 def test_zero1_sharded_matches_allreduce():

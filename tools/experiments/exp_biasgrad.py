@@ -11,7 +11,7 @@ import sys, time
 sys.path.insert(0, '/root/repo')
 import jax, jax.numpy as jnp, numpy as np
 from bigdl_tpu.utils.engine import enable_compile_cache
-enable_compile_cache(implicit=True)
+enable_compile_cache()
 
 N, C, H, W = 256, 192, 56, 56
 rng = np.random.default_rng(0)

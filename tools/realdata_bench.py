@@ -154,10 +154,6 @@ def main():
                     help="reuse/keep the TFRecord files here")
     args = ap.parse_args()
 
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()
-
     import bigdl_tpu.nn as nn
     import bigdl_tpu.optim as optim
     from bigdl_tpu.utils.rng import RNG

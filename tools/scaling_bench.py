@@ -56,7 +56,8 @@ def main():
 
     from bigdl_tpu.utils.engine import Engine
 
-    devices = Engine.probe_backend()  # owns the BENCH_BACKEND_TIMEOUT knob
+    Engine.check_singleton(raise_on_conflict=True)
+    devices = jax.devices()  # a backend that does not come up raises
     n = len(devices)
     nproc = jax.process_count()
     if args.sizes:

@@ -42,10 +42,6 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=42)
     args = ap.parse_args(argv)
 
-    from bigdl_tpu.utils.engine import honor_platform_request
-
-    honor_platform_request()
-
     import bigdl_tpu.nn as nn
     import bigdl_tpu.optim as optim
     from bigdl_tpu.dataset.sample import Sample
