@@ -70,9 +70,13 @@ _TRANSCENDENTAL = {
     "cosine", "tan", "tanh_approx", "atan2", "erf", "erf_inv",
 }
 
-# "%12 = stablehlo.add ..." / '%12 = "stablehlo.reduce_window"(...'
+# "%12 = stablehlo.add ..." / '%12 = "stablehlo.reduce_window"(...' /
+# "%12 = call @f(...)" (func.call at a function's top level, printed bare)
 _OP_RE = re.compile(
-    r"^\s*%[\w#]+(?::\d+)?\s*=\s*\"?(?:stablehlo|chlo|mhlo|func)\.([\w]+)\"?")
+    r"^\s*%[\w#]+(?::\d+)?\s*=\s*\"?"
+    r"(?:(?:stablehlo|chlo|mhlo|func)\.|(?=call\s+@))([\w]+)\"?")
+_FUNC_RE = re.compile(r"^\s*func\.func\s+(?:public\s+|private\s+)?@([\w.$\-]+)")
+_CALLEE_RE = re.compile(r"call\s+@([\w.$\-]+)")
 _LOC_REF_RE = re.compile(r"loc\((#loc\d*)\)\s*$")
 _LOC_DEF_RE = re.compile(r"^(#loc\d*)\s*=\s*loc\((.*)\)\s*$")
 _LOC_NAME_RE = re.compile(r'^"([^"]*)"')
@@ -139,12 +143,14 @@ def scope_of(op_name: str) -> Tuple[str, str]:
             continue
         if not frame or _CALL_FRAME_RE.match(frame) or frame == "pjit":
             continue  # jit(...)/pjit function frames, not module scopes
-        if frame in ("checkpoint", "rematted_computation", "remat"):
+        if frame in ("checkpoint", "rematted_computation", "remat",
+                     "closed_call"):
             # jax.checkpoint's recompute-in-backward inserts these as
             # BARE frames (".../transpose(jvp(2))/checkpoint/
-            # rematted_computation/0/fc1/..."): transform structure,
-            # not module scopes — a Remat-wrapped block's ops must fold
-            # onto the block's own tree path
+            # rematted_computation/0/fc1/..."), and a scan body traced
+            # through core.closed_call gets one too (".../while/body/
+            # closed_call/body/0/..."): transform structure, not module
+            # scopes — the ops must fold onto the block's own tree path
             continue
         kept.append(frame)
     return ".".join(kept), ("bwd" if bwd else "fwd")
@@ -338,8 +344,14 @@ def parse_lowered_text(text: str) -> List[OpCost]:
             loc_defs[m.group(1)] = m.group(2)
     locs = _resolve_locs(loc_defs)
 
-    raw: List[Tuple[str, str, str, str]] = []  # opcode, head, sig, locref
+    raw: List[Tuple[str, str, str, str, str]] = []  # + enclosing func
     pending: List[Tuple[str, str]] = []  # (opcode, head) of open region ops
+    func = ""  # the func.func the current line sits in
+    # private function -> (caller func, loc ref) of each of its call
+    # sites: op names inside a private function (a scan body traced
+    # through closed_call, a jitted helper) are relative to it, and the
+    # scopes that lead to it stand on the call
+    call_sites: Dict[str, List[Tuple[str, Optional[str]]]] = {}
 
     def sig_and_loc(line: str) -> Tuple[str, Optional[str]]:
         m = _LOC_REF_RE.search(line)
@@ -350,22 +362,42 @@ def parse_lowered_text(text: str) -> List[OpCost]:
 
     for line in lines:
         stripped = line.rstrip()
+        m = _FUNC_RE.match(stripped)
+        if m is not None:
+            func = m.group(1)
+            continue
         m = _OP_RE.match(stripped)
         if m is not None:
             opcode = m.group(1)
             if "loc(" in stripped and " : " in stripped:
                 sig, ref = sig_and_loc(stripped)
-                raw.append((opcode, stripped, sig, ref))
+                raw.append((opcode, stripped, sig, ref, func))
+                callee = _CALLEE_RE.search(stripped) \
+                    if opcode == "call" else None
+                if callee is not None:
+                    call_sites.setdefault(callee.group(1), []).append(
+                        (func, ref))
             else:
                 pending.append((opcode, stripped))  # region op opens here
         elif pending and stripped.lstrip().startswith("})") \
                 and "loc(" in stripped:
             opcode, head = pending.pop()
             sig, ref = sig_and_loc(stripped)
-            raw.append((opcode, head, sig, ref))
+            raw.append((opcode, head, sig, ref, func))
 
+    def caller_scopes(func: str, depth: int = 0) -> List[str]:
+        """The op-name prefixes that lead into ``func``, one for each
+        time it runs: each call site's name under each of that caller's
+        own prefixes (``main`` runs once, under none)."""
+        if func not in call_sites or depth > 8:
+            return [""]
+        return ["/".join(p for p in (outer, locs.get(ref, "")) if p)
+                for caller, ref in call_sites[func]
+                for outer in caller_scopes(caller, depth + 1)]
+
+    prefixes = {func: caller_scopes(func) for func in call_sites}
     ops: List[OpCost] = []
-    for opcode, head, sig, ref in raw:
+    for opcode, head, sig, ref, func in raw:
         if opcode in ("constant", "return", "func", "call"):
             continue
         _, result_text = _split_signature(sig)
@@ -373,10 +405,14 @@ def parse_lowered_text(text: str) -> List[OpCost]:
         operand_text, _ = _split_signature(sig)
         _, operand_bytes = _type_cost(operand_text)
         name = locs.get(ref, "") if ref else ""
-        path, direction = scope_of(name)
         flops, trans = _instr_flops(opcode, head, sig, out_elems)
-        ops.append(OpCost(path, direction, opcode, flops, trans,
-                          out_bytes + operand_bytes))
+        # a function called from several sites is inlined at each: its
+        # ops cost once a site, under that site's scopes
+        for prefix in prefixes.get(func, ("",)):
+            path, direction = scope_of(
+                f"{prefix}/{name}" if prefix else name)
+            ops.append(OpCost(path, direction, opcode, flops, trans,
+                              out_bytes + operand_bytes))
     return ops
 
 

@@ -71,7 +71,9 @@ def test_sequence_parallel_matches_dense(seq_mesh, strategy, causal):
     q, k, v = _rand_qkv(b=1, h=8, s=64, d=8, seed=2)
     fn = make_sequence_parallel_attention(seq_mesh, strategy=strategy,
                                           causal=causal)
-    out = fn(q, k, v)
+    # under jit, as every caller runs it: an eager shard_map dispatches
+    # every primitive of the ring as its own 8-device program
+    out = jax.jit(fn)(q, k, v)
     out_ref = dot_product_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)

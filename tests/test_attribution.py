@@ -50,6 +50,29 @@ def test_scope_of_unwraps_autodiff_frames():
     assert scope_of("w") == ("", "fwd")
 
 
+def test_private_function_ops_cost_once_per_call_site():
+    """A jitted helper lowers to ONE private function whose op names are
+    relative to it; the scopes stand on each ``call @helper``.  Its ops
+    are attributed to every call site, not to '' or to the first."""
+    @jax.jit
+    def helper(x, w):
+        return jnp.tanh(x @ w)
+
+    def f(x, w):
+        with jax.named_scope("a"):
+            y = helper(x, w)
+        with jax.named_scope("b"):
+            return helper(y, w)
+
+    text = attribution.lowered_text(
+        jax.jit(f).lower(jnp.ones((4, 8)), jnp.ones((8, 8))))
+    assert text.count("call @helper") == 2  # shared, not inlined
+    dots = {op.path: op.flops
+            for op in attribution.parse_lowered_text(text)
+            if op.opcode == "dot_general"}
+    assert dots == {"a": 2 * 4 * 8 * 8, "b": 2 * 4 * 8 * 8}
+
+
 def test_stamp_scope_names_and_off_switch():
     m = _mlp()
     stamp_scope_names(m)

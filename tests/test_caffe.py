@@ -248,6 +248,7 @@ def _roundtrip(model, input_shape, x):
     (the reference round-trip contract, ``CaffePersister.scala:47``)."""
     import jax.numpy as jnp
 
+    from bigdl_tpu.parallel.train_step import EvalStep
     from bigdl_tpu.utils.caffe_persister import save_caffe
 
     proto = tempfile.mktemp(suffix=".prototxt")
@@ -256,8 +257,9 @@ def _roundtrip(model, input_shape, x):
     reloaded, _, _ = CaffeLoader(proto, weights).load()
     reloaded.evaluate()
     model.evaluate()
-    a = np.asarray(model.forward(jnp.asarray(x)))
-    b = np.asarray(reloaded.forward(jnp.asarray(x)))
+    # the compiled inference forward: an eager forward of a zoo model
+    # compiles every layer's op on its own
+    a, b = (np.asarray(EvalStep(m).run(x)) for m in (model, reloaded))
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
     return proto, weights
 

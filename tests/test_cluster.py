@@ -40,8 +40,8 @@ from bigdl_tpu.parallel import cluster
 from bigdl_tpu.utils.config import set_config
 from bigdl_tpu.utils.rng import RNG
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-WORKER = os.path.join(REPO, "tests", "multihost_worker.py")
+from multihost_cluster import (WORKER, assert_same_params, launch_cluster,
+                               run_cluster, wait_all, worker_env)
 
 
 def setup_function(_fn):
@@ -398,43 +398,27 @@ def test_sigterm_interrupts_retry_backoff(tmp_path, monkeypatch):
 
 
 # -- E2E: the distributed fault matrix on live clusters ----------------------
-# the flock-serialized allocator with the recent-port ledger: concurrent
-# test processes (and back-to-back clusters in one test) no longer race
-# each other into the same coordinator port (deflake, ISSUE 20)
-_free_port = cluster._free_port
+#: what every live-cluster run below shares: 8 iterations (2 epochs of
+#: the worker's 64 records), synchronous checkpoints so the last
+#: committed step is pinned, and a watchdog that fires within seconds.
+#: Deadline 6 not 3: under a loaded CI host the first tracing step alone
+#: can stall a worker past 3 s of missed heartbeats and fire a spurious
+#: peer_lost (deflake, ISSUE 20)
+E2E = dict(BIGDL_TEST_ITERS=8, BIGDL_CLUSTER_DEADLINE=6,
+           BIGDL_HEARTBEAT_INTERVAL=0.2, BIGDL_ASYNC_CHECKPOINT=0,
+           BIGDL_RETRY_BACKOFF=0.05)
 
 
-def _worker_env(**extra) -> dict:
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS", "BIGDL_FAULTS")}
-    env["BIGDL_REPO"] = REPO
-    env.update({k: str(v) for k, v in extra.items()})
-    return env
-
-
-def _launch_cluster(nproc: int, **extra) -> list:
-    port = _free_port()
-    return [subprocess.Popen(
-        [sys.executable, WORKER],
-        env=_worker_env(BIGDL_COORDINATOR_ADDRESS=f"127.0.0.1:{port}",
-                        BIGDL_NUM_PROCESSES=nproc, BIGDL_PROCESS_ID=pid,
-                        **extra),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-        for pid in range(nproc)]
-
-
-def _wait_all(procs, timeout: int):
-    outs = []
-    try:
-        for p in procs:
-            stdout, _ = p.communicate(timeout=timeout)
-            outs.append(stdout.decode(errors="replace"))
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
-                p.wait()
-    return [p.returncode for p in procs], outs
+@pytest.fixture(scope="module")
+def uninterrupted_params(tmp_path_factory) -> str:
+    """The uninterrupted 2-process control, run ONCE: every recovery
+    test below compares its final params with this same file (how often
+    a job checkpoints does not move its trajectory)."""
+    d = tmp_path_factory.mktemp("uninterrupted")
+    return run_cluster(d / "un.npz", nproc=2, timeout=120,
+                       BIGDL_TEST_CKPT=str(d / "ckpt_un"),
+                       BIGDL_TEST_CKPT_EVERY=4,
+                       BIGDL_CLUSTER_DIR=str(d / "hb_un"), **E2E)
 
 
 def _events_by_process(tele_dir: str):
@@ -453,12 +437,30 @@ def _events_by_process(tele_dir: str):
     return out
 
 
-def _assert_same_params(path_a: str, path_b: str, tol=1e-6):
-    a, b = np.load(path_a), np.load(path_b)
-    assert set(a.files) == set(b.files) and len(a.files) > 0
-    for k in a.files:
-        np.testing.assert_allclose(a[k], b[k], rtol=tol, atol=tol,
-                                   err_msg=f"param {k} diverged")
+#: the installed jax runtime's last words when its distributed client
+#: ends the process on coordinator loss
+_RUNTIME_FATAL = "JAX distributed service detected fatal errors"
+
+
+def _exited_on_peer_loss(code: int, output: str) -> bool:
+    """A survivor's exit once a peer is lost: through its own watchdog
+    (43), or through the jax distributed runtime — the FIRST watchdog
+    abort takes the jax coordinator down with it, and the other hosts'
+    runtime clients may then terminate their process on coordinator
+    loss before their own watchdog wins the race: SIGABRT, or from the
+    installed jax a plain exit 1, which counts only when the process's
+    output carries the runtime's own line — an uncaught exception in
+    the watchdog or heartbeat code also exits 1, and must fail."""
+    return (code in (cluster.EXIT_PEER_LOST, -signal.SIGABRT)
+            or (code == 1 and _RUNTIME_FATAL in output))
+
+
+#: an interrupted run against the uninterrupted width-2 control: the
+#: same trajectory (width 4 against width 2 measured 3e-8 apart, PR 24)
+SAME = dict(rtol=1e-6, atol=1e-6)
+#: a run that finished at ANOTHER width than it started at: the
+#: cross-width tolerance of tests/test_multihost.py
+CROSS_WIDTH = dict(rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.deadline(240)
@@ -470,25 +472,15 @@ def test_peer_wedge_surviving_hosts_exit_instead_of_hanging(tmp_path):
     instead of hanging until the harness timeout.  The run logs carry
     ``cluster/peer_lost`` and a flight dump."""
     tele = tmp_path / "tele"
-    procs = _launch_cluster(
+    procs = launch_cluster(
         2, BIGDL_TEST_OUT=str(tmp_path / "never.npz"),
-        BIGDL_TEST_ITERS=8, BIGDL_TEST_CKPT=str(tmp_path / "ckpt"),
+        BIGDL_TEST_CKPT=str(tmp_path / "ckpt"),
         BIGDL_TEST_CKPT_EVERY=2, BIGDL_FAULTS="peer_wedge@3:p1",
         BIGDL_CLUSTER_DIR=str(tmp_path / "hb"),
-        # deadline 6 not 3: under a loaded CI host the first tracing
-        # step alone can stall a worker past 3 s of missed heartbeats
-        # and fire a spurious peer_lost (deflake, ISSUE 20)
-        BIGDL_CLUSTER_DEADLINE=6, BIGDL_HEARTBEAT_INTERVAL=0.2,
-        BIGDL_TELEMETRY=str(tele), BIGDL_ASYNC_CHECKPOINT=0,
-        BIGDL_RETRY_BACKOFF=0.05)
-    codes, outs = _wait_all(procs, timeout=120)
-    # the FIRST watchdog abort (43) takes the jax coordinator down with
-    # it, and the other host's distributed-runtime client may then
-    # SIGABRT on coordinator loss before its own watchdog wins the
-    # race — either way it EXITED, which is the property: no hang
-    assert all(c in (cluster.EXIT_PEER_LOST, -signal.SIGABRT)
-               for c in codes), (codes, outs[0][-2000:],
-                                 outs[1][-2000:])
+        BIGDL_TELEMETRY=str(tele), **E2E)
+    codes, outs = wait_all(procs, timeout=120)
+    assert all(_exited_on_peer_loss(c, o) for c, o in zip(codes, outs)), (
+        codes, outs[0][-2000:], outs[1][-2000:])
     assert cluster.EXIT_PEER_LOST in codes, (codes, outs[0][-2000:])
     assert not (tmp_path / "never.npz").exists()
     by_proc = _events_by_process(str(tele))
@@ -498,7 +490,8 @@ def test_peer_wedge_surviving_hosts_exit_instead_of_hanging(tmp_path):
 
 
 @pytest.mark.deadline(360)
-def test_commit_crash_never_yields_mixed_step_restore(tmp_path):
+def test_commit_crash_never_yields_mixed_step_restore(
+        tmp_path, uninterrupted_params):
     """``commit_crash@4:p1``: p1 dies AFTER reaching the step-4 commit
     point but BEFORE its barrier ack, so the manifest stays at step 2
     even though the coordinator's step-4 checkpoint is durable and
@@ -506,18 +499,10 @@ def test_commit_crash_never_yields_mixed_step_restore(tmp_path):
     step-2 checkpoint on every host — model.4 exists on disk, and is
     still structurally invisible — and the finished run must match an
     uninterrupted one."""
-    base = dict(BIGDL_TEST_ITERS=8, BIGDL_TEST_CKPT_EVERY=2,
-                BIGDL_CLUSTER_DEADLINE=6, BIGDL_HEARTBEAT_INTERVAL=0.2,
-                BIGDL_ASYNC_CHECKPOINT=0, BIGDL_RETRY_BACKOFF=0.05)
-    # uninterrupted control
-    un = str(tmp_path / "un.npz")
-    codes, outs = _wait_all(_launch_cluster(
-        2, BIGDL_TEST_OUT=un, BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"),
-        BIGDL_CLUSTER_DIR=str(tmp_path / "hb_un"), **base), timeout=120)
-    assert codes == [0, 0], (codes, outs[0][-2000:], outs[1][-2000:])
+    base = dict(BIGDL_TEST_CKPT_EVERY=2, **E2E)
     # incarnation 0: dies in the commit window
     ckpt = str(tmp_path / "ckpt")
-    codes, outs = _wait_all(_launch_cluster(
+    codes, outs = wait_all(launch_cluster(
         2, BIGDL_TEST_OUT=str(tmp_path / "crashed.npz"),
         BIGDL_TEST_CKPT=ckpt, BIGDL_CLUSTER_DIR=str(tmp_path / "hb"),
         BIGDL_FAULTS="commit_crash@4:p1", **base), timeout=120)
@@ -532,7 +517,7 @@ def test_commit_crash_never_yields_mixed_step_restore(tmp_path):
     # incarnation 1: fresh cluster, no faults, same dirs
     tele = tmp_path / "tele"
     out = str(tmp_path / "resumed.npz")
-    codes, outs = _wait_all(_launch_cluster(
+    codes, outs = wait_all(launch_cluster(
         2, BIGDL_TEST_OUT=out, BIGDL_TEST_CKPT=ckpt,
         BIGDL_CLUSTER_DIR=str(tmp_path / "hb"),
         BIGDL_TELEMETRY=str(tele), **base), timeout=120)
@@ -546,28 +531,22 @@ def test_commit_crash_never_yields_mixed_step_restore(tmp_path):
     # NO MIXED STEPS: every host resumed at the manifest step, not at
     # the newer-but-uncertified one
     assert sources == {0: 2, 1: 2}, sources
-    _assert_same_params(out, un)
+    assert_same_params(out, uninterrupted_params, **SAME)
 
 
 @pytest.mark.deadline(420)
-def test_supervised_peer_kill_restart_matches_uninterrupted(tmp_path):
+def test_supervised_peer_kill_restart_matches_uninterrupted(
+        tmp_path, uninterrupted_params):
     """The ISSUE 7 acceptance path, on the live 4-process cluster:
     SIGKILL one of 4 workers mid-epoch under the supervisor.  The
     surviving hosts' watchdogs fire within the deadline (distinct exit
     code — no indefinite collective hang), the supervisor restarts the
     full cluster, auto-resume lands on the cluster-consistent step-4
     checkpoint, and the final params equal the uninterrupted run's."""
-    base = dict(BIGDL_TEST_ITERS=8, BIGDL_TEST_CKPT_EVERY=4,
-                BIGDL_CLUSTER_DEADLINE=6, BIGDL_HEARTBEAT_INTERVAL=0.2,
-                BIGDL_ASYNC_CHECKPOINT=0, BIGDL_RETRY_BACKOFF=0.05)
-    un = str(tmp_path / "un.npz")
-    codes, outs = _wait_all(_launch_cluster(
-        4, BIGDL_TEST_OUT=un, BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"),
-        BIGDL_CLUSTER_DIR=str(tmp_path / "hb_un"), **base), timeout=180)
-    assert codes == [0, 0, 0, 0], (codes, outs[0][-2000:])
+    base = dict(BIGDL_TEST_CKPT_EVERY=4, **E2E)
     for attempt in ("first", "last"):
         out = str(tmp_path / f"supervised_{attempt}.npz")
-        env = _worker_env(BIGDL_TEST_OUT=out,
+        env = worker_env(BIGDL_TEST_OUT=out,
                           BIGDL_TEST_CKPT=str(tmp_path /
                                               f"ckpt_{attempt}"),
                           BIGDL_FAULTS="peer_kill@6:p2", **base)
@@ -590,18 +569,19 @@ def test_supervised_peer_kill_restart_matches_uninterrupted(tmp_path):
         assert rc == 0, sup.exit_history
         assert sup.restarts == 1, sup.exit_history
         assert -signal.SIGKILL in first, first  # the injected kill
-        # every survivor EXITED (no hang): via its own watchdog (43)
-        # or SIGABRTed by the jax runtime when the first watchdog
-        # abort took the coordinator down — and at least one abort
-        # came from the watchdog itself, within its settle window
-        survivors = [c for c in first if c != -signal.SIGKILL]
-        assert all(c in (cluster.EXIT_PEER_LOST, -signal.SIGABRT)
-                   for c in survivors), first
+        # every survivor EXITED (no hang), and at least one abort came
+        # from the watchdog itself, within its settle window
+        logs = tmp_path / f"logs_{attempt}"
+        survivors = [
+            (c, (logs / f"inc0.p{i}.log").read_text(errors="replace"))
+            for i, c in enumerate(first) if c != -signal.SIGKILL]
+        assert all(_exited_on_peer_loss(c, o) for c, o in survivors), (
+            first, [o[-1000:] for _c, o in survivors])
         assert cluster.EXIT_PEER_LOST in first, first
         assert sup.exit_history[1] == [0, 0, 0, 0], sup.exit_history
         assert os.path.exists(out), \
             "restarted cluster must publish params"
-        _assert_same_params(out, un)
+        assert_same_params(out, uninterrupted_params, **SAME)
         break
 
 
@@ -723,7 +703,8 @@ def test_supervisor_min_n_validation():
 
 
 @pytest.mark.deadline(420)
-def test_supervised_peer_kill_min_n_recovers_at_reduced_width(tmp_path):
+def test_supervised_peer_kill_min_n_recovers_at_reduced_width(
+        tmp_path, uninterrupted_params):
     """The ISSUE 12 acceptance path: on the live 4-process cluster a
     kept ``peer_kill@6:p2`` fault models a host that NEVER comes back
     (it fires in every full-width incarnation).  With ``--min-n 2`` the
@@ -732,17 +713,10 @@ def test_supervised_peer_kill_min_n_recovers_at_reduced_width(tmp_path):
     BTPU checkpoint (topology-portable — announced as cluster/reshard),
     and the finished run's params equal an uninterrupted run's, with
     zero manual intervention."""
-    base = dict(BIGDL_TEST_ITERS=8, BIGDL_TEST_CKPT_EVERY=4,
-                BIGDL_CLUSTER_DEADLINE=6, BIGDL_HEARTBEAT_INTERVAL=0.2,
-                BIGDL_ASYNC_CHECKPOINT=0, BIGDL_RETRY_BACKOFF=0.05)
-    un = str(tmp_path / "un.npz")
-    codes, outs = _wait_all(_launch_cluster(
-        2, BIGDL_TEST_OUT=un, BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"),
-        BIGDL_CLUSTER_DIR=str(tmp_path / "hb_un"), **base), timeout=120)
-    assert codes == [0, 0], (codes, outs[0][-2000:], outs[1][-2000:])
+    base = dict(BIGDL_TEST_CKPT_EVERY=4, **E2E)
     tele = tmp_path / "tele"
     out = str(tmp_path / "degraded.npz")
-    env = _worker_env(BIGDL_TEST_OUT=out,
+    env = worker_env(BIGDL_TEST_OUT=out,
                       BIGDL_TEST_CKPT=str(tmp_path / "ckpt"),
                       BIGDL_TELEMETRY=str(tele),
                       BIGDL_FAULTS="peer_kill@6:p2", **base)
@@ -770,7 +744,7 @@ def test_supervised_peer_kill_min_n_recovers_at_reduced_width(tmp_path):
     # mixed-width trajectory (iters 1-4 at width 4, 5-8 at width 2) vs
     # the width-2 uninterrupted control: the cross-width tolerance the
     # process-count-invariance tests (tests/test_multihost.py) pin
-    _assert_same_params(out, un, tol=2e-4)
+    assert_same_params(out, uninterrupted_params, **CROSS_WIDTH)
     # the width-2 workers announced the reshard on restore
     by_proc = _events_by_process(str(tele))
     marks = [e for events in by_proc.values() for e in events

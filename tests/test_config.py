@@ -75,7 +75,7 @@ def test_bench_infer_legs_run_and_account():
     import bench
 
     for quantized in (False, True):
-        row = bench.run_infer_config("vgg16_cifar10", batch=8, iters=1,
+        row = bench.run_infer_config("vgg16_cifar10", batch=2, iters=1,
                                      quantized=quantized)
         assert row["img_s"] > 0, row
         # cost accounting present (cpu has no peak, so no utilization)

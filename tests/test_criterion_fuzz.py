@@ -4,6 +4,8 @@ settings (the reduction/weighting algebra is where criterion
 implementations quietly diverge; the optimizer fuzz caught exactly such
 a divergence in SGD dampening)."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 import torch
@@ -16,6 +18,15 @@ def _cmp(ours_loss, ours_grad, t_loss, t_grad, tag, rtol=1e-4, atol=1e-5):
                                rtol=rtol, atol=atol, err_msg=f"{tag} loss")
     np.testing.assert_allclose(np.asarray(ours_grad), t_grad.numpy(),
                                rtol=rtol, atol=atol, err_msg=f"{tag} grad")
+
+
+def _loss_and_grad(crit, x, target):
+    """What ``crit.forward`` and ``crit.backward`` compute (the loss and
+    its gradient through ``update_output``), as ONE compiled program per
+    sampled shape: run eagerly, every primitive of both is a compile of
+    its own at every new shape."""
+    return jax.jit(jax.value_and_grad(
+        lambda xx: jnp.sum(crit.update_output(xx, target))))(x)
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -31,8 +42,7 @@ def test_classnll_fuzz(seed):
         y = rng.randint(0, c, n)
 
         crit = nn.ClassNLLCriterion(weights=w, size_average=size_avg)
-        loss = crit.forward(logp, y)
-        grad = crit.backward(logp, y)
+        loss, grad = _loss_and_grad(crit, logp, y)
 
         tx = torch.tensor(logp, requires_grad=True)
         tcrit = torch.nn.NLLLoss(
@@ -75,8 +85,7 @@ def test_elementwise_criterion_fuzz(seed):
                       torch.nn.KLDivLoss(reduction=red), logq, pr))
 
         for crit, tcrit, xi, ti in cases:
-            loss = crit.forward(xi, ti)
-            grad = crit.backward(xi, ti)
+            loss, grad = _loss_and_grad(crit, xi, ti)
             tx = torch.tensor(xi, requires_grad=True)
             tl = tcrit(tx, torch.tensor(ti))
             tl.backward()

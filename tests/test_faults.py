@@ -11,8 +11,6 @@ verified-good checkpoint."""
 import json
 import os
 import signal
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -26,8 +24,7 @@ from bigdl_tpu.optim.trigger import Trigger
 from bigdl_tpu.telemetry.health import HealthError
 from bigdl_tpu.utils.config import set_config
 from bigdl_tpu.utils.rng import RNG
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from multihost_cluster import assert_same_params, launch_cluster, wait_all
 
 
 def setup_function(_fn):
@@ -314,38 +311,31 @@ def test_kill_worker_is_ungraceful_and_restart_resumes(tmp_path,
     from the last TRIGGERED checkpoint and matches the uninterrupted
     run.  (Subprocess test: SIGKILL in-process would take pytest with
     it.)  Synchronous checkpointing pins the last committed step."""
-    worker = os.path.join(REPO, "tests", "multihost_worker.py")
     ckpt = tmp_path / "ckpt"
     ckpt.mkdir()
 
-    def run_single(tag, **extra):
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("XLA_FLAGS", "JAX_PLATFORMS", "BIGDL_FAULTS")}
-        env.update(BIGDL_REPO=REPO, BIGDL_TEST_OUT=str(tmp_path / tag),
-                   BIGDL_TEST_ITERS="6", BIGDL_ASYNC_CHECKPOINT="0",
-                   **{k: str(v) for k, v in extra.items()})
-        return subprocess.run([sys.executable, worker], env=env,
-                              capture_output=True, timeout=420)
+    def launch(tag, **extra):
+        return launch_cluster(1, BIGDL_TEST_OUT=str(tmp_path / tag),
+                              BIGDL_TEST_ITERS=6, BIGDL_ASYNC_CHECKPOINT=0,
+                              BIGDL_TEST_CKPT_EVERY=2, **extra)
 
-    r = run_single("clean.npz", BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"),
-                   BIGDL_TEST_CKPT_EVERY=2)
-    assert r.returncode == 0, r.stdout[-2000:]
-
-    r = run_single("killed.npz", BIGDL_TEST_CKPT=str(ckpt),
-                   BIGDL_TEST_CKPT_EVERY=2, BIGDL_FAULTS="kill_worker@4")
-    assert r.returncode == -signal.SIGKILL, (r.returncode, r.stdout[-2000:])
+    # the uninterrupted and the killed job do not depend on each other:
+    # started together, both waited for
+    clean = launch("clean.npz", BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"))
+    killed = launch("killed.npz", BIGDL_TEST_CKPT=str(ckpt),
+                    BIGDL_FAULTS="kill_worker@4")
+    (code,), (text,) = wait_all(clean)
+    assert code == 0, text[-2000:]
+    (code,), (text,) = wait_all(killed)
+    assert code == -signal.SIGKILL, (code, text[-2000:])
     assert not (tmp_path / "killed.npz").exists()
     assert any(f.startswith("model.2") for f in os.listdir(ckpt))
 
-    r = run_single("resumed.npz", BIGDL_TEST_CKPT=str(ckpt),
-                   BIGDL_TEST_CKPT_EVERY=2)
-    assert r.returncode == 0, r.stdout[-2000:]
-    a = np.load(tmp_path / "clean.npz")
-    b = np.load(tmp_path / "resumed.npz")
-    assert set(a.files) == set(b.files) and len(a.files) > 0
-    for k in a.files:
-        np.testing.assert_allclose(a[k], b[k], rtol=1e-6, atol=1e-7,
-                                   err_msg=f"param {k} diverged")
+    (code,), (text,) = wait_all(launch("resumed.npz",
+                                       BIGDL_TEST_CKPT=str(ckpt)))
+    assert code == 0, text[-2000:]
+    assert_same_params(tmp_path / "clean.npz", tmp_path / "resumed.npz",
+                       rtol=1e-6, atol=1e-7)
 
 
 # -- torn checkpoints: verify, quarantine, fall back -------------------------

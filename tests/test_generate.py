@@ -7,6 +7,7 @@ forward argmax, token for token), sampled-decode determinism keyed on
 same-shape weight rollout, and the live streamed-HTTP e2e with the
 retrace detector armed and a graceful drain."""
 
+import functools
 import json
 import os
 import re
@@ -58,17 +59,30 @@ def gen_executor():
     return model, _executor(model)
 
 
+_REF_LEN = 32  # the cache bucket every test decodes in
+
+
+@functools.lru_cache(maxsize=None)
+def _ref_step(model):
+    from bigdl_tpu.parallel.train_step import EvalStep
+
+    return EvalStep(model)
+
+
 def _full_forward_greedy(model, prompt, n):
     """Reference: re-run the FULL context each step, argmax the last
-    position — the numerics the KV cache must reproduce."""
-    seq = list(np.asarray(prompt).reshape(-1))
-    out_tokens = []
-    for _ in range(n):
-        out = np.asarray(model.forward(np.asarray([seq], np.int32)))
-        tok = int(np.argmax(out[0, len(seq) - 1]))
-        out_tokens.append(tok)
-        seq.append(tok)
-    return out_tokens
+    position — the numerics the KV cache must reproduce.  The context
+    is right-padded to one fixed length (causal attention: what stands
+    after a position cannot reach it), so the whole reference is ONE
+    compiled program per model and not one per sequence length."""
+    prompt = np.asarray(prompt).reshape(-1)
+    assert len(prompt) + n <= _REF_LEN
+    seq = np.zeros((1, _REF_LEN), np.int32)
+    seq[0, :len(prompt)] = prompt
+    for at in range(len(prompt), len(prompt) + n):
+        out = np.asarray(_ref_step(model).run(seq))
+        seq[0, at] = np.argmax(out[0, at - 1])
+    return seq[0, len(prompt):len(prompt) + n].tolist()
 
 
 # -- cache buckets + stacked store -------------------------------------------

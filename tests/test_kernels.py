@@ -36,7 +36,13 @@ def _both_legs(fn, x, seed=1, rtol=1e-5, atol=1e-6, monkeypatch=None):
     outs = {}
     for mode in ("xla", "pallas"):
         monkeypatch.setenv("BIGDL_KERNELS", mode)
-        y, vjp = jax.vjp(fn, x)
+        dispatch.clear_decisions()
+        # compiled (run eagerly, a Pallas kernel in interpret mode
+        # dispatches every primitive of its body as its own program),
+        # through a function of this leg's own so that no trace of the
+        # other leg can be found in a cache
+        y, vjp = jax.vjp(jax.jit(lambda a: fn(a)), x)
+        assert dispatch.decisions(), f"{mode} leg was not traced"
         outs[mode] = (y, vjp)
     y1, vjp1 = outs["xla"]
     y2, vjp2 = outs["pallas"]
@@ -335,7 +341,7 @@ def test_xla_mode_bypasses_pallas_everywhere(monkeypatch):
     x = jnp.asarray(_rng(12).randn(2, 4, 9, 9).astype(np.float32))
     for layer in layers:
         layer.evaluate()
-        y, vjp = jax.vjp(layer.update_output, x)
+        y, vjp = jax.vjp(jax.jit(layer.update_output), x)
         vjp(jnp.ones_like(y))
     recs = dispatch.decisions()
     assert recs, "kernel-library layers must record dispatch decisions"

@@ -424,10 +424,14 @@ def test_sync_path_byte_identical_when_local_mode_off():
         step.aot_scan(x, y, jax.random.key(0), 3)
         return step
 
-    plain = compile_sync()
-    set_config(BigDLConfig(local_sync_h=4, local_sync_stale=1,
-                           local_sync_grace=0.25))
-    knobbed = compile_sync()
+    # both from ONE call site: the program text carries the source
+    # lines of its callers, and two call sites differ in nothing else
+    steps = []
+    for knobs in (None, BigDLConfig(local_sync_h=4, local_sync_stale=1,
+                                    local_sync_grace=0.25)):
+        set_config(knobs)
+        steps.append(compile_sync())
+    plain, knobbed = steps
     a = comms_facts(plain._scan_cache[1], mesh=mesh)
     b = comms_facts(knobbed._scan_cache[1], mesh=mesh)
     assert (a["bytes"], a["count"]) == (b["bytes"], b["count"])

@@ -9,6 +9,7 @@ import pytest
 import bigdl_tpu.nn as nn
 from bigdl_tpu import models
 from bigdl_tpu.nn.module import functional_call, state_dict
+from bigdl_tpu.parallel.train_step import EvalStep
 
 
 def _check_train_step(model, x_shape, n_classes, rtol_loss=0.6):
@@ -39,19 +40,19 @@ def test_lenet5():
 
 def test_vgg_cifar():
     m = models.build_vgg_for_cifar10(10)
-    out = m.evaluate().forward(jnp.ones((2, 3, 32, 32)))
+    out = EvalStep(m).run(jnp.ones((2, 3, 32, 32)))
     assert out.shape == (2, 10)
 
 
 def test_inception_v1():
     m = models.build_inception_v1(1000)
-    out = m.evaluate().forward(jnp.ones((2, 3, 224, 224)))
+    out = EvalStep(m).run(jnp.ones((2, 3, 224, 224)))
     assert out.shape == (2, 1000)
 
 
 def test_inception_v1_aux():
     m = models.build_inception_v1(100, with_aux=True)
-    outs = m.evaluate().forward(jnp.ones((1, 3, 224, 224)))
+    outs = EvalStep(m).run(jnp.ones((1, 3, 224, 224)))
     assert isinstance(outs, list) and len(outs) == 3
     for o in outs:
         assert o.shape == (1, 100)
@@ -59,14 +60,14 @@ def test_inception_v1_aux():
 
 def test_inception_v2():
     m = models.build_inception_v2(1000)
-    out = m.evaluate().forward(jnp.ones((1, 3, 224, 224)))
+    out = EvalStep(m).run(jnp.ones((1, 3, 224, 224)))
     assert out.shape == (1, 1000)
 
 
 @pytest.mark.parametrize("depth,block_out", [(18, 512), (50, 2048)])
 def test_resnet_imagenet(depth, block_out):
     m = models.build_resnet(depth, 1000)
-    out = m.evaluate().forward(jnp.ones((1, 3, 224, 224)))
+    out = EvalStep(m).run(jnp.ones((1, 3, 224, 224)))
     assert out.shape == (1, 1000)
 
 

@@ -10,105 +10,42 @@ equivalence discipline (SURVEY §4) applied across a process boundary.
 """
 
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
-# the flock-serialized allocator with the recent-port ledger: two tests
-# (or two pytest workers) grabbing ports back-to-back can otherwise race
-# the same ephemeral port into both clusters (deflake, ISSUE 20)
-from bigdl_tpu.parallel.cluster import _free_port
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-WORKER = os.path.join(REPO, "tests", "multihost_worker.py")
+from multihost_cluster import assert_same_params, run_cluster, start_cluster
 
 
-def _worker_env(**extra) -> dict:
-    # the worker sets its own XLA_FLAGS/platform before importing jax
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env["BIGDL_REPO"] = REPO
-    env.update({k: str(v) for k, v in extra.items()})
-    return env
+#: cluster against single process: the summation order of the
+#: all-reduce differs
+TOL = dict(rtol=2e-4, atol=1e-5)
 
 
-def _run_cluster(tmp_path, tag: str, nproc: int = 2, expect_out: bool = True,
-                 timeout: int = 420, codes=None, **extra) -> str:
-    """Run the worker on an ``nproc``-process cluster; return the
-    coordinator's saved-params path.  ``expect_out=False`` for runs that
-    legitimately end without publishing params (graceful preemption).
-    ``codes`` maps process index -> expected returncode for runs where a
-    nonzero exit IS the asserted behavior (a shed straggler exits 43).
-    The generous default ``timeout`` is deliberate: these tests spin
-    real jax.distributed clusters and must stay green on loaded CI
-    machines (deflake budget, ISSUE 5)."""
-    port = _free_port()
-    out = str(tmp_path / f"{tag}.npz")
-    procs = [
-        subprocess.Popen(
-            [sys.executable, WORKER],
-            env=_worker_env(BIGDL_COORDINATOR_ADDRESS=f"127.0.0.1:{port}",
-                            BIGDL_NUM_PROCESSES=nproc, BIGDL_PROCESS_ID=pid,
-                            BIGDL_TEST_OUT=out, **extra),
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-        for pid in range(nproc)
-    ]
-    outputs = []
-    try:
-        for p in procs:
-            stdout, _ = p.communicate(timeout=timeout)
-            outputs.append(stdout.decode(errors="replace"))
-    finally:
-        for p in procs:  # a hung collective must not leak live workers
-            if p.poll() is None:
-                p.kill()
-                p.wait()
-    for pid, (p, text) in enumerate(zip(procs, outputs)):
-        want = (codes or {}).get(pid, 0)
-        assert p.returncode == want, (
-            f"cluster worker p{pid} exited {p.returncode} "
-            f"(expected {want}):\n{text[-4000:]}")
-    if expect_out:
-        assert os.path.exists(out), "coordinator did not write params"
-    return out
-
-
-def _run_single(tmp_path, tag: str, **extra) -> str:
-    out = str(tmp_path / f"{tag}.npz")
-    r = subprocess.run([sys.executable, WORKER],
-                       env=_worker_env(BIGDL_TEST_OUT=out, **extra),
-                       capture_output=True, timeout=420)
-    text = r.stdout.decode(errors="replace") + r.stderr.decode(errors="replace")
-    assert r.returncode == 0, f"single-process worker failed:\n{text[-4000:]}"
-    return out
-
-
-def _assert_same_params(path_a: str, path_b: str):
-    a, b = np.load(path_a), np.load(path_b)
-    assert set(a.files) == set(b.files) and len(a.files) > 0
-    for k in a.files:
-        np.testing.assert_allclose(a[k], b[k], rtol=2e-4, atol=1e-5,
-                                   err_msg=f"param {k} diverged")
+@pytest.fixture(scope="module")
+def single_process_params(tmp_path_factory) -> str:
+    """The single-process control with no extra setting, run ONCE: four
+    cluster tests compare their weights with this same file."""
+    return run_cluster(tmp_path_factory.mktemp("control") / "sp.npz",
+                       nproc=1)
 
 
 @pytest.mark.deadline(240)
-def test_two_process_training_matches_single_process(tmp_path):
-    mp = _run_cluster(tmp_path, "mp")
-    sp = _run_single(tmp_path, "sp")
-    _assert_same_params(mp, sp)
+def test_two_process_training_matches_single_process(
+        tmp_path, single_process_params):
+    mp = run_cluster(tmp_path / "mp.npz")
+    assert_same_params(mp, single_process_params, **TOL)
 
 
 @pytest.mark.deadline(300)
-def test_four_process_training_matches_single_process(tmp_path):
+def test_four_process_training_matches_single_process(
+        tmp_path, single_process_params):
     """Scale the control-plane test to 4 processes (4 x 2 virtual devices
     = an 8-device global mesh): the trajectory must still match the
     single process — the multi-host path's behavior is process-count
     invariant, the property pod-scale training rests on."""
-    mp = _run_cluster(tmp_path, "mp4", nproc=4)
-    sp = _run_single(tmp_path, "sp4")
-    _assert_same_params(mp, sp)
+    mp = run_cluster(tmp_path / "mp4.npz", nproc=4)
+    assert_same_params(mp, single_process_params, **TOL)
 
 
 @pytest.mark.deadline(240)
@@ -119,28 +56,28 @@ def test_two_process_sparse_sync_matches_dense_single_process(tmp_path):
     forced DENSE (``BIGDL_SPARSE=off``) — cross-process sync exactness
     and sparse-vs-dense numerics in one trajectory, duplicate indices
     and the padding index included."""
-    mp = _run_cluster(tmp_path, "mp_sparse", BIGDL_TEST_SPARSE=1)
-    sp = _run_single(tmp_path, "sp_sparse", BIGDL_TEST_SPARSE=1,
-                     BIGDL_SPARSE="off")
-    _assert_same_params(mp, sp)
+    mp = start_cluster(tmp_path / "mp_sparse.npz", BIGDL_TEST_SPARSE=1)
+    sp = start_cluster(tmp_path / "sp_sparse.npz", nproc=1,
+                       BIGDL_TEST_SPARSE=1, BIGDL_SPARSE="off")
+    assert_same_params(mp(), sp(), **TOL)
 
 
 @pytest.mark.deadline(240)
-def test_two_process_zero1_matches_single_process(tmp_path):
+def test_two_process_zero1_matches_single_process(
+        tmp_path, single_process_params):
     """ZeRO-1 optimizer-state sharding across the process boundary."""
-    mp = _run_cluster(tmp_path, "mp_z1", BIGDL_TEST_ZERO1=1)
-    sp = _run_single(tmp_path, "sp_z1")
-    _assert_same_params(mp, sp)
+    mp = run_cluster(tmp_path / "mp_z1.npz", BIGDL_TEST_ZERO1=1)
+    assert_same_params(mp, single_process_params, **TOL)
 
 
 @pytest.mark.deadline(240)
-def test_two_process_fsdp_matches_single_process(tmp_path):
+def test_two_process_fsdp_matches_single_process(
+        tmp_path, single_process_params):
     """ZeRO-3: the PARAMETERS shard across the process boundary — no
     process holds a whole replica — and the trajectory still equals the
     single-process run (gather_replicated reassembles for the save)."""
-    mp = _run_cluster(tmp_path, "mp_fsdp", BIGDL_TEST_FSDP=1)
-    sp = _run_single(tmp_path, "sp_fsdp")
-    _assert_same_params(mp, sp)
+    mp = run_cluster(tmp_path / "mp_fsdp.npz", BIGDL_TEST_FSDP=1)
+    assert_same_params(mp, single_process_params, **TOL)
 
 
 @pytest.mark.deadline(240)
@@ -149,7 +86,7 @@ def test_two_process_checkpoint_single_writer(tmp_path):
     gathers but only the coordinator writes files."""
     ckpt = tmp_path / "ckpt"
     ckpt.mkdir()
-    _run_cluster(tmp_path, "mp_ck", BIGDL_TEST_CKPT=str(ckpt))
+    run_cluster(tmp_path / "mp_ck.npz", BIGDL_TEST_CKPT=str(ckpt))
     files = sorted(os.listdir(ckpt))
     assert any(f.startswith("model.") for f in files), files
     assert any(f.startswith("optimMethod.") for f in files), files
@@ -160,7 +97,7 @@ def test_two_process_batch_feed_non_dp_layouts(tmp_path):
     """shard_local_batch must scale the global batch by how far the DATA
     axis spans processes, not by the raw process count (a multi-host
     model-parallel mesh feeds the full batch from every process)."""
-    _run_cluster(tmp_path, "mp_scale", BIGDL_TEST_PROBE_SCALE=1)
+    run_cluster(tmp_path / "mp_scale.npz", BIGDL_TEST_PROBE_SCALE=1)
 
 
 def test_distributed_dataset_shards_partition():
@@ -207,18 +144,20 @@ def test_two_process_preempt_resume_matches_uninterrupted(tmp_path):
     # 8 iterations x global batch 16 over 64 records = 2 epochs; epoch 2
     # is SHUFFLED (deterministically by (seed, epoch)) so the resume
     # must reproduce the mid-epoch order, not just a fresh epoch
-    un = _run_cluster(tmp_path, "preempt_un",
-                      BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"), **base)
-    pre = _run_cluster(tmp_path, "preempt_pre", expect_out=False,
-                       BIGDL_TEST_CKPT=str(ckpt),
-                       BIGDL_FAULTS="preempt@6", **base)
+    # neither run depends on the other: started together, both waited for
+    un = start_cluster(tmp_path / "preempt_un.npz",
+                       BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"), **base)
+    pre = start_cluster(tmp_path / "preempt_pre.npz",
+                        BIGDL_TEST_CKPT=str(ckpt),
+                        BIGDL_FAULTS="preempt@6", **base)
+    un, pre = un(), pre(expect_out=False)
     assert not os.path.exists(pre), "preempted run must not publish params"
     # final checkpoint landed at the preempted iteration
     assert any(f.startswith("model.6") for f in os.listdir(ckpt)), \
         sorted(os.listdir(ckpt))
-    resumed = _run_cluster(tmp_path, "preempt_res",
-                           BIGDL_TEST_CKPT=str(ckpt), **base)
-    _assert_same_params(resumed, un)
+    resumed = run_cluster(tmp_path / "preempt_res.npz",
+                          BIGDL_TEST_CKPT=str(ckpt), **base)
+    assert_same_params(resumed, un, **TOL)
 
 
 @pytest.mark.deadline(420)
@@ -241,11 +180,11 @@ def test_two_process_fleet_observability_blames_slow_host(tmp_path):
       collective waiting on the straggler."""
     tele = tmp_path / "tele"
     tele.mkdir()
-    _run_cluster(tmp_path, "fleet",
-                 BIGDL_TEST_FLEET=1, BIGDL_TEST_ITERS=10,
-                 BIGDL_FAULTS="straggle@1:p1:250",
-                 BIGDL_TELEMETRY=str(tele), BIGDL_METRICS_PORT=0,
-                 BIGDL_FLEET_INTERVAL="0.3")
+    run_cluster(tmp_path / "fleet.npz",
+                BIGDL_TEST_FLEET=1, BIGDL_TEST_ITERS=10,
+                BIGDL_FAULTS="straggle@1:p1:250",
+                BIGDL_TELEMETRY=str(tele), BIGDL_METRICS_PORT=0,
+                BIGDL_FLEET_INTERVAL="0.3")
     import glob
 
     from bigdl_tpu.telemetry import schema
@@ -318,14 +257,13 @@ def test_two_process_local_sgd_sheds_straggler(tmp_path):
                 BIGDL_LOCAL_SYNC_H=4, BIGDL_LOCAL_SYNC_STALE=2,
                 BIGDL_LOCAL_SYNC_GRACE="0.5",
                 BIGDL_HEARTBEAT_INTERVAL="0.2")
-    healthy = _run_cluster(
-        tmp_path, "shed_healthy",
+    healthy = run_cluster(
+        tmp_path / "shed_healthy.npz",
         BIGDL_CLUSTER_DIR=str(tmp_path / "cluster_healthy"), **base)
-    out = _run_cluster(
-        tmp_path, "shed", codes={1: 43},
-        BIGDL_FAULTS="straggle@4:p1:250",
-        BIGDL_CLUSTER_DIR=str(cluster),
-        BIGDL_TELEMETRY=str(tele), **base)
+    out = run_cluster(tmp_path / "shed.npz", codes={1: 43},
+                      BIGDL_FAULTS="straggle@4:p1:250",
+                      BIGDL_CLUSTER_DIR=str(cluster),
+                      BIGDL_TELEMETRY=str(tele), **base)
 
     def dataset_nll(path):
         # the worker's exact data (rng order matters) pushed through its
@@ -405,11 +343,13 @@ def test_two_process_sharded_validation_matches_full(tmp_path):
     collectively (optim/DistriValidator.scala:35 re-scope): the cluster's
     merged score must equal the single process evaluating the FULL set,
     and the trained weights must stay equivalent."""
-    mp = _run_cluster(tmp_path, "mp_val", BIGDL_TEST_SHARDED_VAL=1)
-    sp = _run_single(tmp_path, "sp_val", BIGDL_TEST_SHARDED_VAL=1)
+    mp = start_cluster(tmp_path / "mp_val.npz", BIGDL_TEST_SHARDED_VAL=1)
+    sp = start_cluster(tmp_path / "sp_val.npz", nproc=1,
+                       BIGDL_TEST_SHARDED_VAL=1)
+    mp, sp = mp(), sp()
     a, b = np.load(mp), np.load(sp)
     np.testing.assert_allclose(a["__score"], b["__score"], rtol=1e-6)
-    _assert_same_params(mp, sp)
+    assert_same_params(mp, sp, **TOL)
 
 
 @pytest.mark.deadline(600)
@@ -427,19 +367,19 @@ def test_four_process_preempt_resume_on_two_matches_uninterrupted(tmp_path):
     ckpt = tmp_path / "ckpt"
     ckpt.mkdir()
     base = dict(BIGDL_TEST_ITERS=8, BIGDL_TEST_CKPT_EVERY=4)
-    un = _run_cluster(tmp_path, "el_un", nproc=4,
-                      BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"), **base)
-    pre = _run_cluster(tmp_path, "el_pre", nproc=4, expect_out=False,
-                       BIGDL_TEST_CKPT=str(ckpt),
-                       BIGDL_FAULTS="preempt@6", **base)
+    un = run_cluster(tmp_path / "el_un.npz", nproc=4,
+                     BIGDL_TEST_CKPT=str(tmp_path / "ckpt_un"), **base)
+    pre = run_cluster(tmp_path / "el_pre.npz", nproc=4, expect_out=False,
+                      BIGDL_TEST_CKPT=str(ckpt),
+                      BIGDL_FAULTS="preempt@6", **base)
     assert not os.path.exists(pre), "preempted run must not publish params"
     assert any(f.startswith("model.6") for f in os.listdir(ckpt)), \
         sorted(os.listdir(ckpt))
     tele = tmp_path / "tele_el"
-    resumed = _run_cluster(tmp_path, "el_res", nproc=2,
-                           BIGDL_TEST_CKPT=str(ckpt),
-                           BIGDL_TELEMETRY=str(tele), **base)
-    _assert_same_params(resumed, un)
+    resumed = run_cluster(tmp_path / "el_res.npz", nproc=2,
+                          BIGDL_TEST_CKPT=str(ckpt),
+                          BIGDL_TELEMETRY=str(tele), **base)
+    assert_same_params(resumed, un, **TOL)
     # both width-2 workers restored the width-4 checkpoint and said so
     from bigdl_tpu.telemetry.schema import read_events
 

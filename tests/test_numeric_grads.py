@@ -30,7 +30,10 @@ def _layer_fn(layer):
     def fn(x):
         return layer.update_output(x)
 
-    return fn
+    # compiled: check_grads evaluates fn and its VJP several times, and
+    # run eagerly a Pallas kernel in interpret mode dispatches every
+    # primitive of its body as a program of its own, each time
+    return jax.jit(fn)
 
 
 CASES = [

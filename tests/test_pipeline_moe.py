@@ -42,7 +42,7 @@ def test_pipeline_matches_sequential(n_micro):
     stacked = _stacked_blocks(s, d)
     x = jnp.asarray(np.random.RandomState(1).randn(batch, d)
                     .astype(np.float32))
-    fn = make_pipeline_fn(_block, mesh, n_micro)
+    fn = jax.jit(make_pipeline_fn(_block, mesh, n_micro))
     got = fn(stacked, x)
     want = _sequential_ref(stacked, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -59,7 +59,9 @@ def test_pipeline_gradients_match_sequential():
                     .astype(np.float32))
     fn = make_pipeline_fn(_block, mesh, n_micro)
 
-    g_pipe = jax.grad(lambda p: jnp.sum(fn(p, x) ** 2))(stacked)
+    # under jit, as a train step differentiates it: an eager shard_map
+    # dispatches every primitive of the schedule as its own 4-device program
+    g_pipe = jax.jit(jax.grad(lambda p: jnp.sum(fn(p, x) ** 2)))(stacked)
     g_ref = jax.grad(lambda p: jnp.sum(_sequential_ref(p, x) ** 2))(stacked)
     for a, b in zip(g_pipe, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -72,8 +72,10 @@ def test_value_and_grad_parity(shape, k, s, pads, tie_heavy):
     assert pallas_pool_supported(x, dims, strides, fpads)
 
     y_ref, vjp_ref = jax.vjp(lambda a: _sas_pool(a, dims, strides, fpads), x)
+    # compiled: run eagerly, the interpreted kernel dispatches every
+    # primitive of its body as a program of its own
     y_pal, vjp_pal = jax.vjp(
-        lambda a: maxpool_argmax(a, dims, strides, fpads), x)
+        jax.jit(lambda a: maxpool_argmax(a, dims, strides, fpads)), x)
     np.testing.assert_array_equal(np.asarray(y_ref, np.float32),
                                   np.asarray(y_pal, np.float32))
 
@@ -108,7 +110,7 @@ def test_layer_uses_kernel_and_matches_oracle():
     layer = nn.SpatialMaxPooling(3, 3, 2, 2).ceil()
     dims, strides, pads, _ = layer._window(x)
     assert pallas_pool_supported(x, dims, strides, pads)
-    y, vjp = jax.vjp(lambda a: layer.forward(a), x)
+    y, vjp = jax.vjp(jax.jit(lambda a: layer.forward(a)), x)
 
     tx = torch.tensor(x32, requires_grad=True)
     ty = torch.nn.functional.max_pool2d(tx, 3, 2, 0, ceil_mode=True)
@@ -132,7 +134,8 @@ def test_nan_propagates():
     y_ref = _sas_pool(x, dims, strides, fpads)
     np.testing.assert_array_equal(np.isnan(np.asarray(y, np.float32)),
                                   np.isnan(np.asarray(y_ref, np.float32)))
-    y2, _ = jax.vjp(lambda a: maxpool_argmax(a, dims, strides, fpads), x)
+    y2, _ = jax.vjp(
+        jax.jit(lambda a: maxpool_argmax(a, dims, strides, fpads)), x)
     assert np.isnan(np.asarray(y2, np.float32)).sum() == 1
 
 
@@ -146,7 +149,8 @@ def test_negative_nan_propagates():
     x = x.at[0, 0, 1, 1].set(jnp.asarray(np.float32(neg_nan)))
     assert bool(jnp.isnan(x[0, 0, 1, 1]))
     dims, strides, fpads = _full((2, 2), (2, 2), ((0, 0), (0, 0)))
-    y, _ = jax.vjp(lambda a: maxpool_argmax(a, dims, strides, fpads), x)
+    y, _ = jax.vjp(
+        jax.jit(lambda a: maxpool_argmax(a, dims, strides, fpads)), x)
     assert np.isnan(np.asarray(y, np.float32)).sum() == 1
 
 

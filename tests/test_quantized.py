@@ -197,7 +197,6 @@ def test_int8_calibrated_inception_bytes_not_worse_than_bf16():
 
     from bigdl_tpu.models import registry
     from bigdl_tpu.nn.module import functional_call, state_dict
-    from bigdl_tpu.nn.quantized import calibrate
     from bigdl_tpu.telemetry.device import normalize_cost_analysis
 
     x = np.random.RandomState(8).randn(2, 3, 224, 224).astype(np.float32)
@@ -222,7 +221,13 @@ def test_int8_calibrated_inception_bytes_not_worse_than_bf16():
                            jnp.bfloat16)
     RNG.set_seed(54)
     q = quantize(registry.build_model("inception_v1").evaluate())
-    calibrate(q, [x])
+    # the static scales as calibrate() leaves them (Python floats, trace
+    # constants), without its eager pass over Inception at 224: that is
+    # ~100 one-op compiles which only decide each scale's VALUE, and no
+    # byte count reads it (calibrate() itself: test_calibrated_* above)
+    for m in q.modules():
+        if hasattr(m, "act_scale"):
+            m.act_scale = 0.05
     int8_bytes = fwd_bytes(q)
     assert bf16_bytes > 0 and int8_bytes > 0
     assert int8_bytes <= bf16_bytes, (

@@ -130,17 +130,20 @@ def test_checkpoint_resume_over_remote_scheme(memfs):
     assert o2.state["neval"] == 6
 
 
-def test_retry_restores_from_remote_checkpoint(memfs):
+def test_retry_restores_from_remote_checkpoint(memfs, monkeypatch):
     """An injected mid-training failure recovers from the memory://
     checkpoint through the retry loop (failure path + remote IO
     composed)."""
+    monkeypatch.setenv("BIGDL_RETRY_BACKOFF", "0.05")  # the retry, not the wait
     import numpy as np
 
     import bigdl_tpu.nn as nn
     import bigdl_tpu.optim as optim
     from bigdl_tpu.dataset.sample import Sample
     from bigdl_tpu.utils.rng import RNG
-    from tests.test_training_loop import ExceptionLayer
+    # the module pytest already imported, not a second copy of it under
+    # another name: the persistence registry holds one class per name
+    from test_training_loop import ExceptionLayer
 
     rng = np.random.default_rng(1)
     x = rng.normal(size=(32, 4)).astype(np.float32)
@@ -159,5 +162,8 @@ def test_retry_restores_from_remote_checkpoint(memfs):
                      optim.Trigger.several_iteration(2))
     o.overwrite_checkpoint()
     o.optimize()
+    # 8 iterations, the failed one, and iteration 5 replayed from model.4
+    assert ExceptionLayer.count == 10
+    assert o.model is not model  # restored from memory://
     assert o.state["neval"] >= 8  # completed despite the injected failure
     assert memfs.exists("/bigdl_ckpt/retry")

@@ -11,6 +11,7 @@ import pytest
 
 import bigdl_tpu.nn as nn
 import bigdl_tpu.optim as optim
+from bigdl_tpu import faults, telemetry
 from bigdl_tpu.dataset.sample import Sample
 from bigdl_tpu.optim.trigger import Trigger
 from bigdl_tpu.parallel.mesh import make_mesh
@@ -69,17 +70,24 @@ def test_save_restore_preserves_sharded_layout(tmp_path):
     assert abs(l1 - l2) < 1e-6
 
 
-def test_optimizer_sharded_backend_retry_and_resume(tmp_path):
+@pytest.fixture
+def crash_at_6(monkeypatch):
+    """A host-side failure at iteration 6, once (``crash@6`` of the
+    fault plan): on a mesh the step can not carry the failure itself."""
+    monkeypatch.setenv("BIGDL_FAULTS", "crash@6")
+    monkeypatch.setenv("BIGDL_RETRY_BACKOFF", "0.05")
+    faults.reset()
+    yield
+    faults.reset()
+
+
+def test_optimizer_sharded_backend_retry_and_resume(tmp_path, crash_at_6,
+                                                    caplog):
     """End-to-end through the Optimizer: sharded checkpoints fire on the
     trigger, an injected failure restores from the newest one, and the
     run completes."""
-    from tests.test_training_loop import ExceptionLayer
-
     samples, _, _ = _data(n=32)
-    ExceptionLayer.count = 0
-    model = nn.Sequential(nn.Linear(8, 16), ExceptionLayer(fail_at=6),
-                          nn.Tanh(), nn.Linear(16, 2), nn.LogSoftMax())
-    o = optim.DistriOptimizer(model, samples, nn.ClassNLLCriterion(),
+    o = optim.DistriOptimizer(_mlp(5), samples, nn.ClassNLLCriterion(),
                               batch_size=16,
                               end_trigger=Trigger.max_iteration(8),
                               mesh=make_mesh())
@@ -87,7 +95,15 @@ def test_optimizer_sharded_backend_retry_and_resume(tmp_path):
     o.set_checkpoint(str(tmp_path), Trigger.several_iteration(2),
                      backend="sharded")
     o.overwrite_checkpoint()
-    o.optimize()
+    sink = telemetry.MemorySink()
+    with telemetry.run(sinks=[sink]), caplog.at_level("INFO"):
+        o.optimize()
+    names = [e.get("name") for e in sink.events]
+    assert names.count("run/retry") == 1  # the injected failure, once
+    # ... and the retry resumed from the newest checkpoint at that point
+    restored = [r.getMessage() for r in caplog.records
+                if "will restore sharded state" in r.getMessage()]
+    assert len(restored) == 1 and restored[0].endswith("sharded.4"), restored
     assert o.state["neval"] >= 8
     latest = latest_step_dir(str(tmp_path))
     assert latest is not None and os.path.basename(latest) == "sharded.8"

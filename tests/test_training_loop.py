@@ -13,11 +13,13 @@ import pytest
 
 import bigdl_tpu.nn as nn
 import bigdl_tpu.optim as optim
+from bigdl_tpu import telemetry
 from bigdl_tpu.dataset.sample import Sample
 from bigdl_tpu.nn.module import Module, functional_call, state_dict
 from bigdl_tpu.optim.trigger import Trigger
 from bigdl_tpu.parallel.mesh import make_mesh
 from bigdl_tpu.parallel.train_step import TrainStep, bf16_truncate
+from bigdl_tpu.utils.module_format import register
 
 
 def _make_data(n=64, dim=4, seed=0):
@@ -231,9 +233,11 @@ def test_regularizer_and_freeze_in_train_step():
     assert not np.allclose(np.asarray(model.get(0).weight), 0)
 
 
+@register  # persistable: the retry restores it from the checkpoint
 class ExceptionLayer(Module):
     """Fault injection (``utils/TestUtils.scala:103`` ExceptionTest): throws
-    on the Nth forward."""
+    on the Nth forward — counted on the host each time the compiled step
+    RUNS (a count at trace time would see one forward, the trace)."""
 
     count = 0
 
@@ -241,14 +245,18 @@ class ExceptionLayer(Module):
         super().__init__()
         self.fail_at = fail_at
 
-    def update_output(self, input):
+    def _tick(self):
         ExceptionLayer.count += 1
         if ExceptionLayer.count == self.fail_at:
             raise RuntimeError("injected failure")
+
+    def update_output(self, input):
+        jax.debug.callback(self._tick)
         return input
 
 
-def test_retry_recovers_from_checkpoint(tmp_path):
+def test_retry_recovers_from_checkpoint(tmp_path, monkeypatch):
+    monkeypatch.setenv("BIGDL_RETRY_BACKOFF", "0.05")  # the retry, not the wait
     samples, _, _ = _make_data(n=32)
     ExceptionLayer.count = 0
     model = nn.Sequential(nn.Linear(4, 8), ExceptionLayer(fail_at=6), nn.Tanh(),
@@ -257,7 +265,15 @@ def test_retry_recovers_from_checkpoint(tmp_path):
                              end_trigger=Trigger.max_iteration(8))
     o.set_optim_method(optim.SGD(learning_rate=0.1))
     o.set_checkpoint(str(tmp_path), Trigger.several_iteration(2)).overwrite_checkpoint()
-    trained = o.optimize()
+    sink = telemetry.MemorySink()
+    with telemetry.run(sinks=[sink]):
+        o.optimize()
+    assert len([e for e in sink.events if e.get("name") == "run/retry"]) == 1
+    # 8 iterations, the one that failed, and iteration 5 a second time:
+    # the retry resumed from model.4, the newest checkpoint at the
+    # failure, and not from the weights it held (9) or from scratch (14)
+    assert ExceptionLayer.count == 10
+    assert o.model is not model  # the restored module took its place
     assert o.state["neval"] >= 8  # completed despite the injected failure
     assert os.path.exists(str(tmp_path))
 
