@@ -21,8 +21,11 @@ Knob: ``BIGDL_KERNELS`` (read at trace time):
 Every decision is emitted as a ``kernel/dispatch`` telemetry instant
 (op, backend, reason) at TRACE time — one instant per compilation, not
 per step — so PR 4's attribution can say which backend each module's
-HLO actually contains.  A small in-process ring (:func:`decisions`)
-records the same tuples for tests and the micro-bench harness.
+HLO actually contains.  A leg that launches through
+``pallas_util.plane_call`` adds how it was launched
+(``planes_per_block``, ``grid``: :func:`launched`).  A small in-process
+ring (:func:`decisions`) records the same for tests and the micro-bench
+harness.
 
 Caveat: the knob is read when a function is traced.  A jit-cached
 executable does not re-dispatch when the env changes; tests flip the
@@ -35,18 +38,36 @@ import contextlib
 import contextvars
 import os
 from collections import deque
-from typing import Callable, Deque, List, Tuple
+from typing import Callable, Deque, Dict, List, Tuple
 
 from bigdl_tpu import telemetry
 
 __all__ = ["kernel_mode", "choose_backend", "dispatch", "use_interpret",
-           "spmd_partitioned", "auto_pallas", "decisions",
-           "clear_decisions", "MODES"]
+           "spmd_partitioned", "auto_pallas", "launched", "Decision",
+           "decisions", "clear_decisions", "MODES"]
 
 MODES = ("auto", "pallas", "xla")
 
-#: last N (op, backend, reason) decisions, trace-time order
-_DECISIONS: Deque[Tuple[str, str, str]] = deque(maxlen=256)
+
+class Decision(tuple):
+    """One ``(op, backend, reason)`` triple — it unpacks, compares and
+    sorts as that — which also carries, in ``launch``, what the leg's
+    launcher said of itself (``plane_call``: ``planes_per_block`` and
+    ``grid``; empty for a leg that reports nothing)."""
+
+    launch: Dict[str, object]
+
+    def __new__(cls, op: str, backend: str, reason: str, **launch):
+        self = super().__new__(cls, (op, backend, reason))
+        self.launch = launch
+        return self
+
+
+#: last N decisions, trace-time order
+_DECISIONS: Deque[Decision] = deque(maxlen=256)
+
+#: the launch facts of the leg :func:`dispatch` is running, while it runs
+_LAUNCH = contextvars.ContextVar("bigdl_kernel_launch", default=None)
 
 #: True while the current thread traces a step that XLA will partition
 #: over a multi-device mesh (see :func:`spmd_partitioned`)
@@ -117,28 +138,45 @@ def auto_pallas() -> Tuple[bool, str]:
     return True, "auto:tpu"
 
 
-def note(op: str, backend: str, reason: str) -> None:
+def note(op: str, backend: str, reason: str, **launch) -> None:
     """Record + emit one dispatch decision (shared by :func:`dispatch`
     and call sites with bespoke selection logic, e.g. the argmax pool
     and the attention auto-backend)."""
-    _DECISIONS.append((op, backend, reason))
+    _DECISIONS.append(Decision(op, backend, reason, **launch))
     telemetry.instant("kernel/dispatch", op=op, backend=backend,
-                      reason=reason)
+                      reason=reason, **launch)
+
+
+def launched(**facts) -> None:
+    """A launcher's word on the leg being dispatched, at trace time:
+    ``plane_call`` reports ``planes_per_block`` and ``grid`` here, and
+    they ride on that leg's decision.  Outside :func:`dispatch` (a
+    kernel called bare) there is no decision to ride on."""
+    holder = _LAUNCH.get()
+    if holder is not None:
+        holder.update(facts)
 
 
 def dispatch(op: str, pallas_fn: Callable, xla_fn: Callable,
              supported: bool, *args, **kwargs):
     """Run ``pallas_fn`` or ``xla_fn`` per :func:`choose_backend`,
-    recording the decision.  Called at trace time inside the op's
-    custom-vjp forward/backward rules."""
+    recording the decision — once the leg is traced, so that it holds
+    what the leg's launcher :func:`launched`.  Called at trace time
+    inside the op's custom-vjp forward/backward rules."""
     backend, reason = choose_backend(op, supported)
-    note(op, backend, reason)
     fn = pallas_fn if backend == "pallas" else xla_fn
-    return fn(*args, **kwargs)
+    launch: Dict[str, object] = {}
+    token = _LAUNCH.set(launch)
+    try:
+        return fn(*args, **kwargs)
+    finally:
+        _LAUNCH.reset(token)
+        note(op, backend, reason, **launch)
 
 
-def decisions() -> List[Tuple[str, str, str]]:
-    """Recent (op, backend, reason) tuples — test/bench introspection."""
+def decisions() -> List[Decision]:
+    """Recent decisions, each an (op, backend, reason) triple with its
+    ``launch`` facts — test/bench introspection."""
     return list(_DECISIONS)
 
 

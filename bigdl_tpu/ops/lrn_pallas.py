@@ -292,30 +292,30 @@ def within_channel_lrn_supported(x, size: int) -> bool:
 
 def _wcl_fwd_kernel(xp_ref, y_ref, sc_ref, *, h: int, w: int, size: int,
                     lo: int, alpha: float, beta: float):
-    xp = xp_ref[0]                      # [Hp, Wp]
+    xp = xp_ref[...]                    # [P, Hp, Wp]: a block of planes
     sq = xp * xp
     ws = None
     for dh in range(size):
         for dw in range(size):
-            tap = sq[dh:dh + h, dw:dw + w]
+            tap = sq[:, dh:dh + h, dw:dw + w]
             ws = tap if ws is None else ws + tap
     scale = 1.0 + ws * (alpha / (size * size))
-    sc_ref[0] = scale
-    y_ref[0] = xp[lo:lo + h, lo:lo + w] * _pow(scale, -beta)
+    sc_ref[...] = scale
+    y_ref[...] = xp[:, lo:lo + h, lo:lo + w] * _pow(scale, -beta)
 
 
 def _wcl_bwd_kernel(tp_ref, x_ref, g_ref, sc_ref, dx_ref, *, h: int,
                     w: int, size: int, alpha: float, beta: float):
-    tp = tp_ref[0]                      # transpose-padded t
+    tp = tp_ref[...]                    # transpose-padded t, [P, Hp, Wp]
     ts = None
     for dh in range(size):
         for dw in range(size):
-            tap = tp[dh:dh + h, dw:dw + w]
+            tap = tp[:, dh:dh + h, dw:dw + w]
             ts = tap if ts is None else ts + tap
-    g = g_ref[0]
-    x = x_ref[0]
-    scale = sc_ref[0]
-    dx_ref[0] = g * _pow(scale, -beta) \
+    g = g_ref[...]
+    x = x_ref[...]
+    scale = sc_ref[...]
+    dx_ref[...] = g * _pow(scale, -beta) \
         - (2.0 * alpha * beta / (size * size)) * x * ts
 
 

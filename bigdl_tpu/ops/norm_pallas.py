@@ -8,7 +8,7 @@ layer implementation) expresses the smoothing as a 1-channel depthwise
 input/output channel leaves the 128x128 MXU >99% idle and the op runs
 as serialized HBM-bound window traffic.  The smoothing is really a
 ``kh*kw``-tap shift-accumulate on the VPU, which is exactly what the
-Pallas kernel here does (one padded plane per block, unrolled static
+Pallas kernel here does (whole padded planes per block, unrolled static
 shifts, one write).  Channel reduction, division and thresholding stay
 in XLA — they are elementwise/small reductions XLA fuses into the
 adjacent kernels already.
@@ -81,14 +81,14 @@ def smooth2d_supported(stack, kernel) -> bool:
 
 def _smooth_kernel(vp_ref, w_ref, out_ref, *, h: int, w: int, kh: int,
                    kw: int, flip: bool):
-    vp = vp_ref[0]                      # [Hp, Wp] padded plane
+    vp = vp_ref[...]                    # [P, Hp, Wp] padded planes
     acc = None
     for i in range(kh):
         for j in range(kw):
             wt = w_ref[kh - 1 - i, kw - 1 - j] if flip else w_ref[i, j]
-            tap = vp[i:i + h, j:j + w] * wt
+            tap = vp[:, i:i + h, j:j + w] * wt
             acc = tap if acc is None else acc + tap
-    out_ref[0] = acc
+    out_ref[...] = acc
 
 
 def _smooth_pallas(stack, kernel, pads, flip: bool):

@@ -11,7 +11,7 @@ Two ops the autodiff path got subtly wrong and XLA lowers expensively:
   scatter kernels (the ~50%-of-Inception-step pathology the
   residue-class rewrite in PR-era ``nn/layers/pooling.py`` addressed).
   Here the whole backward — tie count, weight, residue-class gather,
-  stride interleave — is ONE Pallas pass per (n, c) plane.
+  stride interleave — is ONE Pallas pass over the (n, c) planes.
 - ``avg_pool``: Torch ceil-mode average pooling with the asymmetric
   declared-vs-overflow divisor (declared padding counts toward the
   divisor under ``count_include_pad``; ceil-overflow padding never
@@ -99,22 +99,27 @@ def pool_plane_supported(x, dims, strides) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernels (per (n*c) plane; grid = (N*C,))
+# Pallas kernels: a ref is a block of P whole planes, [P, rows, cols],
+# and a body works on all P at once along the leading axis; the grid is
+# ceil(N*C / P), P chosen by ``pallas_util.plane_call`` from the plane
+# shape (256 for a 7x7 bf16 head-pool plane, 1 for a large one)
 # ---------------------------------------------------------------------------
 
 def _taps(xp, k2, s2, out2):
-    """All window taps of a padded 2-D plane as strided [out_h, out_w]
-    views — static slices only."""
+    """All window taps of a block of padded planes as strided
+    [P, out_h, out_w] views — static slices only."""
     (kh, kw), (sh, sw), (oh, ow) = k2, s2, out2
+    p = xp.shape[0]
     for dh in range(kh):
         for dw in range(kw):
-            yield lax.slice(xp, (dh, dw),
-                            (dh + (oh - 1) * sh + 1,
-                             dw + (ow - 1) * sw + 1), (sh, sw))
+            yield lax.slice(xp, (0, dh, dw),
+                            (p, dh + (oh - 1) * sh + 1,
+                             dw + (ow - 1) * sw + 1), (1, sh, sw))
 
 
 def _interleave(parts, s2, l2):
-    """[sh][sw] residue planes of shape [Lh, Lw] -> [Lh*sh, Lw*sw]."""
+    """[sh][sw] residue blocks of shape [P, Lh, Lw] -> [P, Lh*sh,
+    Lw*sw]."""
     (sh, sw), (lh, lw) = s2, l2
     rows = []
     for rh in range(sh):
@@ -122,29 +127,30 @@ def _interleave(parts, s2, l2):
         if sw == 1:
             rows.append(cols[0])
         else:
-            rows.append(jnp.stack(cols, axis=2).reshape(lh, lw * sw))
+            rows.append(jnp.stack(cols, axis=3).reshape(-1, lh, lw * sw))
     if sh == 1:
         return rows[0]
-    return jnp.stack(rows, axis=1).reshape(lh * sh, rows[0].shape[1])
+    return jnp.stack(rows, axis=2).reshape(-1, lh * sh, rows[0].shape[2])
 
 
 def _maxpool_fwd_kernel(xp_ref, y_ref, *, k2, s2, out2):
-    xp = xp_ref[0]
+    xp = xp_ref[...]
     y = None
     for tap in _taps(xp, k2, s2, out2):
         y = tap if y is None else jnp.maximum(y, tap)
-    y_ref[0] = y
+    y_ref[...] = y
 
 
 def _tie_bwd_kernel(xp_ref, yp_ref, gp_ref, dx_ref, *, k2, s2, l2, m2,
                     j2, lo2, n2):
-    """One plane: tie count -> equal-split weight -> residue gather."""
+    """Each plane: tie count -> equal-split weight -> residue gather."""
     (kh, kw), (sh, sw) = k2, s2
     (lh, lw), (mh, mw) = l2, m2
     (jh_max, jw_max), (lo_h, lo_w), (h, w) = j2, lo2, n2
-    xp = xp_ref[0]
-    yp = yp_ref[0]
-    gp = gp_ref[0]
+    xp = xp_ref[...]
+    yp = yp_ref[...]
+    gp = gp_ref[...]
+    p = xp.shape[0]
 
     cnt = None
     for tap in _taps(xp, k2, s2, (mh, mw)):
@@ -156,62 +162,86 @@ def _tie_bwd_kernel(xp_ref, yp_ref, gp_ref, dx_ref, *, k2, s2, l2, m2,
     for rh in range(sh):
         cols = []
         for rw in range(sw):
-            xr = lax.slice(xp, (rh + jh_max * sh, rw + jw_max * sw),
-                           (rh + jh_max * sh + (lh - 1) * sh + 1,
+            xr = lax.slice(xp, (0, rh + jh_max * sh, rw + jw_max * sw),
+                           (p, rh + jh_max * sh + (lh - 1) * sh + 1,
                             rw + jw_max * sw + (lw - 1) * sw + 1),
-                           (sh, sw))
-            acc = jnp.zeros((lh, lw), gp.dtype)
+                           (1, sh, sw))
+            acc = jnp.zeros((p, lh, lw), gp.dtype)
             for jh in range(-(-(kh - rh) // sh)):
                 if rh + sh * jh >= kh:
                     continue
                 for jw in range(-(-(kw - rw) // sw)):
                     if rw + sw * jw >= kw:
                         continue
-                    yj = yp[jh_max - jh:jh_max - jh + lh,
+                    yj = yp[:, jh_max - jh:jh_max - jh + lh,
                             jw_max - jw:jw_max - jw + lw]
-                    wj = wgt[jh_max - jh:jh_max - jh + lh,
+                    wj = wgt[:, jh_max - jh:jh_max - jh + lh,
                              jw_max - jw:jw_max - jw + lw]
                     acc = acc + jnp.where(xr == yj, wj, 0.0)
             cols.append(acc)
         parts.append(cols)
     dxp = _interleave(parts, s2, l2)
-    dx_ref[0] = dxp[lo_h:lo_h + h, lo_w:lo_w + w]
+    dx_ref[...] = dxp[:, lo_h:lo_h + h, lo_w:lo_w + w]
+
+
+def _plane_sum(v):
+    """[P, rows, cols] -> [P, 1, 1] in float32, an axis at a time: a
+    reduction over both at once leaves Mosaic a 1-D value to reshape,
+    which it refuses."""
+    s = jnp.sum(v.astype(jnp.float32), axis=2, keepdims=True)
+    return jnp.sum(s, axis=1, keepdims=True)
 
 
 def _avg_fwd_kernel(xp_ref, inv_ref, y_ref, *, k2, s2, out2):
-    xp = xp_ref[0]
+    xp = xp_ref[...]
+    if xp.shape[1:] == tuple(k2):
+        # the window is the whole padded plane (a head pool): every tap
+        # below would be one element, so the same sum is taken as one
+        # reduction of the plane instead of kh*kw shifted adds
+        y_ref[...] = (_plane_sum(xp) * inv_ref[...]).astype(y_ref.dtype)
+        return
     s = None
     for tap in _taps(xp, k2, s2, out2):
         s = tap if s is None else s + tap
-    y_ref[0] = s * inv_ref[0]
+    y_ref[...] = s * inv_ref[...]
 
 
 def _avg_bwd_kernel(wp_ref, dx_ref, *, k2, s2, l2, j2, lo2, n2):
     (kh, kw), (sh, sw) = k2, s2
     (lh, lw) = l2
     (jh_max, jw_max), (lo_h, lo_w), (h, w) = j2, lo2, n2
-    wp = wp_ref[0]
+    wp = wp_ref[...]
+    p = wp.shape[0]
+    if (lh, lw) == (kh, kw) and (sh, sw) == (1, 1):
+        # the window is the whole padded plane: the extended grid holds
+        # its one weight and zeros, each shift below lands one tap on
+        # the weight, so every position reads it.  Taken as the plane's
+        # sum (exact: the rest is zero), which Mosaic can broadcast over
+        # rows and columns; a [P, 1, 1] slice it cannot
+        dx_ref[...] = jnp.broadcast_to(_plane_sum(wp), (p, h, w)) \
+            .astype(dx_ref.dtype)
+        return
     parts = []
     for rh in range(sh):
         cols = []
         for rw in range(sw):
-            acc = jnp.zeros((lh, lw), wp.dtype)
+            acc = jnp.zeros((p, lh, lw), wp.dtype)
             for jh in range(-(-(kh - rh) // sh)):
                 if rh + sh * jh >= kh:
                     continue
                 for jw in range(-(-(kw - rw) // sw)):
                     if rw + sw * jw >= kw:
                         continue
-                    acc = acc + wp[jh_max - jh:jh_max - jh + lh,
+                    acc = acc + wp[:, jh_max - jh:jh_max - jh + lh,
                                    jw_max - jw:jw_max - jw + lw]
             cols.append(acc)
         parts.append(cols)
     dxp = _interleave(parts, s2, l2)
-    dx_ref[0] = dxp[lo_h:lo_h + h, lo_w:lo_w + w]
+    dx_ref[...] = dxp[:, lo_h:lo_h + h, lo_w:lo_w + w]
 
 
 def _plane_call(kernel, inputs, out_hw, b, dtype, bcast=()):
-    """Thin adapter onto the shared per-plane launcher
+    """Thin adapter onto the shared plane launcher
     (``ops/pallas_util.py``) — single [out_hw, dtype] output."""
     return _shared_plane_call(kernel, inputs, [(out_hw, dtype)], b,
                               _dispatch.use_interpret(), bcast=bcast)

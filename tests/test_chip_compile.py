@@ -15,6 +15,10 @@ over the library's lock.  ``is_tpu_device()`` still sees the CPU here,
 so each case steers it with ``monkeypatch``.
 """
 
+import json
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -23,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from bigdl_tpu.ops import attention, dispatch
 from bigdl_tpu.ops.lrn_pallas import cross_map_lrn, within_channel_lrn
 from bigdl_tpu.ops.pool_pallas import avg_pool, maxpool_tie_split
+from test_kernels import _launches
 
 
 @pytest.fixture(scope="module")
@@ -61,10 +66,12 @@ def as_tpu(monkeypatch):
     dispatch.clear_decisions()
 
 
-def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1):
+def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1, as_traced=False):
     """Compiled text of ``op``'s value and VJP.  The cotangent is an
     ARGUMENT placed on the described device: a backward whose inputs do
-    not depend on such an argument is lowered for the CPU instead."""
+    not depend on such an argument is lowered for the CPU instead.
+    ``as_traced``: every instruction with its operands' shapes, which
+    is how a device trace names its events."""
     def fwd_bwd(*args):
         *xs, g = args
         y, vjp = jax.vjp(op, *xs)
@@ -73,7 +80,15 @@ def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1):
     xs = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * n_in
     g = jax.ShapeDtypeStruct(jax.eval_shape(op, *xs).shape, dtype,
                              sharding=sharding)
-    return jax.jit(fwd_bwd).lower(*xs, g).compile().as_text()
+    compiled = jax.jit(fwd_bwd).lower(*xs, g).compile()
+    if not as_traced:
+        return compiled.as_text()
+    from jax._src.lib import xla_client
+
+    options = xla_client._xla.HloPrintOptions()
+    options.print_operand_shape = True
+    (module,) = compiled.runtime_executable().hlo_modules()
+    return module.to_string(options)
 
 
 def _backends(op_prefix):
@@ -120,6 +135,101 @@ def test_avg_pool_stride1_compiles(one_chip, as_tpu):
     assert _backends("pool_avg") == {
         ("pool_avg.fwd", "pallas"), ("pool_avg.bwd", "pallas")}
     assert "tpu_custom_call" in text
+
+
+def _head_pool(x):
+    dims, strides, pads = _pool_window(7, 1)
+    return avg_pool(x, dims, strides, pads, pads, True, True)
+
+
+@pytest.mark.parametrize("config,shape", [
+    ("inception_v1_imagenet", (256, 1024, 7, 7)),
+    ("resnet50_imagenet", (128, 2048, 7, 7)),
+])
+def test_head_pool_is_the_call_the_benchmark_reads(config, shape, one_chip,
+                                                   as_tpu):
+    """The one-chip cells find the head pool in a device trace by the
+    ``match`` patterns of their configuration, which name the custom
+    call's operand and result shapes.  A cell whose patterns match
+    nothing loses ``kernel.pallas_share`` and ``kernel.pallas_roofline``
+    and is refused (PR 25, ResNet-50, whose only Pallas kernel this
+    is): the compiled step must hold one instruction for each pattern,
+    launched a block of planes a grid step."""
+    text = _fwd_bwd_text(_head_pool, shape, jnp.bfloat16, one_chip,
+                         as_traced=True)
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "configs", config + ".json")
+    with open(path) as f:
+        patterns = {k["name"]: k["match"]
+                    for k in json.load(f)["pallas_kernels"]
+                    if k["kernel"] == "pool_avg"}
+    assert set(patterns) == {"pool_avg.fwd", "pool_avg.bwd"}
+    for name, pattern in patterns.items():
+        hits = [ln for ln in text.splitlines() if re.search(pattern, ln)]
+        assert len(hits) == 1, (name, hits)
+    launches = _launches("pool_avg")
+    assert set(launches) == {"pool_avg.fwd", "pool_avg.bwd"}
+    for launch in launches.values():
+        per_block = launch["planes_per_block"]
+        assert per_block > 1
+        assert launch["grid"] == (-(-262144 // per_block),)
+
+
+def test_head_pool_compiles_with_a_ragged_last_block(one_chip, as_tpu):
+    """300 planes, 256 a grid step: the second block is 44 planes and
+    Mosaic has to take it."""
+    text = _fwd_bwd_text(_head_pool, (3, 100, 7, 7), jnp.bfloat16, one_chip)
+    assert text.count("tpu_custom_call") >= 2
+    assert _launches("pool_avg") == {
+        "pool_avg.fwd": {"planes_per_block": 256, "grid": (2,)},
+        "pool_avg.bwd": {"planes_per_block": 256, "grid": (2,)}}
+
+
+def _pallas_grids(fn, *args):
+    """[(grid, [block shapes])] of every ``pallas_call`` traced in
+    ``fn``'s value and VJP."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                found.append((tuple(gm.grid),
+                              [tuple(getattr(d, "block_size", d)
+                                     for d in b.block_shape)
+                               for b in gm.block_mappings]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    def fwd_bwd(x):
+        y, vjp = jax.vjp(fn, x)
+        return vjp(y)
+
+    walk(jax.make_jaxpr(fwd_bwd)(*args).jaxpr)
+    return found
+
+
+def test_large_planes_keep_one_plane_a_grid_step(as_tpu):
+    """What the block rule must leave alone.  Cross-map LRN has its own
+    launcher (a [C + halo, HW tile] slab a grid step over (N, tiles)),
+    so no plane launch rides on its decisions; and a plane stack whose
+    planes are large (within-channel LRN on 384x384 images) comes out of
+    the shared launcher at one plane a step, grid (N*C,), as before."""
+    x = jax.ShapeDtypeStruct((32, 192, 56, 56), jnp.bfloat16)
+    grids = _pallas_grids(lambda a: cross_map_lrn(a, 5, 1e-4, 0.75, 1.0), x)
+    assert [g for g, _ in grids] == [(32, 5), (32, 5)]
+    assert {b for _, blocks in grids for b in blocks} \
+        == {(1, 196, 640), (1, 192, 640)}
+    assert _launches("lrn_cross_map") == {"lrn_cross_map.fwd": {},
+                                          "lrn_cross_map.bwd": {}}
+
+    x = jax.ShapeDtypeStruct((2, 3, 384, 384), jnp.float32)
+    grids = _pallas_grids(lambda a: within_channel_lrn(a, 5, 1e-4, 0.75), x)
+    assert [g for g, _ in grids] == [(6,), (6,)]
+    assert {b[0] for _, blocks in grids for b in blocks} == {1}
+    assert _launches("lrn_within_channel") == {
+        "lrn_within_channel.fwd": {"planes_per_block": 1, "grid": (6,)},
+        "lrn_within_channel.bwd": {"planes_per_block": 1, "grid": (6,)}}
 
 
 @pytest.mark.parametrize("name,shape,make_op", [

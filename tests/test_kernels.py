@@ -243,6 +243,116 @@ def test_avg_pool_parity(shape, k, s, p, count_include_pad, monkeypatch):
                monkeypatch=monkeypatch)
 
 
+def _launches(op_prefix):
+    return {r[0]: r.launch for r in dispatch.decisions()
+            if r[0].startswith(op_prefix) and r[1] == "pallas"}
+
+
+# 300 planes of at most 4 KB each way: 256 share a grid step (4 MB over
+# 2 buffers x 8 KB), and the second step's block is ragged, 44 of 256
+BLOCKED_AVG_CASES = [
+    # (shape, k, pads, declared): a window that IS the padded plane ...
+    ((2, 150, 7, 7), (7, 7), ((0, 0), (0, 0)), ((0, 0), (0, 0))),
+    ((3, 100, 5, 5), (7, 7), ((1, 1), (1, 1)), ((1, 1), (1, 1))),
+    ((2, 150, 4, 6), (5, 8), ((1, 0), (1, 1)), ((1, 0), (1, 1))),
+    # ... and one that slides over it, 3x3/s1 "same" padding
+    ((2, 150, 6, 6), (3, 3), ((1, 1), (1, 1)), ((1, 1), (1, 1))),
+]
+
+
+@pytest.mark.parametrize("shape,k,p,declared", BLOCKED_AVG_CASES)
+@pytest.mark.parametrize("count_include_pad", [True, False])
+def test_blocked_avg_pool_parity(shape, k, p, declared, count_include_pad,
+                                 monkeypatch):
+    """Many planes a grid step, the last block ragged: value and VJP of
+    the blocked kernels against the XLA leg (``reduce_window`` and its
+    transpose), with and without the padding in the divisor."""
+    x = jnp.asarray(_rng(30).randn(*shape).astype(np.float32))
+    dims, strides, pads = _full(k, (1, 1), p)
+    y = _both_legs(lambda a: avg_pool(a, dims, strides, pads,
+                                      ((0, 0), (0, 0)) + declared,
+                                      count_include_pad, True), x,
+                   monkeypatch=monkeypatch)
+    if k != (3, 3):
+        assert y.shape[2:] == (1, 1)
+    launches = _launches("pool_avg")
+    assert launches["pool_avg.fwd"] == {"planes_per_block": 256,
+                                        "grid": (2,)}
+    # the backward's extended grid is wider (13x13 float32 for a 7x7
+    # window: two tiles of 8 rows), so fewer planes fit; ragged as well
+    per_block = launches["pool_avg.bwd"]["planes_per_block"]
+    assert 1 < per_block <= 256 and 300 % per_block
+    assert launches["pool_avg.bwd"]["grid"] == (-(-300 // per_block),)
+
+
+def test_blocked_avg_pool_parity_bf16(monkeypatch):
+    """The benchmark's dtype: the whole-plane form accumulates in
+    float32, the XLA leg however it likes; both round to bfloat16."""
+    x = jnp.asarray(_rng(31).randn(2, 150, 7, 7), jnp.bfloat16)
+    dims, strides, pads = _full((7, 7), (1, 1), ((0, 0), (0, 0)))
+    _both_legs(lambda a: avg_pool(a, dims, strides, pads, pads, True, True),
+               x, rtol=2e-2, atol=2e-2, monkeypatch=monkeypatch)
+    assert all(l["planes_per_block"] == 256 and l["grid"] == (2,)
+               for l in _launches("pool_avg").values())
+
+
+@pytest.mark.parametrize("name,shape,fn,planes,grid", [
+    # backward: three 10x10 extended planes in (8 KB each in float32),
+    # 6x6 out (4 KB)
+    ("pool_tie_split", (2, 150, 6, 6),
+     lambda a: maxpool_tie_split(
+         a, *_full((3, 3), (1, 1), ((1, 1), (1, 1)))), 73, (5,)),
+    # backward: 8 KB (9x9 padded) + 3 x 4 KB in, 4 KB out
+    ("lrn_within_channel", (2, 150, 5, 5),
+     lambda a: within_channel_lrn(a, 5, 0.01, 0.75), 85, (4,)),
+])
+def test_blocked_launch_keeps_other_plane_kernels(name, shape, fn, planes,
+                                                  grid, monkeypatch):
+    """The launcher's other kernels on a ragged grid of plane blocks:
+    same values as the XLA leg, as at one plane a step."""
+    x = _rng(32).randn(*shape).astype(np.float32)
+    if name == "pool_tie_split":
+        x = np.round(x * 2.0) / 2.0     # ties inside windows
+    _both_legs(fn, jnp.asarray(x), monkeypatch=monkeypatch)
+    bwd = _launches(name)[name + ".bwd"]
+    assert bwd == {"planes_per_block": planes, "grid": grid}
+
+
+def test_blocked_smoothing_parity(monkeypatch):
+    """``norm_pallas``'s smoothing stack is one plane a record: 300
+    records of 6x6 share grid steps, 256 and a ragged 44."""
+    x = jnp.asarray(_rng(33).randn(300, 2, 6, 6).astype(np.float32))
+    _both_legs(lambda a: subtractive_norm(a, _gauss(3)), x, rtol=1e-4,
+               atol=1e-5, monkeypatch=monkeypatch)
+    # (``_coef``'s single plane of ones is a launch of its own)
+    assert {"planes_per_block": 256, "grid": (2,)} in [
+        r.launch for r in dispatch.decisions()
+        if r[0] == "norm_smooth.fwd"]
+
+
+def test_planes_per_block_reads_the_tiled_footprint():
+    """P is the VMEM budget over the tile-rounded, double-buffered
+    planes: small planes share a step, a large one keeps its own."""
+    from bigdl_tpu.ops.pallas_util import VMEM_BUDGET, planes_per_block
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    # the head pool: a 7x7 (or 13x13) bf16 plane is one (16, 128) tile
+    assert planes_per_block([((7, 7), bf16), ((1, 1), bf16)], 262144) == 256
+    assert planes_per_block([((13, 13), bf16), ((7, 7), bf16)],
+                            262144) == 256
+    # 17 rows of float32 are three (8, 128) tiles, 12 KB
+    assert planes_per_block([((17, 130), f32)], 10 ** 6) \
+        == VMEM_BUDGET // (2 * 3 * 8 * 256 * 4)
+    # never more planes than there are
+    assert planes_per_block([((7, 7), bf16), ((1, 1), bf16)], 6) == 6
+    # cross-map LRN's [C + halo, HW tile] slabs, were they launched
+    # here, and a 384x384 image: one a step
+    assert planes_per_block([((196, 3200), bf16)] + [((192, 3200), bf16)] * 2,
+                            256) == 1
+    assert planes_per_block([((388, 388), f32)] + [((384, 384), f32)] * 2,
+                            64) == 1
+
+
 def test_tie_split_conserves_gradient_mass(monkeypatch):
     """Equal-split semantics: summed input gradient == summed output
     gradient regardless of ties (mass conservation), on both legs."""
@@ -405,6 +515,31 @@ def test_dispatch_emits_telemetry_instant(tmp_path, monkeypatch):
     assert inst and inst[0]["op"] == "lrn_cross_map.fwd" \
         and inst[0]["backend"] == "xla"
     assert not schema.validate_events(events)
+
+
+def test_plane_launch_rides_on_the_dispatch_instant(tmp_path, monkeypatch):
+    """A ``plane_call`` kernel's decision says how it was launched, in
+    the run log's instant as in the ring; an XLA leg says nothing."""
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.telemetry import schema
+
+    dims, strides, pads = _full((7, 7), (1, 1), ((0, 0), (0, 0)))
+    x = jnp.asarray(_rng(17).randn(2, 150, 7, 7).astype(np.float32))
+    telemetry.start_run(str(tmp_path))
+    try:
+        for mode in ("pallas", "xla"):
+            monkeypatch.setenv("BIGDL_KERNELS", mode)
+            jax.jit(lambda a: avg_pool(a, dims, strides, pads, pads, True,
+                                       True))(x)
+    finally:
+        telemetry.end_run()
+    events, errors = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
+    assert not errors and not schema.validate_events(events)
+    by_backend = {e["backend"]: e for e in events
+                  if e.get("name") == "kernel/dispatch"}
+    assert by_backend["pallas"]["planes_per_block"] == 256
+    assert list(by_backend["pallas"]["grid"]) == [2]
+    assert "planes_per_block" not in by_backend["xla"]
 
 
 def test_attention_routing_shares_predicate(monkeypatch):
