@@ -14,31 +14,29 @@ The benchmark's own runs never run this.
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark import compare, reference, run  # noqa: E402
+from benchmark import compare, models, optimizers, reference, run  # noqa: E402
 
 
 def control_numbers(cell, seed: int, quant: str = "int8"):
     """The compared numbers of the lower-precision reference against the
     reference proper, at the cell's batch and check steps."""
     w, conf = cell["workload"], cell["config"]
-    family = importlib.import_module("benchmark.models." + conf["family"])
+    family, recipe = models.load(conf), optimizers.load(conf)
     n = w["check_steps"] * w["batch"]
-    x, y = reference.make_records(seed, n, conf["image"], conf["classes"])
+    x, y = models.make_records(family, seed, n, conf)
     batches = [(x[i:i + w["batch"]], y[i:i + w["batch"]])
                for i in range(0, n, w["batch"])]
-    weights = reference.make_weights(family.param_specs(conf), seed,
+    weights = reference.host_weights(family.param_specs(conf), seed,
                                      conf["init_gain"])
-    want = reference.follow(family, weights, batches, conf["learning_rate"],
-                            conf["momentum"])
-    got = reference.follow(family, weights, batches, conf["learning_rate"],
-                           conf["momentum"], quant=quant)
+    want = reference.follow(family, weights, batches, recipe, conf)
+    got = reference.follow(family, weights, batches, recipe, conf,
+                           quant=quant)
     return compare.numbers(got, want)
 
 

@@ -13,7 +13,9 @@ prints the contract's result line last.
 Everything that belongs to one cell, configuration or per-layer metric is
 found by its name in ``BENCHMARK.json`` and read from a file of its own
 (``workloads/``, ``configs/``, ``layer_metrics/``, ``readers/``,
-``models/``); adding one edits no file that is here.
+``models/``, ``optimizers/``); adding one edits no file that is here.
+What a record is comes from the configuration's family, and what the
+update rule is from the recipe it names: this file holds neither.
 """
 
 from __future__ import annotations
@@ -38,7 +40,8 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import compare, reference, stats, trace as tracelib  # noqa: E402
+from benchmark import (compare, models, optimizers, reference,  # noqa: E402
+                       stats, trace as tracelib)
 
 
 def read_json(*parts: str) -> Dict:
@@ -140,10 +143,11 @@ class Hooks:
 
     STAGES = ("data time", "dispatch time")
 
-    def __init__(self, optimizer, param_keys: List[str], seconds: float,
-                 check_steps: int, warmup_steps: int,
+    def __init__(self, optimizer, first_gradient, param_keys: List[str],
+                 seconds: float, check_steps: int, warmup_steps: int,
                  compiles: CompileCounter, trace_dir: Optional[str]):
         self.o = optimizer
+        self.first_gradient = first_gradient  # of opt_state, by the recipe
         self.param_keys = param_keys      # the model's own order of leaves
         self.seconds = seconds
         self.check_steps = check_steps
@@ -154,7 +158,7 @@ class Hooks:
         self.ends: List[float] = []       # loss-on-host stamp of step i+1
         self.losses: List[float] = []
         self.stage_totals: List[Dict[str, float]] = []
-        self.grad1 = None                 # velocity after step 1
+        self.grad1 = None                 # first gradient, from step 1's state
         self.params_after_check = None
         self.t_open = self.t_close = None
         self.open_step = None             # steps completed at t_open
@@ -203,7 +207,8 @@ class Hooks:
         m = self.o.metrics
         self.stage_totals.append({s: m.total(s) for s in self.STAGES})
         if step == 1:
-            self.grad1 = self._host(self._step().opt_state["velocity"])
+            self.grad1 = self._host(
+                self.first_gradient(self._step().opt_state))
         if step == self.check_steps:
             self.params_after_check = self._host(self._step().params)
 
@@ -216,10 +221,10 @@ class Hooks:
         return [np.asarray(tree[k]) for k in self.param_keys]
 
 
-def build_optimizer(cell: Dict, family, model, samples, devices):
-    """The ``cli train`` recipe: registry model, ``Sample`` records, SGD
-    with momentum, bf16 compute over f32 master weights; the class and
-    the sync mode are the cell's."""
+def build_optimizer(cell: Dict, family, recipe, model, samples, devices):
+    """The ``cli train`` recipe: registry model, ``Sample`` records, the
+    configuration's update rule and compute type over f32 master
+    weights; the class and the sync mode are the cell's."""
     import jax.numpy as jnp
 
     import bigdl_tpu.optim as optim
@@ -253,8 +258,7 @@ def build_optimizer(cell: Dict, family, model, samples, devices):
         kw["mesh"] = Engine.mesh
     o = getattr(optim, w["optimizer"])(
         model, dataset, family.criterion(), **kw)
-    o.set_optim_method(optim.SGD(learning_rate=conf["learning_rate"],
-                                 momentum=conf["momentum"]))
+    o.set_optim_method(recipe.build(conf))
     o.set_compute_dtype(jnp.dtype(conf["compute_dtype"]))
     if w.get("parameter_sync"):
         o.set_parameter_sync(w["parameter_sync"])
@@ -269,14 +273,13 @@ def run_cell(cell: Dict, seed: int, seconds: float, traced: bool,
     import numpy as np
 
     w, conf = cell["workload"], cell["config"]
-    family = importlib.import_module("benchmark.models." + conf["family"])
+    family, recipe = models.load(conf), optimizers.load(conf)
     compiles = CompileCounter()
 
     # -- set-up: records, weights, model, optimizer -------------------------
     phases = [("imports, backend", time.perf_counter())]
     n_records = w["epoch_batches"] * w["batch"]
-    x, y = reference.make_records(seed, n_records, conf["image"],
-                                  conf["classes"])
+    x, y = models.make_records(family, seed, n_records, conf)
     phases.append(("records", time.perf_counter()))
     from bigdl_tpu.dataset.sample import Sample
     from bigdl_tpu.nn.module import load_state_dict, state_dict
@@ -297,12 +300,13 @@ def run_cell(cell: Dict, seed: int, seconds: float, traced: bool,
     load_state_dict(model, dict(zip(own, weights)), strict=False)
     param_keys = list(own)
     del weights, own
-    o, tap = build_optimizer(cell, family, model, samples, devices)
+    o, tap = build_optimizer(cell, family, recipe, model, samples, devices)
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if traced else None
     if traced:
         seconds = min(seconds, w["trace_seconds"])
-    hooks = Hooks(o, param_keys, seconds, w["check_steps"],
-                  w["warmup_steps"], compiles, trace_dir)
+    hooks = Hooks(o, lambda st: recipe.first_gradient(st, conf), param_keys,
+                  seconds, w["check_steps"], w["warmup_steps"], compiles,
+                  trace_dir)
     o.set_train_summary(hooks)
     o.set_end_when(Trigger(hooks.end))
     phases.append(("weights, optimizer", time.perf_counter()))
@@ -332,18 +336,19 @@ def run_cell(cell: Dict, seed: int, seconds: float, traced: bool,
     del o, hooks, model, samples, tap
     gc.collect()
     t_ref = time.perf_counter()
-    w0 = reference.make_weights(specs, seed, conf["init_gain"])
+    w0 = reference.host_weights(specs, seed, conf["init_gain"])
     got["delta_norms"] = reference.leaf_norms(
-        [a - np.asarray(b) for a, b in zip(got.pop("w_after"), w0)])
+        [a - b for a, b in zip(got.pop("w_after"), w0)])
     want = reference.follow(
-        family, w0, [(x[rows], y[rows]) for rows in seen],
-        conf["learning_rate"], conf["momentum"], devices=devices)
+        family, w0, [(x[rows], y[rows]) for rows in seen], recipe, conf,
+        devices=devices)
     numbers = compare.numbers(got, want)
     verdict = compare.judge(numbers, w["limits"])
     for line in compare.lines(numbers, w["limits"]):
         log(line)
     log(f"reference: {time.perf_counter() - t_ref:.1f} s for "
-        f"{w['check_steps']} steps of {w['batch']} records")
+        f"{w['check_steps']} steps of {w['batch']} records, peak "
+        f"{want['device_peak_bytes'] / 1e9:.1f} GB")
 
     window_ok = (result["failed"] == 0 and result["window_compiles"] == 0
                  and result["window_traces"] == 0)

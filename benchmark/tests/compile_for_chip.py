@@ -17,7 +17,6 @@ chip by ``tests/test_chip_compile.py``); for the four-chip cell, where
 every op takes its XLA leg on the chip too, it is the real program.
 """
 
-import importlib
 import os
 import sys
 
@@ -33,19 +32,17 @@ def compile_cell(name: str):
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    import bigdl_tpu.optim as optim
-    from benchmark import run
+    from benchmark import models, optimizers, run
     from bigdl_tpu.parallel.train_step import TrainStep
 
     cell = run.load_cell(name)
     w, conf = cell["workload"], cell["config"]
-    family = importlib.import_module("benchmark.models." + conf["family"])
+    family, recipe = models.load(conf), optimizers.load(conf)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     mesh = Mesh(np.array(topo.devices[:w["chips"]]), ("data",))
     step = TrainStep(family.build(conf), family.criterion(),
-                     optim.SGD(learning_rate=conf["learning_rate"],
-                               momentum=conf["momentum"]),
+                     recipe.build(conf),
                      compute_dtype=jnp.dtype(conf["compute_dtype"]))
     if w["chips"] > 1:
         step.mesh = mesh  # read by _step_fn for its sharding constraint
@@ -57,11 +54,14 @@ def compile_cell(name: str):
             a.shape, a.dtype, sharding=sharding), tree)
 
     key = jax.eval_shape(lambda: jax.random.key(0))
+    x, y = models.make_records(family, 0, 1, conf)  # one record's shapes
+
+    def batch_of(records):
+        return jax.ShapeDtypeStruct((w["batch"], *records.shape[1:]),
+                                    records.dtype, sharding=rows)
+
     args = (shaped(step.params, rep), shaped(step.opt_state, rep),
-            shaped(step.buffers, rep),
-            jax.ShapeDtypeStruct((w["batch"], *conf["image"]), jnp.float32,
-                                 sharding=rows),
-            jax.ShapeDtypeStruct((w["batch"],), jnp.int32, sharding=rows),
+            shaped(step.buffers, rep), batch_of(x), batch_of(y),
             jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep))
     compiled = jax.jit(step._step_fn(), donate_argnums=(0, 1, 2)).lower(
         *args).compile()

@@ -5,6 +5,7 @@ the comparison only: a time taken here means nothing, and none is
 printed under a device metric's name.
 
     JAX_PLATFORMS=cpu python3 benchmark/tests/rehearse.py tiny_inception.c1 [--trace]
+    JAX_PLATFORMS=cpu python3 benchmark/tests/rehearse.py tiny_lm.c1 tiny_lm_adam.c1
     XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
         python3 benchmark/tests/rehearse.py tiny_inception.c4
 """
@@ -25,9 +26,15 @@ def tiny_cell(name: str):
 
     workload = run.read_json(HERE, "data", name + ".workload.json")
     bench = run.read_json(run.ROOT, "BENCHMARK.json")
-    # the published layers with 10 classes, at the workload's tiny batch
-    config = dict(run.read_json(run.HERE, "configs",
-                                workload["config"] + ".json"), classes=10)
+    # a configuration of the benchmark at the workload's tiny batch, cut
+    # as the workload says (the published layers with 10 classes); or one
+    # that only the tests have, from a file beside the workload
+    if "config_file" in workload:
+        config = run.read_json(HERE, "data", workload["config_file"])
+    else:
+        config = run.read_json(run.HERE, "configs",
+                               workload["config"] + ".json")
+    config.update(workload.get("config_overrides", {}))
     return {"name": name, "chips": workload["chips"], "workload": workload,
             "config": config,
             "end_to_end": bench["end_to_end"],
@@ -38,7 +45,7 @@ def tiny_cell(name: str):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("cell")
+    ap.add_argument("cells", nargs="+")
     ap.add_argument("--seed", type=int, default=2 ** 31 + 12345)
     ap.add_argument("--seconds", type=float, default=3.0)
     ap.add_argument("--trace", action="store_true")
@@ -47,16 +54,19 @@ def main():
 
     from benchmark import run
 
-    cell = tiny_cell(args.cell)
-    out = run.run_cell(cell, args.seed, args.seconds, args.trace,
-                       jax.devices()[:cell["chips"]])
-    print(json.dumps({"correct": out["correct"],
-                      "attempted": out["attempted"],
-                      "failed": out["failed"],
-                      "metric_names": sorted(out["metrics"]),
-                      "device": out["device"]["platform"],
-                      "compared": out["compared"]}))
-    return 0 if out["correct"] else 1
+    ok = True
+    for name in args.cells:
+        cell = tiny_cell(name)
+        out = run.run_cell(cell, args.seed, args.seconds, args.trace,
+                           jax.devices()[:cell["chips"]])
+        print(json.dumps({"cell": name, "correct": out["correct"],
+                          "attempted": out["attempted"],
+                          "failed": out["failed"],
+                          "metric_names": sorted(out["metrics"]),
+                          "device": out["device"]["platform"],
+                          "compared": out["compared"]}))
+        ok = ok and out["correct"]
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

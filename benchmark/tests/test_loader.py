@@ -7,7 +7,7 @@ import os
 
 import pytest
 
-from benchmark import run
+from benchmark import models, optimizers, run
 from benchmark.readers import mfu
 
 BENCH = run.read_json(run.ROOT, "BENCHMARK.json")
@@ -26,9 +26,29 @@ def test_every_configuration_file_exists_and_is_used():
         assert c["file"].startswith(tuple(BENCH["paths"]))
         assert conf["name"] == c["name"] and c["name"] in used
         assert conf["source"] == c["source"]
-        importlib.import_module("benchmark.models." + conf["family"])
         for k in conf.get("pallas_kernels", []):
             importlib.import_module("benchmark.kernels." + k["kernel"])
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_configuration_names_a_family_and_a_recipe(entry):
+    """What a record and an update rule are comes from files the
+    configuration names: a family that says what a record is, or the
+    keys the default records are made from; a recipe with its three
+    parts and the keys it reads."""
+    conf = run.read_json(run.ROOT, entry["file"])
+    family = models.load(conf)
+    for part in ("build", "criterion", "param_specs", "loss_sum"):
+        assert callable(getattr(family, part)), part
+    assert hasattr(family, "BLOCK_ROWS")
+    assert callable(getattr(family, "make_records", None)) or \
+        {"image", "classes"} <= set(conf)
+    assert os.path.isfile(os.path.join(
+        run.HERE, "optimizers", conf["optimizer"] + ".py"))
+    recipe = optimizers.load(conf)
+    for part in ("build", "first_gradient", "update"):
+        assert callable(getattr(recipe, part)), part
+    assert callable(recipe.update(conf))  # every key it reads is there
 
 
 @pytest.mark.parametrize("name", CELLS)

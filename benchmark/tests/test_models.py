@@ -3,12 +3,13 @@ in float32, where the two must agree to rounding: same loss, same
 gradient, leaf by leaf.  Also pins ``flops_per_record`` of each
 configuration file to the derivation in its family module."""
 
-import importlib
 import json
 import os
 
 import numpy as np
 import pytest
+
+from benchmark import models
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
@@ -32,15 +33,15 @@ def test_reference_matches_system_in_float32(name, batch, tol):
     import jax
     import jax.numpy as jnp
 
-    from benchmark import reference
+    from benchmark import optimizers, reference
     from bigdl_tpu.nn.module import functional_call, load_state_dict, \
         state_dict
 
     conf = dict(config(name), classes=10)
-    family = importlib.import_module("benchmark.models." + conf["family"])
+    family = models.load(conf)
     specs = family.param_specs(conf)
     weights = reference.make_weights(specs, 7, conf["init_gain"])
-    x, y = reference.make_records(7, batch, conf["image"], conf["classes"])
+    x, y = models.make_records(family, 7, batch, conf)
     model = family.build(conf)
     own = state_dict(model, kind="param")
     assert [tuple(v.shape) for v in own.values()] == \
@@ -59,7 +60,8 @@ def test_reference_matches_system_in_float32(name, batch, tol):
 
     got_loss, got = jax.value_and_grad(system_loss)(
         dict(zip(keys, weights)))
-    want = reference.follow(family, weights, [(x, y)], 0.01, 0.9)
+    want = reference.follow(family, weights, [(x, y)],
+                            optimizers.load(conf), conf)
     assert abs(float(got_loss) - want["losses"][0]) < 1e-4
     got_norms = reference.leaf_norms([got[k] for k in keys])
     scale = np.maximum(want["grad1_norms"], np.median(want["grad1_norms"]))
@@ -69,7 +71,7 @@ def test_reference_matches_system_in_float32(name, batch, tol):
 @pytest.mark.parametrize("name,batch,tol", FAMILIES)
 def test_flops_per_record_is_the_derivation(name, batch, tol):
     conf = config(name)
-    family = importlib.import_module("benchmark.models." + conf["family"])
+    family = models.load(conf)
     assert family.flops_per_record(conf)["total"] == conf["flops_per_record"]
     assert sum(int(np.prod(s["shape"])) for s in family.param_specs(conf)) \
         == conf["parameters"]
