@@ -15,7 +15,9 @@ from bigdl_tpu.models.inception import (  # noqa: F401
 from bigdl_tpu.models.lenet import build_lenet5  # noqa: F401
 from bigdl_tpu.models.resnet import build_resnet, build_resnet_cifar  # noqa: F401
 from bigdl_tpu.models.rnn import build_lstm_classifier, build_simple_rnn  # noqa: F401
-from bigdl_tpu.models.transformer import build_transformer_lm  # noqa: F401
+from bigdl_tpu.models.transformer import (  # noqa: F401
+    DecoderPlan, LayerPlan, build_decoder_lm, build_transformer_lm,
+    tiny_decoder_plan)
 from bigdl_tpu.models.vgg import (  # noqa: F401
     build_vgg16, build_vgg19, build_vgg_for_cifar10,
 )

@@ -50,10 +50,11 @@ def _build_model(name: str, num_classes: int):
 
 
 #: sequence models take [batch, time] int token ids, not images.
-SEQ_MODELS = ("lstm", "transformer")
 # shared with the analyzer's canonical input specs (models/registry.py)
 from bigdl_tpu.models.registry import (  # noqa: E402
-    LM_SEQ_LEN, LSTM_SEQ_LEN, LSTM_VOCAB)
+    LANGUAGE_MODELS, LM_SEQ_LEN, LSTM_SEQ_LEN, LSTM_VOCAB)
+
+SEQ_MODELS = ("lstm",) + LANGUAGE_MODELS
 
 
 @functools.lru_cache(maxsize=2)
@@ -167,7 +168,7 @@ def cmd_train(args) -> None:
         criterion = nn.MSECriterion()
         val_methods = [optim.Loss(nn.MSECriterion())]
         val_samples = samples[:256]
-    elif args.model == "transformer":
+    elif args.model in LANGUAGE_MODELS:
         samples = [Sample(x[i], y[i]) for i in range(len(x))]
         criterion = nn.TimeDistributedCriterion(nn.ClassNLLCriterion(), size_average=True)
         val_methods = [optim.Loss(
@@ -252,7 +253,7 @@ def cmd_test(args) -> None:
         raise SystemExit("test needs --model-snapshot or --checkpoint")
     x, y = _load_data(args.model, args.folder, "test", args.num_classes)
     samples = [Sample(x[i], y[i]) for i in range(len(x))]
-    if args.model == "transformer":
+    if args.model in LANGUAGE_MODELS:
         methods = [optim.Loss(
             nn.TimeDistributedCriterion(nn.ClassNLLCriterion(), size_average=True))]
     else:
@@ -280,8 +281,9 @@ def cmd_perf(args) -> None:
     from bigdl_tpu.utils.rng import RNG
 
     RNG.set_seed(0)
-    num_classes = args.num_classes or {"lstm": 2, "transformer": 256}.get(
-        args.model, 1000)
+    num_classes = args.num_classes or (
+        256 if args.model in LANGUAGE_MODELS
+        else {"lstm": 2}.get(args.model, 1000))
     model = _build_model(args.model, num_classes)
     rng = np.random.default_rng(0)
     criterion = nn.ClassNLLCriterion()

@@ -12,8 +12,8 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, NamedTuple
 
-__all__ = ["ModelEntry", "MODELS", "model_names", "build_model",
-           "input_spec", "train_pieces"]
+__all__ = ["ModelEntry", "MODELS", "LANGUAGE_MODELS", "model_names",
+           "build_model", "input_spec", "train_pieces"]
 
 
 class ModelEntry(NamedTuple):
@@ -104,6 +104,13 @@ def _transformer(num_classes: int = 0):
     return models.build_transformer_lm(vocab_size=num_classes or 256)
 
 
+def _decoder_lm(num_classes: int = 0):
+    from bigdl_tpu import models
+
+    return models.build_decoder_lm(
+        models.tiny_decoder_plan(num_classes or 256))
+
+
 def _dlrm(num_classes: int = 0):
     from bigdl_tpu import models
 
@@ -124,6 +131,9 @@ MODELS: Dict[str, ModelEntry] = {
     "autoencoder": ModelEntry(_autoencoder, _flat(28 * 28)),
     "lstm": ModelEntry(_lstm, _tokens(LSTM_SEQ_LEN)),
     "transformer": ModelEntry(_transformer, _tokens(LM_SEQ_LEN)),
+    # a current decoder from a per-layer plan (models/transformer.py
+    # build_decoder_lm) at a tiny plan; trains like "transformer"
+    "decoder_lm": ModelEntry(_decoder_lm, _tokens(LM_SEQ_LEN)),
     # recsys ranking (models/dlrm.py): [batch, 13 count + 8 categorical]
     # int32 features -> click log-probs; the sparse-sync proof shape
     "dlrm": ModelEntry(_dlrm, _tokens(DLRM_FEATURES)),
@@ -147,6 +157,10 @@ def input_spec(name: str, batch: int = 2):
                        f"{model_names()}")
     return MODELS[name].spec(batch)
 
+
+#: next-token language models: [batch, seq] ids in, log-probs per position
+#: out, trained with a time-distributed ClassNLL averaged over positions
+LANGUAGE_MODELS = ("transformer", "decoder_lm")
 
 #: models whose output is ClassNLL-compatible (log-probs over classes,
 #: integer labels).  A model in MODELS but not here (and not special-
@@ -174,7 +188,7 @@ def train_pieces(name: str, batch: int = 2):
     if name == "autoencoder":
         return (nn.MSECriterion(),
                 jax.ShapeDtypeStruct((batch, 28 * 28), jnp.float32))
-    if name == "transformer":
+    if name in LANGUAGE_MODELS:
         return (nn.TimeDistributedCriterion(nn.ClassNLLCriterion(),
                                             size_average=True),
                 jax.ShapeDtypeStruct((batch, LM_SEQ_LEN), jnp.int32))

@@ -1,33 +1,42 @@
-"""Transformer language model — the long-context flagship.
+"""Transformer language models.
 
 The reference has no attention or transformer models (SURVEY §5
-"Long-context ... Absent"); this model exists to exercise the
-capabilities the TPU build adds on top of the reference's sequence
-story (RNN/TimeDistributed): the Pallas flash kernel and ring/Ulysses
-sequence parallelism over a mesh ``seq`` axis.
+"Long-context ... Absent"); these exist to exercise what the TPU build
+adds on top of the reference's sequence story (RNN/TimeDistributed).
 
-``build_transformer_lm`` returns a causal decoder LM:
-token embedding + learned positions -> N pre-norm TransformerBlocks ->
-final LayerNorm -> vocab head (log-probs per position, so
+``build_transformer_lm`` returns a small causal decoder LM in the 2017
+style: token embedding + learned positions -> N pre-norm
+TransformerBlocks (LayerNorm, multi-head attention, GELU MLP) -> final
+LayerNorm -> vocab head (log-probs per position, so
 ``TimeDistributedCriterion(ClassNLLCriterion(), size_average=True)``
 trains it — size_average averages the per-step losses; the default sums
-them, scaling the loss by sequence length).
+them, scaling the loss by sequence length).  It is the registry's
+``transformer`` at toy widths, the model the generation server and the
+sequence-parallel dry runs use; ``sp_mesh``/``sp_axis``/``sp_strategy``
+route every block's attention through shard_map'd ring or Ulysses
+attention for sequences larger than one chip holds.
 
-``sp_mesh``/``sp_axis``/``sp_strategy`` route every block's attention
-through shard_map'd ring or Ulysses attention for sequences larger than
-one chip holds.
+``build_decoder_lm`` builds a current decoder from a per-layer plan
+(:class:`DecoderPlan`): RMS normalisation, rotary positions, grouped-
+query attention whose kind (full or window) and head count differ by
+layer, a per-head output gate, and a dense gated or a routed sparse
+feed-forward per layer, every block under ``nn.Remat``.  It trains with
+the same criterion; the benchmark's ``laguna_s_2_1`` configuration is
+such a plan at published widths.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 import bigdl_tpu.nn as nn
 from bigdl_tpu.nn.module import Module, Parameter
 
-__all__ = ["build_transformer_lm", "PositionalEmbedding"]
+__all__ = ["build_transformer_lm", "PositionalEmbedding", "build_decoder_lm",
+           "DecoderPlan", "LayerPlan", "VocabHead", "tiny_decoder_plan"]
 
 
 class PositionalEmbedding(Module):
@@ -92,3 +101,97 @@ def build_transformer_lm(vocab_size: int, num_layers: int = 4,
     from bigdl_tpu.nn.layers.scan import maybe_scan
 
     return maybe_scan(model, scan)
+
+
+class LayerPlan(NamedTuple):
+    """One decoder layer: ``attention`` is ``"full"`` or ``"window"``,
+    ``heads`` its query heads, ``ffn`` ``"dense"`` or ``"sparse"``."""
+    attention: str
+    heads: int
+    ffn: str
+
+
+class DecoderPlan(NamedTuple):
+    """Everything :func:`build_decoder_lm` builds from.  ``rotary_full``
+    / ``rotary_window`` are the :class:`nn.Rotary` of each attention
+    kind; ``held`` the ``(first, count)`` experts a sparse layer has
+    here of its ``n_experts``; ``normalize`` whether the chosen experts'
+    weights are renormalised to sum to one before ``routed_scale``."""
+    vocab_size: int
+    hidden_size: int
+    head_dim: int
+    kv_heads: int
+    layers: Sequence[LayerPlan]
+    window: int
+    rotary_full: Optional[nn.Rotary]
+    rotary_window: Optional[nn.Rotary]
+    dense_width: int
+    expert_width: int = 0
+    shared_width: int = 0
+    n_experts: int = 0
+    top_k: int = 0
+    held: Optional[Tuple[int, int]] = None
+    routed_scale: float = 1.0
+    gate: Optional[str] = "per_head"
+    eps: float = 1e-6
+    normalize: bool = True
+
+
+class VocabHead(Module):
+    """Vocabulary projection (no bias) and log-softmax per position, the
+    log-softmax in float32 whatever the activations' dtype."""
+
+    def __init__(self, embed_dim: int, vocab_size: int):
+        super().__init__()
+        self.proj = nn.Linear(embed_dim, vocab_size, with_bias=False)
+
+    def update_output(self, input):
+        logits = self.proj.forward(input).astype(jnp.float32)
+        return jax.nn.log_softmax(logits, axis=-1)
+
+
+def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
+                     backend: str = "auto") -> nn.Module:
+    """Causal decoder-only LM over [batch, seq] token ids, layer by
+    layer as ``plan`` says; output log-probs [batch, seq, vocab]."""
+    model = nn.Sequential(nn.LookupTable(plan.vocab_size, plan.hidden_size))
+    for layer in plan.layers:
+        windowed = layer.attention == "window"
+        if layer.attention not in ("full", "window"):
+            raise ValueError(f"unknown attention kind {layer.attention!r}")
+        attn = nn.GroupedQueryAttention(
+            plan.hidden_size, layer.heads, plan.kv_heads, plan.head_dim,
+            window=plan.window if windowed else None,
+            rotary=plan.rotary_window if windowed else plan.rotary_full,
+            gate=plan.gate, backend=backend)
+        if layer.ffn == "dense":
+            ffn = nn.GatedMLP(plan.hidden_size, plan.dense_width)
+        elif layer.ffn == "sparse":
+            ffn = nn.RoutedExperts(
+                plan.hidden_size, plan.expert_width, plan.n_experts,
+                plan.top_k, held=plan.held, shared_width=plan.shared_width,
+                routed_scale=plan.routed_scale, normalize=plan.normalize)
+        else:
+            raise ValueError(f"unknown feed-forward kind {layer.ffn!r}")
+        block = nn.DecoderBlock(plan.hidden_size, attn, ffn, eps=plan.eps)
+        model.add(nn.Remat(block) if remat else block)
+    model.add(nn.RMSNorm(plan.hidden_size, plan.eps))
+    model.add(VocabHead(plan.hidden_size, plan.vocab_size))
+    return model
+
+
+def tiny_decoder_plan(vocab_size: int = 256) -> DecoderPlan:
+    """The registry's ``decoder_lm``: every mechanism of the builder at
+    a width a CPU trains (dense then window, window, full layers; 16
+    experts, 4 held, 3 a token)."""
+    return DecoderPlan(
+        vocab_size=vocab_size, hidden_size=64, head_dim=16, kv_heads=2,
+        layers=[LayerPlan("full", 4, "dense"), LayerPlan("window", 6,
+                "sparse"), LayerPlan("window", 6, "sparse"),
+                LayerPlan("full", 4, "sparse")],
+        window=8, rotary_full=nn.Rotary(8, theta=500000.0, factor=4.0,
+                                        original_max_position=32,
+                                        attention_factor=1.1),
+        rotary_window=nn.Rotary(16), dense_width=128, expert_width=32,
+        shared_width=32, n_experts=16, top_k=3, held=(0, 4),
+        routed_scale=2.5)

@@ -11,15 +11,17 @@ from __future__ import annotations
 
 import math
 import sys
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from bigdl_tpu.nn.layers.linear import Linear
 from bigdl_tpu.nn.module import Module, Parameter
 
-__all__ = ["LayerNorm", "MultiHeadAttention", "TransformerBlock"]
+__all__ = ["LayerNorm", "MultiHeadAttention", "TransformerBlock",
+           "Rotary", "GroupedQueryAttention", "DecoderBlock"]
 
 
 def generation_cache_context():
@@ -199,7 +201,7 @@ class MultiHeadAttention(Module):
 
 class TransformerBlock(Module):
     """Pre-norm transformer block: LN -> MHA -> residual, LN -> MLP ->
-    residual.  The building block of the long-context flagship model."""
+    residual.  The building block of ``build_transformer_lm``."""
 
     def __init__(self, embed_dim: int, num_heads: int, mlp_ratio: int = 4,
                  dropout: float = 0.0, causal: bool = True, backend="auto"):
@@ -216,3 +218,182 @@ class TransformerBlock(Module):
         h = self.fc1.forward(self.ln2.forward(x))
         h = jax.nn.gelu(h)
         return x + self.fc2.forward(h)
+
+
+class Rotary(NamedTuple):
+    """Rotary position embedding (Su et al. 2021) of one attention layer.
+
+    ``dims`` leading dimensions of every head are rotated in the
+    rotate-half pairing (dimension i with i + dims/2), the rest pass
+    through (a partial rotary factor).  ``factor`` > 1 selects YaRN (Peng
+    et al. 2023): the inverse frequencies are blended, dimension by
+    dimension, between the plain ones (extrapolation) and the plain ones
+    divided by ``factor`` (interpolation) along the linear ramp between
+    the dimensions that make ``beta_fast`` and ``beta_slow`` rotations
+    over ``original_max_position`` positions, and cos and sin are
+    multiplied by ``attention_factor``."""
+
+    dims: int
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self) -> np.ndarray:
+        half = self.dims // 2
+        plain = self.theta ** (-np.arange(half, dtype=np.float64) / half)
+        if self.factor == 1.0:
+            return plain
+
+        def ramp_dim(rotations):
+            return self.dims * math.log(self.original_max_position / (
+                rotations * 2 * math.pi)) / (2 * math.log(self.theta))
+
+        low = max(math.floor(ramp_dim(self.beta_fast)), 0)
+        high = min(math.ceil(ramp_dim(self.beta_slow)), self.dims - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+        return plain / self.factor * ramp + plain * (1.0 - ramp)
+
+    def tables(self, positions: int):
+        """``(cos, sin)``, each ``[positions, dims / 2]`` float32."""
+        angle = np.arange(positions, dtype=np.float64)[:, None] \
+            * self.inv_freq()[None, :]
+        return ((np.cos(angle) * self.attention_factor).astype(np.float32),
+                (np.sin(angle) * self.attention_factor).astype(np.float32))
+
+    def apply(self, x):
+        """Rotate ``x`` [B, S, heads, head_dim] at positions 0..S-1, in
+        float32, and hand it back in its own dtype."""
+        cos, sin = (jnp.asarray(t)[None, :, None, :]
+                    for t in self.tables(x.shape[1]))
+        half = self.dims // 2
+        x32 = x.astype(jnp.float32)
+        a, b, rest = (x32[..., :half], x32[..., half:self.dims],
+                      x32[..., self.dims:])
+        out = jnp.concatenate(
+            [a * cos - b * sin, b * cos + a * sin, rest], axis=-1)
+        return out.astype(x.dtype)
+
+
+class GroupedQueryAttention(Module):
+    """Causal self-attention over [batch, seq, embed] with fewer key/
+    value heads than query heads (Ainslie et al. 2023): query head h
+    reads kv head ``h // (num_heads / num_kv_heads)``.  ``head_dim`` is
+    free of ``embed_dim`` (the output projection maps ``num_heads *
+    head_dim`` back), no projection has a bias.
+
+    ``window``: None attends every earlier position; an integer keeps
+    the last ``window`` of them (the position itself included).
+    ``rotary``: a :class:`Rotary` applied to q and k, or None.
+    ``gate="per_head"``: one sigmoid gate a head and position, computed
+    from the layer's input, scales that head's output before the output
+    projection.
+
+    ``backend``: ``auto`` (the rule of ``ops.attention.
+    select_attention_backend``: the Pallas flash kernels on a TPU from
+    ``flash_min_seq()`` positions up, which bound their key-block loop
+    by the window and find kv heads by index; XLA's dense attention
+    elsewhere), ``dense`` or ``flash``.  The decision is announced on a
+    ``kernel/dispatch`` instant with ``window``, ``q_heads``,
+    ``kv_heads`` and, for the flash leg, ``blocks_visited`` of
+    ``blocks_total``.  No KV cache: this layer trains and scores; the
+    generation path still runs ``MultiHeadAttention``."""
+
+    def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, window: Optional[int] = None,
+                 rotary: Optional[Rotary] = None,
+                 gate: Optional[str] = None, backend: str = "auto"):
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads over "
+                             f"{num_kv_heads} kv heads")
+        if gate not in (None, "per_head"):
+            raise ValueError(f"unknown gate {gate!r}")
+        self.embed_dim, self.head_dim = embed_dim, head_dim
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.window, self.rotary, self.gate = window, rotary, gate
+        self.backend = backend
+        self.q_proj = Linear(embed_dim, num_heads * head_dim, with_bias=False)
+        self.k_proj = Linear(embed_dim, num_kv_heads * head_dim,
+                             with_bias=False)
+        self.v_proj = Linear(embed_dim, num_kv_heads * head_dim,
+                             with_bias=False)
+        if gate:
+            self.gate_proj = Linear(embed_dim, num_heads, with_bias=False)
+        self.out_proj = Linear(num_heads * head_dim, embed_dim,
+                               with_bias=False)
+
+    def _attend(self, q, k, v):
+        from bigdl_tpu.ops.attention import (dot_product_attention,
+                                             flash_attention, flash_blocks,
+                                             select_attention_backend)
+        from bigdl_tpu.ops.dispatch import note
+
+        s = q.shape[2]
+        backend, reason = self.backend, "layer:backend"
+        if backend == "auto":
+            backend, reason = select_attention_backend(s, s)
+        facts = dict(window=self.window, q_heads=self.num_heads,
+                     kv_heads=self.num_kv_heads)
+        if backend == "flash":
+            bq, bk, visited, total = flash_blocks(s, s, True, self.window)
+            facts.update(block_q=bq, block_k=bk, blocks_visited=visited,
+                         blocks_total=total)
+        note("attention", "pallas" if backend == "flash" else "xla", reason,
+             **facts)
+        attend = flash_attention if backend == "flash" \
+            else dot_product_attention
+        return attend(q, k, v, causal=True, window=self.window)
+
+    def update_output(self, input):
+        b, s, _ = input.shape
+        h, g, d = self.num_heads, self.num_kv_heads, self.head_dim
+        # one GEMM for every projection of the same input, as
+        # MultiHeadAttention does; the parameters stay separate
+        ws = [self.q_proj.weight, self.k_proj.weight, self.v_proj.weight]
+        if self.gate:
+            ws.append(self.gate_proj.weight)
+        fused = jnp.dot(input, jnp.concatenate(ws, axis=0).T.astype(
+            input.dtype))
+        q, k, v, *gate = jnp.split(
+            fused, np.cumsum([w.shape[0] for w in ws])[:-1].tolist(), axis=-1)
+        q = q.reshape(b, s, h, d)
+        k = k.reshape(b, s, g, d)
+        if self.rotary is not None:
+            q, k = self.rotary.apply(q), self.rotary.apply(k)
+        out = self._attend(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                           v.reshape(b, s, g, d).transpose(0, 2, 1, 3))
+        out = out.transpose(0, 2, 1, 3)          # [B, S, H, D]
+        if gate:
+            out = out * jax.nn.sigmoid(
+                gate[0].astype(jnp.float32)).astype(out.dtype)[..., None]
+        return self.out_proj.forward(out.reshape(b, s, h * d))
+
+    def __repr__(self):
+        return (f"GroupedQueryAttention({self.embed_dim}, heads="
+                f"{self.num_heads}/{self.num_kv_heads}, window={self.window})")
+
+
+class DecoderBlock(Module):
+    """Pre-norm decoder block with RMS normalisation: ``h = x +
+    attn(norm1(x))``, ``y = h + ffn(norm2(h))``.  ``attn`` and ``ffn`` are
+    modules over [batch, seq, embed] (a :class:`GroupedQueryAttention`; a
+    ``GatedMLP`` or a ``RoutedExperts``)."""
+
+    def __init__(self, embed_dim: int, attn: Module, ffn: Module,
+                 eps: float = 1e-6):
+        super().__init__()
+        from bigdl_tpu.nn.layers.normalization import RMSNorm
+
+        self.norm1 = RMSNorm(embed_dim, eps)
+        self.attn = attn
+        self.norm2 = RMSNorm(embed_dim, eps)
+        self.ffn = ffn
+
+    def update_output(self, input):
+        h = input + self.attn.forward(self.norm1.forward(input))
+        return h + self.ffn.forward(self.norm2.forward(h))

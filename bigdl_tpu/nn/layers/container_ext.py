@@ -85,9 +85,22 @@ class Remat(Container):
     def update_output(self, input):
         import jax
 
+        from bigdl_tpu.nn.module import load_state_dict, state_dict
+
         inner = self.layers[0]
-        fn = jax.checkpoint(lambda v: inner.forward(v), policy=self._policy)
-        return fn(input)
+        names = list(state_dict(inner, kind="buffer"))
+
+        def run(v):
+            # buffers a layer advances inside (running statistics, a
+            # routed layer's load) leave the checkpoint as outputs: kept
+            # on the module they would be tracers of the inner trace
+            out = inner.forward(v)
+            after = state_dict(inner, kind="buffer")
+            return out, [after[n] for n in names]
+
+        out, buffers = jax.checkpoint(run, policy=self._policy)(input)
+        load_state_dict(inner, dict(zip(names, buffers)), strict=False)
+        return out
 
 
 class TimeDistributed(Container):

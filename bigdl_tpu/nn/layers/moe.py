@@ -1,24 +1,32 @@
-"""Mixture-of-Experts with expert parallelism over the ``expert`` mesh
-axis.
+"""Mixture-of-experts layers.  Two stand here, with different jobs:
+
+- :class:`RoutedExperts` — what a current decoder's sparse feed-forward
+  is, as ONE chip of an expert-parallel deployment computes it: a
+  float32 softmax router over all ``n_experts``, the ``top_k`` largest
+  per token with renormalised weights, gated-SiLU experts of which this
+  layer HOLDS a contiguous share (``held=(first, count)``), an optional
+  shared expert, and no token ever dropped.  It computes its own
+  experts' part of the result and nothing that stands in for the other
+  chips or their exchange.  This is the layer that runs on a chip (the
+  benchmark's ``laguna_s_2_1`` cell).
+- :class:`MixtureOfExperts` — the GShard/Mesh-TensorFlow DENSE dispatch
+  (one-hot capacity-bucketed einsums in float32, ReLU experts, tokens
+  over capacity zeroed) whose stacked parameters shard over a mesh
+  ``expert`` axis so that XLA lowers dispatch and combine to
+  all-to-alls.  It has run only in the CPU dry run
+  (``__graft_entry__.dryrun_multichip``, ``tests/test_pipeline_moe.py``)
+  and never on a chip.
 
 The reference's nearest relative is the local gating container
 ``MixtureTable`` (``nn/MixtureTable.scala``): gate weights blend expert
-outputs on one machine.  This layer is the scaled TPU-first design: a
-learned router dispatches tokens to E feed-forward experts whose stacked
-parameters shard over the ``expert`` axis — the Mesh-TensorFlow /
-GShard-style DENSE dispatch (one-hot capacity-bucketed einsums) that XLA
-lowers to all-to-all collectives when tokens are data-sharded and experts
-expert-sharded.  No sparse scatter: static shapes keep the MXU fed.
-
-Routing: top-k gating with a per-expert capacity
-``C = ceil(top_k * tokens / E * capacity_factor)``; tokens over capacity
-are dropped (their combine weight is zero), the standard GShard policy.
+outputs on one machine.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +36,8 @@ from bigdl_tpu.nn.module import Module, Parameter
 from bigdl_tpu.nn.init import Xavier
 from bigdl_tpu.utils.rng import next_rng_id, require_rng
 
-__all__ = ["MixtureOfExperts", "expert_sharding_rules"]
+__all__ = ["MixtureOfExperts", "expert_sharding_rules", "GatedMLP",
+           "RoutedExperts"]
 
 
 def expert_sharding_rules(axis: str = "expert"):
@@ -148,3 +157,201 @@ class MixtureOfExperts(Module):
         frac_tokens = jnp.mean(jax.nn.one_hot(top1, self.n_experts), axis=0)
         frac_gates = jnp.mean(gates, axis=0)
         return self.n_experts * jnp.sum(frac_tokens * frac_gates)
+
+
+class GatedMLP(Module):
+    """Gated feed-forward (Shazeer 2020, the SiLU form): ``(silu(x W_gate)
+    * (x W_up)) W_down``, no bias."""
+
+    def __init__(self, d_model: int, width: int):
+        super().__init__()
+        from bigdl_tpu.nn.layers.linear import Linear
+
+        self.d_model, self.width = d_model, width
+        self.gate_proj = Linear(d_model, width, with_bias=False)
+        self.up_proj = Linear(d_model, width, with_bias=False)
+        self.down_proj = Linear(width, d_model, with_bias=False)
+
+    def update_output(self, input):
+        return self.down_proj.forward(
+            jax.nn.silu(self.gate_proj.forward(input))
+            * self.up_proj.forward(input))
+
+    def __repr__(self):
+        return f"GatedMLP({self.d_model}, {self.width})"
+
+
+class RoutedExperts(Module):
+    """Dropless top-k routing over the experts held here.
+
+    Input [..., d_model], output the same shape.  Router: ``p =
+    softmax(x W_r)`` over all ``n_experts`` in float32; the ``top_k``
+    largest; ``w_e = routed_scale * p_e / sum of the chosen p``
+    (``normalize``).  Result: ``shared(x) + sum over the chosen experts
+    that are HELD here of w_e * expert_e(x)``; every expert and the
+    shared expert is a gated-SiLU feed-forward.  ``held=(first, count)``
+    names the contiguous experts this layer has parameters for (default:
+    all); what the others would add is left out, as it is on one chip
+    of an expert-parallel deployment before the combine.
+
+    Static shapes, no dropped token.  The assignments that land here
+    are sorted by expert and the experts run as ONE grouped matrix
+    product (``lax.ragged_dot``) over the first ``capacity`` rows of
+    that order, so the work follows the load and not ``count`` experts x
+    every token.  ``capacity`` is ``CAPACITY_FACTOR`` times the expected
+    load (rounded up to 8 rows, never above the worst case); a step with
+    more assignments than that takes the exact path: every held expert
+    over every token under a dense mask, the same sum.  Either way the
+    result is the reference's for ANY routing.  (The factor is generous
+    because a router trained on a share of the experts learns to prefer
+    the ones that are held, since only they lower the loss: in the
+    benchmark's cell the load of a layer tripled within 60 steps.)
+
+    ``held_load`` (a buffer, so it rides the step's state and costs no
+    sync): rows each held expert received in the last forward, then the
+    rows that took the exact path.  A ``moe/route`` instant at trace
+    time says how the layer was built."""
+
+    #: the fast path's rows over the expected load
+    CAPACITY_FACTOR = 4.0
+
+    def __init__(self, d_model: int, width: int, n_experts: int, top_k: int,
+                 held: Optional[Tuple[int, int]] = None,
+                 shared_width: Optional[int] = None,
+                 routed_scale: float = 1.0, normalize: bool = True):
+        super().__init__()
+        from bigdl_tpu.nn.init import RandomUniform
+        from bigdl_tpu.nn.layers.linear import Linear
+
+        first, count = held if held is not None else (0, n_experts)
+        if not (0 <= first and first + count <= n_experts and count > 0):
+            raise ValueError(f"held={held} outside {n_experts} experts")
+        self.d_model, self.width = d_model, width
+        self.n_experts, self.top_k = n_experts, top_k
+        self.first, self.count = first, count
+        self.routed_scale, self.normalize = routed_scale, normalize
+        init = RandomUniform()
+        self.experts_gate = Parameter(init.init(
+            (count, d_model, width), fan_in=d_model))
+        self.experts_up = Parameter(init.init(
+            (count, d_model, width), fan_in=d_model))
+        self.experts_down = Parameter(init.init(
+            (count, width, d_model), fan_in=width))
+        self.router = Linear(d_model, n_experts, with_bias=False)
+        if shared_width:
+            self.shared = GatedMLP(d_model, shared_width)
+        self.shared_width = shared_width
+        self.register_buffer("held_load", jnp.zeros((count + 1,), jnp.int32))
+
+    def capacity(self, n_tokens: int) -> int:
+        """Rows of the grouped product: the fast path's static size."""
+        worst = n_tokens * min(self.top_k, self.count)
+        expected = n_tokens * self.top_k * self.count / self.n_experts
+        rows = int(math.ceil(self.CAPACITY_FACTOR * expected / 8.0)) * 8
+        return max(8, min(worst, rows))
+
+    def route(self, x2):
+        """x2 [T, D] -> (weights [T, k] float32, experts [T, k] int32)."""
+        logits = jnp.dot(x2.astype(jnp.float32),
+                         self.router.weight.T.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     self.top_k)
+        if self.normalize:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        return self.routed_scale * top_p, top_i
+
+    def _grouped(self, x2, w, local, counts, cap):
+        """The fast path: ``cap`` rows sorted by held expert."""
+        k = self.top_k
+        key = local.reshape(-1)
+        order = jnp.argsort(key, stable=True)[:cap]
+        token = order // k
+        live = key[order] < self.count
+        w_row = jnp.where(live, w.reshape(-1)[order], 0.0)
+        rows = x2[token]
+        sizes = counts[:self.count]
+
+        def product(a, p):
+            # rows past the last group belong to no expert, and what a
+            # grouped product (or its transpose, in the backward pass)
+            # leaves there is unspecified: on a TPU, whatever the memory
+            # held.  Masked on the way in, their cotangent is zero; masked
+            # on the way out, their value is
+            a = jnp.where(live[:, None], a, 0)
+            return jnp.where(live[:, None], jax.lax.ragged_dot(
+                a, p.astype(a.dtype), sizes), 0)
+
+        h = jax.nn.silu(product(rows, self.experts_gate)) \
+            * product(rows, self.experts_up)
+        out = product(h, self.experts_down).astype(jnp.float32) \
+            * w_row[:, None]
+        return jnp.zeros((x2.shape[0], self.d_model), jnp.float32).at[
+            token].add(out)
+
+    def _masked(self, x2, w, local, counts=None):
+        """The exact path: each held expert over every token."""
+        hit = local[:, :, None] == jnp.arange(self.count)[None, None, :]
+        w_dense = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)  # [T,C]
+
+        def one(y, e):
+            wg, wu, wd, we = e
+            h = jax.nn.silu(x2 @ wg.astype(x2.dtype)) \
+                * (x2 @ wu.astype(x2.dtype))
+            out = (h @ wd.astype(x2.dtype)).astype(jnp.float32)
+            return y + we[:, None] * out, None
+
+        y, _ = jax.lax.scan(
+            one, jnp.zeros((x2.shape[0], self.d_model), jnp.float32),
+            (self.experts_gate, self.experts_up, self.experts_down,
+             w_dense.T))
+        return y
+
+    def update_output(self, input):
+        from bigdl_tpu import telemetry
+
+        x2 = input.reshape(-1, self.d_model)
+        t = x2.shape[0]
+        cap = self.capacity(t)
+        worst = t * min(self.top_k, self.count)
+        telemetry.instant("moe/route", experts=self.n_experts,
+                          held_first=self.first, held=self.count,
+                          top_k=self.top_k, tokens=t, capacity=cap,
+                          worst=worst)
+        w, experts = self.route(x2)
+        local = experts - self.first
+        # an assignment to an expert that is not held sorts last
+        local = jnp.where((local >= 0) & (local < self.count), local,
+                          self.count)
+        counts = jnp.sum(
+            local.reshape(-1, 1) == jnp.arange(self.count + 1)[None, :],
+            axis=0, dtype=jnp.int32)
+        n_held = t * self.top_k - counts[self.count]
+        grouped = functools.partial(self._grouped, cap=cap)
+        if cap >= worst:
+            y = grouped(x2, w, local, counts)
+        else:
+            y = jax.lax.cond(n_held <= cap, grouped, self._masked,
+                             x2, w, local, counts)
+        spilled = jnp.where(n_held > cap, n_held, 0)
+        self.held_load = jax.lax.stop_gradient(jnp.concatenate(
+            [counts[:self.count], spilled[None]]))
+        y = y.astype(input.dtype)
+        if self.shared_width:
+            y = y + self.shared.forward(x2)
+        return y.reshape(input.shape)
+
+    def step_counters(self, buffers, tele, layer: str):
+        """``moe/load`` per held expert and ``moe/exact_rows`` of the last
+        step, from this layer's buffers as the step left them (the
+        Optimizer calls this where it has the loss on the host)."""
+        load = np.asarray(buffers["held_load"])
+        for i, rows in enumerate(load[:-1]):
+            tele.counter("moe/load", int(rows), layer=layer,
+                         expert=self.first + i)
+        tele.counter("moe/exact_rows", int(load[-1]), layer=layer)
+
+    def __repr__(self):
+        return (f"RoutedExperts({self.d_model}, {self.width}, experts="
+                f"{self.first}..{self.first + self.count - 1} of "
+                f"{self.n_experts}, top_k={self.top_k})")

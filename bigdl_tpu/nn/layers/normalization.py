@@ -27,7 +27,7 @@ __all__ = [
     "BatchNormalization", "SpatialBatchNormalization", "SpatialCrossMapLRN",
     "SpatialWithinChannelLRN", "SpatialContrastiveNormalization",
     "SpatialDivisiveNormalization", "SpatialSubtractiveNormalization",
-    "Normalize", "Dropout", "L1Penalty",
+    "Normalize", "Dropout", "L1Penalty", "RMSNorm",
 ]
 
 
@@ -373,3 +373,24 @@ class L1Penalty(Module):
 
         penalty.defvjp(fwd, bwd)
         return penalty(input)
+
+
+class RMSNorm(Module):
+    """Root-mean-square normalisation over the last dimension (Zhang &
+    Sennrich 2019): ``w * x / sqrt(mean(x^2) + eps)``, no centring and
+    no bias.  The statistics are taken in float32 whatever the
+    activations' dtype; the result comes back in it."""
+
+    def __init__(self, normalized_size: int, eps: float = 1e-6):
+        super().__init__()
+        self.normalized_size = normalized_size
+        self.eps = eps
+        self.weight = Parameter(jnp.ones((normalized_size,), jnp.float32))
+
+    def update_output(self, input):
+        x = input.astype(jnp.float32)
+        y = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps)
+        return (y * self.weight.astype(jnp.float32)).astype(input.dtype)
+
+    def __repr__(self):
+        return f"RMSNorm({self.normalized_size})"

@@ -21,7 +21,7 @@ from __future__ import annotations
 import functools
 import math
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +30,7 @@ from jax import lax
 __all__ = [
     "dot_product_attention",
     "flash_attention",
+    "flash_blocks",
     "flash_min_seq",
     "is_tpu_device",
     "select_attention_backend",
@@ -46,18 +47,37 @@ _NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 def dot_product_attention(q, k, v, mask=None, causal: bool = False,
-                          scale: Optional[float] = None):
+                          scale: Optional[float] = None,
+                          window: Optional[int] = None):
     """Dense softmax(q k^T / sqrt(d)) v.  mask: broadcastable to
-    [B, H, Sq, Sk], True = attend."""
-    d = q.shape[-1]
+    [B, H, Sq, Sk], True = attend.  ``window`` (with ``causal``) keeps,
+    for query position i, the keys j with ``i - j < window``.  ``k``/``v``
+    may carry fewer heads than ``q`` (grouped-query attention): query
+    head h reads kv head ``h // (H / G)``, found by a reshape of ``q``,
+    never by repeating ``k``."""
+    if window is not None and not causal:
+        raise ValueError("window attention is causal: pass causal=True")
+    b, h, sq, d = q.shape
+    g, sk = k.shape[1], k.shape[2]
+    if h % g:
+        raise ValueError(f"{h} query heads over {g} kv heads")
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
+    grouped = g != h
+    if grouped:  # [B, G, H/G, Sq, D] against [B, G, Sk, D]
+        q = q.reshape(b, g, h // g, sq, d)
+        if mask is not None:
+            mask = jnp.broadcast_to(mask, (b, h, sq, sk)).reshape(
+                b, g, h // g, sq, sk)
+    qk, pv = (("bgrqd,bgkd->bgrqk", "bgrqk,bgkd->bgrqd") if grouped
+              else ("bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"))
+    s = jnp.einsum(qk, q, k, preferred_element_type=jnp.float32) * scale
     if causal:
-        sq, sk = s.shape[-2], s.shape[-1]
         q_pos = lax.broadcasted_iota(jnp.int32, (sq, sk), 0) + (sk - sq)
         k_pos = lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        keep = q_pos >= k_pos
+        if window is not None:
+            keep = keep & (q_pos - k_pos < window)
+        s = jnp.where(keep, s, _NEG_INF)
     if mask is not None:
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
@@ -65,7 +85,8 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     # instead of softmax's uniform distribution over masked positions
     valid = jnp.max(s, axis=-1, keepdims=True) > _NEG_INF / 2
     p = jnp.where(valid, p, 0.0)
-    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+    out = jnp.einsum(pv, p.astype(v.dtype), v)
+    return out.reshape(b, h, sq, d)
 
 
 # ---------------------------------------------------------------------------
@@ -188,13 +209,24 @@ def flash_auto(sq: int, sk: int, masked: bool = False) -> bool:
     return select_attention_backend(sq, sk, masked)[0] == "flash"
 
 
-# Grid layout: (batch*heads, q_blocks, k_blocks) for fwd/dq and
-# (batch*heads, k_blocks, q_blocks) for dkv.  The innermost grid dimension
-# iterates sequentially on-core, so only one (block, d) tile of each
-# operand is VMEM-resident at a time (k/v stream from HBM block-by-block)
-# while the running online-softmax state lives in VMEM scratch — this is
-# what keeps the kernel O(block) in VMEM at arbitrary sequence length.
-# m/l scratch is broadcast over 128 lanes to satisfy TPU tiling.
+# Grid layout: (batch*q_heads, q_blocks, k_blocks VISITED) for fwd/dq and
+# (batch*kv_heads, k_blocks, group, q_blocks VISITED) for dkv.  The
+# innermost grid dimensions iterate sequentially on-core, so only one
+# (block, d) tile of each operand is VMEM-resident at a time (k/v stream
+# from HBM block-by-block) while the running online-softmax state lives
+# in VMEM scratch — this is what keeps the kernel O(block) in VMEM at
+# arbitrary sequence length.  m/l scratch is broadcast over 128 lanes to
+# satisfy TPU tiling.
+#
+# Which blocks are visited: causality bounds a query block's key blocks
+# from above, a window bounds them from below, so the inner grid
+# dimension is the LARGEST count any query block needs (``_visits``) and
+# the block index map adds the first needed block (``_k_bounds``); a
+# step past a query block's last needed block re-reads that last block
+# (no new DMA) and computes nothing.  Grouped-query attention: k/v keep
+# their G heads in HBM and the index map sends query head h to kv head
+# ``h // (H / G)``; the dk/dv kernel walks a kv head's H / G query heads
+# in its third grid dimension and sums them in scratch.
 
 _LANES = 128
 
@@ -204,53 +236,167 @@ def _causal_offset(q_len, kv_len):
     return kv_len - q_len
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_s, m_s, l_s, *,
-                scale: float, causal: bool, q_len: int, kv_len: int):
+def _mx(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
+def _mn(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+class _Geom(NamedTuple):
+    """The static facts every kernel and index map shares."""
+    q_len: int
+    kv_len: int
+    bq: int
+    bk: int
+    causal: bool
+    window: Optional[int]
+    heads: int      # query heads
+    kv_heads: int
+
+    @property
+    def off(self):
+        return _causal_offset(self.q_len, self.kv_len)
+
+    @property
+    def nq(self):
+        return self.q_len // self.bq
+
+    @property
+    def nk(self):
+        return self.kv_len // self.bk
+
+    @property
+    def group(self):
+        return self.heads // self.kv_heads
+
+    def k_bounds(self, qi):
+        """First and last key block the rows of query block ``qi`` need
+        (``qi`` an int or a traced program id)."""
+        q_start = qi * self.bq
+        lo, hi = 0, self.nk - 1
+        if self.causal:
+            hi = _mn(hi, _mx(q_start + self.off + self.bq - 1, 0) // self.bk)
+        if self.window is not None:
+            lo = _mx(q_start + self.off - (self.window - 1), 0) // self.bk
+            lo = _mn(lo, hi)
+        return lo, hi
+
+    def q_bounds(self, ki):
+        """First and last query block that needs key block ``ki``."""
+        k_start = ki * self.bk
+        lo, hi = 0, self.nq - 1
+        if self.causal:
+            lo = _mn(_mx(k_start - self.off, 0) // self.bq, hi)
+        if self.window is not None:
+            hi = _mn(hi, _mx(k_start + self.bk - 2 + self.window - self.off,
+                             0) // self.bq)
+            hi = _mx(hi, lo)
+        return lo, hi
+
+    def k_visits(self):
+        """(inner grid extent, blocks visited, blocks in all) of fwd/dq."""
+        spans = [self.k_bounds(qi) for qi in range(self.nq)]
+        counts = [hi - lo + 1 for lo, hi in spans]
+        return max(counts), sum(counts), self.nq * self.nk
+
+    def q_visits(self):
+        spans = [self.q_bounds(ki) for ki in range(self.nk)]
+        return max(hi - lo + 1 for lo, hi in spans)
+
+    def keep(self, q_start, k_start):
+        """[bq, bk] element mask of one block pair, None when no rule
+        masks anything."""
+        if not self.causal:
+            return None
+        q_pos = q_start + self.off + lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 0)
+        k_pos = k_start + lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 1)
+        keep = q_pos >= k_pos
+        if self.window is not None:
+            keep = keep & (q_pos - k_pos < self.window)
+        return keep
+
+    def interior(self, q_start, k_start):
+        """True where every pair of the block attends, so the element
+        mask can be skipped (most blocks of a long causal sequence)."""
+        if not self.causal:
+            return True
+        inside = k_start + self.bk - 1 <= q_start + self.off
+        if self.window is not None:
+            inside = inside & (q_start + self.off + self.bq - 1 - k_start
+                               < self.window)
+        return inside
+
+    def kv_head(self, bh):
+        """Row of the flattened [B*G, S, D] k/v that flattened query
+        head ``bh`` of [B*H, S, D] reads."""
+        if self.group == 1:
+            return bh
+        return (bh // self.heads) * self.kv_heads \
+            + (bh % self.heads) // self.group
+
+    def q_head(self, bg, r):
+        """Row of [B*H, S, D] of the ``r``-th query head of kv head ``bg``."""
+        if self.group == 1:
+            return bg
+        return (bg // self.kv_heads) * self.heads \
+            + (bg % self.kv_heads) * self.group + r
+
+
+def _masked_and_plain(live, interior, step):
+    """Run ``step(masked)`` under ``live``: with the element mask on the
+    blocks an edge crosses, without it inside."""
     from jax.experimental import pallas as pl
 
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    block_k = k_ref.shape[1]
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-    q_start = qi * block_q
-    k_start = ki * block_k
-    off = _causal_offset(q_len, kv_len)
+    if interior is True:
+        pl.when(live)(lambda: step(False))
+        return
+    pl.when(live & interior)(lambda: step(False))
+    pl.when(live & jnp.logical_not(interior))(lambda: step(True))
 
-    @pl.when(ki == 0)
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                acc_s, m_s, l_s, *, scale: float, geom: _Geom):
+    from jax.experimental import pallas as pl
+
+    qi, j = pl.program_id(1), pl.program_id(2)
+    nj = pl.num_programs(2)
+    lo, hi = geom.k_bounds(qi)
+    ki = lo + j
+    q_start, k_start = qi * geom.bq, ki * geom.bk
+
+    @pl.when(j == 0)
     def _init():
         acc_s[...] = jnp.zeros_like(acc_s)
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
-    live = True
-    if causal:
-        live = q_start + off + block_q - 1 >= k_start
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
+    def _step(masked):
+        q, k_blk, v_blk = q_ref[0], k_ref[0], v_ref[0]
         s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_start + off + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        if masked:
+            s = jnp.where(geom.keep(q_start, k_start), s, _NEG_INF)
         m_prev = m_s[:, 0]
         l_prev = l_s[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.exp(s - m_new[:, None])
+        if masked:
+            p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         m_s[...] = jnp.broadcast_to(m_new[:, None], m_s.shape)
         l_s[...] = jnp.broadcast_to(
             (l_prev * alpha + jnp.sum(p, axis=-1))[:, None], l_s.shape)
         acc_s[...] = acc_s[...] * alpha[:, None] + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32)
+            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    _masked_and_plain(ki <= hi, geom.interior(q_start, k_start), _step)
+
+    @pl.when(j == nj - 1)
     def _finish():
         l = l_s[:, 0]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -259,97 +405,77 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_s, *, scale: float, causal: bool, q_len: int, kv_len: int):
+               dq_s, *, scale: float, geom: _Geom):
     from jax.experimental import pallas as pl
 
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    block_k = k_ref.shape[1]
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-    q_start = qi * block_q
-    k_start = ki * block_k
-    off = _causal_offset(q_len, kv_len)
+    qi, j = pl.program_id(1), pl.program_id(2)
+    nj = pl.num_programs(2)
+    lo, hi = geom.k_bounds(qi)
+    ki = lo + j
+    q_start, k_start = qi * geom.bq, ki * geom.bk
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
-    live = True
-    if causal:
-        live = q_start + off + block_q - 1 >= k_start
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
+    def _step(masked):
+        q, do = q_ref[0], do_ref[0]
         lse = lse_ref[0, :, 0]
         delta = delta_ref[0, :, 0]
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
+        k_blk, v_blk = k_ref[0], v_ref[0]
         s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_start + off + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.exp(s - lse[:, None])
+        if masked:
+            p = jnp.where(geom.keep(q_start, k_start), p, 0.0)
         dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * scale
         dq_s[...] = dq_s[...] + jnp.dot(
-            ds, k_blk, preferred_element_type=jnp.float32)
+            ds.astype(k_blk.dtype), k_blk,
+            preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    _masked_and_plain(ki <= hi, geom.interior(q_start, k_start), _step)
+
+    @pl.when(j == nj - 1)
     def _finish():
         dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_s, dv_s, *,
-                scale: float, causal: bool, q_len: int, kv_len: int):
+                dk_ref, dv_ref, dk_s, dv_s, *, scale: float, geom: _Geom):
     from jax.experimental import pallas as pl
 
-    block_k, d = k_ref.shape[1], k_ref.shape[2]
-    block_q = q_ref.shape[1]
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
-    q_start = qi * block_q
-    k_start = ki * block_k
-    off = _causal_offset(q_len, kv_len)
+    ki, r, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    nr, nj = pl.num_programs(2), pl.num_programs(3)
+    lo, hi = geom.q_bounds(ki)
+    qi = lo + j
+    q_start, k_start = qi * geom.bq, ki * geom.bk
 
-    @pl.when(qi == 0)
+    @pl.when((r == 0) & (j == 0))
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    live = True
-    if causal:
-        live = q_start + off + block_q - 1 >= k_start
-
-    @pl.when(live)
-    def _step():
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        q_blk = q_ref[0].astype(jnp.float32)
-        do_blk = do_ref[0].astype(jnp.float32)
+    def _step(masked):
+        k, v = k_ref[0], v_ref[0]
+        q_blk, do_blk = q_ref[0], do_ref[0]
         lse_blk = lse_ref[0, :, 0]
         delta_blk = delta_ref[0, :, 0]
         s = jnp.dot(q_blk, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_start + off + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_blk[:, None]), 0.0)
+        p = jnp.exp(s - lse_blk[:, None])
+        if masked:
+            p = jnp.where(geom.keep(q_start, k_start), p, 0.0)
         dv_s[...] = dv_s[...] + jnp.dot(
-            p.T, do_blk, preferred_element_type=jnp.float32)
+            p.T.astype(do_blk.dtype), do_blk,
+            preferred_element_type=jnp.float32)
         dp = jnp.dot(do_blk, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta_blk[:, None]) * scale
         dk_s[...] = dk_s[...] + jnp.dot(
-            ds.T, q_blk, preferred_element_type=jnp.float32)
+            ds.T.astype(q_blk.dtype), q_blk,
+            preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    _masked_and_plain(qi <= hi, geom.interior(q_start, k_start), _step)
+
+    @pl.when((r == nr - 1) & (j == nj - 1))
     def _finish():
         dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
@@ -364,31 +490,43 @@ def _pick_block(s: int, pref: int) -> int:
     return max(b, 1)
 
 
-def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret):
+def _geometry(q, k, causal, window, block_q, block_k) -> _Geom:
+    sq, sk = q.shape[2], k.shape[2]
+    return _Geom(sq, sk, _pick_block(sq, block_q), _pick_block(sk, block_k),
+                 causal, window, q.shape[1], k.shape[1])
+
+
+def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
+                    window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
-    sk = k.shape[2]
-    bq = _pick_block(sq, block_q)
-    bk = _pick_block(sk, block_k)
+    g, sk = k.shape[1], k.shape[2]
+    geom = _geometry(q, k, causal, window, block_q, block_k)
+    bq, bk = geom.bq, geom.bk
     qr = q.reshape(b * h, sq, d)
-    kr = k.reshape(b * h, sk, d)
-    vr = v.reshape(b * h, sk, d)
-    grid = (b * h, sq // bq, sk // bk)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               q_len=sq, kv_len=sk)
+    kr = k.reshape(b * g, sk, d)
+    vr = v.reshape(b * g, sk, d)
+
+    def q_map(bh, qi, j):
+        return bh, qi, 0
+
+    def k_map(bh, qi, j):
+        lo, hi = geom.k_bounds(qi)
+        return geom.kv_head(bh), _mn(lo + j, hi), 0
+
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fwd_kernel, scale=scale, geom=geom),
+        grid=(b * h, geom.nq, geom.k_visits()[0]),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bk, d), k_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bq, 1), q_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
@@ -405,59 +543,71 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_bwd_impl(q, k, v, out, lse, do, scale, causal,
-                    block_q, block_k, interpret):
+                    block_q, block_k, interpret, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
-    sk = k.shape[2]
-    bq = _pick_block(sq, block_q)
-    bk = _pick_block(sk, block_k)
+    g, sk = k.shape[1], k.shape[2]
+    geom = _geometry(q, k, causal, window, block_q, block_k)
+    bq, bk = geom.bq, geom.bk
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     qr = q.reshape(b * h, sq, d)
-    kr = k.reshape(b * h, sk, d)
-    vr = v.reshape(b * h, sk, d)
+    kr = k.reshape(b * g, sk, d)
+    vr = v.reshape(b * g, sk, d)
     dor = do.reshape(b * h, sq, d)
     lser = lse.reshape(b * h, sq, 1)
     deltar = delta.reshape(b * h, sq, 1)
 
+    def q_map(bh, qi, j):
+        return bh, qi, 0
+
+    def k_map(bh, qi, j):
+        lo, hi = geom.k_bounds(qi)
+        return geom.kv_head(bh), _mn(lo + j, hi), 0
+
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          q_len=sq, kv_len=sk),
-        grid=(b * h, sq // bq, sk // bk),
+        functools.partial(_dq_kernel, scale=scale, geom=geom),
+        grid=(b * h, geom.nq, geom.k_visits()[0]),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bq, 1), q_map),
+            pl.BlockSpec((1, bq, 1), q_map),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, bq, d), q_map),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
 
+    def kv_map(bg, ki, r, j):
+        return bg, ki, 0
+
+    def qrow_map(bg, ki, r, j):
+        lo, hi = geom.q_bounds(ki)
+        return geom.q_head(bg, r), _mn(lo + j, hi), 0
+
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          q_len=sq, kv_len=sk),
-        grid=(b * h, sk // bk, sq // bq),
+        functools.partial(_dkv_kernel, scale=scale, geom=geom),
+        grid=(b * g, geom.nk, geom.group, geom.q_visits()),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bq, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, d), qrow_map),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bq, d), qrow_map),
+            pl.BlockSpec((1, bq, 1), qrow_map),
+            pl.BlockSpec((1, bq, 1), qrow_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * g, sk, d), k.dtype),
+            jax.ShapeDtypeStruct((b * g, sk, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
@@ -465,43 +615,86 @@ def _flash_bwd_impl(q, k, v, out, lse, do, scale, causal,
         ],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
-    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d))
+    return (dq.reshape(b, h, sq, d), dk.reshape(b, g, sk, d),
+            dv.reshape(b, g, sk, d))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, scale, causal, block_q, block_k, interpret, window):
     out, _ = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
-                             interpret)
+                             interpret, window)
     return out
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret,
+                    window):
     out, lse = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
-                               interpret)
+                               interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
+def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, window,
+                    res, do):
     q, k, v, out, lse = res
     return _flash_bwd_impl(q, k, v, out, lse, do, scale, causal,
-                           block_q, block_k, interpret)
+                           block_q, block_k, interpret, window)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def flash_blocks(sq: int, sk: int, causal: bool = False,
+                 window: Optional[int] = None) -> Tuple[int, int, int, int]:
+    """``(block_q, block_k, blocks_visited, blocks_total)`` of one head
+    of :func:`flash_attention` at its default blocks: what the attention
+    layers announce on their ``kernel/dispatch`` instant."""
+    block_q, block_k = _default_blocks(None, None, window)
+    geom = _Geom(sq, sk, _pick_block(sq, block_q), _pick_block(sk, block_k),
+                 causal, window, 1, 1)
+    _, visited, total = geom.k_visits()
+    return geom.bq, geom.bk, visited, total
+
+
+def _default_blocks(block_q, block_k, window):
+    """1024/512 (the round-5 sweep), or the environment's; under a
+    window neither block is larger than the window (at least 128), so
+    that a query block visits its own key block and the one before it
+    and nothing else: window 512 -> 512/512, two key blocks a query
+    block.  Smaller key blocks visit the same elements in twice the
+    grid steps and cost twice the time: 512/256 read 10.4 ms for the
+    forward of 72 heads at 8,192 positions and 7.4 for dk/dv where 512/512
+    reads 5.5 and 4.1 (my chip runs, PR 28)."""
+    if block_q is None:
+        block_q = int(os.environ.get("BIGDL_FLASH_BLOCK_Q", "1024"))
+        if window is not None:
+            block_q = min(block_q, max(_LANES, window))
+    if block_k is None:
+        block_k = int(os.environ.get("BIGDL_FLASH_BLOCK_K", "512"))
+        if window is not None:
+            block_k = min(block_k, max(_LANES, window))
+    return block_q, block_k
 
 
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
-    """Flash attention (Pallas TPU kernel).  [B, H, S, D] in/out.
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
+    """Flash attention (Pallas TPU kernel).  ``q`` [B, H, S, D], ``k``/
+    ``v`` [B, G, S, D] with G dividing H (grouped-query attention: the
+    block index map finds a query head's kv head, nothing is repeated);
+    out like ``q``.  ``window`` (with ``causal``) keeps keys j with
+    ``i - j < window`` and bounds the key-block loop from below as
+    causality bounds it from above.
 
     O(S) memory: softmax is computed online per q block over streamed k/v
     blocks; backward recomputes p from the saved logsumexp (no S x S
     materialization).  Off-TPU the kernels run in Pallas interpret mode so
-    the identical code path is testable on the CPU mesh.
+    the identical code path is testable on the CPU mesh.  The matrix
+    products take their operands in the inputs' dtype (bfloat16 inputs
+    feed the MXU as bfloat16) and accumulate in float32; softmax
+    statistics are float32.
 
     Block sizes default to 1024/512 (clamped to the sequence):
     the round-5 hardware sweep (`tools/experiments/exp_flash_blocks.py`,
@@ -512,12 +705,12 @@ def flash_attention(q, k, v, causal: bool = False,
     / ``BIGDL_FLASH_BLOCK_K`` override process-wide so sweeps need no
     code change.
     """
-    import os
-
-    if block_q is None:
-        block_q = int(os.environ.get("BIGDL_FLASH_BLOCK_Q", "1024"))
-    if block_k is None:
-        block_k = int(os.environ.get("BIGDL_FLASH_BLOCK_K", "512"))
+    if window is not None and not causal:
+        raise ValueError("window attention is causal: pass causal=True")
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} "
+                         f"kv heads")
+    block_q, block_k = _default_blocks(block_q, block_k, window)
     d = q.shape[-1]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
     if interpret is None:
@@ -526,6 +719,7 @@ def flash_attention(q, k, v, causal: bool = False,
     bq, bk = _pick_block(sq, block_q), _pick_block(sk, block_k)
     if not interpret and ((bq % 8 and bq != sq) or (bk % 8 and bk != sk)):
         # shapes the Mosaic tiling can't express — dense fallback
-        return dot_product_attention(q, k, v, causal=causal, scale=scale)
-    return _flash(q, k, v, scale, causal, block_q, block_k, interpret)
-
+        return dot_product_attention(q, k, v, causal=causal, scale=scale,
+                                     window=window)
+    return _flash(q, k, v, scale, causal, block_q, block_k, interpret,
+                  window)

@@ -239,6 +239,22 @@ class _BatchPrefetcher:
         self._thread.join(timeout=5.0)
 
 
+class _CounterLines:
+    """Takes a layer's counters as a telemetry run does and keeps them
+    as one log line a layer and name: ``[Layer 2.0.ffn] moe/load 213 81
+    ...`` says at the end of a run what no run log was open to hear."""
+
+    def __init__(self):
+        self.values = {}
+
+    def counter(self, name, value, layer="", **labels):
+        self.values.setdefault((layer, name), []).append(value)
+
+    def lines(self):
+        return [f"[Layer {layer}] {name} " + " ".join(map(str, v))
+                for (layer, name), v in self.values.items()]
+
+
 class Optimizer:
     """Factory + base driver.  ``Optimizer(model=..., dataset=...,
     criterion=...)`` picks Local vs Distri by Engine topology, mirroring
@@ -1420,6 +1436,7 @@ class Optimizer:
                               dur=t_end - t_start, loss=loss, records=n,
                               throughput=throughput,
                               epoch=self.state["epoch"])
+                    self._emit_layer_counters(tele, step)
                 if health is not None:
                     # may raise HealthError (never retried — see
                     # optimize()); the probe values are already
@@ -1553,9 +1570,30 @@ class Optimizer:
         step.sync_to_model()
         self._join_checkpoint_write()  # run ends with all writes landed
         log.info(self.metrics.summary())
+        last = _CounterLines()
+        self._emit_layer_counters(last, step)
+        for line in last.lines():
+            log.info(line)
         return self.model
 
     # -- training health (docs/observability.md) ----------------------------
+    def _emit_layer_counters(self, tele, step):
+        """Counters a layer keeps in its buffers (a routed layer's load
+        per held expert), read where the loss has just reached the host:
+        the step that wrote them is complete, so this is a copy of a
+        few numbers and no wait.  ``tele`` is the open telemetry run
+        (every step) or, once at the end of ``optimize()``, the
+        :class:`_CounterLines` that puts the last step's into the log."""
+        if not hasattr(self, "_counting_layers"):
+            self._counting_layers = [
+                (path, m) for path, m in self.model.named_modules()
+                if hasattr(m, "step_counters")]
+        for path, m in self._counting_layers:
+            prefix = path + "." if path else ""
+            own = {leaf: step.buffers[prefix + leaf]
+                   for leaf in m.__dict__["_buffers"]}
+            m.step_counters(own, tele, path)
+
     def _health_observe(self, policy: HealthPolicy, step: TrainStep,
                         loss: float) -> None:
         """Fold this iteration's in-graph probe into the policy: emit the

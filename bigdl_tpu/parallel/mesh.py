@@ -1,7 +1,12 @@
 """Device-mesh utilities (the TPU-native replacement for the reference's
 Engine node/core topology, ``utils/Engine.scala:313-418``).
 
-Axes convention (each axis has working machinery behind it):
+Axes convention.  What has run on chips is the ``data`` axis
+(``DistriOptimizer`` on a four-chip mesh: the benchmark's
+``inception_v1.train.b1024.c4``); the other axes are traced, compiled
+and compared with one device on virtual CPU devices only
+(``__graft_entry__.dryrun_multichip``, the tier-1 tests), never on a
+chip:
 - ``data``  — data parallelism (the reference's only axis;
   ``parallel/train_step.py`` batch sharding + ZeRO-1)
 - ``model`` — tensor parallelism (``TrainStep.extra_sharding_rules``
@@ -11,7 +16,9 @@ Axes convention (each axis has working machinery behind it):
 - ``pipe``  — pipeline stages (``parallel/pipeline.py`` GPipe/ppermute
   schedule)
 - ``expert``— expert parallelism for MoE layers
-  (``nn/layers/moe.py`` GShard-style dense dispatch)
+  (``nn/layers/moe.py`` ``MixtureOfExperts``, GShard-style dense
+  dispatch; ``RoutedExperts``, the layer a chip runs, holds its share
+  of the experts and has no exchange yet)
 """
 
 from __future__ import annotations

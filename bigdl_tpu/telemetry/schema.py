@@ -130,6 +130,12 @@ STREAM_NAMES = frozenset({
     # TRACE-time backend decision — op, backend (pallas|xla), reason —
     # so attribution can name which backend each module compiled to
     "kernel/dispatch",
+    # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
+    # instant per TRACE of a layer (experts, held, top_k, capacity
+    # rows), and per step the rows each held expert received and the
+    # rows that took the exact path (counters, emitted by the Optimizer
+    # where it has the loss on the host)
+    "moe/route", "moe/load", "moe/exact_rows",
     # fault tolerance (bigdl_tpu/faults.py + docs/fault_tolerance.md):
     # injected faults, quarantined torn checkpoints, graceful
     # preemption, and checkpoint auto-resume

@@ -1,0 +1,442 @@
+"""The decoder builder and its layers (RMS norm, rotary, grouped-query
+window and full attention, per-head gate, gated and routed feed-forward)
+against the plain reference ``benchmark/models/laguna.py`` at a tiny
+plan, in float32 on the CPU: loss and every leaf's gradient; the share
+test (what all shares of a sparse layer give adds up to the uncut
+layer); dropless routing at both extremes of imbalance; the windowed
+flash kernels in interpret mode; the rotary tables against the formula.
+"""
+
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import bigdl_tpu.nn as nn
+from bigdl_tpu import models, telemetry
+from bigdl_tpu.nn.module import functional_call, load_state_dict, state_dict
+from bigdl_tpu.ops.attention import (dot_product_attention, flash_attention,
+                                     flash_blocks)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def laguna():
+    """The benchmark's family module (``benchmark`` is importable from
+    the repo root, which the suite runs from)."""
+    import sys
+
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from benchmark.models import laguna as family
+
+    return family
+
+
+def tiny_conf(**over):
+    with open(os.path.join(ROOT, "benchmark", "tests", "data",
+                           "tiny_laguna.config.json")) as fh:
+        conf = json.load(fh)
+    conf.update(over)
+    return conf
+
+
+def _plan_of(kinds):
+    """layer_types / mlp_layer_types / heads for a list of (attention,
+    ffn) pairs: window layers carry 6 query heads, full layers 4."""
+    return dict(
+        num_hidden_layers=len(kinds),
+        layer_types=["sliding_attention" if a == "window"
+                     else "full_attention" for a, _ in kinds],
+        mlp_layer_types=[f for _, f in kinds],
+        num_attention_heads_per_layer=[6 if a == "window" else 4
+                                       for a, _ in kinds])
+
+
+LAYER_KINDS = {
+    "full-dense": [("full", "dense")],
+    "window-sparse": [("window", "sparse")],
+    "full-sparse": [("full", "sparse")],
+    "window-dense": [("window", "dense")],
+    "whole-plan": [("full", "dense"), ("window", "sparse"),
+                   ("window", "sparse"), ("full", "sparse")],
+}
+
+
+@pytest.mark.parametrize("kinds", LAYER_KINDS.values(), ids=LAYER_KINDS)
+def test_loss_and_every_gradient_match_the_plain_reference(kinds, laguna):
+    from benchmark import reference
+
+    conf = tiny_conf(**_plan_of(kinds))
+    specs = laguna.param_specs(conf)
+    weights = reference.make_weights(specs, 11, conf["init_gain"])
+    x, y = laguna.make_records(11, 2, conf)
+    model = laguna.build(conf)
+    own = state_dict(model, kind="param")
+    assert [tuple(v.shape) for v in own.values()] == \
+        [tuple(s["shape"]) for s in specs]
+    keys, buffers = list(own), state_dict(model, kind="buffer")
+    crit = laguna.criterion()
+
+    def system_loss(params):
+        out, _ = functional_call(model, {**params, **buffers},
+                                 jnp.asarray(x), training=True,
+                                 rng=jax.random.key(0))
+        return crit.update_output(out, jnp.asarray(y))
+
+    with jax.default_matmul_precision("highest"):
+        got_loss, got = jax.jit(jax.value_and_grad(system_loss))(
+            dict(zip(keys, weights)))
+        want_loss, want = jax.jit(jax.value_and_grad(
+            lambda p: laguna.loss_sum(p, x, y, conf=conf) / len(x)))(
+                list(weights))
+    assert abs(float(got_loss) - float(want_loss)) < 2e-5
+    for spec, key, w in zip(specs, keys, want):
+        scale = max(float(jnp.max(jnp.abs(w))), 1e-6)
+        gap = float(jnp.max(jnp.abs(got[key] - w))) / scale
+        assert gap < 2e-3, (spec["name"], gap)
+
+
+def _routed(conf, held, weights, **kw):
+    first, count = held
+    layer = nn.RoutedExperts(
+        conf["hidden_size"], conf["moe_intermediate_size"],
+        conf["num_experts_published"], conf["num_experts_per_tok"],
+        held=held, shared_width=conf["shared_expert_intermediate_size"],
+        routed_scale=conf["moe_routed_scaling_factor"], **kw)
+    e_gate, e_up, e_down, w_r, s_gate, s_up, s_down = weights
+    load_state_dict(layer, {
+        "experts_gate": e_gate[first:first + count],
+        "experts_up": e_up[first:first + count],
+        "experts_down": e_down[first:first + count],
+        "router.weight": w_r, "shared.gate_proj.weight": s_gate,
+        "shared.up_proj.weight": s_up, "shared.down_proj.weight": s_down},
+        strict=False)
+    return layer
+
+
+def _sparse_weights(conf, seed, experts):
+    d, w = conf["hidden_size"], conf["moe_intermediate_size"]
+    ws = conf["shared_expert_intermediate_size"]
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape, fan_in):
+        return jnp.asarray(rng.standard_normal(shape) / math.sqrt(fan_in),
+                           jnp.float32)
+
+    return [draw(experts, d, w, fan_in=d), draw(experts, d, w, fan_in=d),
+            draw(experts, w, d, fan_in=w),
+            draw(conf["num_experts_published"], d, fan_in=d),
+            draw(ws, d, fan_in=d), draw(ws, d, fan_in=d),
+            draw(d, ws, fan_in=ws)]
+
+
+def _forward(layer, u):
+    with jax.default_matmul_precision("highest"):
+        out, state = jax.jit(lambda s, v: functional_call(layer, s, v))(
+            state_dict(layer), u)
+    return out, np.asarray(state["held_load"])
+
+
+def test_the_shares_of_a_sparse_layer_add_up_to_the_uncut_layer(laguna):
+    """The guide's share test: 4 shares of 4 of the 16 experts, the
+    shared expert (which every chip computes alike) counted once, give
+    what the uncut reference gives for the whole layer."""
+    conf = tiny_conf()
+    weights = _sparse_weights(conf, 3, experts=16)
+    u = jnp.asarray(np.random.default_rng(4).standard_normal((48, 64)),
+                    jnp.float32)
+    whole = dict(conf, held_experts=[0, 16])
+    want = laguna._sparse(u, weights, whole, None)
+    shared = laguna._gated(u, weights[4:], None)
+    parts, rows = [], 0
+    for share in range(4):
+        out, load = _forward(_routed(conf, (4 * share, 4), weights), u)
+        parts.append(out - shared)
+        rows += int(load[:-1].sum())
+    assert rows == 48 * conf["num_experts_per_tok"]  # every assignment once
+    np.testing.assert_allclose(sum(parts) + shared, want, rtol=2e-4,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("every_token,published", [(True, 32), (True, 3),
+                                                   (False, 32)],
+                         ids=["all-held-chosen-exact-path",
+                              "all-held-chosen-fast-path",
+                              "none-held-chosen"])
+def test_routing_drops_nothing_at_either_extreme(every_token, published,
+                                                 laguna):
+    """A router biased so that every token picks every held expert (the 3
+    held: top-3; the load is 3 x tokens.  Of 32 experts that is over the
+    fast path's capacity and the exact path runs; of 3, all held, the
+    capacity is the worst case and no exact path is built) and one so
+    that none does: all equal the dense-mask reference."""
+    conf = tiny_conf(held_experts=[0, 3], num_experts=3,
+                     num_experts_published=published)
+    weights = _sparse_weights(conf, 5, experts=3)
+    u = np.random.default_rng(6).standard_normal((64, 64)) + 2.0
+    u = jnp.asarray(u, jnp.float32)
+    push = 10.0 if every_token else -10.0
+    weights[3] = weights[3].at[:3].add(push / 64.0)  # u . 1 is ~128
+    layer = _routed(conf, (0, 3), weights)
+    assert layer.capacity(64) == (72 if published == 32 else 192)
+    out, load = _forward(layer, u)
+    want = laguna._sparse(u, weights, conf, None)
+    np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
+    if every_token:
+        # rows an expert, then the rows that took the exact path
+        assert list(load) == [64, 64, 64, 192 if published == 32 else 0]
+        # and the path taken is differentiated like the reference
+        params = state_dict(layer, kind="param")
+        buffers = state_dict(layer, kind="buffer")
+        with jax.default_matmul_precision("highest"):
+            got = jax.jit(jax.grad(lambda p: jnp.sum(functional_call(
+                layer, {**p, **buffers}, u)[0] ** 2)))(params)
+            ref = jax.jit(jax.grad(lambda ws: jnp.sum(
+                laguna._sparse(u, ws, conf, None) ** 2)))(weights)
+        for name, g in zip(("experts_gate", "experts_up", "experts_down",
+                            "router.weight"), ref):
+            np.testing.assert_allclose(got[name], g, rtol=2e-3, atol=1e-3 *
+                                       float(jnp.max(jnp.abs(g))))
+    else:
+        assert list(load) == [0, 0, 0, 0]
+        np.testing.assert_allclose(out, laguna._gated(u, weights[4:], None),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_a_held_expert_without_rows_has_a_zero_gradient():
+    conf = tiny_conf()
+    weights = _sparse_weights(conf, 7, experts=4)
+    weights[3] = weights[3].at[1].add(-8.0 / 64.0)   # nobody picks expert 1
+    layer = _routed(conf, (0, 4), weights)
+    u = jnp.asarray(np.random.default_rng(8).standard_normal((32, 64)) + 2.0,
+                    jnp.float32)
+    params = state_dict(layer, kind="param")
+    buffers = state_dict(layer, kind="buffer")
+    grads = jax.jit(jax.grad(lambda p: jnp.sum(functional_call(
+        layer, {**p, **buffers}, u)[0] ** 2)))(params)
+    for name in ("experts_gate", "experts_up", "experts_down"):
+        assert float(jnp.max(jnp.abs(grads[name][1]))) == 0.0
+        assert float(jnp.max(jnp.abs(grads[name][0]))) > 0.0
+
+
+# (query heads, kv heads, sequence, window, block_q, block_k): group sizes
+# 6 and 9, a sequence the preferred block does not divide, windows smaller
+# and larger than a block, and no window
+FLASH_CASES = [(12, 2, 48, 8, 16, 16), (18, 2, 40, 8, 16, 16),
+               (12, 2, 48, 24, 16, 8), (9, 1, 64, 5, 32, 16),
+               (6, 1, 64, None, 16, 16)]
+
+
+@pytest.mark.parametrize("h,g,s,window,bq,bk", FLASH_CASES)
+def test_windowed_grouped_flash_matches_dense(h, g, s, window, bq, bk):
+    keys = jax.random.split(jax.random.key(h + s), 4)
+    q = jax.random.normal(keys[0], (2, h, s, 16))
+    k = jax.random.normal(keys[1], (2, g, s, 16))
+    v = jax.random.normal(keys[2], (2, g, s, 16))
+    do = jax.random.normal(keys[3], (2, h, s, 16))
+
+    def both(attend):
+        def run(q, k, v):
+            out, vjp = jax.vjp(attend, q, k, v)
+            return (out,) + vjp(do)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(run)(q, k, v)
+
+    got = both(lambda *a: flash_attention(
+        *a, causal=True, window=window, block_q=bq, block_k=bk,
+        interpret=True))
+    want = both(lambda *a: dot_product_attention(
+        *a, causal=True, window=window))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("h,g,s,window,bq,bk", [(4, 4, 128, None, 32, 32),
+                                                (12, 2, 48, 8, 16, 16)],
+                         ids=["equal-heads-as-the-older-callers", "windowed"])
+def test_flash_in_bfloat16_stays_within_two_roundings(h, g, s, window, bq, bk):
+    """The kernels multiply in the inputs' type (float32 accumulation,
+    float32 softmax statistics), so with bfloat16 inputs the
+    probabilities are rounded before the products; every caller
+    (``transformer``, ``MultiHeadAttention``, sequence parallelism) gets
+    this.  Held: output and the three gradients within 2**-7 of the
+    float32 result's largest element (worst measured 0.0053; the kernels
+    that upcast their operands read 0.0033, the output's own rounding)."""
+    keys = jax.random.split(jax.random.key(h + s), 4)
+    shapes = [(2, h, s, 16), (2, g, s, 16), (2, g, s, 16), (2, h, s, 16)]
+    low = [jax.random.normal(k, sh).astype(jnp.bfloat16)
+           for k, sh in zip(keys, shapes)]
+
+    def both(attend, q, k, v, do):
+        def run(q, k, v, do):
+            out, vjp = jax.vjp(attend, q, k, v)
+            return (out,) + vjp(do)
+        return jax.jit(run)(q, k, v, do)
+
+    got = both(lambda *a: flash_attention(
+        *a, causal=True, window=window, block_q=bq, block_k=bk,
+        interpret=True), *low)
+    with jax.default_matmul_precision("highest"):
+        want = both(lambda *a: dot_product_attention(
+            *a, causal=True, window=window),
+            *[a.astype(jnp.float32) for a in low])
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        gap = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)))
+        assert gap <= 2.0 ** -7 * float(jnp.max(jnp.abs(b)))
+
+
+def test_a_window_bounds_the_key_blocks_a_query_block_visits():
+    bq, bk, visited, total = flash_blocks(8192, 8192, True, 512)
+    assert (bq, bk) == (512, 512) and total == 16 * 16
+    assert visited == 1 + 15 * 2              # 2 key blocks a query block
+    _, _, causal_visited, causal_total = flash_blocks(8192, 8192, True)
+    assert causal_visited == sum(2 * (i + 1) for i in range(8))
+    assert causal_total == 8 * 16
+
+
+def _direct_tables(r, head_dim, positions):
+    """The published ``rope_parameters`` entry evaluated element by
+    element, with nothing shared with the program or the reference."""
+    dims = int(head_dim * r["partial_rotary_factor"])
+    cos = np.zeros((positions, dims // 2))
+    sin = np.zeros((positions, dims // 2))
+    for j in range(dims // 2):
+        inv = r["rope_theta"] ** (-2.0 * j / dims)
+        scale = 1.0
+        if r["rope_type"] == "yarn":
+            def dim_of(rot):
+                return dims * math.log(
+                    r["original_max_position_embeddings"]
+                    / (rot * 2 * math.pi)) / (2 * math.log(r["rope_theta"]))
+            low = max(math.floor(dim_of(r["beta_fast"])), 0)
+            high = min(math.ceil(dim_of(r["beta_slow"])), dims - 1)
+            ramp = min(max((j - low) / (high - low), 0.0), 1.0)
+            inv = inv / r["factor"] * ramp + inv * (1.0 - ramp)
+            scale = r["attention_factor"]
+        for p in range(positions):
+            cos[p, j] = math.cos(p * inv) * scale
+            sin[p, j] = math.sin(p * inv) * scale
+    return cos, sin
+
+
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+def test_rotary_tables_are_the_published_formula(kind, laguna):
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "laguna_s_2_1.json")) as fh:
+        published = json.load(fh)
+    r = published["rope_parameters"][kind]
+    want_cos, want_sin = _direct_tables(r, 128, 40)
+    ref_cos, ref_sin, dims = laguna.rotary_tables(r, 128, 40)
+    assert dims == (64 if kind == "full_attention" else 128)
+    rotary = nn.Rotary(dims, theta=r["rope_theta"],
+                       factor=r.get("factor", 1.0),
+                       original_max_position=r.get(
+                           "original_max_position_embeddings", 0),
+                       beta_fast=r.get("beta_fast", 32.0),
+                       beta_slow=r.get("beta_slow", 1.0),
+                       attention_factor=r.get("attention_factor", 1.0))
+    cos, sin = rotary.tables(40)
+    for got in ((cos, sin), (ref_cos, ref_sin)):
+        np.testing.assert_allclose(got[0], want_cos, rtol=0, atol=2e-6)
+        np.testing.assert_allclose(got[1], want_sin, rtol=0, atol=2e-6)
+    if kind == "full_attention":   # YaRN: slow dimensions are interpolated
+        plain = nn.Rotary(dims, theta=r["rope_theta"]).inv_freq()
+        assert rotary.inv_freq()[0] == pytest.approx(plain[0])
+        assert rotary.inv_freq()[-1] == pytest.approx(plain[-1] / 128)
+
+
+def test_rms_norm_takes_its_statistics_in_float32():
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((4, 64)) * 50,
+                    jnp.bfloat16)
+    norm = nn.RMSNorm(64, eps=1e-6)
+    out = norm.forward(x)
+    x32 = np.asarray(x, np.float32)
+    want = x32 / np.sqrt((x32 ** 2).mean(-1, keepdims=True) + 1e-6)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32), want, rtol=1e-2)
+
+
+def test_decoder_block_is_two_residual_branches():
+    """``nn.DecoderBlock`` over a ``nn.GroupedQueryAttention`` and a
+    ``nn.GatedMLP``: ``h = x + attn(norm1(x))``, ``y = h + ffn(norm2(h))``,
+    and the window reaches the attention's XLA leg."""
+    attn = nn.GroupedQueryAttention(64, 6, 2, 16, window=4,
+                                    rotary=nn.Rotary(16), gate="per_head")
+    ffn = nn.GatedMLP(64, 96)
+    block = nn.DecoderBlock(64, attn, ffn, eps=1e-6)
+    x = jnp.asarray(np.random.default_rng(2).standard_normal((2, 12, 64)),
+                    jnp.float32)
+    h = x + attn.forward(block.norm1.forward(x))
+    want = h + ffn.forward(block.norm2.forward(h))
+    np.testing.assert_allclose(block.forward(x), want, rtol=1e-5, atol=1e-6)
+    # position 11 sees positions 8..11 only: an earlier token moves nothing
+    moved = block.forward(x.at[:, 3].add(1.0))
+    np.testing.assert_allclose(moved[:, 11], want[:, 11], rtol=1e-5,
+                               atol=1e-6)
+    assert float(jnp.max(jnp.abs(moved[:, 5] - want[:, 5]))) > 1e-3
+    with pytest.raises(ValueError):
+        nn.GroupedQueryAttention(64, 6, 4, 16)
+
+
+def test_registry_decoder_trains_through_local_optimizer_and_is_traced(
+        tmp_path, caplog):
+    """``cli train --model decoder_lm`` in small: the registry's plan
+    through ``LocalOptimizer`` with the LM criterion; the run log carries
+    the attention legs, the routed layers and their load, and the
+    Optimizer's own log the last step's load of every routed layer."""
+    caplog.set_level("INFO", logger="bigdl_tpu.optim")
+    import bigdl_tpu.optim as optim
+    from bigdl_tpu.dataset.sample import Sample
+    from bigdl_tpu.models import registry
+    from bigdl_tpu.telemetry import schema
+
+    model = registry.build_model("decoder_lm", 64)
+    crit, target = registry.train_pieces("decoder_lm", 4)
+    assert target.shape == (4, registry.LM_SEQ_LEN)
+    ids = np.random.default_rng(0).integers(0, 64, (8, 33)).astype(np.int32)
+    samples = [Sample(row[:-1], row[1:]) for row in ids]
+    telemetry.start_run(str(tmp_path))
+    try:
+        o = optim.LocalOptimizer(model, samples, crit, batch_size=4,
+                                 end_trigger=optim.Trigger.max_epoch(6))
+        o.set_optim_method(optim.SGD(learning_rate=0.1, momentum=0.9))
+        o.optimize()
+    finally:
+        telemetry.end_run()
+    events, errors = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
+    assert not errors and not schema.validate_events(events)
+    steps = [e for e in events if e["kind"] == "step"]
+    assert len(steps) == 12 and steps[-1]["loss"] < steps[0]["loss"]
+    legs = [e for e in events if e.get("name") == "kernel/dispatch"
+            and e["op"] == "attention"]
+    assert {(e["window"], e["q_heads"], e["kv_heads"]) for e in legs} == \
+        {(None, 4, 2), (8, 6, 2)}
+    routes = [e for e in events if e.get("name") == "moe/route"]
+    assert routes and routes[0]["experts"] == 16 and routes[0]["held"] == 4 \
+        and routes[0]["top_k"] == 3 and routes[0]["capacity"] == 384
+    load = [e for e in events if e.get("name") == "moe/load"]
+    assert len(load) == 12 * 3 * 4          # steps x sparse layers x held
+    by_step = sum(e["value"] for e in load[:12])  # the first step's
+    assert 0 < by_step <= 3 * 4 * 32 * 3
+    assert {e["name"] for e in events if e["kind"] == "counter"} >= \
+        {"moe/load", "moe/exact_rows"}
+    said = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("[Layer ")]
+    assert len(said) == 3 * 2               # sparse layers x counter names
+    assert [int(v) for v in said[0].split("moe/load ")[1].split()] == \
+        [e["value"] for e in load[-12:-8]]  # the last step's first layer
+
+
+def test_cli_perf_runs_the_decoder(capsys):
+    from bigdl_tpu.models import cli
+
+    cli.main(["perf", "--model", "decoder_lm", "-b", "2", "-i", "1",
+              "--warmup", "1", "--no-bf16"])
+    assert "records/sec" in capsys.readouterr().out
