@@ -20,6 +20,9 @@ def _pad_stack(arrays: List[np.ndarray], param: Optional[PaddingParam]) -> np.nd
         return np.stack(arrays)
     pad_value = param.padding_value if param else 0.0
     ndim = arrays[0].ndim
+    if any(len(s) != ndim for s in shapes):
+        raise ValueError(f"samples of different rank cannot be padded to "
+                         f"one shape: {sorted(set(shapes))}")
     target = [max(s[d] for s in shapes) for d in range(ndim)]
     if param is not None and param.fixed_length is not None:
         if param.fixed_length < target[0]:
@@ -34,25 +37,55 @@ def _pad_stack(arrays: List[np.ndarray], param: Optional[PaddingParam]) -> np.nd
 
 
 class MiniBatch:
+    """``inputs``/``targets`` are lists of stacked arrays.  A batch made
+    by :meth:`from_samples` is DEFERRED: it keeps the samples and the
+    padding parameters, answers :meth:`size` from their count, and
+    stacks on first use of anything else, on whichever thread asks — so
+    the Optimizer's feeder stacks off the thread that pulls the dataset
+    iterator, and a resume skips batches without stacking them.  One
+    thread owns a batch at a time; stacking is not locked."""
+
     def __init__(self, inputs, targets=None):
-        self.inputs: List[np.ndarray] = inputs if isinstance(inputs, list) else [inputs]
-        self.targets: List[np.ndarray] = (targets if isinstance(targets, list) else [targets]) \
+        self._samples: Optional[Sequence[Sample]] = None
+        self._inputs: List[np.ndarray] = inputs if isinstance(inputs, list) else [inputs]
+        self._targets: List[np.ndarray] = (targets if isinstance(targets, list) else [targets]) \
             if targets is not None else []
 
-    @staticmethod
-    def from_samples(samples: Sequence[Sample],
+    @classmethod
+    def from_samples(cls, samples: Sequence[Sample],
                      feature_padding: Optional[PaddingParam] = None,
                      label_padding: Optional[PaddingParam] = None) -> "MiniBatch":
+        batch = cls([])
+        batch._samples = samples
+        batch._padding = (feature_padding, label_padding)
+        return batch
+
+    def _stack(self) -> "MiniBatch":
+        """Build ``_inputs``/``_targets`` if they are still owed."""
+        if self._samples is None:
+            return self
+        samples, (feature_padding, label_padding) = self._samples, self._padding
         n_feat = len(samples[0].features)
         n_lab = len(samples[0].labels)
-        inputs = [_pad_stack([s.features[i] for s in samples], feature_padding)
-                  for i in range(n_feat)]
-        targets = [_pad_stack([s.labels[i] for s in samples], label_padding)
-                   for i in range(n_lab)]
-        return MiniBatch(inputs, targets or None)
+        self._inputs = [_pad_stack([s.features[i] for s in samples], feature_padding)
+                        for i in range(n_feat)]
+        self._targets = [_pad_stack([s.labels[i] for s in samples], label_padding)
+                         for i in range(n_lab)]
+        self._samples = None
+        return self
+
+    @property
+    def inputs(self) -> List[np.ndarray]:
+        return self._stack()._inputs
+
+    @property
+    def targets(self) -> List[np.ndarray]:
+        return self._stack()._targets
 
     def size(self) -> int:
-        return self.inputs[0].shape[0]
+        if self._samples is not None:
+            return len(self._samples)
+        return self._inputs[0].shape[0]
 
     def get_input(self):
         return self.inputs[0] if len(self.inputs) == 1 else self.inputs

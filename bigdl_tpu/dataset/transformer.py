@@ -50,7 +50,10 @@ class SampleToMiniBatch(Transformer):
 
     ``feature_padding_param``/``label_padding_param`` pad variable-length
     samples to a common shape; ``fixed_length`` pads every batch to the same
-    length — essential on TPU to avoid per-batch recompilation."""
+    length — essential on TPU to avoid per-batch recompilation.
+
+    The batches it yields are deferred (``MiniBatch.from_samples``):
+    whoever first reads one stacks it, not this generator."""
 
     def __init__(self, batch_size: int, feature_padding_param=None,
                  label_padding_param=None, partition_num: Optional[int] = None,

@@ -33,7 +33,7 @@ __all__ = ["Metrics"]
 
 class Metrics:
     """Thread-safe per-stage accumulator.  ``add``/``set``/``timer`` are
-    called concurrently by the driver loop, the prefetch worker, the
+    called concurrently by the driver loop, the prefetch workers, the
     straggler runner, and the async-checkpoint pool — every read and
     write of ``_scalars`` happens under one lock (the telemetry forward
     happens outside it: the tracer has its own).  ``stages()`` and
@@ -43,7 +43,8 @@ class Metrics:
 
     #: the host-loop pipeline order (docs/observability.md): stages are
     #: reported in execution order, not alphabetically
-    _STAGE_ORDER = ("data time", "host to device time",
+    _STAGE_ORDER = ("data time", "batch stack time (overlapped)",
+                    "host to device time",
                     "host to device time (overlapped)", "dispatch time",
                     "compile + first iteration time", "computing time",
                     "validation time", "checkpoint time",

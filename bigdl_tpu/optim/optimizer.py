@@ -150,93 +150,163 @@ if not log.handlers:
 
 
 class _BatchPrefetcher:
-    """Double-buffered input pipeline: a host thread pulls batches from
-    the dataset iterator (running the whole host transform chain) and
-    places them on the mesh (h2d) while the device crunches the previous
-    step.  The reference overlaps input the same way with its dedicated
-    multithreaded transform+batch pipeline
-    (``dataset/image/MTLabeledBGRImgToBatch.scala:31``); under JAX the
-    device dispatch is already async, so pulling transform+h2d off the
-    driver thread is the missing half of the overlap — with it, the
-    Metrics ``data time`` stage collapses to queue-pop time (~0 when the
-    pipeline keeps up).
+    """The input pipeline's overlapped half: ``WORKERS`` host threads
+    feed the mesh while the device crunches earlier steps.  The reference
+    overlaps input the same way with its dedicated multithreaded
+    transform+batch pipeline
+    (``dataset/image/MTLabeledBGRImgToBatch.scala:31``).
 
-    ``depth`` bounds the batches in flight (2 = classic double buffering,
-    also bounding device memory for staged inputs)."""
+    The dataset iterator (the whole host transform chain) stays ONE
+    iterator, pulled under a lock and in order, so observers, random
+    transforms, injected data faults and the epoch permutation see the
+    sequence the synchronous path shows them.  A pull hands its worker a
+    batch that is not stacked yet (``MiniBatch.from_samples``) and a
+    sequence number; off the lock the worker stacks it (Metrics ``batch
+    stack time (overlapped)``, span ``feeder/stack``) and places it
+    (``host to device time (overlapped)``, span ``feeder/place``), both
+    summed over workers: producer-side busy time, not driver stall.  The
+    driver's stall is ``data time``, the wait in :meth:`next`, ~0 when
+    the pipeline keeps up.
+
+    :meth:`next` hands batches out strictly in sequence, an error or the
+    end of the data in its place in the sequence.  ``depth + WORKERS``
+    bounds the batches pulled and not yet handed out (gauge
+    ``prefetch/in_flight``; pinned at 1, the pool never engaged), and
+    with them the device memory for staged inputs; a worker with no slot
+    blocks and costs nothing, which is why one ``WORKERS`` serves a 64 KB
+    batch and a 616 MB one alike."""
+
+    #: from a sweep on the chip (PERF.md section 6, PR 29: one chip's
+    #: cells stop gaining at 4, the four-chip cell at 8, where fresh
+    #: pages for the stacked batches bound it); not a knob
+    WORKERS = min(8, os.cpu_count() or 1)
 
     class _Error:
         def __init__(self, exc):
             self.exc = exc
 
-    def __init__(self, data_iter, place_fn, depth: int, metrics: Metrics):
-        import queue
-        import threading
+    _END = object()
 
+    def __init__(self, data_iter, place_fn, depth: int, metrics: Metrics):
         self._it = data_iter
         self._place = place_fn
         self._metrics = metrics
-        self._q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
-        self._stop = threading.Event()
-        self._thread = threading.Thread(
-            target=self._worker, name="bigdl-prefetch", daemon=True)
-        self._thread.start()
+        self._limit = max(depth, 1) + self.WORKERS
+        self._pull_lock = threading.Lock()  # the iterator, in order
+        self._cv = threading.Condition()    # everything below
+        self._ready: Dict[int, object] = {}  # sequence number -> item
+        self._pulled = 0   # sequence numbers given out
+        self._handed = 0   # the next one next() hands out
+        self._done = False  # the end or an error is in the sequence
+        self._stop = False
+        self._cycle = 0.0  # seconds a worker lately took over one batch
+        self._last_pull = 0.0
+        self._threads = [
+            threading.Thread(target=self._worker, name="bigdl-prefetch",
+                             daemon=True) for _ in range(self.WORKERS)]
+        for t in self._threads:
+            t.start()
+
+    def _pull(self):
+        """The next ``(sequence number, batch)`` of the iterator, its end
+        or its error in a batch's place; None when there is nothing more
+        to pull.  Waits, in the lock, for a slot and for its turn: the
+        workers behind it could not go ahead of it anyway."""
+        with self._pull_lock:
+            with self._cv:
+                while not self._stop and not self._done:
+                    if self._pulled - self._handed >= self._limit:
+                        self._cv.wait()
+                        continue
+                    # pulls keep a WORKERS-th of a batch's cycle apart.
+                    # Workers that start together finish together, and
+                    # nothing else ever parts them: the loop would get
+                    # WORKERS batches at once and then none for a whole
+                    # cycle (on the chip a p90 step of 997 ms beside a
+                    # median of 120, PERF.md section 6, PR 29)
+                    wait = self._last_pull + self._cycle / self.WORKERS \
+                        - time.monotonic()
+                    if wait <= 0:
+                        break
+                    self._cv.wait(wait)
+                if self._stop or self._done:
+                    return None
+                self._last_pull = time.monotonic()
+            try:
+                batch = next(self._it, self._END)
+            except BaseException as e:  # noqa: BLE001 — surfaced on next()
+                batch = self._Error(e)
+            with self._cv:
+                seq = self._pulled
+                self._pulled += 1
+                if batch is self._END or isinstance(batch, self._Error):
+                    self._done = True
+                    self._cv.notify_all()
+                in_flight = self._pulled - self._handed
+        telemetry.gauge("prefetch/in_flight", in_flight)
+        return seq, batch
 
     def _worker(self):
-        try:
-            for batch in self._it:
-                if self._stop.is_set():
-                    return
-                t0 = time.perf_counter()
-                placed = self._place(batch.get_input(), batch.get_target())
-                # recorded under an explicitly-overlapped stage name: the
-                # worker places batches AHEAD of consumption, so this is
-                # producer-side busy time, NOT driver stall — folding it
-                # into the driver's "host to device time" undercounted
-                # data-wait exactly when the pipeline was the bottleneck
-                # (VERDICT r4 Weak #7); the driver-stall instrument is
-                # "data time" (queue-pop wait)
-                self._metrics.add("host to device time (overlapped)",
-                                  time.perf_counter() - t0)
-                self._put_stop_aware((batch.size(), placed))
-            else:
-                self._put_stop_aware(None)  # iterator exhausted
-        except BaseException as e:  # noqa: BLE001 — surfaced on next()
-            # the same stop-aware retry as the item path: dropping the
-            # error sentinel would leave the driver blocked in next()
-            self._put_stop_aware(self._Error(e))
-
-    def _put_stop_aware(self, item):
-        import queue
-
-        while not self._stop.is_set():
-            try:
-                self._q.put(item, timeout=0.5)
-                # producer-side fill level: a queue pinned at 0 means the
-                # input pipeline is the bottleneck; pinned at depth means
-                # the device is (docs/observability.md)
-                telemetry.gauge("prefetch/queue_depth", self._q.qsize())
+        while True:
+            pulled = self._pull()
+            if pulled is None:
                 return
-            except queue.Full:
-                continue
+            seq, item = pulled
+            cycle = None
+            if item is not self._END and not isinstance(item, self._Error):
+                try:
+                    item, cycle = self._stack_and_place(item)
+                except BaseException as e:  # noqa: BLE001 — surfaced on next()
+                    item = self._Error(e)
+            with self._cv:
+                if isinstance(item, self._Error):
+                    self._done = True  # nothing is pulled past an error
+                if cycle is not None:
+                    self._cycle = (self._cycle + cycle) / 2 \
+                        if self._cycle else cycle
+                if not self._stop:
+                    self._ready[seq] = item
+                depth = len(self._ready)
+                self._cv.notify_all()
+            # producer-side fill level: pinned at 0 means the input
+            # pipeline is the bottleneck; at the bound, the device is
+            # (docs/observability.md)
+            telemetry.gauge("prefetch/queue_depth", depth)
+
+    def _stack_and_place(self, batch):
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("feeder/stack"):
+            x, y = batch.get_input(), batch.get_target()
+        t1 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("feeder/place"):
+            placed = self._place(x, y)
+        t2 = time.perf_counter()
+        self._metrics.add("batch stack time (overlapped)", t1 - t0)
+        self._metrics.add("host to device time (overlapped)", t2 - t1)
+        return (batch.size(), placed), t2 - t0
 
     def next(self):
         """(global_batch_size, placed_arrays) or None when exhausted;
         re-raises any producer-side failure on the driver thread (so the
         retry loop sees data errors exactly like compute errors)."""
-        item = self._q.get()
+        with self._cv:
+            while self._handed not in self._ready:
+                self._cv.wait()
+            item = self._ready.pop(self._handed)
+            self._handed += 1
+            self._cv.notify_all()  # a slot is free
         if isinstance(item, self._Error):
             raise item.exc
-        return item
+        return None if item is self._END else item
 
     def close(self):
-        self._stop.set()
-        # unblock a producer stuck on a full queue
-        try:
-            while True:
-                self._q.get_nowait()
-        except Exception:
-            pass
-        self._thread.join(timeout=5.0)
+        with self._cv:
+            self._stop = True
+            self._ready.clear()
+            self._cv.notify_all()  # workers waiting for a slot
+        deadline = time.monotonic() + 5.0
+        for t in self._threads:
+            t.join(timeout=max(deadline - time.monotonic(), 0.0))
 
 
 class _CounterLines:

@@ -101,20 +101,32 @@ def _batch_scale(mesh, batch_axes: Sequence[str]) -> int:
     return k // len(coords)
 
 
+def _host_or_device(a):
+    """``a`` as ``jax.device_put`` may split it: a ``jax.Array`` as it
+    is, anything else as a HOST array, never committed whole to one
+    device on its way to several."""
+    import jax
+
+    return a if isinstance(a, jax.Array) else np.asarray(a)
+
+
 def shard_local_batch(mesh, local, batch_axes: Sequence[str] = (DATA_AXIS,)):
     """Place one process's shard of the global batch onto the mesh.
 
-    Single-host: plain ``device_put`` of the (already global) batch.
+    Single-host: ``device_put`` of the (already global) HOST batch with
+    its sharding, so each device's rows go from the host straight to
+    that device over its own link.  (Committed to device 0 first, 616
+    MB took 74 ms to arrive on four chips and held device 0's memory
+    meanwhile; straight, 32 ms: PERF.md section 6, PR 29.)
     Multi-host: each process passes its LOCAL rows and the global array is
     assembled with ``jax.make_array_from_process_local_data`` — the
     TPU-native analogue of the reference's one-cached-partition-per-node
     feeding (``dataset/DataSet.scala:164-240``)."""
     import jax
-    import jax.numpy as jnp
 
     sharding = data_sharding(mesh, np.ndim(local), batch_axes)
     if mesh_process_count(mesh) == 1:
-        return jax.device_put(jnp.asarray(local), sharding)
+        return jax.device_put(_host_or_device(local), sharding)
     local = np.asarray(local)
     scale = _batch_scale(mesh, batch_axes)
     global_shape = (local.shape[0] * scale,) + local.shape[1:]

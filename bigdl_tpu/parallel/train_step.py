@@ -1136,7 +1136,8 @@ class TrainStep:
         else:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from bigdl_tpu.parallel.mesh import _batch_scale
+            from bigdl_tpu.parallel.mesh import (_batch_scale,
+                                                 _host_or_device)
 
             ax = self.batch_axes[0] if len(self.batch_axes) == 1 \
                 else tuple(self.batch_axes)
@@ -1147,8 +1148,8 @@ class TrainStep:
                 if np.ndim(a) >= 2:
                     spec[1] = ax
                 sharding = NamedSharding(self.mesh, P(*spec))
-                if not multihost:
-                    return jax.device_put(jnp.asarray(a), sharding)
+                if not multihost:  # rows straight to their devices
+                    return jax.device_put(_host_or_device(a), sharding)
                 # multi-host: a is this process's LOCAL rows on axis 1
                 local = np.asarray(a)
                 scale = _batch_scale(self.mesh, self.batch_axes)

@@ -186,9 +186,10 @@ STREAM_NAMES = frozenset({
     "health/nonfinite", "health/skip", "health/loss_spike",
     "health/plateau", "health/grad_explosion", "health/halt",
     # counters / gauges
-    "perf/records_per_sec", "prefetch/queue_depth",
+    "perf/records_per_sec", "prefetch/queue_depth", "prefetch/in_flight",
     # pipeline stages (optim.Metrics forwarding + bench.py)
     "host to device time", "host to device time (overlapped)",
+    "batch stack time (overlapped)",
     "dispatch time", "computing time",
     "compile + first iteration time", "data time", "validation time",
     "checkpoint time", "checkpoint wait time", "h2d", "dispatch",

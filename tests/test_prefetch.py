@@ -6,6 +6,7 @@ training semantics."""
 import time
 
 import numpy as np
+import pytest
 
 import bigdl_tpu.nn as nn
 import bigdl_tpu.optim as optim
@@ -152,7 +153,323 @@ def test_prefetch_surfaces_producer_errors():
                              batch_size=16,
                              end_trigger=Trigger.max_iteration(10))
     o.set_optim_method(optim.SGD(learning_rate=0.1))
-    import pytest
-
     with pytest.raises(RuntimeError, match="injected input failure"):
         o.optimize()
+
+
+# -- the ordered pool (PR 29): W workers stack and place, next() keeps the
+# iterator's order --------------------------------------------------------
+
+def _numbered(n):
+    """Ready batches whose every value is their place in the sequence."""
+    from bigdl_tpu.dataset.minibatch import MiniBatch
+
+    for i in range(n):
+        yield MiniBatch(np.full((2, 3), i, np.float32),
+                        np.full((2,), i, np.int64))
+
+
+def _pool(data_iter, place=lambda x, y: (x, y), depth=2):
+    from bigdl_tpu.optim.metrics import Metrics
+    from bigdl_tpu.optim.optimizer import _BatchPrefetcher
+
+    return _BatchPrefetcher(data_iter, place, depth, Metrics())
+
+
+def _uneven_place(x, y):
+    """Even batches take far longer to place than odd ones, so every odd
+    batch is ready before the even one in front of it."""
+    time.sleep(0.03 if int(x[0, 0]) % 2 == 0 else 0.001)
+    return x, y
+
+
+def _drain(pf):
+    out = []
+    try:
+        while True:
+            item = pf.next()
+            if item is None:
+                return out
+            out.append(item)
+    finally:
+        pf.close()
+
+
+@pytest.fixture
+def several_workers(monkeypatch):
+    """WORKERS follows the machine's cores; these cases need W > 1."""
+    from bigdl_tpu.optim.optimizer import _BatchPrefetcher
+
+    monkeypatch.setattr(_BatchPrefetcher, "WORKERS",
+                        max(_BatchPrefetcher.WORKERS, 4))
+
+
+def _live_workers():
+    import threading
+
+    return [t for t in threading.enumerate()
+            if t.name == "bigdl-prefetch" and t.is_alive()]
+
+
+def test_pool_hands_out_in_iterator_order(several_workers):
+    got = _drain(_pool(_numbered(12), _uneven_place))
+    assert [n for n, _ in got] == [2] * 12
+    assert [int(x[0, 0]) for _, (x, _) in got] == list(range(12))
+    assert [int(y[0]) for _, (_, y) in got] == list(range(12))
+
+
+def test_pool_matches_sync_trajectory_under_uneven_placement(
+        monkeypatch, several_workers):
+    import itertools
+
+    from bigdl_tpu.parallel.train_step import TrainStep
+
+    real, calls = TrainStep._shard_batch, itertools.count()
+
+    def uneven(self, x, y, stacked=False):
+        time.sleep(0.03 if next(calls) % 2 == 0 else 0.001)
+        return real(self, x, y, stacked)
+
+    monkeypatch.setattr(TrainStep, "_shard_batch", uneven)
+    p_params, _ = _train(prefetch=2)
+    s_params, _ = _train(prefetch=0)
+    for k in s_params:
+        np.testing.assert_allclose(p_params[k], s_params[k],
+                                   rtol=1e-5, atol=1e-6, err_msg=k)
+
+
+def test_stacking_error_surfaces_in_its_place_in_the_sequence(
+        several_workers):
+    """The 3rd batch cannot be stacked: batches 1 and 2 arrive, the 3rd
+    ``next()`` raises, though batches 4 and 5 were stacked long before."""
+    from bigdl_tpu.dataset.transformer import SampleToMiniBatch
+
+    samples = _make_data(n=20)
+    samples[9] = Sample(np.zeros((2, 2), np.float32), np.int64(0))
+    pf = _pool(SampleToMiniBatch(4).apply(iter(samples)), _uneven_place)
+    try:
+        assert pf.next()[0] == 4
+        assert pf.next()[0] == 4
+        with pytest.raises(ValueError, match="different rank"):
+            pf.next()
+    finally:
+        pf.close()
+    assert _live_workers() == []
+
+
+def test_close_with_workers_blocked_on_a_full_queue():
+    pf = _pool(_numbered(10 ** 9))
+    deadline = time.monotonic() + 10.0
+    while pf._pulled - pf._handed < pf._limit and \
+            time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert pf._pulled - pf._handed == pf._limit  # full: every worker waits
+    assert len(_live_workers()) >= pf.WORKERS
+    t0 = time.monotonic()
+    pf.close()
+    assert time.monotonic() - t0 < 4.0
+    assert _live_workers() == []
+    assert pf._pulled == pf._limit  # nothing was pulled past the bound
+
+
+class _Observer(Transformer):
+    def __init__(self):
+        self.seen = []
+
+    def apply(self, it):
+        for s in it:
+            self.seen.append(float(s.feature[0]))
+            yield s
+
+
+def _observed(prefetch, iters=9):
+    from bigdl_tpu.dataset.dataset import DataSet
+    from bigdl_tpu.dataset.transformer import SampleToMiniBatch
+    from bigdl_tpu.utils.rng import RNG
+
+    set_config(BigDLConfig(prefetch_batches=prefetch))
+    obs = _Observer()
+    RNG.set_seed(99)  # the epoch permutations
+    ds = DataSet.array(_make_data()).transform(obs).transform(
+        SampleToMiniBatch(16))
+    o = optim.LocalOptimizer(_mlp(), ds, nn.ClassNLLCriterion(),
+                             batch_size=16,
+                             end_trigger=Trigger.max_iteration(iters))
+    o.set_optim_method(optim.SGD(learning_rate=0.1))
+    o.optimize()
+    return obs.seen
+
+
+def test_observer_in_the_chain_sees_the_synchronous_order():
+    """Nine steps of 16 over 64 records cross two epoch boundaries; the
+    pool pulls further ahead than the loop consumes, never differently."""
+    sync, pooled = _observed(0), _observed(2)
+    assert len(sync) >= 9 * 16 and len(pooled) >= len(sync)
+    assert pooled[:len(sync)] == sync
+
+
+def test_in_flight_gauge_exceeds_one_when_placing_is_slow(several_workers):
+    from bigdl_tpu import telemetry
+
+    def slow(x, y):
+        time.sleep(0.02)
+        return x, y
+
+    sink = telemetry.MemorySink()
+    with telemetry.run(sinks=[sink]):
+        pf = _pool(_numbered(8), slow)
+        got = _drain(pf)
+    assert len(got) == 8
+    in_flight = [e["value"] for e in sink.events if e["kind"] == "gauge"
+                 and e["name"] == "prefetch/in_flight"]
+    assert max(in_flight) > 1, in_flight
+    assert max(in_flight) <= pf._limit
+    for stage in ("batch stack time (overlapped)",
+                  "host to device time (overlapped)"):
+        assert pf._metrics.count(stage) == 8
+
+
+def test_pulls_are_paced_apart_not_in_bursts(several_workers):
+    """Workers that start together would finish together for ever, and
+    the loop would get WORKERS batches at once, then none for a cycle.
+    A pull waits until a WORKERS-th of a batch's cycle has passed since
+    the last one: a lower bound, so no load on the machine can fail it."""
+    from bigdl_tpu.optim.optimizer import _BatchPrefetcher
+
+    pulls, w = [], _BatchPrefetcher.WORKERS
+
+    def timed():
+        for batch in _numbered(5 * w):
+            pulls.append(time.monotonic())
+            yield batch
+
+    def place(x, y):
+        time.sleep(0.04)
+        return x, y
+
+    got = _drain(_pool(timed(), place))
+    assert len(got) == 5 * w
+    gaps = np.diff(pulls[2 * w:])  # the first round starts together
+    assert gaps.min() >= 0.5 * 0.04 / w, gaps  # unpaced: microseconds
+
+
+def test_pool_keeps_order_under_more_workers_than_cores(monkeypatch):
+    """Stress: 32 workers, a thread switch every 10 us, 400 batches whose
+    placing takes an uneven sliver of time.  A lost update in the
+    sequence numbers or the reorder buffer breaks the order, drops a
+    batch or lets more than the bound in flight."""
+    import sys
+
+    from bigdl_tpu.optim.optimizer import _BatchPrefetcher
+
+    monkeypatch.setattr(_BatchPrefetcher, "WORKERS", 32)
+    worst = [0]
+
+    def place(x, y):
+        time.sleep((int(x[0, 0]) * 7 % 5) * 1e-4)
+        return x, y
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        pf = _pool(_numbered(400), place)
+        out = []
+        deadline = time.monotonic() + 60.0
+        try:
+            while time.monotonic() < deadline:
+                worst[0] = max(worst[0], pf._pulled - pf._handed)
+                item = pf.next()
+                if item is None:
+                    break
+                out.append(int(item[1][0][0, 0]))
+        finally:
+            pf.close()
+    finally:
+        sys.setswitchinterval(old)
+    assert out == list(range(400))
+    assert worst[0] <= pf._limit == 34
+    assert _live_workers() == []
+
+
+# -- placement (PR 29): a mesh's rows go from the host to the chips that
+# own them, not through device 0 ------------------------------------------
+
+def _mesh4():
+    import jax
+
+    from bigdl_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(devices=jax.devices()[:4])
+
+
+def _puts(monkeypatch):
+    """Records what reaches ``jax.device_put``, and lets it through."""
+    import jax
+
+    seen, real = [], jax.device_put
+
+    def device_put(x, *a, **kw):
+        seen.append(x)
+        return real(x, *a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", device_put)
+    return seen
+
+
+def _check_rows(arr, host, axis=0):
+    """Every addressable shard holds its own rows of ``host`` only."""
+    import jax
+
+    rows = host.shape[axis] // 4
+    assert len(arr.addressable_shards) == 4
+    for k, dev in enumerate(jax.devices()[:4]):
+        shard, = [s for s in arr.addressable_shards if s.device == dev]
+        want = np.take(host, range(k * rows, (k + 1) * rows), axis=axis)
+        assert shard.data.shape == want.shape
+        assert shard.data.devices() == {dev}
+        np.testing.assert_array_equal(np.asarray(shard.data), want)
+
+
+def test_shard_local_batch_puts_host_rows_on_their_own_devices(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.parallel.mesh import data_sharding, shard_local_batch
+
+    mesh = _mesh4()
+    x = np.arange(8 * 3 * 5, dtype=np.float32).reshape(8, 3, 5)
+    y = np.arange(8, dtype=np.int64)
+    before = [jax.device_put(jnp.asarray(a), data_sharding(mesh, a.ndim))
+              for a in (x, y)]  # the parent's placement
+    seen = _puts(monkeypatch)
+    after = [shard_local_batch(mesh, a) for a in (x, y)]
+    # the HOST array reached device_put: nothing was committed whole to
+    # one device on the way (jnp.asarray would hand over a jax.Array)
+    assert [type(a) for a in seen] == [np.ndarray, np.ndarray]
+    for old, new, host in zip(before, after, (x, y)):
+        assert new.sharding == old.sharding
+        assert new.dtype == old.dtype and new.shape == old.shape
+        assert new.committed
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+        _check_rows(new, host)
+    # an array that is on a device already goes as it is
+    assert shard_local_batch(mesh, before[0]) is before[0]
+
+
+def test_stacked_placement_puts_host_rows_on_their_own_devices(monkeypatch):
+    from jax.sharding import PartitionSpec as P
+
+    from bigdl_tpu.parallel.train_step import TrainStep
+
+    step = TrainStep(_mlp(), nn.ClassNLLCriterion(),
+                     optim.SGD(learning_rate=0.1), mesh=_mesh4())
+    x = np.arange(3 * 8 * 4, dtype=np.float32).reshape(3, 8, 4)
+    y = (np.arange(3 * 8) % 2).astype(np.int64).reshape(3, 8)
+    seen = _puts(monkeypatch)
+    xs, ys = step._shard_batch(x, y, stacked=True)
+    assert [type(a) for a in seen] == [np.ndarray, np.ndarray]
+    assert xs.sharding.spec == P(None, "data", None)
+    assert ys.sharding.spec == P(None, "data")
+    assert str(ys.dtype) == "int32"
+    _check_rows(xs, x, axis=1)
+    _check_rows(ys, y.astype(np.int32), axis=1)
