@@ -15,7 +15,7 @@ steady-state traffic exercises MIXED bucket selection; the retrace
 detector is armed for the whole timed window and any in-request-path
 compile after warmup is counted separately (``steady_compiles``).
 
-Emits one ``bench.py``-style JSON line with a per-config row::
+Emits one JSON line with a per-config row::
 
     {"metric": "serving_lenet_qps", "value": 118.3, "unit": "qps",
      "configs": {"serve_lenet": {"qps": ..., "p50_ms": ..., "p99_ms":
@@ -23,8 +23,8 @@ Emits one ``bench.py``-style JSON line with a per-config row::
      "retrace_diagnostics": 0, ...}}}
 
 which ``python -m bigdl_tpu.telemetry diff A B`` and
-``--diff-against BASELINE.json`` (exit 4 on regression, the bench.py
-contract) compare: p50/p99 regress up, qps regresses down, and
+``--diff-against BASELINE.json`` (exit 4 on regression)
+compare: p50/p99 regress up, qps regresses down, and
 ``steady_compiles``/``retrace_diagnostics``/``rejected`` are
 zero-slack counters — ONE production recompile fails the gate.
 
@@ -464,7 +464,7 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             return 2
         if any(r["regressed"] for r in rows):
-            return 4  # the sweep ran; it's just slower — bench.py's code
+            return 4  # the sweep ran; it's just slower
     if slo_burned:
         return 4  # the sweep ran; it blew its declared budget
     return 0

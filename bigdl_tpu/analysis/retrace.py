@@ -1,8 +1,8 @@
 """Pass 3 — retrace/recompile detection for TrainStep/EvalStep.
 
 ``with trace_retraces() as mon:`` registers a monitor on the dispatch
-hook points inside ``parallel/train_step.py``.  Every ``run``/
-``run_scan``/``EvalStep.run`` reports its raw host arguments; the monitor
+hook points inside ``parallel/train_step.py``.  Every ``TrainStep.run``/
+``EvalStep.run`` reports its raw host arguments; the monitor
 computes each leaf's *effective abstract value* (shape, dtype, weak
 typing — exactly the jit cache key ingredients) and, when a later
 dispatch differs, emits a Diagnostic naming the argument and the cause:
@@ -61,11 +61,6 @@ def _signature(args: Dict[str, Any]) -> Dict[str, _LeafSig]:
 
     out: Dict[str, _LeafSig] = {}
     for name, tree in args.items():
-        if name.startswith("static:"):
-            # static (Python-level) arguments enter the compile key by
-            # VALUE, not abstract type — e.g. run_scan's n
-            out[name] = _LeafSig((), f"static={tree!r}", False, False)
-            continue
         leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
         for path, leaf in leaves:
             key = name + "".join(str(p) for p in path)

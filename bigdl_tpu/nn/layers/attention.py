@@ -130,8 +130,8 @@ class MultiHeadAttention(Module):
             # seq 512 up (exp_attention_backend: 734 vs 562 seq/s — the
             # earlier "flash was 53% of the seq-512 step" profile was an
             # artifact of the old 128x128 blocks).  The routing rule
-            # itself lives in ops.attention (shared with bench.py's MFU
-            # correction) and honors the BIGDL_KERNELS kill switch.
+            # itself lives in ops.attention, its one home, and
+            # honors the BIGDL_KERNELS kill switch.
             backend, reason = select_attention_backend(
                 q.shape[2], k.shape[2], mask is not None)
             note("attention",

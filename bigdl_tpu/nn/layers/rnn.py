@@ -318,8 +318,8 @@ class Recurrent(Container):
         [T, B, 4H] gate pre-activation buffer's init broadcast +
         dynamic-update-slice writes); rematerialization trades that HBM
         traffic for one extra fused-gate matmul per step in the
-        backward.  Opt-in — measure per shape
-        (``tools/experiments/exp_lstm_remat.py``)."""
+        backward.  Opt-in — measure per shape (BASELINE.md round 5
+        has the verdict for the large LSTM config)."""
         self._remat_cell = True
         return self
 

@@ -166,10 +166,10 @@ def flash_min_seq() -> int:
 def select_attention_backend(sq: int, sk: int,
                              masked: bool = False) -> Tuple[str, str]:
     """THE auto-backend routing decision — (backend, reason) with
-    backend in {"flash", "dense"} — shared by ``MultiHeadAttention``
-    and ``bench.py``'s flash-MFU correction so the two can never drift
-    (round-5 advisor finding: the bench re-derived this predicate and
-    omitted the mask condition).
+    backend in {"flash", "dense"} — the one home of the predicate:
+    ``MultiHeadAttention`` and whatever accounts for its FLOPs read it
+    here and never re-derive it (a round-5 copy of it had omitted the
+    mask condition).
 
     Rules, in order: the ``BIGDL_KERNELS`` kill switch (``xla`` ->
     dense everywhere, ``pallas`` -> flash wherever structurally legal),
@@ -697,8 +697,8 @@ def flash_attention(q, k, v, causal: bool = False,
     statistics are float32.
 
     Block sizes default to 1024/512 (clamped to the sequence):
-    the round-5 hardware sweep (`tools/experiments/exp_flash_blocks.py`,
-    BASELINE.md) measured seq-4096 training 3.5x FASTER at 1024/512 than
+    the round-5 hardware sweep (BASELINE.md, "exp_flash_blocks")
+    measured seq-4096 training 3.5x FASTER at 1024/512 than
     at the old 128/128 default — small blocks underfill the MXU and pay
     the grid-iteration overhead per tiny tile, exactly the short-seq
     pathology the auto backend routes to dense.  ``BIGDL_FLASH_BLOCK_Q``

@@ -1,6 +1,6 @@
 """Fused LRN kernels (cross-map + within-channel) with exact VJPs.
 
-Round-5 motivation (BENCH_banked_r5.json): inception sits at 0.25 MFU
+Round-5 motivation (BASELINE.md, round 5): inception sat at 0.25 MFU
 and its LRN layers lower to multi-op HLO chains — square, window-sum,
 scale, power, multiply — that XLA leaves as separate HBM-bound fusions
 (the channel window additionally fights TPU tiling: C is non-minor in

@@ -107,7 +107,7 @@ def pallas_pool_supported(x, dims, strides, pads) -> bool:
         return False
     if mode == "auto":
         # OPT-IN until the scatter kernel A/Bs a win on hardware
-        # (tools/experiments/exp_pool_kernel.py)
+        # (BASELINE.md round 5: 60% slower on the chip; ROADMAP D3)
         return False
     return True  # "interpret" / "on": run everywhere (tests)
 

@@ -11,8 +11,8 @@ a peer.  What synchronous data-parallel pays per step — one
 all-reduce over every gradient byte — local SGD pays once per
 ``BIGDL_LOCAL_SYNC_H`` steps as a parameter average (DeepSpark, arXiv
 1602.08191; post-local SGD, arXiv 1808.07217), an ≈ H× reduction in
-comms bytes the comms walker measures and ``bench.py local-sgd``
-diff-gates alongside the achieved loss.
+comms bytes, which the comms walker measures
+(``tests/test_local_sync.py`` holds it to >= 0.8 x H).
 
 This module is the driver the Optimizer runs at iteration
 boundaries.  Two layers:

@@ -216,7 +216,6 @@ class TrainStep:
             self._sparse_tables = {}
         self._avg_cache = None
         self._compiled = None
-        self._scan_cache = None
         self._place_initial()
 
     # -- sharding ----------------------------------------------------------
@@ -442,10 +441,9 @@ class TrainStep:
     # -- the pure step -----------------------------------------------------
     def _step_fn(self, with_health: bool = False, local: bool = False):
         """The pure (params, opt_state, buffers, x, y, key[, grad_scale])
-        -> (params, opt_state, buffers, loss[, health]) function, shared
-        by the per-iteration jit and the scan-of-iterations jit.
-        ``with_health`` appends the fused health 5-vector output (the
-        per-iteration path only — the scan path keeps the 4-tuple).
+        -> (params, opt_state, buffers, loss[, health]) function that
+        :meth:`_build` jits.
+        ``with_health`` appends the fused health 5-vector output.
         The optional trailing ``grad_scale`` scalar is the fault-plan
         input (``grad_fault=True`` dispatches pass it; omitted, the
         multiply never enters the trace).  ``local`` traces the step
@@ -861,34 +859,6 @@ class TrainStep:
         return jax.jit(self._step_fn(with_health=self.health_probe),
                        donate_argnums=(0, 1, 2))
 
-    def _build_scan(self, n: int, stacked: bool):
-        """n train iterations inside ONE compiled call via ``lax.scan`` —
-        amortizes per-dispatch latency and lets XLA overlap steps.  ``stacked``:
-        x/y carry a leading iteration axis (one minibatch per step);
-        otherwise the same batch repeats (the perf-harness protocol).
-        In local mode the body is the vmapped island step, so the scan's
-        per-iteration losses carry an island axis."""
-        step = self._local_step_fn() \
-            if self.parameter_sync == "local" else self._step_fn()
-
-        def many(params, opt_state, buffers, x, y, key):
-            def body(carry, it):
-                p, o, b = carry
-                if stacked:
-                    i, xi, yi = it
-                else:
-                    i, xi, yi = it, x, y
-                p, o, b, loss = step(p, o, b, xi, yi,
-                                     jax.random.fold_in(key, i))
-                return (p, o, b), loss
-
-            xs = (jnp.arange(n), x, y) if stacked else jnp.arange(n)
-            (params, opt_state, buffers), losses = jax.lax.scan(
-                body, (params, opt_state, buffers), xs)
-            return params, opt_state, buffers, losses
-
-        return jax.jit(many, donate_argnums=(0, 1, 2))
-
     # -- host API ----------------------------------------------------------
     def run(self, x, y, key, grad_scale=None) -> float:
         """One training iteration; returns the loss.
@@ -924,7 +894,7 @@ class TrainStep:
         if self._compiled is None:
             # the per-step program is what `cli train` compiles: a
             # restart should load it from the same managed cache as
-            # aot_scan and the serving warmup (docs/compile.md)
+            # the serving warmup (docs/compile.md)
             from bigdl_tpu.utils.engine import enable_compile_cache
 
             enable_compile_cache()
@@ -977,6 +947,29 @@ class TrainStep:
                                _jit_cache_size(self._compiled))
         return loss
 
+    def lower(self, x, y, key):
+        """The program :meth:`run` dispatches, lowered for these
+        arguments and not run (a ``jax.stages.Lowered``): the same
+        :meth:`_build`, the batch placed as ``run`` places it, the fault
+        scalar's slot filled where ``grad_fault`` is armed.  Lowering
+        donates nothing, so the step's state stays as it was, and
+        ``.compile()`` of the result is an executable in hand for a
+        reader of its text, cost or memory: it is never installed."""
+        if self._compiled is None:
+            from bigdl_tpu.utils.engine import enable_compile_cache
+
+            enable_compile_cache()
+            self._compiled = self._build()
+        if not any(isinstance(a, jax.Array) and not a.is_fully_addressable
+                   for a in jax.tree.leaves((x, y))):
+            # (a batch that spans processes was placed by run(): its
+            # rows cannot be placed a second time from this process)
+            x, y = self._shard_batch(x, y)
+        args = (self.params, self.opt_state, self.buffers, x, y, key)
+        if self.grad_fault:
+            args += (jnp.float32(1.0),)
+        return self._compiled.lower(*args)
+
     def _emit_device_facts(self, tracer, x, y, key) -> None:
         """Once per step object: pull the compiled program's cost/memory
         story (telemetry/device.py) so throughput numbers in the log come
@@ -993,12 +986,6 @@ class TrainStep:
         if level == "off" and not comms_on and not memory_on:
             return
 
-        def relower():
-            largs = (self.params, self.opt_state, self.buffers, x, y, key)
-            if self.grad_fault:
-                largs += (jnp.float32(1.0),)
-            return self._compiled.lower(*largs)
-
         lowered = None
         # the comms AND memory walkers both read the POST-SPMD-
         # partitioning HLO (collectives and the schedule don't exist in
@@ -1013,13 +1000,13 @@ class TrainStep:
             nonlocal lowered, compiled
             if compiled is None:
                 if lowered is None:
-                    lowered = relower()
+                    lowered = self.lower(x, y, key)
                 compiled = lowered.compile()
             return compiled
 
         if level != "off":
             try:
-                lowered = relower()
+                lowered = self.lower(x, y, key)
                 facts = _tdev.collect_device_facts(
                     lowered, (self.params, self.opt_state, self.buffers),
                     level="auto" if level == "full" else level)
@@ -1128,70 +1115,11 @@ class TrainStep:
         st["rows"] = list(st.get("rows") or [])[:8]
         tracer.instant("train/sparse", **st)
 
-    def _shard_batch(self, x, y, stacked: bool = False):
+    def _shard_batch(self, x, y):
         if self.mesh is None:
             return jax.tree.map(jnp.asarray, x), jax.tree.map(jnp.asarray, y)
-        if not stacked:
-            shard = lambda a: shard_local_batch(self.mesh, a, self.batch_axes)
-        else:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from bigdl_tpu.parallel.mesh import (_batch_scale,
-                                                 _host_or_device)
-
-            ax = self.batch_axes[0] if len(self.batch_axes) == 1 \
-                else tuple(self.batch_axes)
-            multihost = mesh_process_count(self.mesh) > 1
-
-            def shard(a):  # leading axis is ITERATION; batch is axis 1
-                spec = [None] * np.ndim(a)
-                if np.ndim(a) >= 2:
-                    spec[1] = ax
-                sharding = NamedSharding(self.mesh, P(*spec))
-                if not multihost:  # rows straight to their devices
-                    return jax.device_put(_host_or_device(a), sharding)
-                # multi-host: a is this process's LOCAL rows on axis 1
-                local = np.asarray(a)
-                scale = _batch_scale(self.mesh, self.batch_axes)
-                gshape = (local.shape[0], local.shape[1] * scale) \
-                    + local.shape[2:]
-                return jax.make_array_from_process_local_data(
-                    sharding, local, gshape)
+        shard = lambda a: shard_local_batch(self.mesh, a, self.batch_axes)
         return jax.tree.map(shard, x), jax.tree.map(shard, y)
-
-    def run_scan(self, x, y, key, n: int, stacked: bool = False):
-        """Run ``n`` training iterations in one dispatch; returns the
-        per-iteration losses (device array).  See ``_build_scan``."""
-        if _hooks.hooks_active():
-            # n/stacked are compile-key VALUES: changing either rebuilds
-            # the scan, so the retrace detector must see them by value
-            _hooks.dispatch_event(self, "TrainStep.run_scan",
-                                  {"x": x, "y": y, "key": key,
-                                   "static:n": n,
-                                   "static:stacked": stacked})
-        cache_key = (n, stacked)
-        if getattr(self, "_scan_cache", None) is None \
-                or self._scan_cache[0] != cache_key:
-            self._scan_cache = (cache_key, self._build_scan(n, stacked))
-        x, y = self._shard_batch(x, y, stacked)
-        return self.run_scan_sharded(x, y, key)
-
-    def run_scan_sharded(self, x, y, key):
-        """The dispatch half of :meth:`run_scan` over batch arrays already
-        placed on the mesh — lets benchmarks time h2d and dispatch
-        separately (the scan must have been built by ``run_scan`` or
-        ``aot_scan`` first)."""
-        if getattr(self, "_scan_cache", None) is None:
-            raise RuntimeError("no compiled scan: call run_scan/aot_scan")
-        try:
-            self.params, self.opt_state, self.buffers, losses = \
-                self._scan_cache[1](self.params, self.opt_state,
-                                    self.buffers, x, y, key)
-        except Exception as e:  # noqa: BLE001 - OOM forensics only
-            self._maybe_raise_oom(e, "TrainStep.run_scan_sharded",
-                                  x=x, y=y)
-            raise
-        return losses
 
     def _maybe_raise_oom(self, exc: Exception, context: str,
                          x=None, y=None) -> None:
@@ -1212,77 +1140,6 @@ class TrainStep:
         if y is not None:
             trees["batch_y"] = y
         _tmem.raise_oom(exc, trees, context=context)
-
-    def aot_scan(self, x, y, key, n: int, stacked: bool = False):
-        """AOT-compile the scan-of-n-steps once; installs the executable
-        for ``run_scan`` and returns its XLA cost analysis (the scan BODY
-        is counted once — multiply flops by n for totals).  The result is
-        passed through ``normalize_cost_analysis``: some backends/JAX
-        versions hand back a one-element list instead of the dict (the
-        CPU quirk bench.py also guards), and callers get the dict
-        contract either way."""
-        # AOT is the path restarts/preemption-resumes pay repeatedly —
-        # a warm restart should LOAD this executable, not rebuild it
-        # (docs/compile.md: accelerator-only unless BIGDL_COMPILE_CACHE
-        # opts plain CPU in, =0 opts out)
-        from bigdl_tpu.utils.engine import enable_compile_cache
-
-        enable_compile_cache()
-        x, y = self._shard_batch(x, y, stacked)
-        tracer = _telemetry.get()
-        t0 = time.perf_counter()
-        lowered = self._build_scan(n, stacked).lower(
-            self.params, self.opt_state, self.buffers, x, y, key)
-        try:
-            compiled = lowered.compile()
-        except Exception as e:  # noqa: BLE001 - OOM forensics only
-            # compile-time RESOURCE_EXHAUSTED (the backend sizes the
-            # buffer assignment here) gets the same postmortem a
-            # dispatch OOM does
-            self._maybe_raise_oom(e, "TrainStep.aot_scan", x=x, y=y)
-            raise
-        self._scan_cache = ((n, stacked), compiled)
-        if tracer is not None:
-            tracer.emit("compile", name="TrainStep.aot_scan",
-                        dur=time.perf_counter() - t0, iters=n)
-            self._emit_sparse_instant(tracer)
-            from bigdl_tpu.telemetry import device as _tdev
-            from bigdl_tpu.utils.config import get_config
-
-            if get_config().telemetry_device != "off":
-                # the executable is in hand: the HBM breakdown is free
-                # here ("auto" suffices — "full" would only re-compile)
-                facts = _tdev.collect_device_facts(
-                    lowered, (self.params, self.opt_state, self.buffers),
-                    level="auto")
-                facts.update(_tdev.memory_facts(compiled))
-                if facts:
-                    tracer.emit("device_facts", facts=facts)
-            if self._comms_enabled(get_config()):
-                # the scan executable is in hand: comms facts are a
-                # text parse here, no extra compile (the scan BODY holds
-                # each collective once — already per-iteration numbers)
-                try:
-                    from bigdl_tpu.telemetry import comms as _comms
-
-                    payload = _comms.comms_facts(compiled, mesh=self.mesh,
-                                                 model=self.model)
-                    payload["program"] = "aot_scan"
-                    tracer.emit("comms", **payload)
-                except Exception:  # noqa: BLE001 - comms is an observer
-                    pass
-            if self._memory_enabled(get_config()):
-                # likewise free here: the memory walker reads the same
-                # in-hand executable's scheduled text, and its while-
-                # body recursion reports the peak INSIDE the scanned
-                # step, not the tuple shuffle around it
-                try:
-                    self._emit_memory_event(tracer, compiled,
-                                            program="aot_scan")
-                except Exception:  # noqa: BLE001 - an observer
-                    pass
-        from bigdl_tpu.telemetry.device import normalize_cost_analysis
-        return normalize_cost_analysis(compiled.cost_analysis())
 
     def gather_replicated(self, tree):
         """All-gather cross-process-sharded leaves to replicated (no-op on

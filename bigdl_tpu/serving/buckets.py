@@ -2,8 +2,8 @@
 
 Arrival-size variance is the production recompile hazard: every distinct
 ``[n, ...]`` batch shape is its own jit cache entry, and a compile in
-the request path is a multi-second p99 spike (BENCH_banked_r5.json
-``stages_s``: 32-445s cold compiles).  The policy here quantizes every
+the request path is a multi-second p99 spike (a cold Inception-v1
+step compiles for 65-70 s on a v5e, PERF.md).  The policy here quantizes every
 arrival onto a small, closed set of shapes:
 
 - **batch buckets** — powers of two up to ``max_batch`` (overridable),

@@ -4,7 +4,7 @@ The one-shot serving stack (docs/serving.md) answers a request with a
 single forward; autoregressive generation instead runs ONE forward per
 emitted token over an ever-growing context.  Re-reading the whole
 context every step is the transformer_lm_long MFU cliff (0.40 -> 0.19,
-BENCH_banked_r5.json) — so generation gets its own data path, split the
+BASELINE.md round 5) — so generation gets its own data path, split the
 way *Parallax* (arXiv 1808.02621) splits sparse from dense work:
 
 - **prefill** — the prompt's one big forward.  Rides the existing shape

@@ -283,8 +283,8 @@ def run(path_or_dir: Optional[str] = None,
 @contextmanager
 def maybe_run(meta: Optional[Dict[str, Any]] = None,
               device_facts: bool = True):
-    """Config-gated run ownership for entry points (bench.py,
-    profile_bench, models/cli perf): start a JSONL run when
+    """Config-gated run ownership for entry points (models/cli
+    perf, bench_serving.py): start a JSONL run when
     ``BIGDL_TELEMETRY`` names a directory and no run is active yet.
     Yields the owned run-log path, or None when telemetry is off or an
     OUTER scope owns the stream — in which case that run is left

@@ -34,7 +34,7 @@ writes ONE trace with a pid lane per process, viewable as a fleet
 timeline in Perfetto.  ``fleet`` tails/aggregates a telemetry DIRECTORY
 (one-shot or ``--watch``) — the offline twin of the coordinator's live
 ``/status`` fleet block.  ``diff`` compares two runs (JSONL logs or
-bench.py JSON, mixed freely) and exits nonzero when the candidate
+bench_serving.py JSON, mixed freely) and exits nonzero when the candidate
 regressed beyond the thresholds — the CI gate.  ``attribute`` prints
 the per-module FLOPs/bytes table — computed fresh for a registry model
 (``--model``, CPU-friendly: lower + parse, no run needed) or read back

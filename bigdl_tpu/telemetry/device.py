@@ -37,8 +37,8 @@ __all__ = ["CHIP_PEAKS", "peak_flops_per_device",
 #: bytes/s, HBM GiB.  Source: Google Cloud TPU documentation, the
 #: "System architecture" page of each version (e.g. "TPU v5e": 197
 #: TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM, 1,600 Gbit/s ICI); chips
-#: with no separate int8 rate list their bf16 rate.  bench.py and the
-#: telemetry stream both read this table and no other.
+#: with no separate int8 rate list their bf16 rate.  The telemetry
+#: stream (report, comms, memory) reads this table and no other.
 CHIP_PEAKS = {
     "TPU v2": (45e12, 45e12, 1.0e11, 8),
     "TPU v3": (123e12, 123e12, 1.4e11, 16),
@@ -110,7 +110,7 @@ def hbm_per_device(device_kind: str) -> Optional[int]:
 def normalize_cost_analysis(cost) -> Dict[str, Any]:
     """``cost_analysis()`` returns a dict on some backends/JAX versions
     and a one-element list of dicts on others — always hand back the
-    dict (shared by bench.py's two call sites and :func:`cost_facts`)."""
+    dict (shared by every reader of a cost analysis)."""
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else {}
     return cost or {}

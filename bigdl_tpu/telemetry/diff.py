@@ -2,8 +2,9 @@
 the second one got worse.
 
 ``python -m bigdl_tpu.telemetry diff <runA> <runB>`` accepts either
-JSONL run logs (anything ``schema.read_events`` parses) or ``bench.py``
-output JSON (one object with a ``configs`` table) — in any combination,
+JSONL run logs (anything ``schema.read_events`` parses) or a bench
+artifact (``bench_serving.py``'s output: one JSON object with a
+``configs`` table) — in any combination,
 as long as both sides expose comparable metrics.  Compared, when
 present on both sides:
 
@@ -17,7 +18,7 @@ present on both sides:
 
 Exit code contract (CI-ready): 0 = no regression, 1 = at least one
 metric regressed beyond its threshold, 2 = inputs not comparable.
-``bench.py --diff-against <baseline.json>`` delegates here.
+``bench_serving.py --diff-against <baseline.json>`` delegates here.
 """
 
 from __future__ import annotations
@@ -38,16 +39,16 @@ DEFAULT_THRESHOLD_PCT = 10.0
 #: docs/observability.md): its own knob because HBM regressions are
 #: STEP-function failures — a model that grew 10% past the headroom
 #: OOMs outright, so CI legs near the budget tighten this to ~2-5%
-#: (``bench.py --memory-budget`` / ``telemetry diff
-#: --memory-threshold-pct``) while roomy legs leave the default.
+#: (``telemetry diff --memory-threshold-pct``) while roomy legs leave
+#: the default.
 DEFAULT_MEMORY_THRESHOLD_PCT = 10.0
 
 #: compile_s regression threshold (the compile budget, docs/compile.md):
 #: looser than the runtime threshold by design — compile wall time is
 #: noisier run-to-run than step time, and the class of outlier this
-#: gate exists for (lenet 445 s vs a 2.7 s sibling, BENCH_banked_r5) is
-#: an order of magnitude, not ten percent.  ``bench.py --compile-budget``
-#: / ``telemetry diff --compile-threshold-pct`` tighten it per CI leg.
+#: gate exists for (lenet 445 s vs a 2.7 s sibling, BASELINE.md round
+#: 5) is an order of magnitude, not ten percent.  ``telemetry diff
+#: --compile-threshold-pct`` tightens it per CI leg.
 DEFAULT_COMPILE_THRESHOLD_PCT = 50.0
 
 #: goodput regression threshold (telemetry/ledger.py,
@@ -91,7 +92,7 @@ _RULES: List[Tuple[str, str, str]] = [
     ("comms_s", "lower", "pct"),
     (".comms_bytes", "lower", "pct"),
     (".comms_s", "lower", "pct"),
-    # achieved training loss on bench rows (bench.py --local-sgd): the
+    # achieved training loss on bench rows: the
     # convergence side of the local-SGD trade — the comms_bytes gate
     # alone would bless H=10^6 (zero comms, junk model)
     ("final_loss", "lower", "pct"),
@@ -262,8 +263,8 @@ def run_log_metrics(path: str) -> Dict[str, Any]:
 
 
 def bench_metrics(doc: Dict[str, Any], path: str = "?") -> Dict[str, Any]:
-    """Comparable metrics out of one bench.py JSON line (the object with
-    the per-config ``configs`` table)."""
+    """Comparable metrics out of one bench artifact (the JSON object
+    with the per-config ``configs`` table)."""
     out: Dict[str, Any] = {"kind": "bench", "path": path}
     for name, row in (doc.get("configs") or {}).items():
         if not isinstance(row, dict) or "error" in row:
@@ -289,14 +290,13 @@ def bench_metrics(doc: Dict[str, Any], path: str = "?") -> Dict[str, Any]:
                     "itl_p99_ms", "slo_violations"):
             if row.get(key) is not None:
                 out[f"{name}.{key}"] = float(row[key])
-        # comms snapshot on bench rows (bench.py reads it off the scan
-        # executable) — lets ZeRO/pipeline PRs gate on bytes moved
+        # comms snapshot on bench rows — lets ZeRO/pipeline PRs gate
+        # on bytes moved
         for key in ("comms_bytes", "comms_s", "final_loss"):
             if row.get(key) is not None:
                 out[f"{name}.{key}"] = float(row[key])
-        # memory snapshot on bench rows (bench.py off the scan
-        # executable, bench_serving.py off the warm bucket set) — the
-        # --memory-budget gate's input
+        # memory snapshot on bench rows (bench_serving.py off the warm
+        # bucket set) — the memory threshold's input
         if row.get("peak_hbm_bytes") is not None:
             out[f"{name}.peak_hbm_bytes"] = float(row["peak_hbm_bytes"])
         # goodput roll-up on bench rows (telemetry/ledger.py via the
@@ -308,8 +308,8 @@ def bench_metrics(doc: Dict[str, Any], path: str = "?") -> Dict[str, Any]:
         out["throughput"] = float(doc["value"])
     if doc.get("mfu") is not None:
         out["mfu"] = float(doc["mfu"])
-    # whole-artifact goodput (both benches stamp it off the run that
-    # produced the artifact)
+    # whole-artifact goodput (stamped off the run that produced the
+    # artifact)
     for key in ("goodput_pct", "badput_s"):
         if doc.get(key) is not None:
             out[key] = float(doc[key])
@@ -419,13 +419,13 @@ def format_diff(rows: List[Dict[str, Any]], a: Dict[str, Any],
 
 def main(argv=None) -> int:
     """``python -m bigdl_tpu.telemetry diff`` entry (also callable from
-    bench.py)."""
+    ``bench_serving.py``)."""
     import argparse
     import sys
 
     p = argparse.ArgumentParser(
         prog="bigdl_tpu.telemetry diff",
-        description="compare two runs (JSONL run logs or bench.py JSON) "
+        description="compare two runs (JSONL run logs or bench JSON) "
                     "and exit nonzero on a regression")
     p.add_argument("run_a", help="baseline artifact")
     p.add_argument("run_b", help="candidate artifact")

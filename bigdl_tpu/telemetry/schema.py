@@ -103,7 +103,7 @@ _BASE: Dict[str, tuple] = {"v": (int,), "ts": _NUM, "pid": (int,),
 STREAM_NAMES = frozenset({
     # spans
     "train/iteration", "data_wait", "validation", "checkpoint",
-    "perf/warmup", "perf/timed", "profile/trace", "profile/warmup",
+    "perf/warmup", "perf/timed",
     # serving (bigdl_tpu/serving/, docs/serving.md): startup AOT warmup
     # span, server lifecycle instants, queue gauge, admission counters
     "serve/warmup", "serve/started", "serve/drain", "serve/load",
@@ -187,18 +187,16 @@ STREAM_NAMES = frozenset({
     "health/plateau", "health/grad_explosion", "health/halt",
     # counters / gauges
     "perf/records_per_sec", "prefetch/queue_depth", "prefetch/in_flight",
-    # pipeline stages (optim.Metrics forwarding + bench.py)
+    # pipeline stages (optim.Metrics forwarding)
     "host to device time", "host to device time (overlapped)",
     "batch stack time (overlapped)",
     "dispatch time", "computing time",
     "compile + first iteration time", "data time", "validation time",
-    "checkpoint time", "checkpoint wait time", "h2d", "dispatch",
-    "device",
+    "checkpoint time", "checkpoint wait time",
     # compile-event names (TrainStep/EvalStep dispatch kinds; the
     # serving executor splits startup warmup compiles from the
     # in-request-path compiles a healthy server never emits)
-    "TrainStep.run", "TrainStep.run_sharded", "TrainStep.run_scan",
-    "TrainStep.aot_scan", "EvalStep.run",
+    "TrainStep.run", "TrainStep.run_sharded", "EvalStep.run",
     "ServeExecutor.warmup", "ServeExecutor.compile",
     # the generation executor's prefill/decode compiles split the same
     # way: warmup names are paid once at startup, the in-request-path

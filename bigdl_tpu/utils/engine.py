@@ -59,9 +59,8 @@ def enable_compile_cache(path: Optional[str] = None) -> str:
     as ``compile/cache_hit``/``compile/cache_miss`` instants), and the
     cache-key ingredients are announced per run so a cold restart that
     should have been warm is diagnosable.  Every path that compiles a
-    step calls this before its first compile: ``TrainStep`` (per-step
-    jit and ``aot_scan``), ``BucketedExecutor.warmup`` (serving cold
-    start) and bench.py at import.
+    step calls this before its first compile: ``TrainStep`` (the
+    per-step jit) and ``BucketedExecutor.warmup`` (serving cold start).
 
     Which directory, in this order:
 
@@ -79,8 +78,8 @@ def enable_compile_cache(path: Optional[str] = None) -> str:
     host platform (the tier-1 rig's
     ``--xla_force_host_platform_device_count=8``) segfaults inside XLA,
     and plain CPU pays no compile bill worth caching.  The platform is
-    read WITHOUT initializing a backend (an import-time call from
-    bench.py must not claim the chip): an initialized backend answers
+    read WITHOUT initializing a backend (a call made before the
+    backend is up must not claim the chip): an initialized backend answers
     exactly, else ``JAX_PLATFORMS`` is trusted, and with neither the
     call defers — the step-level callers run again after the backend is
     up and before the first real compile.

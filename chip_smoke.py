@@ -4,8 +4,8 @@
 ``python chip_smoke.py`` drives the main path ONCE on one TPU chip,
 through the entry points a user calls, at the full width and depth of
 Inception-v1 / ImageNet (``models.build_inception_v1(1000)``, input
-``3x224x224``, batch 256, bf16 compute — the flagship configuration of
-``bench.py``), with random weights and synthetic data made from
+``3x224x224``, batch 256, bf16 compute — the configuration of the
+benchmark's first cell), with random weights and synthetic data made from
 ``--seed``:
 
 - **train**  — what ``python -m bigdl_tpu.models.cli train`` does: an
@@ -135,8 +135,8 @@ def make_samples(seed: int, n: int, labels_used: int = 10):
 
 def build_optimizer(cls, model, samples, batch, iters, sync=None, **kw):
     """The ``cli train`` recipe (models/cli.py cmd_train: SGD, learning
-    rate 0.05, momentum 0.9) in bench.py's step configuration (bf16
-    compute, f32 master weights)."""
+    rate 0.05, momentum 0.9) in the benchmark's step configuration
+    (bf16 compute, f32 master weights)."""
     import jax.numpy as jnp
 
     import bigdl_tpu.nn as nn

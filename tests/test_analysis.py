@@ -106,23 +106,6 @@ def test_seeded_duplicate_axis_and_rule_error():
     assert report.rules_fired() == ["shard/rule-error"]
 
 
-def test_retrace_run_scan_static_n_change():
-    m = nn.Sequential(nn.Linear(4, 3), nn.LogSoftMax())
-    step = TrainStep(m, nn.ClassNLLCriterion(),
-                     optim.SGD(learning_rate=0.1))
-    x = jnp.ones((2, 4, 4))  # [n, batch, dim] stacked iterations
-    y = jnp.zeros((2, 4), jnp.int32)
-    with trace_retraces() as mon:
-        step.run_scan(x, y, jax.random.key(0), n=2, stacked=True)
-        step.run_scan(x[:1], y[:1], jax.random.key(1), n=1,
-                      stacked=True)  # n change: rebuild
-    # the x/y leading-dim change is ALSO reported; the static:n finding
-    # is the one naming the real compile-key cause
-    findings = [d for d in mon.report if "static:n" in d.where]
-    assert findings and findings[0].rule == "retrace/shape-change"
-    assert "2 -> 1" in findings[0].message
-
-
 def test_hooks_never_kill_the_step():
     class Exploding:
         def on_dispatch(self, *a):

@@ -218,7 +218,10 @@ def test_comms_event_off_knob_and_single_device_auto():
     assert not [e for e in sink2.events if e.get("kind") == "comms"]
 
 
-def test_comms_event_rides_aot_scan_without_extra_compile():
+def test_comms_event_rides_the_step_once_and_reads_what_lower_gives():
+    """One ``comms`` event a step object, named for the one program
+    there is, and its numbers are those of the executable
+    ``TrainStep.lower`` hands out (the observers' own source)."""
     mesh = make_mesh((2,), ("data",), devices=jax.devices()[:2])
     model = nn.Sequential(nn.Linear(6, 8), nn.Tanh(), nn.Linear(8, 4),
                           nn.LogSoftMax())
@@ -228,12 +231,16 @@ def test_comms_event_rides_aot_scan_without_extra_compile():
     y = np.zeros((8,), np.int64)
     sink = telemetry.MemorySink()
     with telemetry.run(sinks=[sink]):
-        step.aot_scan(x, y, jax.random.key(0), 3)
+        step.run(x, y, jax.random.key(0))
+        step.run(x, y, jax.random.key(1))
     events = [e for e in sink.events if e.get("kind") == "comms"]
     assert len(events) == 1
-    assert events[0]["program"] == "aot_scan"
-    # the scan body holds each collective once: per-iteration numbers
+    assert events[0]["program"] == "train_step"
     assert events[0]["bytes"] > 0
+    facts = comms.comms_facts(
+        step.lower(x, y, jax.random.key(0)).compile(), mesh=mesh)
+    assert (facts["count"], facts["bytes"]) \
+        == (events[0]["count"], events[0]["bytes"])
 
 
 # -- CLI ---------------------------------------------------------------------

@@ -1,9 +1,9 @@
 """Managed persistent compile cache (utils/compile_cache.py,
 docs/compile.md): a same-config second process over the same cache dir
 must LOAD its executables (cache hits > 0, measurably lower compile
-seconds) for both the training aot_scan path and the serving warmup
+seconds) for both the training step and the serving warmup
 path; hits/misses land in the run log as schema-valid instants; and
-the compile budget (`telemetry diff` / `bench.py --compile-budget`)
+the compile budget (`telemetry diff --compile-threshold-pct`)
 flags an injected compile_s regression with a nonzero exit."""
 
 import json
@@ -15,8 +15,8 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-#: one training process: build a TrainStep, AOT-compile a 3-iteration
-#: scan, print the cache monitor snapshot + the run-log path as JSON
+#: one training process: build a TrainStep, run one step (its compile
+#: is the bill), print the cache monitor snapshot + the run-log path as JSON
 _TRAIN_CHILD = """
 import json, sys
 import numpy as np, jax
@@ -33,7 +33,7 @@ with telemetry.run(sys.argv[1]):
                      optim.SGD(learning_rate=0.1))
     x = np.random.RandomState(0).randn(32, 16).astype(np.float32)
     y = np.random.RandomState(0).randint(0, 4, 32)
-    step.aot_scan(x, y, jax.random.key(0), 3)
+    step.run(x, y, jax.random.key(0))
 from bigdl_tpu.utils import compile_cache as cc
 print(json.dumps({"run_log": telemetry.last_run_path(),
                   **cc.monitor().snapshot()}))
@@ -81,7 +81,7 @@ def _run_child(code, cache_dir, tmp_path, *args):
 
 
 @pytest.mark.deadline(420)
-def test_second_process_aot_scan_hits_cache(tmp_path):
+def test_second_process_train_step_hits_cache(tmp_path):
     cache = tmp_path / "cache"
     cold = _run_child(_TRAIN_CHILD, cache, tmp_path, tmp_path / "run1")
     warm = _run_child(_TRAIN_CHILD, cache, tmp_path, tmp_path / "run2")

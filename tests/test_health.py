@@ -439,24 +439,6 @@ def test_diff_bench_json_and_missing_file(tmp_path, capsys):
     assert cli.main(["diff", str(base), str(tmp_path / "nope.json")]) == 2
 
 
-def test_bench_diff_against_flag(tmp_path, monkeypatch, capsys):
-    """bench.py --diff-against delegates to the diff engine and exits 4
-    on a regression (CI contract)."""
-    import bench
-
-    baseline = tmp_path / "baseline.json"
-    baseline.write_text(json.dumps({"metric": "m", "configs": {
-        "lenet_mnist": {"images_per_sec": 10.0**9}}}))  # unbeatable
-    monkeypatch.setenv("BENCH_CONFIGS", "lenet_mnist")
-    monkeypatch.setenv("BENCH_ITERS", "2")
-    monkeypatch.setenv("BENCH_INFER", "0")
-    with pytest.raises(SystemExit) as exc:
-        bench.main(["--diff-against", str(baseline)])
-    assert exc.value.code == 4
-    err = capsys.readouterr().err
-    assert "REGRESSED" in err
-
-
 # -- fleet view --------------------------------------------------------------
 def test_fleet_view_reports_skew_and_lag(tmp_path, capsys):
     from bigdl_tpu.telemetry import __main__ as cli

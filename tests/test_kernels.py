@@ -543,8 +543,8 @@ def test_plane_launch_rides_on_the_dispatch_instant(tmp_path, monkeypatch):
 
 
 def test_attention_routing_shares_predicate(monkeypatch):
-    """BIGDL_KERNELS routes the attention auto-backend too, and
-    bench.py's MFU correction reads the SAME predicate."""
+    """BIGDL_KERNELS routes the attention auto-backend too, through
+    the one predicate every reader shares."""
     from bigdl_tpu.ops.attention import flash_auto, select_attention_backend
 
     monkeypatch.setenv("BIGDL_KERNELS", "xla")

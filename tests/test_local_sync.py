@@ -12,7 +12,7 @@ Three layers:
   averaging cadence, the grace window charged to ``straggler`` badput,
   the hard-shed marker + excuse, the p0 soft-shed carve-out, and the
   victim's status-then-exit ordering;
-* the compiled-program claims — the local-mode scan contains ZERO
+* the compiled-program claims — the local-mode step contains ZERO
   cross-island collectives, the amortized averaging traffic beats the
   synchronous all-reduce by >= 0.8·H, and the synchronous path is
   byte-identical whether or not the local-SGD knobs are set.
@@ -374,9 +374,9 @@ def _registry_pieces(batch=8):
     return model, x, y
 
 
-def test_local_scan_has_zero_collectives_and_beats_sync_comms():
+def test_local_step_has_zero_collectives_and_beats_sync_comms():
     """The tentpole's comms claim, off the EXACT compiled programs: the
-    local-mode scan body contains no collective at all (island locality
+    local-mode step contains no collective at all (island locality
     is structural under shard_map), and the one averaging program paid
     every H steps keeps the reduction at >= 0.8·H of the synchronous
     per-step all-reduce."""
@@ -389,16 +389,16 @@ def test_local_scan_has_zero_collectives_and_beats_sync_comms():
     model, x, y = _registry_pieces()
     sync_step = TrainStep(model, crit, optim.SGD(learning_rate=0.1),
                           mesh=mesh, parameter_sync="allreduce")
-    sync_step.aot_scan(x, y, jax.random.key(0), 4)
-    sync_bytes = comms_facts(sync_step._scan_cache[1],
-                             mesh=mesh)["bytes"]
+    sync_bytes = comms_facts(
+        sync_step.lower(x, y, jax.random.key(0)).compile(),
+        mesh=mesh)["bytes"]
     assert sync_bytes > 0
 
     model2, _, _ = _registry_pieces()
     local_step = TrainStep(model2, crit, optim.SGD(learning_rate=0.1),
                            mesh=mesh, parameter_sync="local")
-    local_step.aot_scan(x, y, jax.random.key(0), 4)
-    lf = comms_facts(local_step._scan_cache[1], mesh=mesh)
+    lf = comms_facts(local_step.lower(x, y, jax.random.key(0)).compile(),
+                     mesh=mesh)
     assert lf["count"] == 0 and lf["bytes"] == 0, lf
     local_step.average_islands()
     avg_bytes = comms_facts(local_step._avg_cache, mesh=mesh)["bytes"]
@@ -421,19 +421,17 @@ def test_sync_path_byte_identical_when_local_mode_off():
         step = TrainStep(model, nn.ClassNLLCriterion(),
                          optim.SGD(learning_rate=0.1), mesh=mesh,
                          parameter_sync="allreduce")
-        step.aot_scan(x, y, jax.random.key(0), 3)
-        return step
+        return step.lower(x, y, jax.random.key(0)).compile()
 
     # both from ONE call site: the program text carries the source
     # lines of its callers, and two call sites differ in nothing else
-    steps = []
+    programs = []
     for knobs in (None, BigDLConfig(local_sync_h=4, local_sync_stale=1,
                                     local_sync_grace=0.25)):
         set_config(knobs)
-        steps.append(compile_sync())
-    plain, knobbed = steps
-    a = comms_facts(plain._scan_cache[1], mesh=mesh)
-    b = comms_facts(knobbed._scan_cache[1], mesh=mesh)
+        programs.append(compile_sync())
+    plain, knobbed = programs
+    a = comms_facts(plain, mesh=mesh)
+    b = comms_facts(knobbed, mesh=mesh)
     assert (a["bytes"], a["count"]) == (b["bytes"], b["count"])
-    assert plain._scan_cache[1].as_text() \
-        == knobbed._scan_cache[1].as_text()
+    assert plain.as_text() == knobbed.as_text()

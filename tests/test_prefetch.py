@@ -226,9 +226,9 @@ def test_pool_matches_sync_trajectory_under_uneven_placement(
 
     real, calls = TrainStep._shard_batch, itertools.count()
 
-    def uneven(self, x, y, stacked=False):
+    def uneven(self, x, y):
         time.sleep(0.03 if next(calls) % 2 == 0 else 0.001)
-        return real(self, x, y, stacked)
+        return real(self, x, y)
 
     monkeypatch.setattr(TrainStep, "_shard_batch", uneven)
     p_params, _ = _train(prefetch=2)
@@ -416,15 +416,15 @@ def _puts(monkeypatch):
     return seen
 
 
-def _check_rows(arr, host, axis=0):
+def _check_rows(arr, host):
     """Every addressable shard holds its own rows of ``host`` only."""
     import jax
 
-    rows = host.shape[axis] // 4
+    rows = host.shape[0] // 4
     assert len(arr.addressable_shards) == 4
     for k, dev in enumerate(jax.devices()[:4]):
         shard, = [s for s in arr.addressable_shards if s.device == dev]
-        want = np.take(host, range(k * rows, (k + 1) * rows), axis=axis)
+        want = host[k * rows:(k + 1) * rows]
         assert shard.data.shape == want.shape
         assert shard.data.devices() == {dev}
         np.testing.assert_array_equal(np.asarray(shard.data), want)
@@ -454,22 +454,3 @@ def test_shard_local_batch_puts_host_rows_on_their_own_devices(monkeypatch):
         _check_rows(new, host)
     # an array that is on a device already goes as it is
     assert shard_local_batch(mesh, before[0]) is before[0]
-
-
-def test_stacked_placement_puts_host_rows_on_their_own_devices(monkeypatch):
-    from jax.sharding import PartitionSpec as P
-
-    from bigdl_tpu.parallel.train_step import TrainStep
-
-    step = TrainStep(_mlp(), nn.ClassNLLCriterion(),
-                     optim.SGD(learning_rate=0.1), mesh=_mesh4())
-    x = np.arange(3 * 8 * 4, dtype=np.float32).reshape(3, 8, 4)
-    y = (np.arange(3 * 8) % 2).astype(np.int64).reshape(3, 8)
-    seen = _puts(monkeypatch)
-    xs, ys = step._shard_batch(x, y, stacked=True)
-    assert [type(a) for a in seen] == [np.ndarray, np.ndarray]
-    assert xs.sharding.spec == P(None, "data", None)
-    assert ys.sharding.spec == P(None, "data")
-    assert str(ys.dtype) == "int32"
-    _check_rows(xs, x, axis=1)
-    _check_rows(ys, y.astype(np.int32), axis=1)

@@ -818,12 +818,28 @@ def test_generate_trace_decomposes_ttft_and_inter_token(gen_server):
     assert metrics.count("# TYPE bigdl_serve_latency_ms histogram") == 1
 
 
+def _wait_until(cond, what, timeout=60.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
 @pytest.mark.deadline(240)
-def test_prefill_flood_is_blamed_on_interference(gen_server):
+def test_prefill_flood_is_blamed_on_interference(gen_server, monkeypatch):
     """A healthy decode stream that stalls because the worker keeps
     prefilling OTHER requests is blamed on prefill_interference — not
-    on its own compute."""
+    on its own compute.
+
+    The order of events is held, not raced: the victim's first decode
+    waits until the whole flood sits in the queue, so every flood
+    prefill finds the victim active; and each flood prefill costs a
+    fixed 30 ms on top of its own, so eight of them clear the verdict's
+    floor whatever the machine's load (a real prefill of this model
+    takes ~1 ms: eight of them do not clear the 5 ms floor)."""
     server = gen_server
+    batcher = server.gen_batcher
+    ex = batcher.executor
     rng = np.random.default_rng(3)
     # warm the generate baseline with sequential healthy requests
     for _ in range(rt.BASELINE_MIN_SAMPLES + 2):
@@ -832,6 +848,20 @@ def test_prefill_flood_is_blamed_on_interference(gen_server):
                                    1, VOCAB, 3).tolist(),
                                 "max_new_tokens": 3})
         assert code == 200
+    flood_queued = threading.Event()
+    real_prefill, real_decode = ex.prefill, ex.decode
+
+    def held_decode(*args, **kwargs):
+        flood_queued.wait(60.0)
+        return real_decode(*args, **kwargs)
+
+    def flood_prefill(*args, **kwargs):
+        if flood_queued.is_set():
+            time.sleep(0.03)
+        return real_prefill(*args, **kwargs)
+
+    monkeypatch.setattr(ex, "decode", held_decode)
+    monkeypatch.setattr(ex, "prefill", flood_prefill)
     results, errors = {}, []
 
     def client(name, prompt, n):
@@ -848,23 +878,30 @@ def test_prefill_flood_is_blamed_on_interference(gen_server):
     victim = threading.Thread(
         target=client,
         args=("flood-victim", rng.integers(1, VOCAB, 4).tolist(), 55))
-    victim.start()
-    time.sleep(0.01)
     flood = [threading.Thread(
         target=client,
         args=(f"flood-{i}", rng.integers(1, VOCAB, 12).tolist(), 2))
         for i in range(8)]
-    for t in flood:
-        t.start()
-        time.sleep(0.005)
+    try:
+        victim.start()
+        _wait_until(lambda: batcher.active() == 1, "the victim's prefill")
+        for t in flood:
+            t.start()
+        _wait_until(lambda: batcher.depth() == len(flood),
+                    "the flood to queue")
+    finally:
+        flood_queued.set()
     victim.join(120.0)
     for t in flood:
         t.join(120.0)
+    assert not victim.is_alive() and not any(t.is_alive() for t in flood)
     assert errors == []
     doc = _get(server.port, "/v1/trace/flood-victim")
-    assert doc["components"].get("prefill_interference", 0.0) > 0.0
-    assert any(s["name"] == "prefill_interference"
-               for s in doc["spans"])
+    stalls = [s for s in doc["spans"]
+              if s["name"] == "prefill_interference"]
+    # one decode slot beside the victim: the flood prefills one by one
+    assert len(stalls) == len(flood)
+    assert doc["components"]["prefill_interference"] >= 30.0 * len(flood)
     assert doc["blame"]["cause"] == "prefill_interference", doc["blame"]
 
 

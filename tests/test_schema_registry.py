@@ -109,8 +109,7 @@ def _sources():
     paths = glob.glob(os.path.join(REPO, "bigdl_tpu", "**", "*.py"),
                       recursive=True)
     paths += glob.glob(os.path.join(REPO, "tools", "*.py"))
-    paths += [os.path.join(REPO, "bench.py"),
-              os.path.join(REPO, "bench_serving.py")]
+    paths += [os.path.join(REPO, "bench_serving.py")]
     # the registry itself and this test don't count as emitters
     skip = os.path.join("telemetry", "schema.py")
     return [p for p in paths if os.path.exists(p) and skip not in p]
@@ -165,8 +164,7 @@ def test_registry_names_are_not_stale():
     lexical scan can't see; dispatch kinds are built dynamically."""
     _, names = _scan()
     allowed_unseen = {"computing time", "TrainStep.run",
-                      "TrainStep.run_sharded", "TrainStep.run_scan",
-                      "EvalStep.run",
+                      "TrainStep.run_sharded", "EvalStep.run",
                       # serving compile events carry their name through
                       # a variable (warmup vs in-request-path), so the
                       # lexical scan can't see the literals

@@ -481,23 +481,6 @@ def test_train_sparse_instant_emitted_and_schema_valid(tmp_path):
     assert sink.status()["sparse"]["saved_bytes"] == row["saved_bytes"]
 
 
-def test_scan_path_carries_sparse_sync():
-    """aot_scan (the bench protocol) engages the same sparse leg inside
-    the scanned body and matches the dense scan's losses."""
-    def run(mode):
-        _cfg(mode)
-        x, y = _batch()
-        st = TrainStep(_classifier(), nn.ClassNLLCriterion(),
-                       optim.SGD(0.1, momentum=0.9))
-        st.aot_scan(x, y, jax.random.key(0), 4)
-        losses = st.run_scan(x, y, jax.random.key(1), 4)
-        return np.asarray(losses), st
-    ld, _ = run("off")
-    ls, st = run("on")
-    assert st._sparse_stats
-    np.testing.assert_allclose(ls, ld, rtol=1e-6)
-
-
 # -- the recsys scenario -----------------------------------------------------
 def test_dlrm_registry_model_trains_and_serves_shapes():
     from bigdl_tpu.models import registry
@@ -550,30 +533,3 @@ def test_dlrm_sparse_matches_dense():
     sparse, ls = run("on")
     assert ld == pytest.approx(ls, rel=1e-6)
     _assert_params_close(dense, sparse)
-
-
-# -- bench honesty -----------------------------------------------------------
-def test_zipf_indices_skew_and_bounds():
-    import bench
-
-    rng = np.random.default_rng(0)
-    ids = bench.zipf_indices(rng, (4000,), 1000, 1.05)
-    assert ids.dtype == np.int32
-    assert ids.min() >= 0 and ids.max() < 1000
-    counts = np.bincount(ids, minlength=1000)
-    # hot head: rank-0 id is much warmer than the tail median
-    assert counts[0] > 20 * max(1, np.median(counts[500:]))
-
-
-@pytest.mark.slow
-def test_bucketed_lstm_leg_accounts_pad_positions():
-    """The bucketed bench protocol: per-bucket sub-legs ride the
-    dataset/text.py bucket set and MFU credits only valid tokens."""
-    import bench
-
-    row = bench._run_config_bucketed("lstm_text", 8, 2, (16, 32))
-    assert set(row["buckets"]) <= {"16", "32"}
-    assert 0 < row["valid_token_frac"] < 1
-    shares = sum(b["share"] for b in row["buckets"].values())
-    assert shares == pytest.approx(1.0, abs=0.01)
-    assert row["images_per_sec"] > 0

@@ -421,7 +421,7 @@ def test_retrace_bridge_attributes_shape_change():
     assert len(_events(sink, "compile")) >= 2  # both shapes compiled
 
 
-def test_aot_scan_respects_device_facts_off(monkeypatch):
+def test_step_respects_device_facts_off(monkeypatch):
     import jax
 
     from bigdl_tpu.parallel.train_step import TrainStep
@@ -434,11 +434,10 @@ def test_aot_scan_respects_device_facts_off(monkeypatch):
     with telemetry.run(sinks=[sink]):
         step = TrainStep(nn.Sequential(nn.Linear(4, 2)),
                          nn.MSECriterion(), optim.SGD(learning_rate=0.1))
-        step.aot_scan(x, y, jax.random.key(0), 2)
         step.run(x, y, jax.random.key(1))
-    # "off" silences BOTH device-facts emitters; compiles still land
+    # "off" silences the device-facts emitter; compiles still land
     assert not _events(sink, "device_facts")
-    assert any(c["name"] == "TrainStep.aot_scan"
+    assert any(c["name"] == "TrainStep.run"
                for c in _events(sink, "compile"))
 
 

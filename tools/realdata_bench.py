@@ -7,9 +7,9 @@ whether input ever stalls the device (Metrics ``data time``).
 This is the proof the framework's input path keeps a chip fed the way the
 reference's SequenceFile + MTLabeledBGRImgToBatch pipeline feeds ImageNet
 (``dataset/DataSet.scala:319`` SeqFileFolder,
-``dataset/image/MTLabeledBGRImgToBatch.scala:31``); the synthetic
-device-resident ``bench.py`` protocol deliberately excludes input, so this
-tool is its real-data complement.
+``dataset/image/MTLabeledBGRImgToBatch.scala:31``); the benchmark's
+cells feed ready float32 ``Sample``s, so this tool is their real-data
+complement until ROADMAP R5 gives it a cell.
 
     # ImageNet shapes on the TPU (writes ~0.6 GB of records first):
     python tools/realdata_bench.py --config inception --iters 16
