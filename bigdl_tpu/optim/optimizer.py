@@ -149,6 +149,69 @@ if not log.handlers:
     log.setLevel(logging.INFO)
 
 
+def _may_read(leaf, buffers: Sequence[np.ndarray]) -> bool:
+    """Whether ``leaf``, a leaf of what a placement returned, may be
+    backed by the memory of one of the host arrays ``buffers``.  Decided
+    from what the leaf shows: a ``jax.Array`` on devices with memory of
+    their own holds a copy, one of the CPU client may BE the host array
+    (it takes an aligned one without a copy); a NumPy array says itself
+    what it shares.  Anything that is not an array or a number is taken
+    to hold what it was given."""
+    if isinstance(leaf, jax.Array):
+        return any(d.platform == "cpu" for d in leaf.devices())
+    if isinstance(leaf, np.ndarray):
+        return any(np.may_share_memory(leaf, b) for b in buffers)
+    return not isinstance(leaf, (int, float, complex, np.generic))
+
+
+class _StagingBuffers:
+    """The host arrays ONE feeder worker stacks its batches into, and the
+    rule for filling them again.  ``np.stack`` into a fresh allocation
+    touches every page first (1.0 GB/s for a 616 MB batch, and threads do
+    not add up); into pages that are there, 2.45 GB/s a thread (PERF.md
+    section 6, PR 29 and PR 31).  So a worker keeps ONE set, one array a
+    leaf of the batch; a batch of another shape or dtype gets fresh
+    arrays, which replace the set.
+
+    The lifetime rule.  Between :meth:`stack` and :meth:`release` the
+    set belongs to the batch being placed.  :meth:`release` takes it
+    back only when (a) what was placed from it is ready: the runtime
+    may read a host buffer until its transfer completes; and (b) no
+    placed array is backed by it (:func:`_may_read`).  Otherwise the
+    arrays stay the batch's for good and the next batch is stacked into
+    fresh ones, as if this class were not there.  Nothing placed is held
+    past :meth:`release`, so no staged batch outlives its step here."""
+
+    def __init__(self):
+        self._free: List[np.ndarray] = []  # nothing reads these any more
+        self._lent: List[np.ndarray] = []  # what the last batch was stacked into
+
+    def stack(self, batch: MiniBatch) -> Tuple[MiniBatch, bool]:
+        """``batch`` stacked, and whether every array went into one that
+        was kept.  A deferred batch comes back as a new one over this
+        set's arrays and stays deferred itself: whoever holds it (a
+        dataset that hands the same batches out every epoch) holds
+        nothing that will be filled again.  One that came stacked comes
+        back as it is, and nothing of it is ever kept or written."""
+        free, self._free = self._free, []
+        staged = batch._stacked(out=free)
+        self._lent = [] if staged is batch else staged.inputs + staged.targets
+        return staged, bool(self._lent) and all(
+            any(a is f for f in free) for a in self._lent)
+
+    def release(self, placed) -> None:
+        """``placed`` is what the placement made of the batch last
+        stacked: wait until it is ready, then keep the arrays for the
+        next batch unless something placed still reads them.  The wait
+        is made for a batch that lent nothing too (one that came
+        stacked), so that the feeder's place stage times the transfer
+        for every batch alike."""
+        lent, self._lent = self._lent, []
+        jax.block_until_ready(placed)
+        if not any(_may_read(leaf, lent) for leaf in jax.tree.leaves(placed)):
+            self._free = lent
+
+
 class _BatchPrefetcher:
     """The input pipeline's overlapped half: ``WORKERS`` host threads
     feed the mesh while the device crunches earlier steps.  The reference
@@ -161,12 +224,15 @@ class _BatchPrefetcher:
     transforms, injected data faults and the epoch permutation see the
     sequence the synchronous path shows them.  A pull hands its worker a
     batch that is not stacked yet (``MiniBatch.from_samples``) and a
-    sequence number; off the lock the worker stacks it (Metrics ``batch
-    stack time (overlapped)``, span ``feeder/stack``) and places it
-    (``host to device time (overlapped)``, span ``feeder/place``), both
+    sequence number; off the lock the worker stacks it into the staging
+    arrays it keeps (:class:`_StagingBuffers`; Metrics ``batch stack time
+    (overlapped)``, span ``feeder/stack``), places it and waits until
+    the placed arrays are ready (``host to device time (overlapped)``,
+    span ``feeder/place``: the transfer, not only its issue), both
     summed over workers: producer-side busy time, not driver stall.  The
     driver's stall is ``data time``, the wait in :meth:`next`, ~0 when
-    the pipeline keeps up.
+    the pipeline keeps up.  Gauge ``prefetch/staging_reuse`` is the share
+    of batches so far that were stacked into kept arrays.
 
     :meth:`next` hands batches out strictly in sequence, an error or the
     end of the data in its place in the sequence.  ``depth + WORKERS``
@@ -176,9 +242,12 @@ class _BatchPrefetcher:
     blocks and costs nothing, which is why one ``WORKERS`` serves a 64 KB
     batch and a 616 MB one alike."""
 
-    #: from a sweep on the chip (PERF.md section 6, PR 29: one chip's
-    #: cells stop gaining at 4, the four-chip cell at 8, where fresh
-    #: pages for the stacked batches bound it); not a knob
+    #: from sweeps on the chip (PERF.md section 6).  PR 29, stacking into
+    #: fresh pages: one chip's cells stopped gaining at 4, the four-chip
+    #: cell at 8.  PR 31, into kept arrays: the feeder alone delivers
+    #: 17.8 / 24.5 / 23.2 / 21.9 batches of 616 MB a second from 2 / 4 / 8 /
+    #: 16 workers (the host's memory bandwidth) where the four-chip loop
+    #: takes 16.7, so 8 has room and 2 has none.  Not a knob
     WORKERS = min(8, os.cpu_count() or 1)
 
     class _Error:
@@ -201,6 +270,8 @@ class _BatchPrefetcher:
         self._stop = False
         self._cycle = 0.0  # seconds a worker lately took over one batch
         self._last_pull = 0.0
+        self._stacked = 0  # batches the workers stacked and placed,
+        self._reused = 0   # and those of them that went into kept arrays
         self._threads = [
             threading.Thread(target=self._worker, name="bigdl-prefetch",
                              daemon=True) for _ in range(self.WORKERS)]
@@ -247,15 +318,16 @@ class _BatchPrefetcher:
         return seq, batch
 
     def _worker(self):
+        staging = _StagingBuffers()
         while True:
             pulled = self._pull()
             if pulled is None:
                 return
             seq, item = pulled
-            cycle = None
+            cycle = reused = None
             if item is not self._END and not isinstance(item, self._Error):
                 try:
-                    item, cycle = self._stack_and_place(item)
+                    item, cycle, reused = self._stack_and_place(item, staging)
                 except BaseException as e:  # noqa: BLE001 — surfaced on next()
                     item = self._Error(e)
             with self._cv:
@@ -264,26 +336,32 @@ class _BatchPrefetcher:
                 if cycle is not None:
                     self._cycle = (self._cycle + cycle) / 2 \
                         if self._cycle else cycle
+                    self._stacked += 1
+                    self._reused += reused
                 if not self._stop:
                     self._ready[seq] = item
                 depth = len(self._ready)
+                reuse = self._reused / max(self._stacked, 1)
                 self._cv.notify_all()
             # producer-side fill level: pinned at 0 means the input
             # pipeline is the bottleneck; at the bound, the device is
             # (docs/observability.md)
             telemetry.gauge("prefetch/queue_depth", depth)
+            telemetry.gauge("prefetch/staging_reuse", reuse)
 
-    def _stack_and_place(self, batch):
+    def _stack_and_place(self, batch, staging):
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("feeder/stack"):
-            x, y = batch.get_input(), batch.get_target()
+            staged, reused = staging.stack(batch)
+            x, y = staged.get_input(), staged.get_target()
         t1 = time.perf_counter()
         with jax.profiler.TraceAnnotation("feeder/place"):
             placed = self._place(x, y)
+            staging.release(placed)
         t2 = time.perf_counter()
         self._metrics.add("batch stack time (overlapped)", t1 - t0)
         self._metrics.add("host to device time (overlapped)", t2 - t1)
-        return (batch.size(), placed), t2 - t0
+        return (batch.size(), placed), t2 - t0, reused
 
     def next(self):
         """(global_batch_size, placed_arrays) or None when exhausted;

@@ -187,6 +187,7 @@ STREAM_NAMES = frozenset({
     "health/plateau", "health/grad_explosion", "health/halt",
     # counters / gauges
     "perf/records_per_sec", "prefetch/queue_depth", "prefetch/in_flight",
+    "prefetch/staging_reuse",
     # pipeline stages (optim.Metrics forwarding)
     "host to device time", "host to device time (overlapped)",
     "batch stack time (overlapped)",

@@ -89,9 +89,9 @@ def stack_calls(monkeypatch):
     calls = []
     real = mb._pad_stack
 
-    def counting(arrays, param):
+    def counting(arrays, param, out=None):
         calls.append(len(arrays))
-        return real(arrays, param)
+        return real(arrays, param, out)
 
     monkeypatch.setattr(mb, "_pad_stack", counting)
     return calls
@@ -130,3 +130,91 @@ def test_unstackable_batch_raises_at_first_use(samples, fpad):
         batch.get_input()
     with pytest.raises(ValueError):  # and again: it is not half built
         batch.slice(0, 1)
+
+
+# -- stacking into arrays the caller passes (PR 31): the feeder's staging
+# buffers.  The values never depend on what was passed -------------------
+
+def _junk_like(arrays):
+    return [np.full_like(a, 113) for a in arrays]
+
+
+@pytest.mark.parametrize("case", CASES, ids=lambda c: c.__name__.strip("_"))
+def test_stacking_into_passed_buffers_equals_a_fresh_stack(case):
+    """Two batches in a row into the same buffers, as a feeder worker
+    stacks them: each equals the batch stacked alone, and is the buffer
+    itself.  The second batch's samples come in the opposite order, so a
+    padded leg has to lay its padding anew over the first batch's rows."""
+    samples, fpad, lpad = case(np.random.default_rng(4))
+    first = _eager(samples, fpad, lpad)
+    buffers = _junk_like(first.inputs + first.targets)
+    for batch_samples in (samples, samples[::-1]):
+        want = _eager(batch_samples, fpad, lpad)
+        lazy = MiniBatch.from_samples(batch_samples, fpad, lpad)
+        made = lazy._stacked(out=buffers)
+        assert all(m is b for m, b in
+                   zip(made.inputs + made.targets, buffers, strict=True))
+        _same(made.inputs, want.inputs)
+        _same(made.targets, want.targets)
+        # the batch itself stays deferred and holds none of the buffers:
+        # stacked on its own it gets arrays that are its for good
+        assert lazy._samples is not None
+        _same(lazy.inputs, want.inputs)
+        _same(lazy.targets, want.targets)
+        assert not any(a is b for a in lazy.inputs + lazy.targets
+                       for b in buffers)
+        assert lazy._stacked(out=buffers) is lazy  # stacked: nothing to make
+        assert made._stacked(out=buffers) is made
+
+
+def _wrong_shape(a):
+    return np.full((a.shape[0] + 1, *a.shape[1:]), 113, a.dtype)
+
+
+def _wrong_dtype(a):
+    return np.full(a.shape, 113, np.float64 if a.dtype != np.float64
+                   else np.float32)
+
+
+@pytest.mark.parametrize("spoil", [_wrong_shape, _wrong_dtype],
+                         ids=lambda f: f.__name__.strip("_"))
+@pytest.mark.parametrize("case", [_equal_shape, _ragged_padded,
+                                  _fixed_length],
+                         ids=lambda c: c.__name__.strip("_"))
+def test_a_buffer_that_does_not_fit_is_left_untouched(case, spoil):
+    samples, fpad, _ = case(np.random.default_rng(5))
+    arrays = [s.features[0] for s in samples]
+    want = mb._pad_stack(arrays, fpad)
+    bad = spoil(want)
+    got = mb._pad_stack(arrays, fpad, out=bad)
+    assert got is not bad and (bad == 113).all()
+    _same(got, want)
+    # through a batch: the misfit leaf is fresh, the one that fits is used
+    _, _, lpad = case(np.random.default_rng(5))
+    eager = _eager(samples, fpad, lpad)
+    good = _junk_like(eager.targets)
+    lazy = MiniBatch.from_samples(samples, fpad, lpad)
+    made = lazy._stacked(out=[bad] + good)
+    assert made.inputs[0] is not bad and (bad == 113).all()
+    assert all(m is g for m, g in zip(made.targets, good, strict=True))
+    _same(made.inputs, eager.inputs)
+    _same(made.targets, eager.targets)
+
+
+def test_samples_of_mixed_dtype_promote_as_before_and_take_no_buffer():
+    arrays = [np.ones((2,), np.float32), np.ones((2,), np.float64)]
+    buf = np.full((2, 2), 113, np.float64)
+    got = mb._pad_stack(arrays, None, out=buf)
+    assert got is not buf and (buf == 113).all()
+    _same(got, np.stack(arrays))
+
+
+def test_fewer_buffers_than_leaves_leaves_the_rest_fresh():
+    samples, _, _ = _multi_feature(np.random.default_rng(6))
+    eager = _eager(samples, None, None)
+    buffers = _junk_like(eager.inputs[:1])
+    lazy = MiniBatch.from_samples(samples)
+    made = lazy._stacked(out=buffers)
+    assert made.inputs[0] is buffers[0]
+    _same(made.inputs, eager.inputs)
+    _same(made.targets, eager.targets)

@@ -3,6 +3,7 @@
 must overlap host transform + h2d with the device step, without changing
 training semantics."""
 
+import itertools
 import time
 
 import numpy as np
@@ -220,8 +221,6 @@ def test_pool_hands_out_in_iterator_order(several_workers):
 
 def test_pool_matches_sync_trajectory_under_uneven_placement(
         monkeypatch, several_workers):
-    import itertools
-
     from bigdl_tpu.parallel.train_step import TrainStep
 
     real, calls = TrainStep._shard_batch, itertools.count()
@@ -454,3 +453,258 @@ def test_shard_local_batch_puts_host_rows_on_their_own_devices(monkeypatch):
         _check_rows(new, host)
     # an array that is on a device already goes as it is
     assert shard_local_batch(mesh, before[0]) is before[0]
+
+
+# -- staging buffers (PR 31): a worker stacks into arrays it keeps, and
+# fills them again only when what was placed from them is ready and
+# nothing placed is backed by them -----------------------------------------
+
+def _to_stack(seqs, rows=4, lengths=None):
+    """Batches that are still to be stacked; every value of batch ``i``
+    is ``i``.  ``lengths`` makes the records ragged (padded with -1)."""
+    from bigdl_tpu.dataset.minibatch import MiniBatch
+    from bigdl_tpu.dataset.sample import PaddingParam
+
+    for i in seqs:
+        if lengths is None:
+            yield MiniBatch.from_samples(
+                [Sample(np.full((3,), i, np.float32), np.int64(i))
+                 for _ in range(rows)])
+        else:
+            yield MiniBatch.from_samples(
+                [Sample(np.full((n,), i, np.float32), np.int64(i))
+                 for n in lengths], PaddingParam(-1.0))
+
+
+def _copying(seen=None):
+    """A placement that copies, as a device with memory of its own does:
+    reuse engages on the CPU.  ``seen`` gets the host arrays it was
+    handed, which are the worker's staging arrays themselves."""
+    def place(x, y):
+        if seen is not None:
+            seen.append(x)
+        return np.array(x), np.array(y)
+    return place
+
+
+@pytest.fixture
+def one_worker(monkeypatch):
+    """One worker makes which array a batch is stacked into a fact."""
+    from bigdl_tpu.optim.optimizer import _BatchPrefetcher
+
+    monkeypatch.setattr(_BatchPrefetcher, "WORKERS", 1)
+
+
+def _reuse_gauge(sink):
+    return [e["value"] for e in sink.events if e["kind"] == "gauge"
+            and e["name"] == "prefetch/staging_reuse"]
+
+
+def test_reuse_engages_under_a_copying_placement_and_keeps_the_trajectory(
+        monkeypatch, several_workers):
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.optim.optimizer import _BatchPrefetcher
+    from bigdl_tpu.parallel.train_step import TrainStep
+
+    # NumPy copies, which the compiled step takes as they are: a
+    # jax.Array of the CPU client would be taken to hold the buffer
+    monkeypatch.setattr(TrainStep, "_shard_batch",
+                        lambda self, x, y: (np.array(x), np.array(y)))
+    iters = 3 * _BatchPrefetcher.WORKERS + 4
+    sink = telemetry.MemorySink()
+    with telemetry.run(sinks=[sink]):
+        p_params, _ = _train(prefetch=2, iters=iters)
+    s_params, _ = _train(prefetch=0, iters=iters)
+    for k in s_params:
+        np.testing.assert_allclose(p_params[k], s_params[k],
+                                   rtol=1e-5, atol=1e-6, err_msg=k)
+    share = _reuse_gauge(sink)
+    assert len(share) >= iters
+    # every worker's first batch is fresh, every later one is reused
+    assert share[-1] >= 1 - _BatchPrefetcher.WORKERS / len(share) > 0.5
+
+
+class _Late(np.ndarray):
+    """A placed array that is ready when the test says so."""
+    gate = None
+
+    def block_until_ready(self):
+        assert self.gate.wait(10.0)
+        return self
+
+
+def test_a_buffer_is_not_filled_again_before_its_placement_is_ready(
+        one_worker):
+    import threading
+
+    seen, gates = [], []
+
+    def place(x, y):
+        seen.append(x)
+        late = np.array(x).view(_Late)
+        late.gate = threading.Event()
+        gates.append(late.gate)
+        return late, np.array(y)
+
+    pf = _pool(_to_stack(range(6)), place)
+    try:
+        deadline = time.monotonic() + 10.0
+        while not gates and time.monotonic() < deadline:
+            time.sleep(0.002)
+        time.sleep(0.1)  # without the wait, batch 1 is in the buffer by now
+        assert len(seen) == 1 and (seen[0] == 0).all()
+        assert pf._handed == 0 and not pf._ready  # not handed out either
+        gates[0].set()
+        n, (x0, _) = pf.next()
+        assert n == 4 and (np.asarray(x0) == 0).all()
+        while len(gates) < 2 and time.monotonic() < deadline:
+            time.sleep(0.002)
+        assert seen[1] is seen[0] and (seen[0] == 1).all()  # filled again
+        assert (np.asarray(x0) == 0).all()  # the placed copy is its own
+        # close() with the worker in that wait: it ends once ready
+        threading.Timer(0.2, gates[1].set).start()
+    finally:
+        pf.close()
+    assert _live_workers() == []
+
+
+def _np_passthrough(x, y):
+    return np.asarray(x), np.asarray(y)
+
+
+def _jnp_asarray(x, y):
+    import jax.numpy as jnp
+
+    return jnp.asarray(x), jnp.asarray(y)
+
+
+def _mesh_rows(x, y):
+    from bigdl_tpu.parallel.mesh import shard_local_batch
+
+    mesh = _mesh4()
+    return shard_local_batch(mesh, x), shard_local_batch(mesh, y)
+
+
+@pytest.mark.parametrize("place", [_np_passthrough, _jnp_asarray,
+                                   _mesh_rows],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_a_placement_backed_by_the_host_array_keeps_it(
+        place, several_workers):
+    """The CPU client takes an aligned host array without a copy, and a
+    test's placement may hand the array through: such a batch keeps its
+    array for good.  Every batch handed out still holds its rows after
+    ``2 x WORKERS`` later ones were stacked, and nothing was reused."""
+    from bigdl_tpu.optim.optimizer import _BatchPrefetcher
+
+    n = 4 * _BatchPrefetcher.WORKERS
+    pf = _pool(_to_stack(range(n)), place)
+    got = _drain(pf)  # all of them alive to the end
+    assert len(got) == n
+    for i, (_, (x, y)) in enumerate(got):
+        assert (np.asarray(x) == i).all() and (np.asarray(y) == i).all(), i
+    assert (pf._stacked, pf._reused) == (n, 0)
+
+
+def test_another_shape_replaces_the_set_and_the_full_shape_is_reused_again(
+        one_worker):
+    """Full, full, a short last batch, full, full, then padded batches
+    of length 5, 5, 7, 5, 5 with their padding in other places."""
+    seen = []
+    batches = itertools.chain(
+        _to_stack([0, 1]), _to_stack([2], rows=3), _to_stack([3, 4]),
+        _to_stack([5], lengths=(5, 2, 1)), _to_stack([6], lengths=(1, 5, 3)),
+        _to_stack([7], lengths=(7, 7, 2)), _to_stack([8], lengths=(2, 5, 5)),
+        _to_stack([9], lengths=(5, 1, 1)))
+    pf = _pool(batches, _copying(seen))
+    got = _drain(pf)
+    assert [n for n, _ in got] == [4, 4, 3, 4, 4, 3, 3, 3, 3, 3]
+    for i, (_, (x, y)) in enumerate(got):
+        assert (y == i).all()
+        assert ((x == i) | (x == -1)).all()
+    assert [x.shape for _, (x, _) in got] == \
+        [(4, 3)] * 2 + [(3, 3)] + [(4, 3)] * 2 + [(3, 5)] * 2 + \
+        [(3, 7)] + [(3, 5)] * 2
+    np.testing.assert_array_equal(
+        got[6][1][0], [[6, -1, -1, -1, -1], [6] * 5, [6, 6, 6, -1, -1]])
+    np.testing.assert_array_equal(
+        got[9][1][0], [[9] * 5, [9, -1, -1, -1, -1], [9, -1, -1, -1, -1]])
+    same = [b is a for a, b in zip(seen, seen[1:])]
+    assert same == [True, False, False, True, False, True, False, False,
+                    True]
+    assert (pf._stacked, pf._reused) == (10, 4)
+
+
+def test_an_error_while_stacking_into_a_kept_buffer_surfaces_in_place(
+        one_worker):
+    from bigdl_tpu.dataset.minibatch import MiniBatch
+
+    def batches():
+        yield from _to_stack([0, 1])
+        yield MiniBatch.from_samples(
+            [Sample(np.zeros((3,), np.float32), np.int64(2)),
+             Sample(np.zeros((2, 2), np.float32), np.int64(2))])
+        yield from _to_stack([3, 4])
+
+    seen = []
+    pf = _pool(batches(), _copying(seen))
+    try:
+        assert (pf.next()[1][0] == 0).all()
+        assert (pf.next()[1][0] == 1).all()
+        assert seen[1] is seen[0]  # the kept buffer was in use
+        with pytest.raises(ValueError, match="different rank"):
+            pf.next()
+    finally:
+        pf.close()
+    assert _live_workers() == []
+    # the next attempt's prefetcher knows nothing of the last one's
+    again = []
+    pf = _pool(_to_stack(range(3)), _copying(again))
+    got = _drain(pf)
+    assert [int(x[0, 0]) for _, (x, _) in got] == [0, 1, 2]
+    assert all(a is not s for a in again for s in seen)
+    assert (pf._stacked, pf._reused) == (3, 2)
+
+
+def test_a_batch_that_came_stacked_is_never_kept_or_written(one_worker):
+    """Its arrays are the dataset's: a feeder that stacked the next batch
+    into them would rewrite the data."""
+    from bigdl_tpu import telemetry
+
+    ready = list(_numbered(5))
+    sink = telemetry.MemorySink()
+    with telemetry.run(sinks=[sink]):
+        got = _drain(_pool(itertools.chain(ready, _to_stack([5, 6])),
+                           _copying()))
+    assert [int(x[0, 0]) for _, (x, _) in got] == list(range(7))
+    for i, batch in enumerate(ready):
+        assert (batch.get_input() == i).all()
+        assert (batch.get_target() == i).all()
+    # one reading a batch, and one more with the end of the data
+    assert _reuse_gauge(sink) == [0, 0, 0, 0, 0, 0, 1 / 7, 1 / 7]
+
+
+@pytest.mark.parametrize("workers", ["one_worker", "several_workers"])
+def test_deferred_batches_a_dataset_keeps_read_the_same_every_epoch(
+        workers, request):
+    """``DataSet.array(list(SampleToMiniBatch(b)(...)))`` hands the same
+    deferred batches out every epoch.  A staging array lent to one of
+    them must not become that batch's own: it is filled again, and the
+    batch would read the last batch's rows the next time round."""
+    request.getfixturevalue(workers)
+    kept = list(_to_stack(range(5))) + list(_to_stack([5], rows=3)) + \
+        list(_to_stack([6, 7], lengths=(5, 2, 1)))
+    for _epoch in range(2):
+        pf = _pool(iter(kept), _copying())
+        got = _drain(pf)
+        assert [n for n, _ in got] == [4] * 5 + [3] * 3
+        for i, (_, (x, y)) in enumerate(got):
+            assert (y == i).all() and ((x == i) | (x == -1)).all(), i
+            assert (x[:, 0] == i).all()
+        if workers == "one_worker":  # the array WAS filled again meanwhile
+            assert (pf._stacked, pf._reused) == (8, 5)
+    # nothing of a lent array stayed with a batch: stacked on its own
+    # now, each still gives its rows
+    for i, batch in enumerate(kept):
+        assert batch._samples is not None
+        assert (batch.get_target() == i).all()
+        assert (batch.get_input()[:, 0] == i).all()

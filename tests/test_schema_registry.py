@@ -145,7 +145,8 @@ def test_every_emitted_kind_is_registered():
 def test_every_emitted_stream_name_is_registered():
     _, names = _scan()
     assert {"train/iteration", "data_wait", "straggler/timeout",
-            "prefetch/queue_depth", "prefetch/in_flight", "profile/armed",
+            "prefetch/queue_depth", "prefetch/in_flight",
+            "prefetch/staging_reuse", "profile/armed",
             "flight/dump",
             "fault/injected", "checkpoint/quarantined",
             "run/preempted", "run/resumed"} <= names, \
