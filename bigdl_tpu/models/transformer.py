@@ -17,12 +17,13 @@ route every block's attention through shard_map'd ring or Ulysses
 attention for sequences larger than one chip holds.
 
 ``build_decoder_lm`` builds a current decoder from a per-layer plan
-(:class:`DecoderPlan`): RMS normalisation, rotary positions, grouped-
-query attention whose kind (full or window) and head count differ by
-layer, a per-head output gate, and a dense gated or a routed sparse
-feed-forward per layer, every block under ``nn.Remat``.  It trains with
-the same criterion; the benchmark's ``laguna_s_2_1`` configuration is
-such a plan at published widths.
+(:class:`DecoderPlan`): RMS normalisation, rotary positions, a mixer
+whose kind differs by layer (grouped-query attention, full or within a
+window, with a gate a head or a channel and optionally normed queries
+and keys; or gated delta-rule linear attention), and a dense gated or a
+routed sparse feed-forward per layer, every block under ``nn.Remat``.
+It trains with the same criterion; the benchmark's ``laguna_s_2_1`` and
+``qwen3_next_80b_a3b`` configurations are such plans at published widths.
 """
 
 from __future__ import annotations
@@ -103,9 +104,15 @@ def build_transformer_lm(vocab_size: int, num_layers: int = 4,
     return maybe_scan(model, scan)
 
 
+#: the kinds of mixer and of feed-forward a :class:`LayerPlan` may name
+ATTENTION_KINDS = ("full", "window", "linear")
+FFN_KINDS = ("dense", "sparse")
+
+
 class LayerPlan(NamedTuple):
-    """One decoder layer: ``attention`` is ``"full"`` or ``"window"``,
-    ``heads`` its query heads, ``ffn`` ``"dense"`` or ``"sparse"``."""
+    """One decoder layer: ``attention`` is ``"full"``, ``"window"`` or
+    ``"linear"``, ``heads`` its query heads (of a linear layer: its value
+    heads), ``ffn`` ``"dense"`` or ``"sparse"``."""
     attention: str
     heads: int
     ffn: str
@@ -116,7 +123,15 @@ class DecoderPlan(NamedTuple):
     / ``rotary_window`` are the :class:`nn.Rotary` of each attention
     kind; ``held`` the ``(first, count)`` experts a sparse layer has
     here of its ``n_experts``; ``normalize`` whether the chosen experts'
-    weights are renormalised to sum to one before ``routed_scale``."""
+    weights are renormalised to sum to one before ``routed_scale``.
+    ``gate`` is the attention layers' (``None``, ``"per_head"``,
+    ``"per_channel"``), ``qk_norm`` whether they norm each head's query
+    and key, ``zero_centred_norm`` whether every norm of the model but a
+    linear layer's gated one scales by ``1 + w``, ``shared_gate`` whether
+    a sparse layer gates its shared expert.  A ``"linear"`` layer is an
+    :class:`nn.GatedDeltaNet` of ``linear_key_heads`` key heads, head
+    sizes ``linear_key_dim`` / ``linear_value_dim`` and a convolution of
+    ``linear_conv`` taps."""
     vocab_size: int
     hidden_size: int
     head_dim: int
@@ -135,6 +150,13 @@ class DecoderPlan(NamedTuple):
     gate: Optional[str] = "per_head"
     eps: float = 1e-6
     normalize: bool = True
+    qk_norm: bool = False
+    zero_centred_norm: bool = False
+    shared_gate: bool = False
+    linear_key_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
 
 
 class VocabHead(Module):
@@ -154,28 +176,47 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
                      backend: str = "auto") -> nn.Module:
     """Causal decoder-only LM over [batch, seq] token ids, layer by
     layer as ``plan`` says; output log-probs [batch, seq, vocab]."""
+    for i, layer in enumerate(plan.layers):
+        # a plan is checked whole before a module of it is built
+        if layer.attention not in ATTENTION_KINDS:
+            raise ValueError(f"layer {i}: unknown attention kind "
+                             f"{layer.attention!r}; known: "
+                             f"{', '.join(ATTENTION_KINDS)}")
+        if layer.ffn not in FFN_KINDS:
+            raise ValueError(f"layer {i}: unknown feed-forward kind "
+                             f"{layer.ffn!r}; known: {', '.join(FFN_KINDS)}")
+    zero = plan.zero_centred_norm
+
+    def head_norm(n):
+        return nn.RMSNorm(n, plan.eps, zero_centred=zero)
+
     model = nn.Sequential(nn.LookupTable(plan.vocab_size, plan.hidden_size))
     for layer in plan.layers:
         windowed = layer.attention == "window"
-        if layer.attention not in ("full", "window"):
-            raise ValueError(f"unknown attention kind {layer.attention!r}")
-        attn = nn.GroupedQueryAttention(
-            plan.hidden_size, layer.heads, plan.kv_heads, plan.head_dim,
-            window=plan.window if windowed else None,
-            rotary=plan.rotary_window if windowed else plan.rotary_full,
-            gate=plan.gate, backend=backend)
+        if layer.attention == "linear":
+            attn = nn.GatedDeltaNet(
+                plan.hidden_size, plan.linear_key_heads, layer.heads,
+                plan.linear_key_dim, plan.linear_value_dim,
+                conv_width=plan.linear_conv, eps=plan.eps)
+        else:
+            attn = nn.GroupedQueryAttention(
+                plan.hidden_size, layer.heads, plan.kv_heads, plan.head_dim,
+                window=plan.window if windowed else None,
+                rotary=plan.rotary_window if windowed else plan.rotary_full,
+                gate=plan.gate, backend=backend,
+                qk_norm=head_norm if plan.qk_norm else None)
         if layer.ffn == "dense":
             ffn = nn.GatedMLP(plan.hidden_size, plan.dense_width)
-        elif layer.ffn == "sparse":
+        else:
             ffn = nn.RoutedExperts(
                 plan.hidden_size, plan.expert_width, plan.n_experts,
                 plan.top_k, held=plan.held, shared_width=plan.shared_width,
-                routed_scale=plan.routed_scale, normalize=plan.normalize)
-        else:
-            raise ValueError(f"unknown feed-forward kind {layer.ffn!r}")
-        block = nn.DecoderBlock(plan.hidden_size, attn, ffn, eps=plan.eps)
+                routed_scale=plan.routed_scale, normalize=plan.normalize,
+                shared_gate=plan.shared_gate)
+        block = nn.DecoderBlock(plan.hidden_size, attn, ffn, eps=plan.eps,
+                                zero_centred=zero)
         model.add(nn.Remat(block) if remat else block)
-    model.add(nn.RMSNorm(plan.hidden_size, plan.eps))
+    model.add(nn.RMSNorm(plan.hidden_size, plan.eps, zero_centred=zero))
     model.add(VocabHead(plan.hidden_size, plan.vocab_size))
     return model
 

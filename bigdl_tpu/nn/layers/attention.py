@@ -291,7 +291,13 @@ class GroupedQueryAttention(Module):
     ``rotary``: a :class:`Rotary` applied to q and k, or None.
     ``gate="per_head"``: one sigmoid gate a head and position, computed
     from the layer's input, scales that head's output before the output
-    projection.
+    projection.  ``gate="per_channel"``: one gate a channel of every
+    head, and it comes out of the query projection, which is then twice
+    as wide (a head's ``head_dim`` query channels, then its ``head_dim``
+    gate channels).
+    ``qk_norm``: a callable ``head_dim -> Module`` that makes the norm
+    of one head's query and, called again, of one head's key (an
+    ``RMSNorm``); both are applied before the rotation.
 
     ``backend``: ``auto`` (the rule of ``ops.attention.
     select_attention_backend``: the Pallas flash kernels on a TPU from
@@ -306,24 +312,29 @@ class GroupedQueryAttention(Module):
     def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, window: Optional[int] = None,
                  rotary: Optional[Rotary] = None,
-                 gate: Optional[str] = None, backend: str = "auto"):
+                 gate: Optional[str] = None, backend: str = "auto",
+                 qk_norm=None):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads over "
                              f"{num_kv_heads} kv heads")
-        if gate not in (None, "per_head"):
+        if gate not in (None, "per_head", "per_channel"):
             raise ValueError(f"unknown gate {gate!r}")
         self.embed_dim, self.head_dim = embed_dim, head_dim
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.window, self.rotary, self.gate = window, rotary, gate
         self.backend = backend
-        self.q_proj = Linear(embed_dim, num_heads * head_dim, with_bias=False)
+        q_width = 2 * head_dim if gate == "per_channel" else head_dim
+        self.q_proj = Linear(embed_dim, num_heads * q_width, with_bias=False)
         self.k_proj = Linear(embed_dim, num_kv_heads * head_dim,
                              with_bias=False)
         self.v_proj = Linear(embed_dim, num_kv_heads * head_dim,
                              with_bias=False)
-        if gate:
+        if gate == "per_head":
             self.gate_proj = Linear(embed_dim, num_heads, with_bias=False)
+        if qk_norm is not None:
+            self.q_norm, self.k_norm = qk_norm(head_dim), qk_norm(head_dim)
+        self.qk_norm = qk_norm is not None
         self.out_proj = Linear(num_heads * head_dim, embed_dim,
                                with_bias=False)
 
@@ -338,7 +349,8 @@ class GroupedQueryAttention(Module):
         if backend == "auto":
             backend, reason = select_attention_backend(s, s)
         facts = dict(window=self.window, q_heads=self.num_heads,
-                     kv_heads=self.num_kv_heads)
+                     kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+                     gate=self.gate, qk_norm=self.qk_norm)
         if backend == "flash":
             bq, bk, visited, total = flash_blocks(s, s, True, self.window)
             facts.update(block_q=bq, block_k=bk, blocks_visited=visited,
@@ -355,14 +367,18 @@ class GroupedQueryAttention(Module):
         # one GEMM for every projection of the same input, as
         # MultiHeadAttention does; the parameters stay separate
         ws = [self.q_proj.weight, self.k_proj.weight, self.v_proj.weight]
-        if self.gate:
+        if self.gate == "per_head":
             ws.append(self.gate_proj.weight)
         fused = jnp.dot(input, jnp.concatenate(ws, axis=0).T.astype(
             input.dtype))
         q, k, v, *gate = jnp.split(
             fused, np.cumsum([w.shape[0] for w in ws])[:-1].tolist(), axis=-1)
+        if self.gate == "per_channel":
+            q, channel_gate = jnp.split(q.reshape(b, s, h, 2 * d), 2, axis=-1)
         q = q.reshape(b, s, h, d)
         k = k.reshape(b, s, g, d)
+        if self.qk_norm:
+            q, k = self.q_norm.forward(q), self.k_norm.forward(k)
         if self.rotary is not None:
             q, k = self.rotary.apply(q), self.rotary.apply(k)
         out = self._attend(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
@@ -371,6 +387,9 @@ class GroupedQueryAttention(Module):
         if gate:
             out = out * jax.nn.sigmoid(
                 gate[0].astype(jnp.float32)).astype(out.dtype)[..., None]
+        elif self.gate == "per_channel":
+            out = out * jax.nn.sigmoid(
+                channel_gate.astype(jnp.float32)).astype(out.dtype)
         return self.out_proj.forward(out.reshape(b, s, h * d))
 
     def __repr__(self):
@@ -381,17 +400,18 @@ class GroupedQueryAttention(Module):
 class DecoderBlock(Module):
     """Pre-norm decoder block with RMS normalisation: ``h = x +
     attn(norm1(x))``, ``y = h + ffn(norm2(h))``.  ``attn`` and ``ffn`` are
-    modules over [batch, seq, embed] (a :class:`GroupedQueryAttention`; a
-    ``GatedMLP`` or a ``RoutedExperts``)."""
+    modules over [batch, seq, embed] (a :class:`GroupedQueryAttention` or a
+    ``GatedDeltaNet``; a ``GatedMLP`` or a ``RoutedExperts``);
+    ``zero_centred`` is the two norms' (``RMSNorm``)."""
 
     def __init__(self, embed_dim: int, attn: Module, ffn: Module,
-                 eps: float = 1e-6):
+                 eps: float = 1e-6, zero_centred: bool = False):
         super().__init__()
         from bigdl_tpu.nn.layers.normalization import RMSNorm
 
-        self.norm1 = RMSNorm(embed_dim, eps)
+        self.norm1 = RMSNorm(embed_dim, eps, zero_centred)
         self.attn = attn
-        self.norm2 = RMSNorm(embed_dim, eps)
+        self.norm2 = RMSNorm(embed_dim, eps, zero_centred)
         self.ffn = ffn
 
     def update_output(self, input):

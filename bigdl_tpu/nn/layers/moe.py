@@ -189,7 +189,9 @@ class RoutedExperts(Module):
     largest; ``w_e = routed_scale * p_e / sum of the chosen p``
     (``normalize``).  Result: ``shared(x) + sum over the chosen experts
     that are HELD here of w_e * expert_e(x)``; every expert and the
-    shared expert is a gated-SiLU feed-forward.  ``held=(first, count)``
+    shared expert is a gated-SiLU feed-forward.  ``shared_gate``: the
+    shared expert's result is scaled by ``sigmoid(x w_s)``, one scalar a
+    token from a weight of its own.  ``held=(first, count)``
     names the contiguous experts this layer has parameters for (default:
     all); what the others would add is left out, as it is on one chip
     of an expert-parallel deployment before the combine.
@@ -214,11 +216,16 @@ class RoutedExperts(Module):
 
     #: the fast path's rows over the expected load
     CAPACITY_FACTOR = 4.0
+    #: held experts x rows of one block of the exact path: the largest
+    #: that leaves a step of 8 held experts x 8,192 tokens one block, the
+    #: compiled step it was before the blocks
+    EXACT_ROWS = 65536
 
     def __init__(self, d_model: int, width: int, n_experts: int, top_k: int,
                  held: Optional[Tuple[int, int]] = None,
                  shared_width: Optional[int] = None,
-                 routed_scale: float = 1.0, normalize: bool = True):
+                 routed_scale: float = 1.0, normalize: bool = True,
+                 shared_gate: bool = False):
         super().__init__()
         from bigdl_tpu.nn.init import RandomUniform
         from bigdl_tpu.nn.layers.linear import Linear
@@ -241,6 +248,9 @@ class RoutedExperts(Module):
         if shared_width:
             self.shared = GatedMLP(d_model, shared_width)
         self.shared_width = shared_width
+        self.shared_gated = bool(shared_gate and shared_width)
+        if self.shared_gated:
+            self.shared_gate = Linear(d_model, 1, with_bias=False)
         self.register_buffer("held_load", jnp.zeros((count + 1,), jnp.int32))
 
     def capacity(self, n_tokens: int) -> int:
@@ -290,10 +300,25 @@ class RoutedExperts(Module):
             token].add(out)
 
     def _masked(self, x2, w, local, counts=None):
-        """The exact path: each held expert over every token."""
+        """The exact path: each held expert over every token, the tokens
+        in blocks of at most ``EXACT_ROWS / count`` rows, each block
+        recomputed in the backward pass (a ``lax.cond`` holds the memory
+        of the branch it does not take too, and what a pass over every
+        token keeps for its backward grows with tokens x experts)."""
         hit = local[:, :, None] == jnp.arange(self.count)[None, None, :]
         w_dense = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)  # [T,C]
+        t = rows = x2.shape[0]
+        while rows * self.count > self.EXACT_ROWS and rows % 2 == 0:
+            rows //= 2
+        if rows == t:
+            return self._masked_rows(x2, w_dense)
+        y = jax.lax.map(
+            jax.checkpoint(lambda block: self._masked_rows(*block)),
+            (x2.reshape(t // rows, rows, -1),
+             w_dense.reshape(t // rows, rows, -1)))
+        return y.reshape(t, self.d_model)
 
+    def _masked_rows(self, x2, w_dense):
         def one(y, e):
             wg, wu, wd, we = e
             h = jax.nn.silu(x2 @ wg.astype(x2.dtype)) \
@@ -338,7 +363,11 @@ class RoutedExperts(Module):
             [counts[:self.count], spilled[None]]))
         y = y.astype(input.dtype)
         if self.shared_width:
-            y = y + self.shared.forward(x2)
+            shared = self.shared.forward(x2)
+            if self.shared_gated:
+                shared = (shared * jax.nn.sigmoid(self.shared_gate.forward(
+                    x2).astype(jnp.float32))).astype(y.dtype)
+            y = y + shared
         return y.reshape(input.shape)
 
     def step_counters(self, buffers, tele, layer: str):

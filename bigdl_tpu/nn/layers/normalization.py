@@ -379,18 +379,27 @@ class RMSNorm(Module):
     """Root-mean-square normalisation over the last dimension (Zhang &
     Sennrich 2019): ``w * x / sqrt(mean(x^2) + eps)``, no centring and
     no bias.  The statistics are taken in float32 whatever the
-    activations' dtype; the result comes back in it."""
+    activations' dtype; the result comes back in it.
 
-    def __init__(self, normalized_size: int, eps: float = 1e-6):
+    ``zero_centred``: the scale is ``1 + w`` with ``w`` starting at 0
+    (the form some decoder families keep, so that weight decay pulls the
+    scale to one and not to zero)."""
+
+    def __init__(self, normalized_size: int, eps: float = 1e-6,
+                 zero_centred: bool = False):
         super().__init__()
         self.normalized_size = normalized_size
         self.eps = eps
-        self.weight = Parameter(jnp.ones((normalized_size,), jnp.float32))
+        self.zero_centred = zero_centred
+        init = jnp.zeros if zero_centred else jnp.ones
+        self.weight = Parameter(init((normalized_size,), jnp.float32))
 
     def update_output(self, input):
         x = input.astype(jnp.float32)
         y = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps)
-        return (y * self.weight.astype(jnp.float32)).astype(input.dtype)
+        scale = self.weight.astype(jnp.float32)
+        y = y * (1.0 + scale if self.zero_centred else scale)
+        return y.astype(input.dtype)
 
     def __repr__(self):
         return f"RMSNorm({self.normalized_size})"

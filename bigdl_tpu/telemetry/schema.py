@@ -136,6 +136,14 @@ STREAM_NAMES = frozenset({
     # rows that took the exact path (counters, emitted by the Optimizer
     # where it has the loss on the host)
     "moe/route", "moe/load", "moe/exact_rows",
+    # linear attention (bigdl_tpu/nn/layers/linear_attention.py
+    # GatedDeltaNet): per step and layer the mean decay exp(g), the mean
+    # beta and the largest state norm over the heads after the last
+    # token (counters, emitted by the Optimizer where it has the loss on
+    # the host; the rule's own trace-time decision is a kernel/dispatch
+    # instant with op=gated_delta_rule)
+    "linear_attn/decay_mean", "linear_attn/beta_mean",
+    "linear_attn/state_norm_max",
     # fault tolerance (bigdl_tpu/faults.py + docs/fault_tolerance.md):
     # injected faults, quarantined torn checkpoints, graceful
     # preemption, and checkpoint auto-resume

@@ -1,5 +1,7 @@
 """The windowed, grouped flash-attention kernels at the shapes of the
-benchmark's ``laguna_s_2_1`` cell, compiled for a described TPU v5e in
+benchmark's ``laguna_s_2_1`` cell, and the gated delta rule's chunked
+scan and the 256-wide gated attention at those of its
+``qwen3_next_80b_a3b`` cell, compiled for a described TPU v5e in
 the style of ``test_chip_compile.py`` (whose fixtures describe the
 topology inside a module-scoped fixture and steer ``is_tpu_device``):
 ``[1, 72, 8192, 128]`` queries over 8 kv heads under a 512 window, and
@@ -23,10 +25,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CASES = {"window": (72, 512), "full": (48, None)}
 
 
-def _traced_text(heads, window, sharding):
-    q = jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16,
+def _traced_text(heads, window, sharding, kv_heads=8, seq=8192, dim=128):
+    q = jax.ShapeDtypeStruct((1, heads, seq, dim), jnp.bfloat16,
                              sharding=sharding)
-    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((1, kv_heads, seq, dim), jnp.bfloat16,
                               sharding=sharding)
 
     def fwd_bwd(q, k, v, do):
@@ -34,7 +36,10 @@ def _traced_text(heads, window, sharding):
             *a, causal=True, window=window), q, k, v)
         return out, vjp(do)
 
-    compiled = jax.jit(fwd_bwd).lower(q, kv, kv, q).compile()
+    return _as_traced(jax.jit(fwd_bwd).lower(q, kv, kv, q).compile())
+
+
+def _as_traced(compiled):
     from jax._src.lib import xla_client
 
     options = xla_client._xla.HloPrintOptions()
@@ -57,3 +62,56 @@ def test_cell_attention_kernels_compile_and_are_found(family, one_chip,  # noqa:
     for k in kernels:
         found = [line for line in calls if re.search(k["match"], line)]
         assert len(found) == (1 if k["family"] == family else 0), k["name"]
+
+
+def _qwen3_next():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3_next_80b_a3b.json")) as fh:
+        return json.load(fh)
+
+
+def test_gated_256_wide_attention_compiles_and_is_found(one_chip,  # noqa: F811
+                                                        as_tpu):  # noqa: F811
+    """16 query heads of 256 on 2 kv heads at 16,384 positions: the blocks
+    the 128-wide cells run at fit the chip's fast memory at twice the
+    head size too."""
+    text = _traced_text(16, None, one_chip, kv_heads=2, seq=16384, dim=256)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    for k in _qwen3_next()["attention_kernels"]:
+        assert len([c for c in calls if re.search(k["match"], c)]) == 1, \
+            k["name"]
+
+
+def test_delta_rule_scan_compiles_and_is_found(one_chip):  # noqa: F811
+    """The chunked scan with its backward at 32 heads of 128 x 128 over
+    16,384 positions: it fits, its triangular systems are inverted by
+    XLA's own block inversion once a forward (and once more where the
+    backward computes the chunks again), a forward and a backward scan
+    over the 256 chunks are there, and the configuration's patterns find
+    them and tell them apart."""
+    from bigdl_tpu.ops.delta_rule import gated_delta_rule
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fwd_bwd(q, k, v, g, beta, do):
+        out, vjp = jax.vjp(gated_delta_rule, q, k, v, g, beta)
+        return out, vjp(do)
+
+    wide = shaped(1, 32, 16384, 128)
+    row = shaped(1, 32, 16384, dtype=jnp.float32)
+    compiled = jax.jit(fwd_bwd).lower(wide, wide, wide, row, row,
+                                      wide).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+    lines = _as_traced(compiled).splitlines()
+    conf = _qwen3_next()
+    found = {k["name"]: [ln for ln in lines if re.search(k["match"], ln)]
+             for k in conf["delta_kernels"]}
+    assert len(found["delta.scan"]) == 2
+    assert len(found["delta.bwd_scan"]) == 1
+    assert found["delta.bwd_scan"][0] in found["delta.scan"]
+    assert all(re.search(conf["delta_match"], ln)
+               for ln in found["delta.scan"])
+    assert [ln for ln in lines if "InvertDiagBlocksLowerTriangular" in ln
+            and re.search(conf["delta_match"], ln)]
