@@ -128,7 +128,10 @@ STREAM_NAMES = frozenset({
     "compile/cache_hit", "compile/cache_miss", "compile/cache",
     # kernel dispatch (bigdl_tpu/ops/dispatch.py): one instant per
     # TRACE-time backend decision — op, backend (pallas|xla), reason —
-    # so attribution can name which backend each module compiled to
+    # so attribution can name which backend each module compiled to;
+    # a leg adds how it was launched (plane kernels: planes_per_block,
+    # grid; op=gated_delta_rule: leg, chunk, chunks, heads, key_dim,
+    # value_dim and, on its Pallas leg, chunks_per_block, grid)
     "kernel/dispatch",
     # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
     # instant per TRACE of a layer (experts, held, top_k, capacity

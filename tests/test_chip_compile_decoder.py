@@ -1,6 +1,7 @@
 """The windowed, grouped flash-attention kernels at the shapes of the
 benchmark's ``laguna_s_2_1`` cell, and the gated delta rule's chunked
-scan and the 256-wide gated attention at those of its
+scan (the XLA leg, and the Pallas leg's two chunk kernels around the
+same scan) and the 256-wide gated attention at those of its
 ``qwen3_next_80b_a3b`` cell, compiled for a described TPU v5e in
 the style of ``test_chip_compile.py`` (whose fixtures describe the
 topology inside a module-scoped fixture and steer ``is_tpu_device``):
@@ -83,17 +84,14 @@ def test_gated_256_wide_attention_compiles_and_is_found(one_chip,  # noqa: F811
             k["name"]
 
 
-def test_delta_rule_scan_compiles_and_is_found(one_chip):  # noqa: F811
-    """The chunked scan with its backward at 32 heads of 128 x 128 over
-    16,384 positions: it fits, its triangular systems are inverted by
-    XLA's own block inversion once a forward (and once more where the
-    backward computes the chunks again), a forward and a backward scan
-    over the 256 chunks are there, and the configuration's patterns find
-    them and tell them apart."""
+def _delta_rule_lines(sharding):
+    """The rule's value and VJP at the cell's shape, compiled: the lines
+    of its text as a trace names them, and what each of the
+    configuration's ``delta_kernels`` patterns finds among them."""
     from bigdl_tpu.ops.delta_rule import gated_delta_rule
 
     def shaped(*shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     def fwd_bwd(q, k, v, g, beta, do):
         out, vjp = jax.vjp(gated_delta_rule, q, k, v, g, beta)
@@ -108,10 +106,50 @@ def test_delta_rule_scan_compiles_and_is_found(one_chip):  # noqa: F811
     conf = _qwen3_next()
     found = {k["name"]: [ln for ln in lines if re.search(k["match"], ln)]
              for k in conf["delta_kernels"]}
+    # a forward and a backward scan over the 256 chunks, told apart
     assert len(found["delta.scan"]) == 2
     assert len(found["delta.bwd_scan"]) == 1
     assert found["delta.bwd_scan"][0] in found["delta.scan"]
     assert all(re.search(conf["delta_match"], ln)
                for ln in found["delta.scan"])
+    return lines, found, conf
+
+
+def test_delta_rule_scan_compiles_and_is_found(one_chip):  # noqa: F811
+    """The chunked scan with its backward at 32 heads of 128 x 128 over
+    16,384 positions, XLA's leg (what a mesh runs): it fits, its
+    triangular systems are inverted by XLA's own block inversion once a
+    forward (and once more where the backward computes the chunks
+    again), a forward and a backward scan over the 256 chunks are there,
+    and the configuration's patterns find them and tell them apart."""
+    from bigdl_tpu.ops import dispatch
+
+    dispatch.clear_decisions()
+    lines, _, conf = _delta_rule_lines(one_chip)
+    assert [d[1] for d in dispatch.decisions()
+            if d[0] == "gated_delta_rule"] == ["xla"]
     assert [ln for ln in lines if "InvertDiagBlocksLowerTriangular" in ln
             and re.search(conf["delta_match"], ln)]
+
+
+def test_delta_rule_kernels_compile_and_are_read_as_the_rule(one_chip,  # noqa: F811
+                                                             as_tpu):  # noqa: F811
+    """The same on a TPU's own leg: the chunk-local work is two Mosaic
+    calls around the same scans.  The benchmark's reading stays in
+    place: two scans and one of them the backward's, no call that
+    ``delta.call_fwd`` or ``delta.call_bwd`` would count on top of them
+    (the kernels return chunked arrays, never ``[.., 32, 16384, 128]``),
+    every Mosaic call named by ``delta_match``, and XLA's block inversion
+    gone with the systems, which stay in VMEM."""
+    from bigdl_tpu.ops import delta_rule, dispatch
+
+    lines, found, conf = _delta_rule_lines(one_chip)
+    (said,) = [d for d in dispatch.decisions() if d[0] == "gated_delta_rule"]
+    assert tuple(said) == ("gated_delta_rule", "pallas", "auto:tpu")
+    assert said.launch["chunks_per_block"] == delta_rule.CHUNK_BLOCK
+    assert said.launch["grid"] == (1, 32, 256 // delta_rule.CHUNK_BLOCK)
+    assert found["delta.call_fwd"] == [] and found["delta.call_bwd"] == []
+    calls = [ln for ln in lines if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert all(re.search(conf["delta_match"], ln) for ln in calls)
+    assert not [ln for ln in lines if "InvertDiagBlocksLowerTriangular" in ln]

@@ -127,6 +127,118 @@ def test_bfloat16_inputs_keep_their_type_and_a_float32_state():
     assert gap <= 2.0 ** -6 * float(jnp.max(jnp.abs(want)))
 
 
+# -- the Pallas leg (interpret mode here) against the XLA leg and the definition -------
+
+# (sequence, decay, beta, dtype) at 2 heads of 128 x 128, chunks of 64 in
+# grid steps of CHUNK_BLOCK: a state forgotten at once and one kept at
+# 0.999 a token, beta at both ends, lengths the chunk block does and
+# does not divide, bfloat16 in and out
+KERNEL_CASES = {
+    "forgets-at-once": (130, "near0", "mid", jnp.float32),
+    "keeps-0.999-beta-near-one": (200, "near1", "near-one", jnp.float32),
+    "beta-near-zero": (150, "mid", "near-zero", jnp.float32),
+    "padded-to-the-chunk-block": (300, "mid", "mid", jnp.float32),
+    "whole-chunk-blocks": (512, "mid", "mid", jnp.float32),
+    "bfloat16": (200, "mid", "mid", jnp.bfloat16),
+}
+
+
+def _kernel_inputs(s, decay, strength, dtype):
+    (q, k, v, g, beta), do = _rule_inputs(s, decay, "mid", b=1, h=2, dk=128,
+                                          dv=128)
+    beta = {"mid": beta, "near-zero": 1e-3 * beta,
+            "near-one": 1.0 - 1e-3 * beta}[strength]
+    return tuple(a.astype(dtype) for a in (q, k, v)) + (g, beta), \
+        do.astype(dtype)
+
+
+@pytest.mark.parametrize("s,decay,strength,dtype", KERNEL_CASES.values(),
+                         ids=KERNEL_CASES)
+def test_pallas_leg_is_the_xla_leg_and_the_definition(s, decay, strength,
+                                                      dtype, monkeypatch):
+    args, do = _kernel_inputs(s, decay, strength, dtype)
+
+    def all_of(rule, mode):
+        def run(*a):
+            (out, state), vjp = jax.vjp(
+                lambda *x: rule(*x, return_state=True), *a)
+            return (out, state) + vjp((do.astype(out.dtype),
+                                       0.1 * jnp.ones_like(state)))
+        monkeypatch.setenv("BIGDL_KERNELS", mode)
+        dispatch.clear_decisions()
+        with jax.default_matmul_precision("highest"):
+            got = jax.jit(run)(*args)
+        return got, [d for d in dispatch.decisions()
+                     if d[0] == "gated_delta_rule"]
+
+    got, said = all_of(gated_delta_rule, "pallas")
+    assert said and all(d[1] == "pallas" for d in said)
+    xla, said = all_of(gated_delta_rule, "xla")
+    assert said and all(d[1] == "xla" for d in said)
+    want, _ = all_of(gated_delta_rule_recurrent, "xla")
+    assert got[0].dtype == dtype and got[1].dtype == jnp.float32
+    # float32: both legs are the definition to rounding; bfloat16: the
+    # legs round the same products, and sit as far from the definition
+    close, far = (2e-5, 2e-5) if dtype == jnp.float32 else (2.0 ** -6,
+                                                              2.0 ** -4)
+    for name, a, b, c in zip(("o", "state", "dq", "dk", "dv", "dg", "dbeta"),
+                             got, xla, want):
+        a, b, c = (x.astype(jnp.float32) for x in (a, b, c))
+        scale = max(float(jnp.max(jnp.abs(c))), 1e-3)
+        assert float(jnp.max(jnp.abs(a - b))) < close * scale, name
+        assert float(jnp.max(jnp.abs(a - c))) < far * scale, name
+
+
+# (kernel mode, on a TPU, under a mesh, head size) -> (backend, reason)
+DISPATCH_CASES = {
+    "auto-off-tpu": (None, False, False, 128, "xla", "auto:off-tpu"),
+    "dims-of-16": ("pallas", False, False, 16, "xla", "unsupported-shape"),
+    "partitioned": (None, True, True, 128, "xla", "auto:spmd-partitioned"),
+    "auto-on-tpu": (None, True, False, 128, "pallas", "auto:tpu"),
+    "forced": ("pallas", False, False, 128, "pallas",
+               "forced:BIGDL_KERNELS=pallas"),
+    "switched-off": ("xla", True, False, 128, "xla",
+                     "forced:BIGDL_KERNELS=xla"),
+}
+
+
+@pytest.mark.parametrize("mode,on_tpu,meshed,dim,backend,reason",
+                         DISPATCH_CASES.values(), ids=DISPATCH_CASES)
+def test_the_rule_picks_its_leg_from_what_it_can_see(mode, on_tpu, meshed, dim,
+                                                     backend, reason,
+                                                     monkeypatch):
+    from bigdl_tpu.ops import attention, delta_rule
+    from bigdl_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(attention, "is_tpu_device", lambda: on_tpu)
+    if mode is None:
+        monkeypatch.delenv("BIGDL_KERNELS", raising=False)
+    else:
+        monkeypatch.setenv("BIGDL_KERNELS", mode)
+    wide = jax.ShapeDtypeStruct((1, 2, 1100, dim), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((1, 2, 1100), jnp.float32)
+    mesh = make_mesh((2,), devices=jax.devices()[:2]) if meshed else None
+    dispatch.clear_decisions()
+    with dispatch.spmd_partitioned(mesh):
+        # a function of its own: a trace that is cached decides nothing
+        out = jax.eval_shape(lambda *a: gated_delta_rule(*a), wide, wide,
+                             wide, row, row)
+    assert out.shape == wide.shape and out.dtype == jnp.bfloat16
+    (said,) = [d for d in dispatch.decisions() if d[0] == "gated_delta_rule"]
+    assert tuple(said) == ("gated_delta_rule", backend, reason)
+    assert said.launch["chunk"] == 64 and said.launch["heads"] == 2
+    if backend == "pallas":
+        per_step = delta_rule.CHUNK_BLOCK
+        chunks = -(-1100 // (64 * per_step)) * per_step
+        assert said.launch == dict(
+            leg="chunk-kernels-scan", chunk=64, chunks=chunks, heads=2,
+            key_dim=dim, value_dim=dim, chunks_per_block=per_step,
+            grid=(1, 2, chunks // per_step))
+    else:
+        assert said.launch == dict(leg="chunked-scan", chunk=64, chunks=18,
+                                   heads=2, key_dim=dim, value_dim=dim)
+
+
 # -- the layers, each against the family's plain reference ------------------------
 
 def _draw(rng, *shape, fan_in):
