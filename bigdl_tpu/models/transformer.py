@@ -20,10 +20,13 @@ attention for sequences larger than one chip holds.
 (:class:`DecoderPlan`): RMS normalisation, rotary positions, a mixer
 whose kind differs by layer (grouped-query attention, full or within a
 window, with a gate a head or a channel and optionally normed queries
-and keys; or gated delta-rule linear attention), and a dense gated or a
-routed sparse feed-forward per layer, every block under ``nn.Remat``.
-It trains with the same criterion; the benchmark's ``laguna_s_2_1`` and
-``qwen3_next_80b_a3b`` configurations are such plans at published widths.
+and keys; gated delta-rule linear attention; or a double-gated short
+convolution), and a dense gated or a routed sparse feed-forward per
+layer (softmax scores, or sigmoid scores with a bias that chooses),
+every block under ``nn.Remat``, the head its own matrix or the
+embedding's.  It trains with the same criterion; the benchmark's
+``laguna_s_2_1``, ``qwen3_next_80b_a3b`` and ``lfm2_24b_a2b``
+configurations are such plans at published widths.
 """
 
 from __future__ import annotations
@@ -105,14 +108,18 @@ def build_transformer_lm(vocab_size: int, num_layers: int = 4,
 
 
 #: the kinds of mixer and of feed-forward a :class:`LayerPlan` may name
-ATTENTION_KINDS = ("full", "window", "linear")
+ATTENTION_KINDS = ("full", "window", "linear", "conv")
 FFN_KINDS = ("dense", "sparse")
 
 
 class LayerPlan(NamedTuple):
-    """One decoder layer: ``attention`` is ``"full"``, ``"window"`` or
-    ``"linear"``, ``heads`` its query heads (of a linear layer: its value
-    heads), ``ffn`` ``"dense"`` or ``"sparse"``."""
+    """One decoder layer: ``attention`` is the kind of its MIXER,
+    ``"full"``, ``"window"``, ``"linear"`` or ``"conv"`` (the field keeps
+    the name it had when every mixer was an attention; a ``"conv"`` layer
+    is an :class:`nn.GatedShortConv` and attends to nothing), ``heads``
+    its query heads (of a linear layer: its value heads; a ``"conv"``
+    layer has none and ignores it), ``ffn`` ``"dense"`` or
+    ``"sparse"``."""
     attention: str
     heads: int
     ffn: str
@@ -131,7 +138,13 @@ class DecoderPlan(NamedTuple):
     a sparse layer gates its shared expert.  A ``"linear"`` layer is an
     :class:`nn.GatedDeltaNet` of ``linear_key_heads`` key heads, head
     sizes ``linear_key_dim`` / ``linear_value_dim`` and a convolution of
-    ``linear_conv`` taps."""
+    ``linear_conv`` taps; a ``"conv"`` layer an :class:`nn.GatedShortConv`
+    of ``conv_taps`` taps.  ``router_score`` (``"softmax"`` or
+    ``"sigmoid"``) and ``router_bias`` (a bias an expert that enters the
+    choice of the ``top_k`` and not their weights) are the sparse
+    layers' (:class:`nn.RoutedExperts`).  ``tie_embeddings``: the head
+    projects with the embedding's own matrix, one parameter read in two
+    places."""
     vocab_size: int
     hidden_size: int
     head_dim: int
@@ -157,19 +170,40 @@ class DecoderPlan(NamedTuple):
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv: int = 4
+    conv_taps: int = 3
+    router_score: str = "softmax"
+    router_bias: bool = False
+    tie_embeddings: bool = False
 
 
 class VocabHead(Module):
     """Vocabulary projection (no bias) and log-softmax per position, the
-    log-softmax in float32 whatever the activations' dtype."""
+    log-softmax in float32 whatever the activations' dtype.  ``tied_to``:
+    the model's :class:`nn.LookupTable`, whose ``[vocab, embed]`` matrix
+    is then the projection too (borrowed, not owned: the model has ONE
+    such parameter, and its gradient is the embedding's plus the
+    head's)."""
 
-    def __init__(self, embed_dim: int, vocab_size: int):
+    def __init__(self, embed_dim: int, vocab_size: int,
+                 tied_to: Optional[nn.LookupTable] = None):
         super().__init__()
-        self.proj = nn.Linear(embed_dim, vocab_size, with_bias=False)
+        if tied_to is None:
+            self.proj = nn.Linear(embed_dim, vocab_size, with_bias=False)
+        else:
+            if (tied_to.n_index, tied_to.n_output) != (vocab_size, embed_dim):
+                raise ValueError(
+                    f"a head of {vocab_size} x {embed_dim} tied to a table "
+                    f"of {tied_to.n_index} x {tied_to.n_output}")
+            self.borrow("embedding", tied_to)
+        self.tied = tied_to is not None
 
     def update_output(self, input):
-        logits = self.proj.forward(input).astype(jnp.float32)
-        return jax.nn.log_softmax(logits, axis=-1)
+        if self.tied:
+            logits = jnp.dot(
+                input, self.embedding.weight.T.astype(input.dtype))
+        else:
+            logits = self.proj.forward(input)
+        return jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
 
 
 def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
@@ -190,10 +224,16 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
     def head_norm(n):
         return nn.RMSNorm(n, plan.eps, zero_centred=zero)
 
-    model = nn.Sequential(nn.LookupTable(plan.vocab_size, plan.hidden_size))
+    # a tied table stays on the dense path: the head's gradient is dense
+    # and has to meet the embedding's in one leaf
+    embedding = nn.LookupTable(plan.vocab_size, plan.hidden_size,
+                               sparse=False if plan.tie_embeddings else None)
+    model = nn.Sequential(embedding)
     for layer in plan.layers:
         windowed = layer.attention == "window"
-        if layer.attention == "linear":
+        if layer.attention == "conv":
+            attn = nn.GatedShortConv(plan.hidden_size, taps=plan.conv_taps)
+        elif layer.attention == "linear":
             attn = nn.GatedDeltaNet(
                 plan.hidden_size, plan.linear_key_heads, layer.heads,
                 plan.linear_key_dim, plan.linear_value_dim,
@@ -212,12 +252,14 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
                 plan.hidden_size, plan.expert_width, plan.n_experts,
                 plan.top_k, held=plan.held, shared_width=plan.shared_width,
                 routed_scale=plan.routed_scale, normalize=plan.normalize,
-                shared_gate=plan.shared_gate)
+                shared_gate=plan.shared_gate, score=plan.router_score,
+                select_bias=plan.router_bias)
         block = nn.DecoderBlock(plan.hidden_size, attn, ffn, eps=plan.eps,
                                 zero_centred=zero)
         model.add(nn.Remat(block) if remat else block)
     model.add(nn.RMSNorm(plan.hidden_size, plan.eps, zero_centred=zero))
-    model.add(VocabHead(plan.hidden_size, plan.vocab_size))
+    model.add(VocabHead(plan.hidden_size, plan.vocab_size,
+                        tied_to=embedding if plan.tie_embeddings else None))
     return model
 
 

@@ -97,17 +97,8 @@ class GatedDeltaNet(Module):
         self.out_proj = Linear(values, embed_dim, with_bias=False)
         self.register_buffer("state_stats", jnp.zeros((3,), jnp.float32))
 
-    def _conv(self, x):
-        """x [B, S, C]: ``y_t = sum_i w[:, i] x_{t - (width - 1) + i}``,
-        positions before the first read as zero; then SiLU."""
-        width, s = self.conv_width, x.shape[1]
-        w = self.conv_weight.astype(jnp.float32)
-        padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-        y = sum(padded[:, i:i + s].astype(jnp.float32) * w[:, i]
-                for i in range(width))
-        return jax.nn.silu(y).astype(x.dtype)
-
     def update_output(self, input):
+        from bigdl_tpu.nn.layers.short_conv import causal_depthwise_conv
         from bigdl_tpu.ops.delta_rule import gated_delta_rule
 
         b, s, _ = input.shape
@@ -118,7 +109,11 @@ class GatedDeltaNet(Module):
         qkvz = self.in_proj_qkvz.forward(input)
         ba = self.in_proj_ba.forward(input).astype(f32)
         mixed, z = qkvz[..., :2 * keys + values], qkvz[..., 2 * keys + values:]
-        q, k, v = jnp.split(self._conv(mixed), [keys, 2 * keys], axis=-1)
+        # the convolution is the short-convolution mixer's too; the SiLU
+        # after it is this layer's own
+        mixed = jax.nn.silu(causal_depthwise_conv(
+            mixed, self.conv_weight)).astype(mixed.dtype)
+        q, k, v = jnp.split(mixed, [keys, 2 * keys], axis=-1)
 
         def unit(x):
             x = x.reshape(b, s, hk, dk).astype(f32)

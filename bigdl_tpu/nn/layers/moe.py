@@ -2,13 +2,15 @@
 
 - :class:`RoutedExperts` — what a current decoder's sparse feed-forward
   is, as ONE chip of an expert-parallel deployment computes it: a
-  float32 softmax router over all ``n_experts``, the ``top_k`` largest
+  float32 router over all ``n_experts`` (softmax scores, or sigmoid
+  scores with a bias an expert that enters the choice only), the
+  ``top_k`` largest
   per token with renormalised weights, gated-SiLU experts of which this
   layer HOLDS a contiguous share (``held=(first, count)``), an optional
   shared expert, and no token ever dropped.  It computes its own
   experts' part of the result and nothing that stands in for the other
   chips or their exchange.  This is the layer that runs on a chip (the
-  benchmark's ``laguna_s_2_1`` cell).
+  benchmark's three decoder cells).
 - :class:`MixtureOfExperts` — the GShard/Mesh-TensorFlow DENSE dispatch
   (one-hot capacity-bucketed einsums in float32, ReLU experts, tokens
   over capacity zeroed) whose stacked parameters shard over a mesh
@@ -184,12 +186,20 @@ class GatedMLP(Module):
 class RoutedExperts(Module):
     """Dropless top-k routing over the experts held here.
 
-    Input [..., d_model], output the same shape.  Router: ``p =
-    softmax(x W_r)`` over all ``n_experts`` in float32; the ``top_k``
-    largest; ``w_e = routed_scale * p_e / sum of the chosen p``
-    (``normalize``).  Result: ``shared(x) + sum over the chosen experts
+    Input [..., d_model], output the same shape.  Router, in float32
+    over all ``n_experts``: scores ``p = softmax(x W_r)``
+    (``score="softmax"``) or ``p = sigmoid(x W_r)``, each expert on its
+    own (``score="sigmoid"``); the ``top_k`` experts with the largest
+    ``p``, or with the largest ``p + b`` under ``select_bias`` (``b``
+    one number an expert, a parameter whose gradient is exactly zero:
+    it moves the CHOICE and never a weight; a recipe that balances the
+    load moves it without a gradient, and none does here); ``w_e =
+    routed_scale * p_e / sum of the chosen p`` (``normalize``; sigmoid
+    scores add ``SIGMOID_NORM_EPS`` to that sum, since it can be near
+    zero).  Result: ``shared(x) + sum over the chosen experts
     that are HELD here of w_e * expert_e(x)``; every expert and the
-    shared expert is a gated-SiLU feed-forward.  ``shared_gate``: the
+    shared expert is a gated-SiLU feed-forward; ``shared_width`` 0 or
+    None: no shared expert.  ``shared_gate``: the
     shared expert's result is scaled by ``sigmoid(x w_s)``, one scalar a
     token from a weight of its own.  ``held=(first, count)``
     names the contiguous experts this layer has parameters for (default:
@@ -214,6 +224,10 @@ class RoutedExperts(Module):
     rows that took the exact path.  A ``moe/route`` instant at trace
     time says how the layer was built."""
 
+    #: the score functions a router may have
+    SCORES = ("softmax", "sigmoid")
+    #: added to the sum of the chosen sigmoid scores before it divides
+    SIGMOID_NORM_EPS = 1e-6
     #: the fast path's rows over the expected load
     CAPACITY_FACTOR = 4.0
     #: held experts x rows of one block of the exact path: the largest
@@ -225,7 +239,8 @@ class RoutedExperts(Module):
                  held: Optional[Tuple[int, int]] = None,
                  shared_width: Optional[int] = None,
                  routed_scale: float = 1.0, normalize: bool = True,
-                 shared_gate: bool = False):
+                 shared_gate: bool = False, score: str = "softmax",
+                 select_bias: bool = False):
         super().__init__()
         from bigdl_tpu.nn.init import RandomUniform
         from bigdl_tpu.nn.layers.linear import Linear
@@ -233,6 +248,10 @@ class RoutedExperts(Module):
         first, count = held if held is not None else (0, n_experts)
         if not (0 <= first and first + count <= n_experts and count > 0):
             raise ValueError(f"held={held} outside {n_experts} experts")
+        if score not in self.SCORES:
+            raise ValueError(f"unknown router score {score!r}; known: "
+                             f"{', '.join(self.SCORES)}")
+        self.score = score
         self.d_model, self.width = d_model, width
         self.n_experts, self.top_k = n_experts, top_k
         self.first, self.count = first, count
@@ -245,6 +264,10 @@ class RoutedExperts(Module):
         self.experts_down = Parameter(init.init(
             (count, width, d_model), fan_in=width))
         self.router = Linear(d_model, n_experts, with_bias=False)
+        self.has_select_bias = bool(select_bias)
+        if select_bias:
+            self.select_bias = Parameter(jnp.zeros((n_experts,),
+                                                   jnp.float32))
         if shared_width:
             self.shared = GatedMLP(d_model, shared_width)
         self.shared_width = shared_width
@@ -265,10 +288,21 @@ class RoutedExperts(Module):
         logits = jnp.dot(x2.astype(jnp.float32),
                          self.router.weight.T.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                     self.top_k)
+        if self.score == "softmax":
+            scores, eps = jax.nn.softmax(logits, axis=-1), None
+        else:
+            scores, eps = jax.nn.sigmoid(logits), self.SIGMOID_NORM_EPS
+        if self.has_select_bias:
+            # the bias chooses and does not weigh: the indices carry no
+            # gradient, and the weights are the scores themselves
+            _, top_i = jax.lax.top_k(
+                scores + self.select_bias.astype(jnp.float32), self.top_k)
+            top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+        else:
+            top_p, top_i = jax.lax.top_k(scores, self.top_k)
         if self.normalize:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            total = jnp.sum(top_p, axis=-1, keepdims=True)
+            top_p = top_p / (total if eps is None else total + eps)
         return self.routed_scale * top_p, top_i
 
     def _grouped(self, x2, w, local, counts, cap):
@@ -342,7 +376,9 @@ class RoutedExperts(Module):
         telemetry.instant("moe/route", experts=self.n_experts,
                           held_first=self.first, held=self.count,
                           top_k=self.top_k, tokens=t, capacity=cap,
-                          worst=worst)
+                          worst=worst, score=self.score,
+                          select_bias=self.has_select_bias,
+                          shared=bool(self.shared_width))
         w, experts = self.route(x2)
         local = experts - self.first
         # an assignment to an expert that is not held sorts last
@@ -371,13 +407,20 @@ class RoutedExperts(Module):
         return y.reshape(input.shape)
 
     def step_counters(self, buffers, tele, layer: str):
-        """``moe/load`` per held expert and ``moe/exact_rows`` of the last
-        step, from this layer's buffers as the step left them (the
-        Optimizer calls this where it has the loss on the host)."""
+        """``moe/load`` per held expert, their sum ``moe/held_rows`` (what
+        a cut to a share of the experts drifts in), the largest and the
+        mean of them (``moe/held_rows_max`` over ``moe/held_rows_mean`` is
+        the imbalance the grouped product sees) and ``moe/exact_rows`` of
+        the last step, from this layer's buffers as the step left them
+        (the Optimizer calls this where it has the loss on the host)."""
         load = np.asarray(buffers["held_load"])
-        for i, rows in enumerate(load[:-1]):
+        held = load[:-1]
+        for i, rows in enumerate(held):
             tele.counter("moe/load", int(rows), layer=layer,
                          expert=self.first + i)
+        tele.counter("moe/held_rows", int(held.sum()), layer=layer)
+        tele.counter("moe/held_rows_max", int(held.max()), layer=layer)
+        tele.counter("moe/held_rows_mean", float(held.mean()), layer=layer)
         tele.counter("moe/exact_rows", int(load[-1]), layer=layer)
 
     def __repr__(self):

@@ -145,6 +145,17 @@ class Module:
     def register_buffer(self, name: str, value):
         self.__dict__["_buffers"][name] = jnp.asarray(value)
 
+    def borrow(self, name: str, owner: "Module"):
+        """Keep ``owner`` under ``self.<name>`` WITHOUT owning it: a
+        module that reads a parameter another module of the same tree
+        holds (a vocabulary head tied to the embedding).  ``owner`` is
+        no child of this module, so its parameters are listed once,
+        under the path the tree already gives them; whatever binds
+        state to the tree (``functional_call``, a ``TrainStep``) binds
+        it there, both readers see the one array, and its gradient is
+        the sum over both uses."""
+        self.__dict__[name] = owner
+
     # -- naming ------------------------------------------------------------
     def set_name(self, name: str) -> "Module":
         self.__dict__["_name"] = name

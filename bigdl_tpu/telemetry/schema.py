@@ -131,14 +131,26 @@ STREAM_NAMES = frozenset({
     # so attribution can name which backend each module compiled to;
     # a leg adds how it was launched (plane kernels: planes_per_block,
     # grid; op=gated_delta_rule: leg, chunk, chunks, heads, key_dim,
-    # value_dim and, on its Pallas leg, chunks_per_block, grid)
+    # value_dim and, on its Pallas leg, chunks_per_block, grid;
+    # op=attention: window, q_heads, kv_heads, head_dim and the flash
+    # leg's blocks; op=gated_short_conv: taps, channels, tokens)
     "kernel/dispatch",
     # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
     # instant per TRACE of a layer (experts, held, top_k, capacity
-    # rows), and per step the rows each held expert received and the
-    # rows that took the exact path (counters, emitted by the Optimizer
-    # where it has the loss on the host)
-    "moe/route", "moe/load", "moe/exact_rows",
+    # rows, the router's score function, whether a bias enters the
+    # choice, whether there is a shared expert), and per step the rows
+    # each held expert received, their sum, largest and mean (max over
+    # mean: the imbalance the grouped product sees) and the rows that
+    # took the exact path (counters, emitted by the Optimizer where it
+    # has the loss on the host)
+    "moe/route", "moe/load", "moe/exact_rows", "moe/held_rows",
+    "moe/held_rows_max", "moe/held_rows_mean",
+    # short convolution (bigdl_tpu/nn/layers/short_conv.py
+    # GatedShortConv): per step and layer the root mean square of what
+    # enters the convolution (B * u) and of the layer's output
+    # (counters, as above; the layer's trace-time kernel/dispatch
+    # instant is op=gated_short_conv with taps, channels, tokens)
+    "short_conv/gate_in_rms", "short_conv/out_rms",
     # linear attention (bigdl_tpu/nn/layers/linear_attention.py
     # GatedDeltaNet): per step and layer the mean decay exp(g), the mean
     # beta and the largest state norm over the heads after the last

@@ -26,10 +26,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CASES = {"window": (72, 512), "full": (48, None)}
 
 
-def _traced_text(heads, window, sharding, kv_heads=8, seq=8192, dim=128):
-    q = jax.ShapeDtypeStruct((1, heads, seq, dim), jnp.bfloat16,
+def _traced_text(heads, window, sharding, kv_heads=8, seq=8192, dim=128,
+                 batch=1):
+    q = jax.ShapeDtypeStruct((batch, heads, seq, dim), jnp.bfloat16,
                              sharding=sharding)
-    kv = jax.ShapeDtypeStruct((1, kv_heads, seq, dim), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((batch, kv_heads, seq, dim), jnp.bfloat16,
                               sharding=sharding)
 
     def fwd_bwd(q, k, v, do):
@@ -153,3 +154,102 @@ def test_delta_rule_kernels_compile_and_are_read_as_the_rule(one_chip,  # noqa: 
     assert len(calls) == 2
     assert all(re.search(conf["delta_match"], ln) for ln in calls)
     assert not [ln for ln in lines if "InvertDiagBlocksLowerTriangular" in ln]
+
+
+def _lfm2():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "lfm2_24b_a2b.json")) as fh:
+        return json.load(fh)
+
+
+def test_64_wide_attention_compiles_and_is_found(one_chip, as_tpu):  # noqa: F811
+    """Two records of 32 query heads of 64 on 8 kv heads at 8,192
+    positions: half a lane row a head.  Mosaic takes the blocks the
+    128-wide cells run at as they are (a block's last dimension is the
+    whole head), k and v are not repeated, and the configuration's
+    patterns find the three calls by their flattened ``[64, 8192, 64]``
+    and ``[16, 8192, 64]`` results."""
+    text = _traced_text(32, None, one_chip, kv_heads=8, seq=8192, dim=64,
+                        batch=2)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    assert not re.search(r"bf16\[2,32,8192,64\]\S* broadcast", text)
+    conf = _lfm2()
+    for k in conf["attention_kernels"]:
+        assert len([c for c in calls if re.search(k["match"], c)]) == 1, \
+            k["name"]
+    assert conf["attention_kernel_args"]["full"] == {
+        "heads": 2 * 32, "kv_heads": 2 * 8, "seq": 8192, "head_dim": 64,
+        "window": None, "itemsize": 2, "layers": 1}
+    # the other decoder cells' patterns do not claim these calls
+    for other in ("laguna_s_2_1", "qwen3_next_80b_a3b"):
+        with open(os.path.join(ROOT, "benchmark", "configs",
+                               other + ".json")) as fh:
+            theirs = json.load(fh)["attention_kernels"]
+        assert not [c for c in calls for k in theirs
+                    if re.search(k["match"], c)]
+
+
+def test_gated_short_conv_compiles_and_is_found(one_chip):  # noqa: F811
+    """The mixer's gates and three-tap convolution, value and VJP, at two
+    records of 8,192 positions and 2,048 channels in bfloat16: XLA's
+    fusions for the chip.  The configuration's ``shortconv_match`` finds
+    what reads the projected ``[2, 8192, 6144]`` or carries the three taps
+    and holds no matrix product, its ``shortconv_kernels`` count one
+    forward and one backward, and neither claims a projection."""
+    from bigdl_tpu.nn.layers.short_conv import causal_depthwise_conv
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def gates(projected, taps):
+        gate_in, gate_out, u = jnp.split(projected, 3, axis=-1)
+        return gate_out * causal_depthwise_conv(
+            gate_in * u, taps).astype(projected.dtype)
+
+    def fwd_bwd(projected, taps, do):
+        out, vjp = jax.vjp(gates, projected, taps)
+        return out, vjp(do)
+
+    compiled = jax.jit(fwd_bwd).lower(
+        shaped(2, 8192, 6144), shaped(2048, 3, dtype=jnp.float32),
+        shaped(2, 8192, 2048)).compile()
+    lines = [ln for ln in _as_traced(compiled).splitlines() if " fusion(" in ln
+             and "ENTRY" not in ln]
+    conf = _lfm2()
+    reads = [ln for ln in lines
+             if re.search(r"fusion\(.*bf16\[2,8192,6144\]", ln)]
+    assert len(reads) >= 3 and all(re.search(conf["shortconv_match"], ln)
+                                   for ln in reads)
+    counted = [k["direction"] for ln in lines
+               for k in conf["shortconv_kernels"] if re.search(k["match"], ln)]
+    assert sorted(counted) == ["bwd", "fwd"]
+    for product in (
+            "%fusion.1 = bf16[2,8192,6144]{2,1,0} fusion(bf16[2,8192,2048]"
+            "{2,1,0} %x, bf16[6144,2048]{1,0} %w), kind=kOutput",
+            "%fusion.2 = (f32[2,8192]{1,0}, bf16[2,8192,2048]{2,1,0}) fusion("
+            "bf16[2,8192,2048]{2,1,0} %x, bf16[2048,2048]{1,0} %w, "
+            "bf16[2,8192,6144]{2,1,0} %p, f32[2048]{0} %a, f32[2048]{0} %b, "
+            "f32[2048]{0} %c), kind=kOutput",
+            "%fusion.3 = bf16[2,8192,2048]{2,1,0} fusion(bf16[2,8192,2048]"
+            "{2,1,0} %x, f32[2048]{0} %w, f32[2,8192]{1,0} %r), kind=kLoop"):
+        assert not re.search(conf["shortconv_match"], product)
+        assert not [k for k in conf["shortconv_kernels"]
+                    if re.search(k["match"], product)]
+    step = {
+        "fwd": "%fusion.207 = (f32[]{:T(128)}, bf16[2,8192,2048]{2,1,0:T(8,128)"
+               "(2,1)}) fusion(bf16[2,8192,6144]{2,1,0:T(8,128)(2,1)} "
+               "%fusion.356), kind=kLoop, calls=%fused_computation.407",
+        "again": "%slice_multiply_fusion.20 = bf16[2,8192,2048]{2,1,0:T(8,128)"
+                 "(2,1)} fusion(bf16[2,8192,6144]{2,1,0:T(8,128)(2,1)} "
+                 "%fusion.412), kind=kLoop, calls=%fused_computation.670",
+        "bwd": "%slice_multiply_fusion.19 = (bf16[2,8192,2048]{2,1,0:T(8,128)"
+               "(2,1)}, bf16[2,8192,2048]{2,1,0:T(8,128)(2,1)}) fusion("
+               "bf16[2,8192,6144]{2,1,0:T(8,128)(2,1)} %fusion.412, "
+               "bf16[2,8192,2048]{2,1,0:T(8,128)(2,1)} %get-tuple-element.481)"
+               ", kind=kLoop, calls=%fused_computation.669"}
+    found = {name: [k["direction"] for k in conf["shortconv_kernels"]
+                    if re.search(k["match"], event)]
+             for name, event in step.items()}
+    assert found == {"fwd": ["fwd"], "again": ["fwd"], "bwd": ["bwd"]}
+    assert all(re.search(conf["shortconv_match"], e) for e in step.values())

@@ -429,7 +429,7 @@ def test_registry_decoder_trains_through_local_optimizer_and_is_traced(
         {"moe/load", "moe/exact_rows"}
     said = [r.getMessage() for r in caplog.records
             if r.getMessage().startswith("[Layer ")]
-    assert len(said) == 3 * 2               # sparse layers x counter names
+    assert len(said) == 3 * 5               # sparse layers x counter names
     assert [int(v) for v in said[0].split("moe/load ")[1].split()] == \
         [e["value"] for e in load[-12:-8]]  # the last step's first layer
 
