@@ -7,9 +7,12 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
+from bigdl_tpu import telemetry
 from bigdl_tpu.nn.module import Container, Module
+from bigdl_tpu.ops.attention import FLASH_LSE, FLASH_OUT
 
 __all__ = [
     "Concat", "ConcatTable", "ParallelTable", "MapTable", "TimeDistributed",
@@ -61,6 +64,24 @@ class MapTable(Container):
         return [m.forward(x) for x in input]
 
 
+_save_named = jax.checkpoint_policies.save_only_these_names(FLASH_OUT,
+                                                             FLASH_LSE)
+
+
+def _keep_named(prim, *avals, **params):
+    """What :class:`Remat` keeps when it is given no policy: the values
+    a kernel names, each said on a ``remat/keep`` instant as the
+    backward pass's trace decides it."""
+    keep = _save_named(prim, *avals, **params)
+    if keep:
+        (aval,) = avals
+        telemetry.instant(
+            "remat/keep", kept=params["name"], shape=list(aval.shape),
+            dtype=str(aval.dtype),
+            bytes=aval.size * aval.dtype.itemsize)
+    return keep
+
+
 class Remat(Container):
     """Gradient checkpointing / rematerialization boundary: activations
     inside the wrapped module are NOT saved for the backward pass —
@@ -75,6 +96,17 @@ class Remat(Container):
     Exact: forward values and gradients are bit-identical to the
     unwrapped module (dropout keys derive from the same fold_in chain on
     recompute), only the memory/compute schedule changes.
+
+    One class of value is kept all the same: what a kernel inside the
+    module names for it, today the flash forward kernel's output and
+    logsumexp (``ops.attention.FLASH_OUT``, ``FLASH_LSE``), whose
+    recomputation is quadratic in the sequence and whose bytes are
+    linear in it: ``batch x heads x seq x (head_dim x itemsize + 4)`` an
+    attention layer.  A module that names nothing keeps nothing and
+    lowers as it always did.  Each kept value is said on a trace-time
+    ``remat/keep`` instant (``kept``, ``shape``, ``dtype``, ``bytes``).
+    ``policy``: a policy of ``jax.checkpoint_policies`` in place of
+    that rule (``nothing_saveable`` recomputes the kernel too).
     """
 
     def __init__(self, module: Module, policy=None):
@@ -83,8 +115,6 @@ class Remat(Container):
         self._policy = policy
 
     def update_output(self, input):
-        import jax
-
         from bigdl_tpu.nn.module import load_state_dict, state_dict
 
         inner = self.layers[0]
@@ -98,7 +128,8 @@ class Remat(Container):
             after = state_dict(inner, kind="buffer")
             return out, [after[n] for n in names]
 
-        out, buffers = jax.checkpoint(run, policy=self._policy)(input)
+        policy = _keep_named if self._policy is None else self._policy
+        out, buffers = jax.checkpoint(run, policy=policy)(input)
         load_state_dict(inner, dict(zip(names, buffers)), strict=False)
         return out
 

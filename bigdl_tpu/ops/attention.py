@@ -26,8 +26,11 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = [
+    "FLASH_OUT",
+    "FLASH_LSE",
     "dot_product_attention",
     "flash_attention",
     "flash_blocks",
@@ -40,6 +43,13 @@ __all__ = [
 ]
 
 _NEG_INF = -1e30
+
+#: the names the flash forward kernel's output and logsumexp carry among
+#: the backward rule's residuals (``jax.ad_checkpoint.checkpoint_name``):
+#: ``nn.Remat`` keeps what they name, so its backward pass recomputes a
+#: block without running the kernel again
+FLASH_OUT = "flash_attention/out"
+FLASH_LSE = "flash_attention/lse"
 
 
 # ---------------------------------------------------------------------------
@@ -630,6 +640,10 @@ def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret,
                     window):
     out, lse = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
                                interpret, window)
+    # the two residuals that cost a kernel to rebuild, named for a
+    # checkpoint policy to keep (the identity under any other)
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

@@ -145,6 +145,13 @@ STREAM_NAMES = frozenset({
     # has the loss on the host)
     "moe/route", "moe/load", "moe/exact_rows", "moe/held_rows",
     "moe/held_rows_max", "moe/held_rows_mean",
+    # rematerialization (bigdl_tpu/nn/layers/container_ext.py Remat):
+    # one instant per value a block keeps for its backward pass in place
+    # of recomputing it, as the TRACE of that pass decides it: the name
+    # a kernel gave the value (kept: flash_attention/out,
+    # flash_attention/lse), its shape, dtype and bytes; a block that
+    # names nothing, or is given a policy of its own, says nothing
+    "remat/keep",
     # short convolution (bigdl_tpu/nn/layers/short_conv.py
     # GatedShortConv): per step and layer the root mean square of what
     # enters the convolution (B * u) and of the layer's output

@@ -293,3 +293,141 @@ def test_auto_backend_threshold_routing(monkeypatch):
     assert run(512, tpu=True) == "flash"    # at the threshold: flash
     assert run(256, tpu=True) == "dense"    # below: dense (no spy call)
     assert run(512, tpu=False) == "dense"   # off-TPU: always dense
+
+
+# ---------------------------------------------------------------------------
+# what nn.Remat keeps of a block that holds the flash kernels
+# ---------------------------------------------------------------------------
+
+EMBED, SEQ, BATCH = 32, 32, 2
+NOTHING = jax.checkpoint_policies.nothing_saveable
+
+
+def _head_norm(dim):
+    import bigdl_tpu.nn as nn
+
+    return nn.RMSNorm(dim, 1e-6)
+
+
+#: the benchmark cells' four attention shapes in miniature:
+#: (query heads, kv heads, head size, options of the layer)
+FLASH_BLOCKS = {
+    "full": (4, 2, 16, dict(gate="per_head")),
+    "window": (6, 2, 16, dict(gate="per_head", window=8)),
+    "gated": (4, 1, 32, dict(gate="per_channel", qk_norm=_head_norm)),
+    "heads64": (4, 2, 64, dict(qk_norm=_head_norm)),
+}
+
+
+def _decoder_block(mixer):
+    """A decoder block around one of ``FLASH_BLOCKS`` on the flash leg,
+    or around a mixer that holds no flash kernel."""
+    import bigdl_tpu.nn as nn
+
+    if mixer in FLASH_BLOCKS:
+        heads, kv, dim, options = FLASH_BLOCKS[mixer]
+        attn = nn.GroupedQueryAttention(EMBED, heads, kv, dim,
+                                        rotary=nn.Rotary(dim),
+                                        backend="flash", **options)
+    elif mixer == "dot_product":
+        attn = nn.GroupedQueryAttention(EMBED, 4, 2, 16,
+                                        rotary=nn.Rotary(16), backend="dense")
+    elif mixer == "short_conv":
+        attn = nn.GatedShortConv(EMBED, taps=3)
+    else:
+        attn = nn.GatedDeltaNet(EMBED, 2, 4, 8, 8, conv_width=4)
+    return nn.DecoderBlock(EMBED, attn, nn.GatedMLP(EMBED, 2 * EMBED))
+
+
+def _block_gradient(model):
+    """``(state, x) -> gradient`` of a scalar of the model's output, and
+    its arguments."""
+    from bigdl_tpu.nn.module import functional_call, state_dict
+
+    def loss(state, x):
+        return jnp.sum(functional_call(model, state, x)[0] ** 2)
+
+    x = jnp.asarray(np.random.RandomState(3).randn(BATCH, SEQ, EMBED),
+                    jnp.float32)
+    return jax.grad(loss, argnums=(0, 1)), (state_dict(model), x)
+
+
+def _three_ways(block):
+    import bigdl_tpu.nn as nn
+
+    return {"bare": block, "kept": nn.Remat(block),
+            "recomputed": nn.Remat(block, policy=NOTHING)}
+
+
+@pytest.mark.parametrize("family", FLASH_BLOCKS)
+def test_remat_keeping_flash_results_leaves_gradient_bits(family):
+    """The gradient through ``nn.Remat(DecoderBlock)``, which keeps the
+    forward kernel's output and logsumexp, is the bare block's and the
+    fully recomputed block's byte for byte: kept and recomputed values
+    are the same bits.  Compiled without the backend's optimisations, so
+    that the three programs do the same arithmetic in the same order."""
+    plain = {"xla_backend_optimization_level": 0,
+             "xla_llvm_disable_expensive_passes": True}
+    grads = {}
+    for way, model in _three_ways(_decoder_block(family)).items():
+        grad, args = _block_gradient(model)
+        out = jax.jit(grad).lower(*args).compile(compiler_options=plain)(*args)
+        grads[way] = [np.asarray(leaf) for leaf in jax.tree.leaves(out)]
+    assert all(np.abs(leaf).max() > 0 for leaf in grads["bare"])
+    for way in ("kept", "recomputed"):
+        for ours, bare in zip(grads[way], grads["bare"]):
+            np.testing.assert_array_equal(ours, bare)
+
+
+@pytest.mark.parametrize("family", FLASH_BLOCKS)
+def test_remat_runs_the_flash_forward_once(family, tmp_path):
+    """Three ``pallas_call``s an attention layer in the gradient of a
+    rematerialised block (forward, dq, dk/dv) where a policy that keeps
+    nothing holds four, and a ``remat/keep`` instant for each kept value
+    with its name and bytes."""
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.ops.attention import FLASH_LSE, FLASH_OUT
+    from bigdl_tpu.telemetry import schema
+
+    heads, _, dim, _ = FLASH_BLOCKS[family]
+    calls = {}
+    telemetry.start_run(str(tmp_path))
+    try:
+        for way, model in _three_ways(_decoder_block(family)).items():
+            grad, args = _block_gradient(model)
+            calls[way] = str(jax.make_jaxpr(grad)(*args)).count("pallas_call")
+    finally:
+        telemetry.end_run()
+    assert calls == {"bare": 3, "kept": 3, "recomputed": 4}
+    events, errors = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
+    assert not errors and not schema.validate_events(events)
+    kept = {e["kept"]: e for e in events if e.get("name") == "remat/keep"}
+    assert len(kept) == 2       # the one block that keeps, said once
+    assert kept[FLASH_OUT]["shape"] == [BATCH, heads, SEQ, dim]
+    assert kept[FLASH_OUT]["bytes"] == BATCH * heads * SEQ * dim * 4
+    assert kept[FLASH_LSE]["dtype"] == "float32"
+    assert kept[FLASH_LSE]["bytes"] == BATCH * heads * SEQ * 4
+
+
+@pytest.mark.parametrize("mixer", ["dot_product", "short_conv", "delta_rule"])
+def test_remat_of_a_block_that_names_nothing_lowers_as_before(mixer,
+                                                              tmp_path):
+    """A block without a flash kernel has nothing to keep: its gradient
+    lowers to the text it had when ``nn.Remat`` kept nothing at all, and
+    no ``remat/keep`` instant is sent."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.telemetry import schema
+
+    block = _decoder_block(mixer)
+    texts = []
+    telemetry.start_run(str(tmp_path))
+    try:
+        for model in (nn.Remat(block), nn.Remat(block, policy=NOTHING)):
+            grad, args = _block_gradient(model)
+            texts.append(jax.jit(grad).lower(*args).as_text())
+    finally:
+        telemetry.end_run()
+    assert texts[0] == texts[1]
+    events, _ = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
+    assert not [e for e in events if e.get("name") == "remat/keep"]

@@ -253,3 +253,61 @@ def test_gated_short_conv_compiles_and_is_found(one_chip):  # noqa: F811
              for name, event in step.items()}
     assert found == {"fwd": ["fwd"], "again": ["fwd"], "bwd": ["bwd"]}
     assert all(re.search(conf["shortconv_match"], e) for e in step.values())
+
+
+#: a decoder cell's attention layers: configuration, pattern family and
+#: the kernels' shape (batch, query heads, kv heads, head size,
+#: positions, window)
+REMAT_LAYERS = {
+    "laguna.full": ("laguna_s_2_1", "full", 1, 48, 8, 128, 8192, None),
+    "laguna.window": ("laguna_s_2_1", "window", 1, 72, 8, 128, 8192, 512),
+    "qwen3_next.gated": ("qwen3_next_80b_a3b", "full", 1, 16, 2, 256, 16384,
+                         None),
+    "lfm2.heads64": ("lfm2_24b_a2b", "full", 2, 32, 8, 64, 8192, None),
+}
+
+
+@pytest.mark.parametrize("layer", REMAT_LAYERS)
+def test_remat_holds_one_forward_call_the_patterns_find(
+        layer, one_chip, as_tpu):  # noqa: F811
+    """The flash kernels at each decoder cell's shape under ``nn.Remat``,
+    compiled for the chip: the backward pass keeps the forward kernel's
+    output and logsumexp, so the gradient holds ONE forward call beside
+    dq and dk/dv (a policy that keeps nothing holds two:
+    ``tests/test_attention.py`` counts both in whole blocks), each still
+    found by the configuration's ``attention_kernels`` pattern (operand
+    and result shapes are what they were: the benchmark's readers keep
+    reading them)."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.nn.module import functional_call
+
+    config, family, batch, heads, kv_heads, dim, seq, window = \
+        REMAT_LAYERS[layer]
+
+    class Flash(nn.Module):
+        def update_output(self, qkv):
+            return attention.flash_attention(*qkv, causal=True,
+                                             window=window)
+
+    model = nn.Remat(Flash())
+
+    def loss(*qkv):
+        return jnp.sum(functional_call(model, {}, list(qkv))[0]
+                       .astype(jnp.float32) ** 2)
+
+    q = jax.ShapeDtypeStruct((batch, heads, seq, dim), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((batch, kv_heads, seq, dim), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    calls = [line for line in _as_traced(compiled).splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as fh:
+        kernels = json.load(fh)["attention_kernels"]
+    found = {k["direction"]: len([c for c in calls
+                                  if re.search(k["match"], c)])
+             for k in kernels if k["family"] == family}
+    assert found == {"fwd": 1, "dq": 1, "dkv": 1}

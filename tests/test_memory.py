@@ -125,8 +125,53 @@ def test_remat_lowers_activations_at_peak():
     acts_plain, _ = peak_acts(False)
     acts_remat, out_remat = peak_acts(True)
     assert acts_remat < 0.5 * acts_plain, (acts_remat, acts_plain)
+    # on a TPU these blocks take the flash leg and each keeps its
+    # forward kernel's output and logsumexp (the test below): with them
+    # the rematerialised model still holds under half
+    kept = 2 * (2 * 4 * 256 * 32 * 4 + 2 * 4 * 256 * 4)
+    assert acts_remat + kept < 0.5 * acts_plain, (acts_remat, kept)
     # and the whole peak shrinks too — remat trades HBM for FLOPs
     assert out_remat["peak_bytes"] > 0
+
+
+def test_remat_of_flash_blocks_saves_the_kernel_results_and_little_else():
+    """Blocks on the flash leg under ``nn.Remat`` keep the forward
+    kernel's output and logsumexp for the backward pass: JAX's own list
+    of saved residuals holds ``batch x heads x seq x head_dim`` in the
+    compute dtype and ``batch x heads x seq`` float32 a layer and, beside
+    them, only arguments, constants and the values that cross from one
+    block to the next.  (The walker cannot say it here: in the
+    interpreted kernel's loops the two are carried buffers without a
+    scope.)"""
+    import jax.numpy as jnp
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from bigdl_tpu.nn.module import functional_call, state_dict
+
+    batch, seq, embed, heads, layers = 2, 256, 128, 4, 2
+    blocks = nn.Sequential(*[
+        nn.Remat(nn.TransformerBlock(embed, heads, causal=True,
+                                     backend="flash"))
+        for _ in range(layers)])
+
+    def loss(state, x):
+        return jnp.sum(functional_call(blocks, state, x)[0]
+                       .astype(jnp.float32))
+
+    half = jax.tree.map(lambda a: a.astype(jnp.bfloat16), state_dict(blocks))
+    saved = saved_residuals(loss, half,
+                            jnp.zeros((batch, seq, embed), jnp.bfloat16))
+    kernel = [aval for aval, _ in saved
+              if aval.shape in ((batch, heads, seq, embed // heads),
+                                (batch, heads, seq))]
+    assert sum(a.size * a.dtype.itemsize for a in kernel) == layers * (
+        batch * heads * seq * (embed // heads) * 2 + batch * heads * seq * 4)
+    assert sorted(str(a.dtype) for a in kernel) == \
+        ["bfloat16"] * layers + ["float32"] * layers
+    # beside them: arguments, constants, and what enters the next block
+    assert all(aval.shape == (batch, seq, embed) or "argument" in why
+               or "constant" in why
+               for aval, why in saved if aval not in kernel)
 
 
 def test_scope_of_drops_bare_remat_frames():
