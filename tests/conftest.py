@@ -46,18 +46,91 @@ def pytest_configure(config):
         "markers", "slow: excluded from the tier-1 `-m 'not slow'` run")
 
 
+# Tracing allocates containers by the million.  At the interpreter's
+# default a young collection (with jax's own callback on it) starts every
+# 700 allocations, 1,400 times in a minute of tests, and every tenth
+# promotes: ``test_kernels``, ``test_numeric_grads`` and ``test_analysis``
+# took 131 s of the suite and take 97 s at these thresholds (PR 37).
+gc.set_threshold(50_000, 20, 100)
+
+
+def _collect_and_freeze():
+    """What is alive now stays: it goes to the collector's permanent
+    generation.  Collection imports jax, torch and every test module,
+    millions of objects that every later collection of an older
+    generation walked again: the three modules named above take 72 s
+    with this."""
+    gc.collect()
+    gc.freeze()
+
+
+def pytest_collection_finish(session):
+    _collect_and_freeze()
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _drop_compiled_programs():
     """A module's compiled programs go when the module is done.  Kept,
     they are ~200 MB a module and 3 GB after ten, and every later trace
     and compile in the process slows with them: ``test_windowed_fuzz``
     takes 40 s alone, 50 s after four other modules and 89 s at the end
-    of the whole suite, and 39 s after those four with this in place."""
+    of the whole suite, and 39 s after those four with this in place.
+    What the module imported late (tensorflow, a zoo) is frozen too."""
     yield
     import jax
 
     jax.clear_caches()
-    gc.collect()
+    _collect_and_freeze()
+
+
+@pytest.fixture(scope="session")
+def topo():
+    """A ``v5e:2x2`` topology that is described, not present: the installed
+    TPU compiler lowers for its devices.  Described once a session (3 s),
+    inside a fixture and never at import: libtpu belongs to one process
+    at a time."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="session")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Dispatch as it decides on a TPU, in the default kernel mode
+    (``is_tpu_device()`` sees the CPU here)."""
+    from bigdl_tpu.ops import attention, dispatch
+
+    monkeypatch.setattr(attention, "is_tpu_device", lambda: True)
+    monkeypatch.delenv("BIGDL_KERNELS", raising=False)
+    dispatch.clear_decisions()
+
+
+@pytest.fixture(scope="module")
+def described_compiles_stay_out_of_the_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip: the cache is off for a
+    module that compiles so."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
 
 
 @pytest.fixture(autouse=True)

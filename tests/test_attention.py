@@ -10,6 +10,9 @@ import pytest
 from bigdl_tpu.ops import dot_product_attention, flash_attention
 from bigdl_tpu.parallel.sequence import make_sequence_parallel_attention
 
+#: the oracle, compiled: eagerly it dispatches a dozen programs a call
+dense = jax.jit(dot_product_attention, static_argnames=("causal",))
+
 
 def _rand_qkv(b=2, h=2, s=64, d=16, seed=0, dtype=jnp.float32):
     rng = np.random.RandomState(seed)
@@ -21,8 +24,11 @@ def _rand_qkv(b=2, h=2, s=64, d=16, seed=0, dtype=jnp.float32):
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_matches_dense(causal):
     q, k, v = _rand_qkv(s=64)
-    out_ref = dot_product_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16)
+    out_ref = dense(q, k, v, causal=causal)
+    # under jit: an interpreted kernel dispatches every primitive of
+    # every grid step as its own program
+    out = jax.jit(lambda *a: flash_attention(
+        *a, causal=causal, block_q=16, block_k=16))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -38,8 +44,8 @@ def test_flash_grads_match_dense(causal):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
                                        block_q=8, block_k=8) ** 2)
 
-    g_ref = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    g = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
+    g = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
@@ -50,8 +56,9 @@ def test_flash_cross_attention_lengths():
     q = jnp.asarray(rng.randn(1, 2, 16, 8).astype(np.float32))
     k = jnp.asarray(rng.randn(1, 2, 48, 8).astype(np.float32))
     v = jnp.asarray(rng.randn(1, 2, 48, 8).astype(np.float32))
-    out_ref = dot_product_attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, causal=True, block_q=8, block_k=16)
+    out_ref = dense(q, k, v, causal=True)
+    out = jax.jit(lambda *a: flash_attention(
+        *a, causal=True, block_q=8, block_k=16))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -74,7 +81,7 @@ def test_sequence_parallel_matches_dense(seq_mesh, strategy, causal):
     # under jit, as every caller runs it: an eager shard_map dispatches
     # every primitive of the ring as its own 8-device program
     out = jax.jit(fn)(q, k, v)
-    out_ref = dot_product_attention(q, k, v, causal=causal)
+    out_ref = dense(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -94,17 +101,17 @@ def test_data_x_seq_ring_matches_dense():
     fn = make_sequence_parallel_attention(mesh, strategy="ring",
                                           causal=True, batch_axis="data")
     out = jax.jit(fn)(q, k, v)
-    out_ref = dot_product_attention(q, k, v, causal=True)
+    out_ref = dense(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
     # under jit, as TrainStep differentiates it: an eager shard_map
     # dispatches every primitive of the ring as its own 8-device program
     g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
                          argnums=(0, 1, 2)))(q, k, v)
-    g_ref = jax.grad(
+    g_ref = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(
             dot_product_attention(q, k, v, causal=True) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
@@ -124,7 +131,7 @@ def test_ring_attention_differentiable(seq_mesh):
     # jitted (the form the train step uses; eager shard_map runs each
     # primitive of the ring as its own 8-device program)
     g = jax.jit(jax.grad(loss_sp, argnums=(0, 1, 2)))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
@@ -137,7 +144,7 @@ def test_ring_attention_jits_under_mesh(seq_mesh):
     fn = make_sequence_parallel_attention(seq_mesh, strategy="ring",
                                           causal=True)
     out = jax.jit(fn)(q, k, v)
-    out_ref = dot_product_attention(q, k, v, causal=True)
+    out_ref = dense(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -159,7 +166,7 @@ def test_multihead_attention_layer():
         y, _ = functional_call(mha, p, x, training=True)
         return jnp.sum(y ** 2)
 
-    grads = jax.grad(loss)(params)
+    grads = jax.jit(jax.grad(loss))(params)
     assert set(grads) == set(params)
     assert all(np.isfinite(np.asarray(g)).all() for g in grads.values())
 

@@ -8,11 +8,12 @@ other kernel test runs — sees none of that.  Nothing executes, so these
 cases say nothing about values or times; parity lives in
 ``test_kernels.py`` and the chip run in ``chip_smoke.py``.
 
-The topology is described inside a module-scoped fixture and only
-there: loading libtpu at import (or in a ``skipif``/``parametrize``
-argument, or in ``conftest.py``) would make every pytest worker fight
-over the library's lock.  ``is_tpu_device()`` still sees the CPU here,
-so each case steers it with ``monkeypatch``.
+The topology is the session's (``topo`` and ``one_chip`` of
+``conftest.py``), described inside a fixture and only there: loading
+libtpu at import (or in a ``skipif``/``parametrize`` argument) would
+make every pytest worker fight over the library's lock.
+``is_tpu_device()`` still sees the CPU here, so each case steers it
+with ``as_tpu``.
 """
 
 import json
@@ -22,48 +23,14 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 from bigdl_tpu.ops import attention, dispatch
 from bigdl_tpu.ops.lrn_pallas import cross_map_lrn, within_channel_lrn
 from bigdl_tpu.ops.pool_pallas import avg_pool, maxpool_tie_split
 from test_kernels import _launches
 
-
-@pytest.fixture(scope="module")
-def topo():
-    import os
-
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described device is written to the persistent
-    # cache but cannot be read back without a chip: keep it off here
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture
-def as_tpu(monkeypatch):
-    """Dispatch as it decides on a TPU, in the default kernel mode."""
-    monkeypatch.setattr(attention, "is_tpu_device", lambda: True)
-    monkeypatch.delenv("BIGDL_KERNELS", raising=False)
-    dispatch.clear_decisions()
+pytestmark = pytest.mark.usefixtures(
+    "described_compiles_stay_out_of_the_cache")
 
 
 def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1, as_traced=False):

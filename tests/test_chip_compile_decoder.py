@@ -3,8 +3,8 @@ benchmark's ``laguna_s_2_1`` cell, and the gated delta rule's chunked
 scan (the XLA leg, and the Pallas leg's two chunk kernels around the
 same scan) and the 256-wide gated attention at those of its
 ``qwen3_next_80b_a3b`` cell, compiled for a described TPU v5e in
-the style of ``test_chip_compile.py`` (whose fixtures describe the
-topology inside a module-scoped fixture and steer ``is_tpu_device``):
+the style of ``test_chip_compile.py`` (the session's ``topo``,
+``one_chip`` and ``as_tpu`` of ``conftest.py``):
 ``[1, 72, 8192, 128]`` queries over 8 kv heads under a 512 window, and
 ``[1, 48, 8192, 128]`` full.  Nothing executes.  The compiled text, with
 operand shapes as a device trace names its events, is also what the
@@ -20,10 +20,11 @@ import jax.numpy as jnp
 import pytest
 
 from bigdl_tpu.ops import attention
-from test_chip_compile import as_tpu, one_chip, topo  # noqa: F401
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CASES = {"window": (72, 512), "full": (48, None)}
+pytestmark = pytest.mark.usefixtures(
+    "described_compiles_stay_out_of_the_cache")
 
 
 def _traced_text(heads, window, sharding, kv_heads=8, seq=8192, dim=128,
@@ -51,8 +52,8 @@ def _as_traced(compiled):
 
 
 @pytest.mark.parametrize("family", CASES)
-def test_cell_attention_kernels_compile_and_are_found(family, one_chip,  # noqa: F811
-                                                      as_tpu):  # noqa: F811
+def test_cell_attention_kernels_compile_and_are_found(family, one_chip,
+                                                      as_tpu):
     heads, window = CASES[family]
     text = _traced_text(heads, window, one_chip)
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
@@ -72,8 +73,7 @@ def _qwen3_next():
         return json.load(fh)
 
 
-def test_gated_256_wide_attention_compiles_and_is_found(one_chip,  # noqa: F811
-                                                        as_tpu):  # noqa: F811
+def test_gated_256_wide_attention_compiles_and_is_found(one_chip, as_tpu):
     """16 query heads of 256 on 2 kv heads at 16,384 positions: the blocks
     the 128-wide cells run at fit the chip's fast memory at twice the
     head size too."""
@@ -116,7 +116,7 @@ def _delta_rule_lines(sharding):
     return lines, found, conf
 
 
-def test_delta_rule_scan_compiles_and_is_found(one_chip):  # noqa: F811
+def test_delta_rule_scan_compiles_and_is_found(one_chip):
     """The chunked scan with its backward at 32 heads of 128 x 128 over
     16,384 positions, XLA's leg (what a mesh runs): it fits, its
     triangular systems are inverted by XLA's own block inversion once a
@@ -133,8 +133,8 @@ def test_delta_rule_scan_compiles_and_is_found(one_chip):  # noqa: F811
             and re.search(conf["delta_match"], ln)]
 
 
-def test_delta_rule_kernels_compile_and_are_read_as_the_rule(one_chip,  # noqa: F811
-                                                             as_tpu):  # noqa: F811
+def test_delta_rule_kernels_compile_and_are_read_as_the_rule(one_chip,
+                                                             as_tpu):
     """The same on a TPU's own leg: the chunk-local work is two Mosaic
     calls around the same scans.  The benchmark's reading stays in
     place: two scans and one of them the backward's, no call that
@@ -162,7 +162,7 @@ def _lfm2():
         return json.load(fh)
 
 
-def test_64_wide_attention_compiles_and_is_found(one_chip, as_tpu):  # noqa: F811
+def test_64_wide_attention_compiles_and_is_found(one_chip, as_tpu):
     """Two records of 32 query heads of 64 on 8 kv heads at 8,192
     positions: half a lane row a head.  Mosaic takes the blocks the
     128-wide cells run at as they are (a block's last dimension is the
@@ -190,7 +190,7 @@ def test_64_wide_attention_compiles_and_is_found(one_chip, as_tpu):  # noqa: F81
                     if re.search(k["match"], c)]
 
 
-def test_gated_short_conv_compiles_and_is_found(one_chip):  # noqa: F811
+def test_gated_short_conv_compiles_and_is_found(one_chip):
     """The mixer's gates and three-tap convolution, value and VJP, at two
     records of 8,192 positions and 2,048 channels in bfloat16: XLA's
     fusions for the chip.  The configuration's ``shortconv_match`` finds
@@ -268,8 +268,8 @@ REMAT_LAYERS = {
 
 
 @pytest.mark.parametrize("layer", REMAT_LAYERS)
-def test_remat_holds_one_forward_call_the_patterns_find(
-        layer, one_chip, as_tpu):  # noqa: F811
+def test_remat_holds_one_forward_call_the_patterns_find(layer, one_chip,
+                                                        as_tpu):
     """The flash kernels at each decoder cell's shape under ``nn.Remat``,
     compiled for the chip: the backward pass keeps the forward kernel's
     output and logsumexp, so the gradient holds ONE forward call beside

@@ -7,6 +7,7 @@ layer); dropless routing at both extremes of imbalance; the windowed
 flash kernels in interpret mode; the rotary tables against the formula.
 """
 
+import functools
 import json
 import math
 import os
@@ -17,33 +18,20 @@ import numpy as np
 import pytest
 
 import bigdl_tpu.nn as nn
-from bigdl_tpu import models, telemetry
-from bigdl_tpu.nn.module import functional_call, load_state_dict, state_dict
+from bigdl_tpu.nn.module import functional_call, state_dict
 from bigdl_tpu.ops.attention import (dot_product_attention, flash_attention,
                                      flash_blocks)
+import decoder_cases
+from decoder_cases import (ROOT, call, check_loss_and_every_gradient,
+                           check_routed_gradients, compiled, drawn, routed,
+                           sparse_weights, train_through_local_optimizer)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+tiny_conf = functools.partial(decoder_cases.tiny_conf, "laguna")
 
 
 @pytest.fixture(scope="module")
 def laguna():
-    """The benchmark's family module (``benchmark`` is importable from
-    the repo root, which the suite runs from)."""
-    import sys
-
-    if ROOT not in sys.path:
-        sys.path.insert(0, ROOT)
-    from benchmark.models import laguna as family
-
-    return family
-
-
-def tiny_conf(**over):
-    with open(os.path.join(ROOT, "benchmark", "tests", "data",
-                           "tiny_laguna.config.json")) as fh:
-        conf = json.load(fh)
-    conf.update(over)
-    return conf
+    return decoder_cases.family("laguna")
 
 
 def _plan_of(kinds):
@@ -70,77 +58,7 @@ LAYER_KINDS = {
 
 @pytest.mark.parametrize("kinds", LAYER_KINDS.values(), ids=LAYER_KINDS)
 def test_loss_and_every_gradient_match_the_plain_reference(kinds, laguna):
-    from benchmark import reference
-
-    conf = tiny_conf(**_plan_of(kinds))
-    specs = laguna.param_specs(conf)
-    weights = reference.make_weights(specs, 11, conf["init_gain"])
-    x, y = laguna.make_records(11, 2, conf)
-    model = laguna.build(conf)
-    own = state_dict(model, kind="param")
-    assert [tuple(v.shape) for v in own.values()] == \
-        [tuple(s["shape"]) for s in specs]
-    keys, buffers = list(own), state_dict(model, kind="buffer")
-    crit = laguna.criterion()
-
-    def system_loss(params):
-        out, _ = functional_call(model, {**params, **buffers},
-                                 jnp.asarray(x), training=True,
-                                 rng=jax.random.key(0))
-        return crit.update_output(out, jnp.asarray(y))
-
-    with jax.default_matmul_precision("highest"):
-        got_loss, got = jax.jit(jax.value_and_grad(system_loss))(
-            dict(zip(keys, weights)))
-        want_loss, want = jax.jit(jax.value_and_grad(
-            lambda p: laguna.loss_sum(p, x, y, conf=conf) / len(x)))(
-                list(weights))
-    assert abs(float(got_loss) - float(want_loss)) < 2e-5
-    for spec, key, w in zip(specs, keys, want):
-        scale = max(float(jnp.max(jnp.abs(w))), 1e-6)
-        gap = float(jnp.max(jnp.abs(got[key] - w))) / scale
-        assert gap < 2e-3, (spec["name"], gap)
-
-
-def _routed(conf, held, weights, **kw):
-    first, count = held
-    layer = nn.RoutedExperts(
-        conf["hidden_size"], conf["moe_intermediate_size"],
-        conf["num_experts_published"], conf["num_experts_per_tok"],
-        held=held, shared_width=conf["shared_expert_intermediate_size"],
-        routed_scale=conf["moe_routed_scaling_factor"], **kw)
-    e_gate, e_up, e_down, w_r, s_gate, s_up, s_down = weights
-    load_state_dict(layer, {
-        "experts_gate": e_gate[first:first + count],
-        "experts_up": e_up[first:first + count],
-        "experts_down": e_down[first:first + count],
-        "router.weight": w_r, "shared.gate_proj.weight": s_gate,
-        "shared.up_proj.weight": s_up, "shared.down_proj.weight": s_down},
-        strict=False)
-    return layer
-
-
-def _sparse_weights(conf, seed, experts):
-    d, w = conf["hidden_size"], conf["moe_intermediate_size"]
-    ws = conf["shared_expert_intermediate_size"]
-    rng = np.random.default_rng(seed)
-
-    def draw(*shape, fan_in):
-        return jnp.asarray(rng.standard_normal(shape) / math.sqrt(fan_in),
-                           jnp.float32)
-
-    return [draw(experts, d, w, fan_in=d), draw(experts, d, w, fan_in=d),
-            draw(experts, w, d, fan_in=w),
-            draw(conf["num_experts_published"], d, fan_in=d),
-            draw(ws, d, fan_in=d), draw(ws, d, fan_in=d),
-            draw(d, ws, fan_in=ws)]
-
-
-def _forward(layer, u):
-    with jax.default_matmul_precision("highest"):
-        out, state = jax.jit(lambda s, v: functional_call(layer, s, v))(
-            state_dict(layer), u)
-    return out, np.asarray(state["held_load"])
+    check_loss_and_every_gradient(laguna, tiny_conf(**_plan_of(kinds)), 11)
 
 
 def test_the_shares_of_a_sparse_layer_add_up_to_the_uncut_layer(laguna):
@@ -148,17 +66,19 @@ def test_the_shares_of_a_sparse_layer_add_up_to_the_uncut_layer(laguna):
     shared expert (which every chip computes alike) counted once, give
     what the uncut reference gives for the whole layer."""
     conf = tiny_conf()
-    weights = _sparse_weights(conf, 3, experts=16)
+    weights = sparse_weights(conf, 3, experts=16)
     u = jnp.asarray(np.random.default_rng(4).standard_normal((48, 64)),
                     jnp.float32)
     whole = dict(conf, held_experts=[0, 16])
-    want = laguna._sparse(u, weights, whole, None)
-    shared = laguna._gated(u, weights[4:], None)
+    want = compiled(lambda v, ws: laguna._sparse(v, ws, whole, None),
+                    u, weights)
+    shared = compiled(lambda v, ws: laguna._gated(v, ws, None),
+                      u, weights[4:])
     parts, rows = [], 0
     for share in range(4):
-        out, load = _forward(_routed(conf, (4 * share, 4), weights), u)
+        out, state = call(routed(conf, (4 * share, 4), weights), u)
         parts.append(out - shared)
-        rows += int(load[:-1].sum())
+        rows += int(np.asarray(state["held_load"])[:-1].sum())
     assert rows == 48 * conf["num_experts_per_tok"]  # every assignment once
     np.testing.assert_allclose(sum(parts) + shared, want, rtol=2e-4,
                                atol=2e-5)
@@ -178,42 +98,35 @@ def test_routing_drops_nothing_at_either_extreme(every_token, published,
     that none does: all equal the dense-mask reference."""
     conf = tiny_conf(held_experts=[0, 3], num_experts=3,
                      num_experts_published=published)
-    weights = _sparse_weights(conf, 5, experts=3)
+    weights = sparse_weights(conf, 5, experts=3)
     u = np.random.default_rng(6).standard_normal((64, 64)) + 2.0
     u = jnp.asarray(u, jnp.float32)
     push = 10.0 if every_token else -10.0
     weights[3] = weights[3].at[:3].add(push / 64.0)  # u . 1 is ~128
-    layer = _routed(conf, (0, 3), weights)
+    layer = routed(conf, (0, 3), weights)
     assert layer.capacity(64) == (72 if published == 32 else 192)
-    out, load = _forward(layer, u)
-    want = laguna._sparse(u, weights, conf, None)
-    np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
+    out, state = call(layer, u)
+    load = np.asarray(state["held_load"])
+    sparse = lambda v, ws: laguna._sparse(v, ws, conf, None)  # noqa: E731
+    np.testing.assert_allclose(out, compiled(sparse, u, weights),
+                               rtol=2e-4, atol=2e-5)
     if every_token:
         # rows an expert, then the rows that took the exact path
         assert list(load) == [64, 64, 64, 192 if published == 32 else 0]
         # and the path taken is differentiated like the reference
-        params = state_dict(layer, kind="param")
-        buffers = state_dict(layer, kind="buffer")
-        with jax.default_matmul_precision("highest"):
-            got = jax.jit(jax.grad(lambda p: jnp.sum(functional_call(
-                layer, {**p, **buffers}, u)[0] ** 2)))(params)
-            ref = jax.jit(jax.grad(lambda ws: jnp.sum(
-                laguna._sparse(u, ws, conf, None) ** 2)))(weights)
-        for name, g in zip(("experts_gate", "experts_up", "experts_down",
-                            "router.weight"), ref):
-            np.testing.assert_allclose(got[name], g, rtol=2e-3, atol=1e-3 *
-                                       float(jnp.max(jnp.abs(g))))
+        check_routed_gradients(layer, u, sparse, weights)
     else:
         assert list(load) == [0, 0, 0, 0]
-        np.testing.assert_allclose(out, laguna._gated(u, weights[4:], None),
-                                   rtol=2e-4, atol=2e-5)
+        shared = compiled(lambda v, ws: laguna._gated(v, ws, None),
+                          u, weights[4:])
+        np.testing.assert_allclose(out, shared, rtol=2e-4, atol=2e-5)
 
 
 def test_a_held_expert_without_rows_has_a_zero_gradient():
     conf = tiny_conf()
-    weights = _sparse_weights(conf, 7, experts=4)
+    weights = sparse_weights(conf, 7, experts=4)
     weights[3] = weights[3].at[1].add(-8.0 / 64.0)   # nobody picks expert 1
-    layer = _routed(conf, (0, 4), weights)
+    layer = routed(conf, (0, 4), weights)
     u = jnp.asarray(np.random.default_rng(8).standard_normal((32, 64)) + 2.0,
                     jnp.float32)
     params = state_dict(layer, kind="param")
@@ -236,10 +149,8 @@ FLASH_CASES = [(12, 2, 48, 8, 16, 16), (18, 2, 40, 8, 16, 16),
 @pytest.mark.parametrize("h,g,s,window,bq,bk", FLASH_CASES)
 def test_windowed_grouped_flash_matches_dense(h, g, s, window, bq, bk):
     keys = jax.random.split(jax.random.key(h + s), 4)
-    q = jax.random.normal(keys[0], (2, h, s, 16))
-    k = jax.random.normal(keys[1], (2, g, s, 16))
-    v = jax.random.normal(keys[2], (2, g, s, 16))
-    do = jax.random.normal(keys[3], (2, h, s, 16))
+    q, k, v, do = (drawn(jax.random.normal, key, (2, heads, s, 16))
+                   for key, heads in zip(keys, (h, g, g, h)))
 
     def both(attend):
         def run(q, k, v):
@@ -270,7 +181,7 @@ def test_flash_in_bfloat16_stays_within_two_roundings(h, g, s, window, bq, bk):
     that upcast their operands read 0.0033, the output's own rounding)."""
     keys = jax.random.split(jax.random.key(h + s), 4)
     shapes = [(2, h, s, 16), (2, g, s, 16), (2, g, s, 16), (2, h, s, 16)]
-    low = [jax.random.normal(k, sh).astype(jnp.bfloat16)
+    low = [drawn(jax.random.normal, k, sh).astype(jnp.bfloat16)
            for k, sh in zip(keys, shapes)]
 
     def both(attend, q, k, v, do):
@@ -288,8 +199,9 @@ def test_flash_in_bfloat16_stays_within_two_roundings(h, g, s, window, bq, bk):
             *[a.astype(jnp.float32) for a in low])
     for a, b in zip(got, want):
         assert a.dtype == jnp.bfloat16
-        gap = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)))
-        assert gap <= 2.0 ** -7 * float(jnp.max(jnp.abs(b)))
+        b = np.asarray(b)
+        gap = float(np.abs(np.asarray(a, np.float32) - b).max())
+        assert gap <= 2.0 ** -7 * float(np.abs(b).max())
 
 
 def test_a_window_bounds_the_key_blocks_a_query_block_visits():
@@ -373,11 +285,11 @@ def test_decoder_block_is_two_residual_branches():
     block = nn.DecoderBlock(64, attn, ffn, eps=1e-6)
     x = jnp.asarray(np.random.default_rng(2).standard_normal((2, 12, 64)),
                     jnp.float32)
-    h = x + attn.forward(block.norm1.forward(x))
-    want = h + ffn.forward(block.norm2.forward(h))
-    np.testing.assert_allclose(block.forward(x), want, rtol=1e-5, atol=1e-6)
+    h = x + call(attn, call(block.norm1, x)[0])[0]
+    want = h + call(ffn, call(block.norm2, h)[0])[0]
+    np.testing.assert_allclose(call(block, x)[0], want, rtol=1e-5, atol=1e-6)
     # position 11 sees positions 8..11 only: an earlier token moves nothing
-    moved = block.forward(x.at[:, 3].add(1.0))
+    moved = call(block, x.at[:, 3].add(1.0))[0]
     np.testing.assert_allclose(moved[:, 11], want[:, 11], rtol=1e-5,
                                atol=1e-6)
     assert float(jnp.max(jnp.abs(moved[:, 5] - want[:, 5]))) > 1e-3
@@ -386,32 +298,19 @@ def test_decoder_block_is_two_residual_branches():
 
 
 def test_registry_decoder_trains_through_local_optimizer_and_is_traced(
-        tmp_path, caplog):
+        tmp_path):
     """``cli train --model decoder_lm`` in small: the registry's plan
     through ``LocalOptimizer`` with the LM criterion; the run log carries
     the attention legs, the routed layers and their load, and the
     Optimizer's own log the last step's load of every routed layer."""
-    caplog.set_level("INFO", logger="bigdl_tpu.optim")
-    import bigdl_tpu.optim as optim
-    from bigdl_tpu.dataset.sample import Sample
     from bigdl_tpu.models import registry
-    from bigdl_tpu.telemetry import schema
 
     model = registry.build_model("decoder_lm", 64)
     crit, target = registry.train_pieces("decoder_lm", 4)
     assert target.shape == (4, registry.LM_SEQ_LEN)
     ids = np.random.default_rng(0).integers(0, 64, (8, 33)).astype(np.int32)
-    samples = [Sample(row[:-1], row[1:]) for row in ids]
-    telemetry.start_run(str(tmp_path))
-    try:
-        o = optim.LocalOptimizer(model, samples, crit, batch_size=4,
-                                 end_trigger=optim.Trigger.max_epoch(6))
-        o.set_optim_method(optim.SGD(learning_rate=0.1, momentum=0.9))
-        o.optimize()
-    finally:
-        telemetry.end_run()
-    events, errors = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
-    assert not errors and not schema.validate_events(events)
+    events, said = train_through_local_optimizer(
+        model, crit, [(row[:-1], row[1:]) for row in ids], tmp_path, epochs=6)
     steps = [e for e in events if e["kind"] == "step"]
     assert len(steps) == 12 and steps[-1]["loss"] < steps[0]["loss"]
     legs = [e for e in events if e.get("name") == "kernel/dispatch"
@@ -427,8 +326,6 @@ def test_registry_decoder_trains_through_local_optimizer_and_is_traced(
     assert 0 < by_step <= 3 * 4 * 32 * 3
     assert {e["name"] for e in events if e["kind"] == "counter"} >= \
         {"moe/load", "moe/exact_rows"}
-    said = [r.getMessage() for r in caplog.records
-            if r.getMessage().startswith("[Layer ")]
     assert len(said) == 3 * 5               # sparse layers x counter names
     assert [int(v) for v in said[0].split("moe/load ")[1].split()] == \
         [e["value"] for e in load[-12:-8]]  # the last step's first layer

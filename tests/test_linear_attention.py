@@ -8,11 +8,7 @@ gated shared expert each against the plain reference of
 builder's check of a plan; the counters and instants of a telemetry run.
 """
 
-import json
-import logging
-import math
-import os
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,30 +16,24 @@ import numpy as np
 import pytest
 
 import bigdl_tpu.nn as nn
-from bigdl_tpu import models, telemetry
-from bigdl_tpu.nn.module import functional_call, load_state_dict, state_dict
+import decoder_cases
+from bigdl_tpu import models
+from bigdl_tpu.nn.module import load_state_dict, state_dict
 from bigdl_tpu.ops import dispatch
 from bigdl_tpu.ops.delta_rule import (gated_delta_rule,
                                       gated_delta_rule_recurrent)
+from decoder_cases import (call, check_loss_and_every_gradient,
+                           check_routed_gradients, compiled, draw, drawn,
+                           routed, train_through_local_optimizer)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+tiny_conf = functools.partial(decoder_cases.tiny_conf, "qwen3_next")
+sparse_weights = functools.partial(decoder_cases.sparse_weights,
+                                   shared_gate=True)
 
 
 @pytest.fixture(scope="module")
 def family():
-    if ROOT not in sys.path:
-        sys.path.insert(0, ROOT)
-    from benchmark.models import qwen3_next
-
-    return qwen3_next
-
-
-def tiny_conf(**over):
-    with open(os.path.join(ROOT, "benchmark", "tests", "data",
-                           "tiny_qwen3_next.config.json")) as fh:
-        conf = json.load(fh)
-    conf.update(over)
-    return conf
+    return decoder_cases.family("qwen3_next")
 
 
 # -- the rule -------------------------------------------------------------------
@@ -66,16 +56,17 @@ def _rule_inputs(s, decay, strength, b=2, h=3, dk=8, dv=16):
     keys = jax.random.split(jax.random.key(s), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1,  # noqa: E731
                                          keepdims=True)
-    q = unit(jax.random.normal(keys[0], (b, h, s, dk)))
-    k = unit(jax.random.normal(keys[1], (b, h, s, dk)))
-    v = jax.random.normal(keys[2], (b, h, s, dv))
-    draw = jax.random.uniform(keys[3], (b, h, s))
+    q = unit(drawn(jax.random.normal, keys[0], (b, h, s, dk)))
+    k = unit(drawn(jax.random.normal, keys[1], (b, h, s, dk)))
+    v = drawn(jax.random.normal, keys[2], (b, h, s, dv))
+    draw = drawn(jax.random.uniform, keys[3], (b, h, s))
     g = {"mid": -2.0 * draw, "near1": -1e-3 * draw,
          "near0": -20.0 - 10.0 * draw}[decay]
-    beta = {"mid": jax.random.uniform(keys[4], (b, h, s)),
+    beta = {"mid": drawn(jax.random.uniform, keys[4], (b, h, s)),
             "zero": jnp.zeros((b, h, s)), "one": jnp.ones((b, h, s))}[
                 strength]
-    return (q, k, v, g, beta), jax.random.normal(keys[5], (b, h, s, dv))
+    return (q, k, v, g, beta), drawn(jax.random.normal, keys[5],
+                                     (b, h, s, dv))
 
 
 @pytest.mark.parametrize("s,chunk,decay,strength", RULE_CASES.values(),
@@ -88,23 +79,25 @@ def test_chunked_rule_is_the_token_by_token_rule(s, chunk, decay, strength):
             out, vjp = jax.vjp(rule, *a)
             return (out,) + vjp(do)
         with jax.default_matmul_precision("highest"):
-            return jax.jit(run)(*args)
+            return [np.asarray(x) for x in jax.jit(run)(*args)]
 
     got = both(lambda *a: gated_delta_rule(*a, chunk=chunk))
     want = both(gated_delta_rule_recurrent)
     for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
-        scale = max(float(jnp.max(jnp.abs(b))), 1e-3)
-        assert float(jnp.max(jnp.abs(a - b))) < 2e-5 * scale, name
+        scale = max(float(np.abs(b).max()), 1e-3)
+        assert float(np.abs(a - b).max()) < 2e-5 * scale, name
     if strength == "zero":           # nothing is ever written to the state
-        assert float(jnp.max(jnp.abs(got[0]))) == 0.0
+        assert float(np.abs(got[0]).max()) == 0.0
 
 
 def test_the_rule_hands_out_its_last_state_and_says_how_it_ran():
     args, _ = _rule_inputs(37, "mid", "mid")
     dispatch.clear_decisions()
     with jax.default_matmul_precision("highest"):
-        out, state = gated_delta_rule(*args, chunk=8, return_state=True)
-        want_out, want = gated_delta_rule_recurrent(*args, return_state=True)
+        out, state = jax.jit(lambda *a: gated_delta_rule(
+            *a, chunk=8, return_state=True))(*args)
+        want_out, want = jax.jit(lambda *a: gated_delta_rule_recurrent(
+            *a, return_state=True))(*args)
     assert state.shape == (2, 3, 8, 16) and state.dtype == jnp.float32
     np.testing.assert_allclose(state, want, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(out, want_out, rtol=1e-4, atol=1e-5)
@@ -122,9 +115,9 @@ def test_bfloat16_inputs_keep_their_type_and_a_float32_state():
         *a, chunk=16, return_state=True))(*low, g, beta)
     assert out.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     with jax.default_matmul_precision("highest"):
-        want = gated_delta_rule_recurrent(*low, g, beta)
-    gap = float(jnp.max(jnp.abs(out.astype(jnp.float32) - want)))
-    assert gap <= 2.0 ** -6 * float(jnp.max(jnp.abs(want)))
+        want = np.asarray(jax.jit(gated_delta_rule_recurrent)(*low, g, beta))
+    gap = float(np.abs(np.asarray(out, np.float32) - want).max())
+    assert gap <= 2.0 ** -6 * float(np.abs(want).max())
 
 
 # -- the Pallas leg (interpret mode here) against the XLA leg and the definition -------
@@ -183,10 +176,10 @@ def test_pallas_leg_is_the_xla_leg_and_the_definition(s, decay, strength,
                                                               2.0 ** -4)
     for name, a, b, c in zip(("o", "state", "dq", "dk", "dv", "dg", "dbeta"),
                              got, xla, want):
-        a, b, c = (x.astype(jnp.float32) for x in (a, b, c))
-        scale = max(float(jnp.max(jnp.abs(c))), 1e-3)
-        assert float(jnp.max(jnp.abs(a - b))) < close * scale, name
-        assert float(jnp.max(jnp.abs(a - c))) < far * scale, name
+        a, b, c = (np.asarray(x, np.float32) for x in (a, b, c))
+        scale = max(float(np.abs(c).max()), 1e-3)
+        assert float(np.abs(a - b).max()) < close * scale, name
+        assert float(np.abs(a - c).max()) < far * scale, name
 
 
 # (kernel mode, on a TPU, under a mesh, head size) -> (backend, reason)
@@ -241,28 +234,17 @@ def test_the_rule_picks_its_leg_from_what_it_can_see(mode, on_tpu, meshed, dim,
 
 # -- the layers, each against the family's plain reference ------------------------
 
-def _draw(rng, *shape, fan_in):
-    return jnp.asarray(rng.standard_normal(shape) / math.sqrt(fan_in),
-                       jnp.float32)
-
-
-def _call(layer, u):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda s, v: functional_call(layer, s, v))(
-            state_dict(layer), u)
-
-
 def test_gated_delta_net_is_the_reference_layer(family):
     conf = tiny_conf()
     rng = np.random.default_rng(1)
     d, hk, hv, dk, dv = 64, 2, 4, 8, 8
     keys, values = hk * dk, hv * dv
-    weights = [_draw(rng, 2 * keys + values, 4, fan_in=4),
-               _draw(rng, hv, fan_in=4), _draw(rng, hv, fan_in=4),
-               _draw(rng, 2 * keys + 2 * values, d, fan_in=d),
-               _draw(rng, 2 * hv, d, fan_in=d),
-               1.0 + _draw(rng, dv, fan_in=100),
-               _draw(rng, d, values, fan_in=values)]
+    weights = [draw(rng, 2 * keys + values, 4, fan_in=4),
+               draw(rng, hv, fan_in=4), draw(rng, hv, fan_in=4),
+               draw(rng, 2 * keys + 2 * values, d, fan_in=d),
+               draw(rng, 2 * hv, d, fan_in=d),
+               1.0 + draw(rng, dv, fan_in=100),
+               draw(rng, d, values, fan_in=values)]
     layer = nn.GatedDeltaNet(d, hk, hv, dk, dv, conv_width=4)
     names = list(state_dict(layer, kind="param"))
     assert names == ["conv_weight", "A_log", "dt_bias",
@@ -271,15 +253,15 @@ def test_gated_delta_net_is_the_reference_layer(family):
     load_state_dict(layer, dict(zip(names, weights)), strict=False)
     # two whole chunks of the rule's 64 tokens and a part of a third
     u = jnp.asarray(rng.standard_normal((2, 136, d)), jnp.float32)
-    out, state = _call(layer, u)
-    with jax.default_matmul_precision("highest"):
-        want = jnp.stack([family._linear_attention(row, weights, conf, None)
-                          for row in u])
+    out, state = call(layer, u)
+    want = compiled(lambda rows, ws: jnp.stack(
+        [family._linear_attention(row, ws, conf, None) for row in rows]),
+        u, weights)
     np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
     decay, beta, norm = np.asarray(state["state_stats"])
     assert 0.0 < decay < 1.0 and 0.0 < beta < 1.0 and norm > 0.0
     # causal: a later token moves no earlier output
-    moved, _ = _call(layer, u.at[:, 30].add(1.0))
+    moved, _ = call(layer, u.at[:, 30].add(1.0))
     np.testing.assert_allclose(moved[:, :30], out[:, :30], rtol=1e-5,
                                atol=1e-6)
     assert float(jnp.max(jnp.abs(moved[:, 33] - out[:, 33]))) > 1e-4
@@ -291,10 +273,10 @@ def test_gated_normed_attention_is_the_reference_layer(family):
     conf = tiny_conf()
     rng = np.random.default_rng(2)
     d, h, g, dh = 64, 4, 2, 16
-    weights = [_draw(rng, h * 2 * dh, d, fan_in=d),
-               _draw(rng, g * dh, d, fan_in=d), _draw(rng, g * dh, d, fan_in=d),
-               _draw(rng, dh, fan_in=25), _draw(rng, dh, fan_in=25),
-               _draw(rng, d, h * dh, fan_in=h * dh)]
+    weights = [draw(rng, h * 2 * dh, d, fan_in=d),
+               draw(rng, g * dh, d, fan_in=d), draw(rng, g * dh, d, fan_in=d),
+               draw(rng, dh, fan_in=25), draw(rng, dh, fan_in=25),
+               draw(rng, d, h * dh, fan_in=h * dh)]
     layer = nn.GroupedQueryAttention(
         d, h, g, dh, rotary=nn.Rotary(4, theta=1e7), gate="per_channel",
         qk_norm=lambda n: nn.RMSNorm(n, 1e-6, zero_centred=True))
@@ -304,10 +286,10 @@ def test_gated_normed_attention_is_the_reference_layer(family):
     load_state_dict(layer, dict(zip(names, weights)), strict=False)
     u = jnp.asarray(rng.standard_normal((2, 24, d)), jnp.float32)
     dispatch.clear_decisions()
-    out, _ = _call(layer, u)
-    with jax.default_matmul_precision("highest"):
-        want = jnp.stack([family._full_attention(row, weights, conf, None)
-                          for row in u])
+    out, _ = call(layer, u)
+    want = compiled(lambda rows, ws: jnp.stack(
+        [family._full_attention(row, ws, conf, None) for row in rows]),
+        u, weights)
     np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
     (said,) = [d_ for d_ in dispatch.decisions() if d_[0] == "attention"]
     assert said.launch["head_dim"] == 16 and said.launch["qk_norm"] is True
@@ -318,7 +300,7 @@ def test_gated_normed_attention_is_the_reference_layer(family):
 def test_rms_norm_scales_by_w_or_by_one_plus_w(zero_centred, family):
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((5, 32)) * 3, jnp.float32)
-    w = _draw(rng, 32, fan_in=16)
+    w = draw(rng, 32, fan_in=16)
     norm = nn.RMSNorm(32, 1e-6, zero_centred=zero_centred)
     assert float(norm.weight[0]) == (0.0 if zero_centred else 1.0)
     load_state_dict(norm, {"weight": w}, strict=False)
@@ -334,48 +316,18 @@ def test_rms_norm_scales_by_w_or_by_one_plus_w(zero_centred, family):
                                    rtol=1e-5, atol=1e-6)
 
 
-def _sparse_weights(conf, seed, experts):
-    d, w = conf["hidden_size"], conf["moe_intermediate_size"]
-    ws = conf["shared_expert_intermediate_size"]
-    rng = np.random.default_rng(seed)
-    return [_draw(rng, experts, d, w, fan_in=d),
-            _draw(rng, experts, d, w, fan_in=d),
-            _draw(rng, experts, w, d, fan_in=w),
-            _draw(rng, conf["num_experts_published"], d, fan_in=d),
-            _draw(rng, ws, d, fan_in=d), _draw(rng, ws, d, fan_in=d),
-            _draw(rng, d, ws, fan_in=ws), _draw(rng, 1, d, fan_in=d)]
-
-
-def _routed(conf, held, weights):
-    first, count = held
-    layer = nn.RoutedExperts(
-        conf["hidden_size"], conf["moe_intermediate_size"],
-        conf["num_experts_published"], conf["num_experts_per_tok"],
-        held=held, shared_width=conf["shared_expert_intermediate_size"],
-        shared_gate=True)
-    e_gate, e_up, e_down, w_r, s_gate, s_up, s_down, w_sg = weights
-    load_state_dict(layer, {
-        "experts_gate": e_gate[first:first + count],
-        "experts_up": e_up[first:first + count],
-        "experts_down": e_down[first:first + count],
-        "router.weight": w_r, "shared.gate_proj.weight": s_gate,
-        "shared.up_proj.weight": s_up, "shared.down_proj.weight": s_down,
-        "shared_gate.weight": w_sg}, strict=False)
-    return layer
-
-
 def test_gated_shared_expert_is_the_reference_layer(family):
     conf = tiny_conf()
-    weights = _sparse_weights(conf, 5, experts=4)
+    weights = sparse_weights(conf, 5, experts=4)
     u = jnp.asarray(np.random.default_rng(6).standard_normal((48, 64)),
                     jnp.float32)
-    layer = _routed(conf, (0, 4), weights)
+    layer = routed(conf, (0, 4), weights)
     assert list(state_dict(layer, kind="param"))[-1] == "shared_gate.weight"
-    out, _ = _call(layer, u)
-    with jax.default_matmul_precision("highest"):
-        want = family._sparse(u, weights, conf, None)
-        ungated = family._sparse(
-            u, weights[:7] + [jnp.zeros_like(weights[7])], conf, None)
+    out, _ = call(layer, u)
+    sparse = lambda v, ws: family._sparse(v, ws, conf, None)  # noqa: E731
+    want = compiled(sparse, u, weights)
+    ungated = compiled(sparse, u,
+                       weights[:7] + [jnp.zeros_like(weights[7])])
     np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
     assert float(jnp.max(jnp.abs(want - ungated))) > 1e-3  # the gate acts
     # without the option the layer has no such parameter
@@ -388,17 +340,16 @@ def test_the_shares_of_this_layer_add_up_to_the_uncut_layer(family):
     shared expert (which every chip computes alike) counted once, give
     what the uncut reference gives for the whole layer."""
     conf = tiny_conf()
-    weights = _sparse_weights(conf, 7, experts=16)
+    weights = sparse_weights(conf, 7, experts=16)
     u = jnp.asarray(np.random.default_rng(8).standard_normal((48, 64)),
                     jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        want = family._sparse(u, weights, dict(conf, held_experts=[0, 16]),
-                              None)
-        shared = family._sigmoid(u @ weights[7].T) * family._gated(
-            u, weights[4:7], None)
+    want = compiled(lambda v, ws: family._sparse(
+        v, ws, dict(conf, held_experts=[0, 16]), None), u, weights)
+    shared = compiled(lambda v, ws: family._sigmoid(
+        v @ ws[7].T) * family._gated(v, ws[4:7], None), u, weights)
     parts, rows = [], 0
     for share in range(4):
-        out, state = _call(_routed(conf, (4 * share, 4), weights), u)
+        out, state = call(routed(conf, (4 * share, 4), weights), u)
         parts.append(out - shared)
         rows += int(np.asarray(state["held_load"])[:-1].sum())
     assert rows == 48 * conf["num_experts_per_tok"]  # every assignment once
@@ -417,37 +368,9 @@ def test_hybrid_plan_loss_and_every_gradient_match_the_reference(layers,
     """``build_decoder_lm`` on a linear layer alone and on a whole period
     (linear, linear, linear, full), every layer sparse with a gated shared
     expert: the loss and every leaf's gradient, on seeded weights."""
-    from benchmark import reference
-
-    conf = tiny_conf(num_hidden_layers=layers)
     assert family.layers_of(tiny_conf()) == ["linear"] * 3 + ["full"]
-    specs = family.param_specs(conf)
-    weights = reference.make_weights(specs, 13, conf["init_gain"])
-    x, y = family.make_records(13, 2, conf)
-    model = family.build(conf)
-    own = state_dict(model, kind="param")
-    assert [tuple(v.shape) for v in own.values()] == \
-        [tuple(s["shape"]) for s in specs]
-    keys, buffers = list(own), state_dict(model, kind="buffer")
-    crit = family.criterion()
-
-    def system_loss(params):
-        out, _ = functional_call(model, {**params, **buffers},
-                                 jnp.asarray(x), training=True,
-                                 rng=jax.random.key(0))
-        return crit.update_output(out, jnp.asarray(y))
-
-    with jax.default_matmul_precision("highest"):
-        got_loss, got = jax.jit(jax.value_and_grad(system_loss))(
-            dict(zip(keys, weights)))
-        want_loss, want = jax.jit(jax.value_and_grad(
-            lambda p: family.loss_sum(p, x, y, conf=conf) / len(x)))(
-                list(weights))
-    assert abs(float(got_loss) - float(want_loss)) < 2e-5
-    for spec, key, w in zip(specs, keys, want):
-        scale = max(float(jnp.max(jnp.abs(w))), 1e-6)
-        gap = float(jnp.max(jnp.abs(got[key] - w))) / scale
-        assert gap < 2e-3, (spec["name"], gap)
+    check_loss_and_every_gradient(
+        family, tiny_conf(num_hidden_layers=layers), 13)
 
 
 def test_the_builder_checks_a_plan_before_it_builds_a_module(monkeypatch):
@@ -480,49 +403,16 @@ def test_defaults_leave_the_registrys_decoder_as_it_was():
     assert float(state_dict(model)["1.0.norm1.weight"][0]) == 1.0
 
 
-class _Keep(logging.Handler):
-    """The messages of one logger, whatever an earlier test's redirect
-    did to its propagation."""
-
-    def __init__(self):
-        super().__init__(logging.INFO)
-        self.said = []
-
-    def emit(self, record):
-        self.said.append(record.getMessage())
-
-
 def test_hybrid_plan_trains_through_local_optimizer_and_is_traced(
         tmp_path, family):
     """The tiny hybrid plan through ``LocalOptimizer``: the loss falls,
     the run log carries the rule's and the attention's ``kernel/dispatch``
     instants and the ``linear_attn/*`` counters of every linear layer, and
     the Optimizer's own log the last step's."""
-    import bigdl_tpu.optim as optim
-    from bigdl_tpu.dataset.sample import Sample
-    from bigdl_tpu.telemetry import schema
-
-    logger, keep = logging.getLogger("bigdl_tpu.optim"), _Keep()
-    level = logger.level
-    logger.addHandler(keep)
-    logger.setLevel(logging.INFO)
     conf = tiny_conf()
-    model = family.build(conf)
     x, y = family.make_records(3, 8, conf)
-    samples = [Sample(a, b) for a, b in zip(x, y)]
-    telemetry.start_run(str(tmp_path))
-    try:
-        o = optim.LocalOptimizer(model, samples, family.criterion(),
-                                 batch_size=4,
-                                 end_trigger=optim.Trigger.max_epoch(5))
-        o.set_optim_method(optim.SGD(learning_rate=0.1, momentum=0.9))
-        o.optimize()
-    finally:
-        telemetry.end_run()
-        logger.removeHandler(keep)
-        logger.setLevel(level)
-    events, errors = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
-    assert not errors and not schema.validate_events(events)
+    events, said = train_through_local_optimizer(
+        family.build(conf), family.criterion(), zip(x, y), tmp_path, epochs=5)
     steps = [e for e in events if e["kind"] == "step"]
     assert len(steps) == 10 and steps[-1]["loss"] < steps[0]["loss"]
     legs = [e for e in events if e.get("name") == "kernel/dispatch"]
@@ -544,8 +434,7 @@ def test_hybrid_plan_trains_through_local_optimizer_and_is_traced(
     decay = [e["value"] for e in events
              if e.get("name") == "linear_attn/decay_mean"]
     assert all(v < 1.0 for v in decay)
-    said = [m for m in keep.said
-            if m.startswith("[Layer ") and "linear_attn/" in m]
+    said = [m for m in said if "linear_attn/" in m]
     assert len(said) == 3 * 3                   # linear layers x names
     assert float(said[0].split("linear_attn/decay_mean ")[1]) == \
         pytest.approx(decay[-3])
@@ -559,24 +448,15 @@ def test_the_exact_path_in_blocks_of_rows_is_the_exact_path(monkeypatch,
     gradients."""
     conf = tiny_conf(held_experts=[0, 3], num_experts=3,
                      num_experts_published=32)
-    weights = _sparse_weights(conf, 9, experts=3)
+    weights = sparse_weights(conf, 9, experts=3)
     weights[3] = weights[3].at[:3].add(10.0 / 64.0)
     u = jnp.asarray(np.random.default_rng(10).standard_normal((64, 64)) + 2.0,
                     jnp.float32)
     monkeypatch.setattr(nn.RoutedExperts, "EXACT_ROWS", 48)
-    layer = _routed(conf, (0, 3), weights)
-    out, state = _call(layer, u)
+    layer = routed(conf, (0, 3), weights)
+    out, state = call(layer, u)
     assert list(np.asarray(state["held_load"])) == [64, 64, 64, 192]
-    params = state_dict(layer, kind="param")
-    buffers = state_dict(layer, kind="buffer")
-    with jax.default_matmul_precision("highest"):
-        want = family._sparse(u, weights, conf, None)
-        got = jax.jit(jax.grad(lambda p: jnp.sum(functional_call(
-            layer, {**p, **buffers}, u)[0] ** 2)))(params)
-        ref = jax.jit(jax.grad(lambda ws: jnp.sum(
-            family._sparse(u, ws, conf, None) ** 2)))(weights)
-    np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
-    for name, g in zip(("experts_gate", "experts_up", "experts_down",
-                        "router.weight"), ref):
-        np.testing.assert_allclose(got[name], g, rtol=2e-3, atol=1e-3 *
-                                   float(jnp.max(jnp.abs(g))))
+    sparse = lambda v, ws: family._sparse(v, ws, conf, None)  # noqa: E731
+    np.testing.assert_allclose(out, compiled(sparse, u, weights),
+                               rtol=2e-4, atol=2e-5)
+    check_routed_gradients(layer, u, sparse, weights)

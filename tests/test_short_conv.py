@@ -8,10 +8,7 @@ sigmoid router whose bias chooses and does not weigh; the share test at
 whole; the counters and instants of a telemetry run.
 """
 
-import json
-import logging
-import os
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,38 +16,28 @@ import numpy as np
 import pytest
 
 import bigdl_tpu.nn as nn
-from bigdl_tpu import models, telemetry
+import decoder_cases
+from bigdl_tpu import models
 from bigdl_tpu.models.transformer import VocabHead
 from bigdl_tpu.nn.layers import short_conv
 from bigdl_tpu.nn.module import functional_call, load_state_dict, state_dict
-from test_linear_attention import _Keep, _call, _draw
+from decoder_cases import (call, check_loss_and_every_gradient, draw,
+                           train_through_local_optimizer)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+tiny_conf = functools.partial(decoder_cases.tiny_conf, "lfm2")
 
 
 @pytest.fixture(scope="module")
 def family():
-    if ROOT not in sys.path:
-        sys.path.insert(0, ROOT)
-    from benchmark.models import lfm2
-
-    return lfm2
-
-
-def tiny_conf(**over):
-    with open(os.path.join(ROOT, "benchmark", "tests", "data",
-                           "tiny_lfm2.config.json")) as fh:
-        conf = json.load(fh)
-    conf.update(over)
-    return conf
+    return decoder_cases.family("lfm2")
 
 
 # -- the mixer ------------------------------------------------------------------
 
 def _mixer(seed, d=16, taps=3):
     rng = np.random.default_rng(seed)
-    weights = [_draw(rng, d, taps, fan_in=taps), _draw(rng, 3 * d, d, fan_in=d),
-               _draw(rng, d, d, fan_in=d)]
+    weights = [draw(rng, d, taps, fan_in=taps), draw(rng, 3 * d, d, fan_in=d),
+               draw(rng, d, d, fan_in=d)]
     layer = nn.GatedShortConv(d, taps=taps)
     load_state_dict(layer, dict(zip(
         ("conv_weight", "in_proj.weight", "out_proj.weight"), weights)),
@@ -90,8 +77,8 @@ def test_changing_a_token_moves_no_earlier_output_and_no_other_record():
     layer, _ = _mixer(3)
     u = jnp.asarray(np.random.default_rng(4).standard_normal((2, 20, 16)),
                     jnp.float32)
-    base, _ = _call(layer, u)
-    moved, _ = _call(layer, u.at[0, 9].add(1.0))
+    base, _ = call(layer, u)
+    moved, _ = call(layer, u.at[0, 9].add(1.0))
     np.testing.assert_array_equal(moved[0, :9], base[0, :9])
     np.testing.assert_array_equal(moved[1], base[1])
     # three taps: positions 9, 10 and 11 see token 9, position 12 does not
@@ -106,7 +93,7 @@ def test_a_filter_of_the_last_tap_alone_is_the_two_gates(family):
     d = 8
     layer = nn.GatedShortConv(d, taps=3)
     rng = np.random.default_rng(5)
-    w_in = _draw(rng, 3 * d, d, fan_in=d)
+    w_in = draw(rng, 3 * d, d, fan_in=d)
     u = jnp.asarray(rng.standard_normal((1, 12, d)), jnp.float32)
 
     def run(taps):
@@ -114,7 +101,7 @@ def test_a_filter_of_the_last_tap_alone_is_the_two_gates(family):
             "conv_weight": jnp.tile(jnp.asarray(taps, jnp.float32), (d, 1)),
             "in_proj.weight": w_in, "out_proj.weight": jnp.eye(d)},
             strict=False)
-        return _call(layer, u)[0]
+        return call(layer, u)[0]
 
     with jax.default_matmul_precision("highest"):
         gate_in, gate_out, x = jnp.split(u @ w_in.T, 3, axis=-1)
@@ -216,9 +203,9 @@ def test_gated_delta_net_gives_what_it_gave_before_the_convolution_left(
         return shared(x, weight)
 
     monkeypatch.setattr(short_conv, "causal_depthwise_conv", spy)
-    got, got_state = _call(now, u)
+    got, got_state = call(now, u)
     assert seen == [(2, 40, 2 * 2 * 8 + 4 * 8)]
-    want, want_state = _call(before, u)
+    want, want_state = call(before, u)
     assert len(seen) == 1                       # the parent's never calls it
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
@@ -232,7 +219,7 @@ def _router(bias, n=16, k=4, d=8, normalize=True, seed=8):
     layer = nn.RoutedExperts(d, 4, n, k, held=(0, 4), score="sigmoid",
                              select_bias=True, normalize=normalize)
     rng = np.random.default_rng(seed)
-    load_state_dict(layer, {"router.weight": _draw(rng, n, d, fan_in=d),
+    load_state_dict(layer, {"router.weight": draw(rng, n, d, fan_in=d),
                             "select_bias": jnp.asarray(bias, jnp.float32)},
                     strict=False)
     x = jnp.asarray(rng.standard_normal((32, d)), jnp.float32)
@@ -315,11 +302,11 @@ def test_four_ranks_of_sixteen_experts_add_up_to_the_uncut_layer(family):
     conf = dict(num_experts_per_tok=k, norm_topk_prob=True,
                 routed_scaling_factor=1, held_experts=[0, n])
     rng = np.random.default_rng(11)
-    weights = [_draw(rng, n, d, width, fan_in=d),
-               _draw(rng, n, d, width, fan_in=d),
-               _draw(rng, n, width, d, fan_in=width),
+    weights = [draw(rng, n, d, width, fan_in=d),
+               draw(rng, n, d, width, fan_in=d),
+               draw(rng, n, width, d, fan_in=width),
                jnp.asarray(0.05 * rng.standard_normal(n), jnp.float32),
-               _draw(rng, n, d, fan_in=d)]
+               draw(rng, n, d, fan_in=d)]
     u = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
     with jax.default_matmul_precision("highest"):
         want = family.sparse(u, weights, conf)
@@ -333,7 +320,7 @@ def test_four_ranks_of_sixteen_experts_add_up_to_the_uncut_layer(family):
             "experts_up": e_up[first:first + 16],
             "experts_down": e_down[first:first + 16],
             "select_bias": bias, "router.weight": w_r}, strict=False)
-        out, state = _call(layer, u)
+        out, state = call(layer, u)
         parts.append(out)
         rows += int(np.asarray(state["held_load"])[:-1].sum())
         with jax.default_matmul_precision("highest"):
@@ -363,7 +350,7 @@ def test_a_tied_head_is_one_leaf_whose_gradient_is_both_uses(family):
     assert [k for k, v in own.items() if v.shape == (32, 16)] == ["0.weight"]
     assert len(state_dict(untied, kind="param")) == len(own) + 1
     rng = np.random.default_rng(12)
-    weights = {k: _draw(rng, *v.shape, fan_in=v.shape[-1])
+    weights = {k: draw(rng, *v.shape, fan_in=v.shape[-1])
                for k, v in own.items()}
     ids = jnp.asarray(rng.integers(0, 32, (2, 10)), jnp.int32)
     y = jnp.asarray(rng.integers(0, 32, (2, 10)), jnp.int32)
@@ -418,42 +405,11 @@ def test_lfm2_plan_loss_and_every_gradient_match_the_reference(over, family):
     feed-forward, on an attention layer with the routed one, and on the
     cut (published layers 1-5: conv dense; full, conv, conv, conv sparse),
     head tied: the loss and every leaf's gradient, on seeded weights."""
-    from benchmark import reference
-
-    conf = tiny_conf(**over)
     assert [(layer["mixer"], layer["ffn"]) for layer in
             family.layers_of(tiny_conf())] == [
         ("conv", "dense"), ("full", "sparse")] + [("conv", "sparse")] * 3
-    specs = family.param_specs(conf)
-    weights = reference.make_weights(specs, 13, conf["init_gain"])
-    x, y = family.make_records(13, 2, conf)
-    model = family.build(conf)
-    own = state_dict(model, kind="param")
-    assert [tuple(v.shape) for v in own.values()] == \
-        [tuple(s["shape"]) for s in specs]
-    keys, buffers = list(own), state_dict(model, kind="buffer")
-    crit = family.criterion()
-
-    def system_loss(params):
-        out, _ = functional_call(model, {**params, **buffers},
-                                 jnp.asarray(x), training=True,
-                                 rng=jax.random.key(0))
-        return crit.update_output(out, jnp.asarray(y))
-
-    with jax.default_matmul_precision("highest"):
-        got_loss, got = jax.jit(jax.value_and_grad(system_loss))(
-            dict(zip(keys, weights)))
-        want_loss, want = jax.jit(jax.value_and_grad(
-            lambda p: family.loss_sum(p, x, y, conf=conf) / len(x)))(
-                list(weights))
-    assert abs(float(got_loss) - float(want_loss)) < 2e-5
-    for spec, key, w in zip(specs, keys, want):
-        if spec["name"].endswith("expert_bias"):
-            assert not np.asarray(got[key]).any() and not np.asarray(w).any()
-            continue
-        scale = max(float(jnp.max(jnp.abs(w))), 1e-6)
-        gap = float(jnp.max(jnp.abs(got[key] - w))) / scale
-        assert gap < 2e-3, (spec["name"], gap)
+    check_loss_and_every_gradient(family, tiny_conf(**over), 13,
+                                  zero_gradient_leaves=("expert_bias",))
 
 
 def test_the_builder_names_the_conv_kind_and_leaves_other_plans_alone():
@@ -477,31 +433,10 @@ def test_lfm2_plan_trains_through_local_optimizer_and_is_traced(tmp_path,
     carries the convolution's ``kernel/dispatch`` instants, the router's
     ``moe/route`` facts and the per-step counters of both, and the
     Optimizer's own log the last step's."""
-    import bigdl_tpu.optim as optim
-    from bigdl_tpu.dataset.sample import Sample
-    from bigdl_tpu.telemetry import schema
-
-    logger, keep = logging.getLogger("bigdl_tpu.optim"), _Keep()
-    level = logger.level
-    logger.addHandler(keep)
-    logger.setLevel(logging.INFO)
     conf = tiny_conf()
-    model = family.build(conf)
     x, y = family.make_records(3, 8, conf)
-    samples = [Sample(a, b) for a, b in zip(x, y)]
-    telemetry.start_run(str(tmp_path))
-    try:
-        o = optim.LocalOptimizer(model, samples, family.criterion(),
-                                 batch_size=4,
-                                 end_trigger=optim.Trigger.max_epoch(5))
-        o.set_optim_method(optim.SGD(learning_rate=0.1, momentum=0.9))
-        o.optimize()
-    finally:
-        telemetry.end_run()
-        logger.removeHandler(keep)
-        logger.setLevel(level)
-    events, errors = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
-    assert not errors and not schema.validate_events(events)
+    events, said = train_through_local_optimizer(
+        family.build(conf), family.criterion(), zip(x, y), tmp_path, epochs=5)
     steps = [e for e in events if e["kind"] == "step"]
     assert len(steps) == 10 and steps[-1]["loss"] < steps[0]["loss"]
     legs = [e for e in events if e.get("name") == "kernel/dispatch"]
@@ -530,6 +465,5 @@ def test_lfm2_plan_trains_through_local_optimizer_and_is_traced(tmp_path,
     assert total[0]["value"] == sum(e["value"] for e in load[:4])
     assert worst[0]["value"] == max(e["value"] for e in load[:4])
     assert mean[0]["value"] == pytest.approx(total[0]["value"] / 4)
-    said = [m for m in keep.said if m.startswith("[Layer ")]
     assert len([m for m in said if "short_conv/" in m]) == 4 * 2
     assert len([m for m in said if "moe/held_rows" in m]) == 4 * 3
