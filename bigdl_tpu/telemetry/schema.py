@@ -137,8 +137,13 @@ STREAM_NAMES = frozenset({
     "kernel/dispatch",
     # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
     # instant per TRACE of a layer (experts, held, top_k, capacity
-    # rows, the router's score function, whether a bias enters the
-    # choice, whether there is a shared expert), and per step the rows
+    # rows, worst = the most rows that can land here, combine = how the
+    # sorted rows return to their tokens: "fold" where capacity ==
+    # tokens x top_k, so the order is a whole permutation and its
+    # inverse gathers tokens x top_k rows in both passes; "scatter_add"
+    # where a prefix of capacity rows is scatter-added; the router's
+    # score function, whether a bias enters the choice, whether there
+    # is a shared expert), and per step the rows
     # each held expert received, their sum, largest and mean (max over
     # mean: the imbalance the grouped product sees) and the rows that
     # took the exact path (counters, emitted by the Optimizer where it

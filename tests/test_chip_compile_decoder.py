@@ -8,15 +8,19 @@ the style of ``test_chip_compile.py`` (the session's ``topo``,
 ``[1, 72, 8192, 128]`` queries over 8 kv heads under a 512 window, and
 ``[1, 48, 8192, 128]`` full.  Nothing executes.  The compiled text, with
 operand shapes as a device trace names its events, is also what the
-configuration's ``attention_kernels`` patterns have to find.
+configuration's ``attention_kernels`` patterns have to find.  Last, a
+routed layer at the LFM2 and Laguna cells' shapes: which of its two
+combines each compiles to, and that ``routed_match`` finds the rows.
 """
 
 import json
+import math
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from bigdl_tpu.ops import attention
@@ -311,3 +315,87 @@ def test_remat_holds_one_forward_call_the_patterns_find(layer, one_chip,
                                   if re.search(k["match"], c)])
              for k in kernels if k["family"] == family}
     assert found == {"fwd": 1, "dq": 1, "dkv": 1}
+
+
+#: a decoder cell's routed layer: configuration, tokens a step, the
+#: layer's arguments, the combine its shapes choose
+ROUTED_LAYERS = {
+    "lfm2.whole-order": ("lfm2_24b_a2b", 16384, dict(
+        d_model=2048, width=1536, n_experts=64, top_k=4, held=(0, 16),
+        score="sigmoid", select_bias=True), "fold"),
+    "laguna.prefix": ("laguna_s_2_1", 8192, dict(
+        d_model=3072, width=1024, n_experts=256, top_k=10, held=(0, 8),
+        routed_scale=2.5), "scatter_add"),
+}
+
+
+@pytest.mark.parametrize("case", ROUTED_LAYERS)
+def test_routed_layer_combines_by_its_shapes(case, one_chip, monkeypatch):
+    """``nn.RoutedExperts`` forward and backward in bfloat16, for the
+    chip.  Compiled at LFM2's shapes, the sorted order is whole (65,536 =
+    4 x 16,384 rows): no scatter touches a ``[65536, *]`` row array, the
+    two gathers by the order's inverse and the two sums over ``top_k``
+    are there, and the configuration's ``routed_match`` finds every
+    instruction that holds the 65,536 rows in ANY view (a ``[16384, 4,
+    2048]`` one would fall out of ``moe.routed64_share``).  Lowered at
+    Laguna's (10,240 of 81,920 assignments), the ``conditional`` and its
+    float32 scatter-add of the capacity's rows are still there."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.nn import init
+    from bigdl_tpu.nn.module import functional_call, state_dict
+
+    config, tokens, args, combine = ROUTED_LAYERS[case]
+    said = []
+    monkeypatch.setattr(telemetry, "instant",
+                        lambda name, **attrs: said.append((name, attrs)))
+    # the stacks' values do not reach a compile: zeros, not 600 MB of draws
+    monkeypatch.setattr(init.RandomUniform, "init",
+                        lambda self, shape, **_: np.zeros(shape, np.float32))
+    layer = nn.RoutedExperts(**args)
+    buffers = state_dict(layer, kind="buffer")
+
+    def loss(params, x):
+        y, _ = functional_call(layer, {**params, **buffers}, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def shaped(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    params = {k: shaped(v.shape)
+              for k, v in state_dict(layer, kind="param").items()}
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, shaped((tokens, args["d_model"])))
+    (route,) = [attrs for name, attrs in said if name == "moe/route"]
+    assert route["combine"] == combine
+    rows, d = route["capacity"], args["d_model"]
+    if combine == "scatter_add":
+        # what stays as it was is read off the program as it is handed to
+        # the compiler: compiling the sort of the assignments takes 20 s
+        text = lowered.as_text(dialect="hlo")
+        assert rows < tokens * args["top_k"]
+        assert " conditional(" in text and f"f32[{rows},{d}]" in text
+        assert re.search(rf"= f32\[{tokens},{d}\]\S* scatter\(", text)
+        return
+    text = _as_traced(lowered.compile())
+    scatters = [ln for ln in text.splitlines()
+                if re.search(rf" scatter\(.*\[{rows},\d\d+\]", ln)]
+    assert rows == tokens * args["top_k"] == 65536
+    assert not scatters and " conditional(" not in text
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as fh:
+        routed_match = json.load(fh)["routed_match"]
+    entry = text[text.index("\nENTRY "):].splitlines()
+    wide = {rows * d, rows * args["width"]}
+    held = [ln for ln in entry if any(
+        math.prod(int(n) for n in dims.split(",")) in wide
+        for dims in re.findall(r"\[([\d,]+)\]", ln))]
+    assert len(held) > 20
+    assert all(re.search(routed_match, ln) for ln in held)
+    # rows read by the inverse: [65536, d] in, [65536, d] out, an index a row
+    gathers = [ln for ln in held if re.search(
+        rf"= bf16\[{rows},{d}\]\S* fusion\(bf16\[{rows},{d}\]\S* %\S+, "
+        rf"s32\[{rows}\]\S* %\S+\), kind=kCustom", ln)]
+    sums = [ln for ln in held if re.search(
+        rf"= \w+\[{tokens},{d}\]\S* fusion\(.*bf16\[{rows},{d}\]", ln)]
+    assert len(gathers) == 2 and len(sums) == 2

@@ -138,6 +138,170 @@ def test_a_held_expert_without_rows_has_a_zero_gradient():
         assert float(jnp.max(jnp.abs(grads[name][0]))) > 0.0
 
 
+class _FastPath(nn.RoutedExperts):
+    """``RoutedExperts``' fast path alone over a routing handed in:
+    input ``(x2, w, local)``, the order whole, its last movement in the
+    form ``fold`` names."""
+
+    fold = True
+
+    def update_output(self, input):
+        x2, w, local = input
+        counts = jnp.sum(
+            local.reshape(-1, 1) == jnp.arange(self.count + 1)[None, :],
+            axis=0, dtype=jnp.int32)
+        return self._grouped(x2, w, local, counts, cap=local.size,
+                             fold=self.fold)
+
+
+@functools.lru_cache(maxsize=None)
+def _both_forms(top_k):
+    """One compiled program a ``top_k``: value and every gradient of the
+    fast path over 4 held experts, folded and scatter-added."""
+    layer = _FastPath(16, 24, 8, top_k, held=(0, 4))
+    params = {k: v for k, v in state_dict(layer, kind="param").items()
+              if k.startswith("experts_")}
+    buffers = state_dict(layer, kind="buffer")
+
+    def value_and_gradients(fold, p, x2, w, local):
+        def loss(p, x2, w):
+            layer.fold = fold
+            y, _ = functional_call(layer, {**p, **buffers}, (x2, w, local))
+            return jnp.sum(y ** 2), y
+        (_, y), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                           has_aux=True)(p, x2, w)
+        return y, grads
+
+    def both(x2, w, local):
+        return tuple(value_and_gradients(fold, params, x2, w, local)
+                     for fold in (True, False))
+
+    return jax.jit(both)
+
+
+def _routing(name, tokens=12, held=4):
+    """``local [tokens, top_k]``: the held expert of each choice, ``held``
+    where the chosen expert is not held."""
+    rng = np.random.default_rng(len(name))
+    if name == "an-expert-without-rows":
+        return rng.choice([0, 2, 3, held], (tokens, 3))
+    if name == "every-row-on-one-expert":
+        return np.stack([np.full(tokens, 2), np.full(tokens, held),
+                         np.full(tokens, held)], axis=1)
+    if name == "nothing-held":
+        return np.full((tokens, 3), held)
+    if name == "everything-held":
+        return np.stack([rng.permutation(held)[:3] for _ in range(tokens)])
+    if name == "top-1":
+        return rng.integers(0, held + 1, (tokens, 1))
+    assert name == "tokens-all-held-and-none-held"
+    local = rng.integers(0, held + 1, (tokens, 3))
+    local[::3] = [0, 1, 3]
+    local[1::3] = held
+    return local
+
+
+ROUTINGS = ("an-expert-without-rows", "every-row-on-one-expert",
+            "nothing-held", "everything-held", "top-1",
+            "tokens-all-held-and-none-held")
+
+
+@pytest.mark.parametrize("name", ROUTINGS)
+def test_fold_gives_what_the_scatter_add_gives(name):
+    """Where the sorted order is whole, the fast path ends in a gather by
+    the order's inverse and a sum over ``top_k``; the same inputs through
+    the scatter-add form give the same values and the same gradients by
+    ``x``, the weights and the three expert stacks."""
+    local = _routing(name)
+    rng = np.random.default_rng(21)
+    x2 = rng.standard_normal((local.shape[0], 16)).astype(np.float32)
+    w = rng.uniform(0.1, 1.0, local.shape).astype(np.float32)
+    folded, scattered = _both_forms(local.shape[1])(
+        x2, w, local.astype(np.int32))
+    got, want = jax.tree.leaves(folded), jax.tree.leaves(scattered)
+    assert len(got) == 1 + 3 + 2
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    live = bool((local < 4).any())
+    assert bool(np.any(np.asarray(got[0]))) == live
+
+
+@pytest.mark.parametrize("dtype,top_k", [("float32", 3), ("float32", 1),
+                                         ("bfloat16", 4)])
+def test_spread_and_fold_are_each_the_others_transpose(dtype, top_k):
+    """``_spread`` and ``_fold`` against autodiff of their plain forms
+    ``x[token]`` and ``zeros.at[token].add(float32(rows) * w_row)``: the
+    values, and the cotangents by ``x``, the rows and the weights.  In
+    bfloat16 the plain gather's transpose adds in bfloat16 and
+    ``_spread``'s in float32: never further from the float32 sum."""
+    from bigdl_tpu.nn.layers import moe
+
+    tokens, d = 10, 8
+    rng = np.random.default_rng(top_k)
+    order = rng.permutation(tokens * top_k).astype(np.int32)
+    token = order // top_k
+    x = jnp.asarray(rng.standard_normal((tokens, d)), dtype)
+    rows = jnp.asarray(rng.standard_normal((tokens * top_k, d)), dtype)
+    w_row = rng.uniform(0.0, 1.0, tokens * top_k).astype(np.float32)
+    dy = rng.standard_normal((tokens, d)).astype(np.float32)
+
+    def run(x, rows, w_row, dy):
+        places = moe._places(order, top_k)
+        plain_spread = lambda v: v[token]                    # noqa: E731
+        plain_fold = lambda r, w: jnp.zeros(                 # noqa: E731
+            (tokens, d), jnp.float32).at[token].add(
+                r.astype(jnp.float32) * w[:, None])
+        out = []
+        for spread, fold in (
+                (lambda v: moe._spread(v, token, places, top_k),
+                 lambda r, w: moe._fold(r, w, token, places, top_k)),
+                (plain_spread, plain_fold)):
+            moved, back = jax.vjp(spread, x)
+            y, fold_back = jax.vjp(fold, rows, w_row)
+            out.append((moved, back(rows)[0], y) + fold_back(dy))
+        exact = jnp.zeros(
+            (tokens, d), jnp.float32).at[token].add(
+                rows.astype(jnp.float32))
+        return out[0], out[1], exact
+
+    got, want, exact = jax.jit(run)(x, rows, w_row, dy)
+    for name, a, b in zip(("rows", "dx", "y", "drows", "dw_row"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        if dtype == "bfloat16" and name == "dx":
+            continue
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=1e-5, atol=1e-6, err_msg=name)
+    exact = np.asarray(exact)
+    gap = [float(np.abs(np.asarray(v[1], np.float32) - exact).max())
+           for v in (got, want)]
+    assert gap[0] <= gap[1] + 1e-6
+    assert gap[0] <= 2.0 ** -8 * float(np.abs(exact).max()) + 1e-6
+
+
+@pytest.mark.parametrize("held,combine", [((0, 16), "fold"),
+                                          ((0, 2), "scatter_add")],
+                         ids=["whole-order", "prefix-under-cond"])
+def test_route_instant_names_the_combine(held, combine, monkeypatch):
+    """A layer that holds every expert sorts ALL its assignments
+    (``capacity`` = ``tokens x top_k``) and folds; one that holds a share
+    sorts a prefix under ``lax.cond`` and keeps the scatter-add."""
+    from bigdl_tpu import telemetry
+
+    said = []
+    monkeypatch.setattr(telemetry, "instant",
+                        lambda name, **attrs: said.append((name, attrs)))
+    layer = nn.RoutedExperts(16, 24, 16, 2, held=held)
+    text = jax.jit(lambda s, v: functional_call(layer, s, v)[0]).lower(
+        state_dict(layer), jnp.zeros((64, 16), jnp.float32)).as_text()
+    (route,) = [attrs for name, attrs in said if name == "moe/route"]
+    assert route["combine"] == combine
+    assert (route["capacity"] == 64 * 2) == (combine == "fold")
+    assert route["worst"] == 64 * 2
+    branches = "stablehlo.case" in text or "stablehlo.if" in text
+    assert branches == (combine == "scatter_add")
+
+
 # (query heads, kv heads, sequence, window, block_q, block_k): group sizes
 # 6 and 9, a sequence the preferred block does not divide, windows smaller
 # and larger than a block, and no window
@@ -319,7 +483,8 @@ def test_registry_decoder_trains_through_local_optimizer_and_is_traced(
         {(None, 4, 2), (8, 6, 2)}
     routes = [e for e in events if e.get("name") == "moe/route"]
     assert routes and routes[0]["experts"] == 16 and routes[0]["held"] == 4 \
-        and routes[0]["top_k"] == 3 and routes[0]["capacity"] == 384
+        and routes[0]["top_k"] == 3 and routes[0]["capacity"] == 384 \
+        and routes[0]["combine"] == "fold"          # 384 = 4 x 32 x 3
     load = [e for e in events if e.get("name") == "moe/load"]
     assert len(load) == 12 * 3 * 4          # steps x sparse layers x held
     by_step = sum(e["value"] for e in load[:12])  # the first step's
