@@ -20,13 +20,15 @@ attention for sequences larger than one chip holds.
 (:class:`DecoderPlan`): RMS normalisation, rotary positions, a mixer
 whose kind differs by layer (grouped-query attention, full or within a
 window, with a gate a head or a channel and optionally normed queries
-and keys; gated delta-rule linear attention; or a double-gated short
-convolution), and a dense gated or a routed sparse feed-forward per
-layer (softmax scores, or sigmoid scores with a bias that chooses),
-every block under ``nn.Remat``, the head its own matrix or the
-embedding's.  It trains with the same criterion; the benchmark's
-``laguna_s_2_1``, ``qwen3_next_80b_a3b`` and ``lfm2_24b_a2b``
-configurations are such plans at published widths.
+and keys; gated delta-rule linear attention; a double-gated short
+convolution; or a Mamba-2 state-space mixer), and a dense gated or a
+routed sparse feed-forward per layer (softmax scores, or sigmoid scores
+with a bias that chooses; experts on the model's width or in a latent),
+either of which a layer may lack, every block under ``nn.Remat``, the
+head its own matrix or the embedding's.  It trains with the same
+criterion; the benchmark's ``laguna_s_2_1``, ``qwen3_next_80b_a3b``,
+``lfm2_24b_a2b`` and ``nemotron_3_super_120b_a12b`` configurations are
+such plans at published widths.
 """
 
 from __future__ import annotations
@@ -108,18 +110,21 @@ def build_transformer_lm(vocab_size: int, num_layers: int = 4,
 
 
 #: the kinds of mixer and of feed-forward a :class:`LayerPlan` may name
-ATTENTION_KINDS = ("full", "window", "linear", "conv")
-FFN_KINDS = ("dense", "sparse")
+ATTENTION_KINDS = ("full", "window", "linear", "conv", "ssm", "none")
+FFN_KINDS = ("dense", "sparse", "none")
 
 
 class LayerPlan(NamedTuple):
     """One decoder layer: ``attention`` is the kind of its MIXER,
-    ``"full"``, ``"window"``, ``"linear"`` or ``"conv"`` (the field keeps
-    the name it had when every mixer was an attention; a ``"conv"`` layer
-    is an :class:`nn.GatedShortConv` and attends to nothing), ``heads``
-    its query heads (of a linear layer: its value heads; a ``"conv"``
-    layer has none and ignores it), ``ffn`` ``"dense"`` or
-    ``"sparse"``."""
+    ``"full"``, ``"window"``, ``"linear"``, ``"conv"`` or ``"ssm"`` (the
+    field keeps the name it had when every mixer was an attention; a
+    ``"conv"`` layer is an :class:`nn.GatedShortConv`, an ``"ssm"`` layer
+    an :class:`nn.Mamba2Mixer`, and neither attends to anything),
+    ``heads`` its query heads (of a linear layer: its value heads; of an
+    ``"ssm"`` layer: its state-space heads; a ``"conv"`` layer has none
+    and ignores it), ``ffn`` ``"dense"`` or ``"sparse"``.  ``"none"`` in
+    either place: the layer lacks that part and is the other alone, one
+    norm and one residual add (both ``"none"`` is refused)."""
     attention: str
     heads: int
     ffn: str
@@ -142,9 +147,15 @@ class DecoderPlan(NamedTuple):
     of ``conv_taps`` taps.  ``router_score`` (``"softmax"`` or
     ``"sigmoid"``) and ``router_bias`` (a bias an expert that enters the
     choice of the ``top_k`` and not their weights) are the sparse
-    layers' (:class:`nn.RoutedExperts`).  ``tie_embeddings``: the head
-    projects with the embedding's own matrix, one parameter read in two
-    places."""
+    layers' (:class:`nn.RoutedExperts`), as are ``expert_latent`` (the
+    routed experts work on rows projected to this width and back; None:
+    on the model's own) and ``expert_activation`` (``"silu"``: gated-SiLU
+    experts and shared expert; another of ``nn.layers.moe.ACTIVATIONS``:
+    ungated ones under it).  An ``"ssm"`` layer is an
+    :class:`nn.Mamba2Mixer` of heads of ``ssm_head_dim``, ``ssm_groups``
+    groups, a state of ``ssm_state`` and a convolution of ``ssm_conv``
+    taps.  ``tie_embeddings``: the head projects with the embedding's own
+    matrix, one parameter read in two places."""
     vocab_size: int
     hidden_size: int
     head_dim: int
@@ -174,6 +185,12 @@ class DecoderPlan(NamedTuple):
     router_score: str = "softmax"
     router_bias: bool = False
     tie_embeddings: bool = False
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    expert_latent: Optional[int] = None
+    expert_activation: str = "silu"
 
 
 class VocabHead(Module):
@@ -219,6 +236,9 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
         if layer.ffn not in FFN_KINDS:
             raise ValueError(f"layer {i}: unknown feed-forward kind "
                              f"{layer.ffn!r}; known: {', '.join(FFN_KINDS)}")
+        if layer.attention == layer.ffn == "none":
+            raise ValueError(f"layer {i} has neither a mixer nor a "
+                             f"feed-forward")
     zero = plan.zero_centred_norm
 
     def head_norm(n):
@@ -231,7 +251,14 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
     model = nn.Sequential(embedding)
     for layer in plan.layers:
         windowed = layer.attention == "window"
-        if layer.attention == "conv":
+        if layer.attention == "none":
+            attn = None
+        elif layer.attention == "ssm":
+            attn = nn.Mamba2Mixer(
+                plan.hidden_size, layer.heads, plan.ssm_head_dim,
+                plan.ssm_groups, plan.ssm_state, taps=plan.ssm_conv,
+                eps=plan.eps)
+        elif layer.attention == "conv":
             attn = nn.GatedShortConv(plan.hidden_size, taps=plan.conv_taps)
         elif layer.attention == "linear":
             attn = nn.GatedDeltaNet(
@@ -245,7 +272,9 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
                 rotary=plan.rotary_window if windowed else plan.rotary_full,
                 gate=plan.gate, backend=backend,
                 qk_norm=head_norm if plan.qk_norm else None)
-        if layer.ffn == "dense":
+        if layer.ffn == "none":
+            ffn = None
+        elif layer.ffn == "dense":
             ffn = nn.GatedMLP(plan.hidden_size, plan.dense_width)
         else:
             ffn = nn.RoutedExperts(
@@ -253,7 +282,9 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
                 plan.top_k, held=plan.held, shared_width=plan.shared_width,
                 routed_scale=plan.routed_scale, normalize=plan.normalize,
                 shared_gate=plan.shared_gate, score=plan.router_score,
-                select_bias=plan.router_bias)
+                select_bias=plan.router_bias,
+                activation=plan.expert_activation,
+                latent=plan.expert_latent)
         block = nn.DecoderBlock(plan.hidden_size, attn, ffn, eps=plan.eps,
                                 zero_centred=zero)
         model.add(nn.Remat(block) if remat else block)

@@ -20,6 +20,7 @@ from bigdl_tpu.nn.layers.rnn import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.attention import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.linear_attention import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.short_conv import *  # noqa: F401,F403
+from bigdl_tpu.nn.layers.ssm import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.tree import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.moe import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.scan import *  # noqa: F401,F403

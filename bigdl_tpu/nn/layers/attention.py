@@ -400,20 +400,37 @@ class GroupedQueryAttention(Module):
 class DecoderBlock(Module):
     """Pre-norm decoder block with RMS normalisation: ``h = x +
     attn(norm1(x))``, ``y = h + ffn(norm2(h))``.  ``attn`` and ``ffn`` are
-    modules over [batch, seq, embed] (a :class:`GroupedQueryAttention` or a
-    ``GatedDeltaNet``; a ``GatedMLP`` or a ``RoutedExperts``);
-    ``zero_centred`` is the two norms' (``RMSNorm``)."""
+    modules over [batch, seq, embed] (a :class:`GroupedQueryAttention`, a
+    ``GatedDeltaNet``, a ``GatedShortConv`` or a ``Mamba2Mixer``; a
+    ``GatedMLP`` or a ``RoutedExperts``); ``zero_centred`` is the norms'
+    (``RMSNorm``).  Either part may be ``None``: the block is then the
+    ONE sub-layer it has, ``y = x + attn(norm1(x))`` or ``y = x +
+    ffn(norm2(x))``, one norm and one residual add (the layers of a
+    decoder whose every layer is a mixer or a feed-forward alone)."""
 
-    def __init__(self, embed_dim: int, attn: Module, ffn: Module,
-                 eps: float = 1e-6, zero_centred: bool = False):
+    def __init__(self, embed_dim: int, attn: Optional[Module],
+                 ffn: Optional[Module], eps: float = 1e-6,
+                 zero_centred: bool = False):
         super().__init__()
         from bigdl_tpu.nn.layers.normalization import RMSNorm
 
-        self.norm1 = RMSNorm(embed_dim, eps, zero_centred)
-        self.attn = attn
-        self.norm2 = RMSNorm(embed_dim, eps, zero_centred)
-        self.ffn = ffn
+        if attn is None and ffn is None:
+            raise ValueError("a decoder block with neither a mixer nor a "
+                             "feed-forward")
+        if attn is not None:
+            self.norm1 = RMSNorm(embed_dim, eps, zero_centred)
+            self.attn = attn
+        if ffn is not None:
+            self.norm2 = RMSNorm(embed_dim, eps, zero_centred)
+            self.ffn = ffn
+        self.parts = tuple(name for name, part in (("attn", attn),
+                                                   ("ffn", ffn))
+                           if part is not None)
 
     def update_output(self, input):
-        h = input + self.attn.forward(self.norm1.forward(input))
-        return h + self.ffn.forward(self.norm2.forward(h))
+        h = input
+        if "attn" in self.parts:
+            h = h + self.attn.forward(self.norm1.forward(h))
+        if "ffn" in self.parts:
+            h = h + self.ffn.forward(self.norm2.forward(h))
+        return h

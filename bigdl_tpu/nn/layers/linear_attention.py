@@ -10,6 +10,7 @@ layer.  The recurrence itself is ``ops.delta_rule.gated_delta_rule``
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,18 +24,41 @@ __all__ = ["GatedDeltaNet", "GatedRMSNorm"]
 class GatedRMSNorm(Module):
     """``forward((x, z))``: ``w * x / sqrt(mean(x^2) + eps) * silu(z)``
     over the last dimension, norm before gate, ``w`` starting at 1; all
-    of it in float32, the result in ``x``'s dtype."""
+    of it in float32, the result in ``x``'s dtype.
 
-    def __init__(self, normalized_size: int, eps: float = 1e-6):
+    ``gate_first``: the other order, ``w * norm(x * silu(z))``.
+    ``group_size``: the mean of squares is taken over each run of
+    ``group_size`` channels of the last dimension on its own (it divides
+    ``normalized_size``; ``w`` stays one scale a channel)."""
+
+    def __init__(self, normalized_size: int, eps: float = 1e-6,
+                 gate_first: bool = False,
+                 group_size: Optional[int] = None):
         super().__init__()
+        if group_size is not None and normalized_size % group_size:
+            raise ValueError(f"groups of {group_size} channels over "
+                             f"{normalized_size}")
         self.normalized_size, self.eps = normalized_size, eps
+        self.gate_first, self.group_size = gate_first, group_size
         self.weight = Parameter(jnp.ones((normalized_size,), jnp.float32))
+
+    def _norm(self, y):
+        if self.group_size is None:
+            return y * jax.lax.rsqrt(
+                jnp.mean(y * y, axis=-1, keepdims=True) + self.eps)
+        groups = y.reshape(y.shape[:-1] + (-1, self.group_size))
+        groups = groups * jax.lax.rsqrt(
+            jnp.mean(groups * groups, axis=-1, keepdims=True) + self.eps)
+        return groups.reshape(y.shape)
 
     def update_output(self, input):
         x, z = input
         y = x.astype(jnp.float32)
-        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                              + self.eps)
+        if self.gate_first:
+            y = self._norm(y * jax.nn.silu(z.astype(jnp.float32))) \
+                * self.weight.astype(jnp.float32)
+            return y.astype(x.dtype)
+        y = self._norm(y)
         y = y * self.weight.astype(jnp.float32) \
             * jax.nn.silu(z.astype(jnp.float32))
         return y.astype(x.dtype)

@@ -5,12 +5,13 @@
   float32 router over all ``n_experts`` (softmax scores, or sigmoid
   scores with a bias an expert that enters the choice only), the
   ``top_k`` largest
-  per token with renormalised weights, gated-SiLU experts of which this
-  layer HOLDS a contiguous share (``held=(first, count)``), an optional
-  shared expert, and no token ever dropped.  It computes its own
+  per token with renormalised weights, experts (gated SiLU, or ungated
+  under another activation; on the model's width or in a narrower
+  latent) of which this layer HOLDS a contiguous share (``held=(first,
+  count)``), an optional shared expert, and no token ever dropped.  It computes its own
   experts' part of the result and nothing that stands in for the other
   chips or their exchange.  This is the layer that runs on a chip (the
-  benchmark's three decoder cells).
+  benchmark's decoder cells).
 - :class:`MixtureOfExperts` — the GShard/Mesh-TensorFlow DENSE dispatch
   (one-hot capacity-bucketed einsums in float32, ReLU experts, tokens
   over capacity zeroed) whose stacked parameters shard over a mesh
@@ -39,7 +40,7 @@ from bigdl_tpu.nn.init import Xavier
 from bigdl_tpu.utils.rng import next_rng_id, require_rng
 
 __all__ = ["MixtureOfExperts", "expert_sharding_rules", "GatedMLP",
-           "RoutedExperts"]
+           "FeedForward", "RoutedExperts"]
 
 
 def expert_sharding_rules(axis: str = "expert"):
@@ -183,6 +184,36 @@ class GatedMLP(Module):
         return f"GatedMLP({self.d_model}, {self.width})"
 
 
+#: the activations an expert (and a plain feed-forward) may have;
+#: ``relu2`` is the squared ReLU
+ACTIVATIONS = {"silu": jax.nn.silu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+
+
+class FeedForward(Module):
+    """Ungated feed-forward: ``act(x W_up) W_down``, no bias;
+    ``activation`` one of :data:`ACTIVATIONS`."""
+
+    def __init__(self, d_model: int, width: int, activation: str = "relu2"):
+        super().__init__()
+        from bigdl_tpu.nn.layers.linear import Linear
+
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"unknown activation {activation!r}; known: "
+                             f"{', '.join(ACTIVATIONS)}")
+        self.d_model, self.width, self.activation = d_model, width, activation
+        self.up_proj = Linear(d_model, width, with_bias=False)
+        self.down_proj = Linear(width, d_model, with_bias=False)
+
+    def update_output(self, input):
+        return self.down_proj.forward(
+            ACTIVATIONS[self.activation](self.up_proj.forward(input)))
+
+    def __repr__(self):
+        return (f"FeedForward({self.d_model}, {self.width}, "
+                f"{self.activation})")
+
+
 def _places(order, top_k: int):
     """The inverse of a WHOLE sorted order of the ``(token, choice)``
     pairs, choice-major: ``places[j * tokens + t]`` is the sorted row that
@@ -277,9 +308,19 @@ class RoutedExperts(Module):
     routed_scale * p_e / sum of the chosen p`` (``normalize``; sigmoid
     scores add ``SIGMOID_NORM_EPS`` to that sum, since it can be near
     zero).  Result: ``shared(x) + sum over the chosen experts
-    that are HELD here of w_e * expert_e(x)``; every expert and the
-    shared expert is a gated-SiLU feed-forward; ``shared_width`` 0 or
-    None: no shared expert.  ``shared_gate``: the
+    that are HELD here of w_e * expert_e(x)``.  An expert is ``(act(x
+    W_gate) * (x W_up)) W_down`` under ``activation="silu"`` (the
+    default: the gated-SiLU form) and ``act(x W_up) W_down`` with no
+    gate matrix under any other of :data:`ACTIVATIONS` (``"relu2"`` is
+    the squared ReLU): the activation decides the form; the shared
+    expert has the same form on the model's own width (``shared_width``
+    0 or None: no shared expert).  ``latent``: the routed experts work
+    in a space narrower than the model: ``l = x W_in`` (``d_model ->
+    latent``) BEFORE the dispatch, experts of ``[latent, width]`` and
+    ``[width, latent]``, and ``r W_out`` (``latent -> d_model``) AFTER
+    the combine, so gather, grouped product and scatter-add (or fold)
+    move ``latent``-wide rows; the router and the shared expert read
+    ``x`` itself.  ``shared_gate``: the
     shared expert's result is scaled by ``sigmoid(x w_s)``, one scalar a
     token from a weight of its own.  ``held=(first, count)``
     names the contiguous experts this layer has parameters for (default:
@@ -320,7 +361,7 @@ class RoutedExperts(Module):
     sync): rows each held expert received in the last forward, then the
     rows that took the exact path.  A ``moe/route`` instant at trace
     time says how the layer was built (``combine``: ``"fold"`` or
-    ``"scatter_add"``)."""
+    ``"scatter_add"``; ``latent``, ``activation``, ``gated``)."""
 
     #: the score functions a router may have
     SCORES = ("softmax", "sigmoid")
@@ -338,7 +379,8 @@ class RoutedExperts(Module):
                  shared_width: Optional[int] = None,
                  routed_scale: float = 1.0, normalize: bool = True,
                  shared_gate: bool = False, score: str = "softmax",
-                 select_bias: bool = False):
+                 select_bias: bool = False, activation: str = "silu",
+                 latent: Optional[int] = None):
         super().__init__()
         from bigdl_tpu.nn.init import RandomUniform
         from bigdl_tpu.nn.layers.linear import Linear
@@ -349,25 +391,38 @@ class RoutedExperts(Module):
         if score not in self.SCORES:
             raise ValueError(f"unknown router score {score!r}; known: "
                              f"{', '.join(self.SCORES)}")
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"unknown activation {activation!r}; known: "
+                             f"{', '.join(ACTIVATIONS)}")
         self.score = score
+        self.activation, self.latent = activation, latent
+        #: SiLU experts have a gate matrix, the others none
+        self.gated = gated = activation == "silu"
+        #: the width the routed rows have: the latent, or the model's own
+        self.row_dim = row_dim = latent or d_model
         self.d_model, self.width = d_model, width
         self.n_experts, self.top_k = n_experts, top_k
         self.first, self.count = first, count
         self.routed_scale, self.normalize = routed_scale, normalize
         init = RandomUniform()
-        self.experts_gate = Parameter(init.init(
-            (count, d_model, width), fan_in=d_model))
+        if gated:
+            self.experts_gate = Parameter(init.init(
+                (count, row_dim, width), fan_in=row_dim))
         self.experts_up = Parameter(init.init(
-            (count, d_model, width), fan_in=d_model))
+            (count, row_dim, width), fan_in=row_dim))
         self.experts_down = Parameter(init.init(
-            (count, width, d_model), fan_in=width))
+            (count, width, row_dim), fan_in=width))
         self.router = Linear(d_model, n_experts, with_bias=False)
         self.has_select_bias = bool(select_bias)
         if select_bias:
             self.select_bias = Parameter(jnp.zeros((n_experts,),
                                                    jnp.float32))
+        if latent:
+            self.latent_in = Linear(d_model, latent, with_bias=False)
+            self.latent_out = Linear(latent, d_model, with_bias=False)
         if shared_width:
-            self.shared = GatedMLP(d_model, shared_width)
+            self.shared = GatedMLP(d_model, shared_width) if gated \
+                else FeedForward(d_model, shared_width, activation)
         self.shared_width = shared_width
         self.shared_gated = bool(shared_gate and shared_width)
         if self.shared_gated:
@@ -403,6 +458,20 @@ class RoutedExperts(Module):
             top_p = top_p / (total if eps is None else total + eps)
         return self.routed_scale * top_p, top_i
 
+    def _hidden(self, product, stacks):
+        """An expert's hidden rows from ``product(stack)`` of its input
+        with each of ``stacks`` (gate and up, or up alone)."""
+        act = ACTIVATIONS[self.activation]
+        if self.gated:
+            return act(product(stacks[0])) * product(stacks[1])
+        return act(product(stacks[0]))
+
+    def _stacks(self):
+        """The experts' input-side stacks, in the order ``_hidden`` takes
+        them."""
+        return (self.experts_gate, self.experts_up) if self.gated \
+            else (self.experts_up,)
+
     def _grouped(self, x2, w, local, counts, cap, fold):
         """The fast path: ``cap`` rows sorted by held expert.  ``fold``:
         the order is whole, and rows move by ``_spread`` and ``_fold``."""
@@ -429,13 +498,12 @@ class RoutedExperts(Module):
             return jnp.where(live[:, None], jax.lax.ragged_dot(
                 a, p.astype(a.dtype), sizes), 0)
 
-        h = jax.nn.silu(product(rows, self.experts_gate)) \
-            * product(rows, self.experts_up)
+        h = self._hidden(lambda p: product(rows, p), self._stacks())
         out = product(h, self.experts_down)
         if fold:
             return _fold(out, w_row, token, places, k)
         out = out.astype(jnp.float32) * w_row[:, None]
-        return jnp.zeros((x2.shape[0], self.d_model), jnp.float32).at[
+        return jnp.zeros((x2.shape[0], self.row_dim), jnp.float32).at[
             token].add(out)
 
     def _masked(self, x2, w, local, counts=None):
@@ -455,20 +523,18 @@ class RoutedExperts(Module):
             jax.checkpoint(lambda block: self._masked_rows(*block)),
             (x2.reshape(t // rows, rows, -1),
              w_dense.reshape(t // rows, rows, -1)))
-        return y.reshape(t, self.d_model)
+        return y.reshape(t, self.row_dim)
 
     def _masked_rows(self, x2, w_dense):
         def one(y, e):
-            wg, wu, wd, we = e
-            h = jax.nn.silu(x2 @ wg.astype(x2.dtype)) \
-                * (x2 @ wu.astype(x2.dtype))
+            *stacks, wd, we = e
+            h = self._hidden(lambda p: x2 @ p.astype(x2.dtype), stacks)
             out = (h @ wd.astype(x2.dtype)).astype(jnp.float32)
             return y + we[:, None] * out, None
 
         y, _ = jax.lax.scan(
-            one, jnp.zeros((x2.shape[0], self.d_model), jnp.float32),
-            (self.experts_gate, self.experts_up, self.experts_down,
-             w_dense.T))
+            one, jnp.zeros((x2.shape[0], self.row_dim), jnp.float32),
+            self._stacks() + (self.experts_down, w_dense.T))
         return y
 
     def update_output(self, input):
@@ -487,8 +553,12 @@ class RoutedExperts(Module):
                           combine="fold" if fold else "scatter_add",
                           score=self.score,
                           select_bias=self.has_select_bias,
-                          shared=bool(self.shared_width))
+                          shared=bool(self.shared_width),
+                          latent=self.latent, activation=self.activation,
+                          gated=self.gated)
         w, experts = self.route(x2)
+        # the rows the experts take: the tokens, or their latent projection
+        rows = self.latent_in.forward(x2) if self.latent else x2
         local = experts - self.first
         # an assignment to an expert that is not held sorts last
         local = jnp.where((local >= 0) & (local < self.count), local,
@@ -499,14 +569,16 @@ class RoutedExperts(Module):
         n_held = t * self.top_k - counts[self.count]
         grouped = functools.partial(self._grouped, cap=cap, fold=fold)
         if cap >= worst:
-            y = grouped(x2, w, local, counts)
+            y = grouped(rows, w, local, counts)
         else:
             y = jax.lax.cond(n_held <= cap, grouped, self._masked,
-                             x2, w, local, counts)
+                             rows, w, local, counts)
         spilled = jnp.where(n_held > cap, n_held, 0)
         self.held_load = jax.lax.stop_gradient(jnp.concatenate(
             [counts[:self.count], spilled[None]]))
         y = y.astype(input.dtype)
+        if self.latent:
+            y = self.latent_out.forward(y)
         if self.shared_width:
             shared = self.shared.forward(x2)
             if self.shared_gated:
@@ -535,4 +607,5 @@ class RoutedExperts(Module):
     def __repr__(self):
         return (f"RoutedExperts({self.d_model}, {self.width}, experts="
                 f"{self.first}..{self.first + self.count - 1} of "
-                f"{self.n_experts}, top_k={self.top_k})")
+                f"{self.n_experts}, top_k={self.top_k}, latent="
+                f"{self.latent}, activation={self.activation})")

@@ -133,7 +133,8 @@ STREAM_NAMES = frozenset({
     # grid; op=gated_delta_rule: leg, chunk, chunks, heads, key_dim,
     # value_dim and, on its Pallas leg, chunks_per_block, grid;
     # op=attention: window, q_heads, kv_heads, head_dim and the flash
-    # leg's blocks; op=gated_short_conv: taps, channels, tokens)
+    # leg's blocks; op=gated_short_conv: taps, channels, tokens; op=ssd:
+    # chunk, chunks, heads, head_dim, state, groups)
     "kernel/dispatch",
     # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
     # instant per TRACE of a layer (experts, held, top_k, capacity
@@ -143,7 +144,9 @@ STREAM_NAMES = frozenset({
     # inverse gathers tokens x top_k rows in both passes; "scatter_add"
     # where a prefix of capacity rows is scatter-added; the router's
     # score function, whether a bias enters the choice, whether there
-    # is a shared expert), and per step the rows
+    # is a shared expert, the latent width the routed rows have or null,
+    # the experts' activation and whether they are gated), and per step
+    # the rows
     # each held expert received, their sum, largest and mean (max over
     # mean: the imbalance the grouped product sees) and the rows that
     # took the exact path (counters, emitted by the Optimizer where it
@@ -171,6 +174,13 @@ STREAM_NAMES = frozenset({
     # instant with op=gated_delta_rule)
     "linear_attn/decay_mean", "linear_attn/beta_mean",
     "linear_attn/state_norm_max",
+    # state-space mixer (bigdl_tpu/nn/layers/ssm.py Mamba2Mixer): per
+    # step and layer the mean decay a token exp(dt A), the mean step dt
+    # and the largest state norm over the heads after the last token
+    # (counters, as above; the scan's own trace-time decision is a
+    # kernel/dispatch instant with op=ssd: chunk, chunks, heads,
+    # head_dim, state, groups)
+    "ssm/decay_mean", "ssm/dt_mean", "ssm/state_norm_max",
     # fault tolerance (bigdl_tpu/faults.py + docs/fault_tolerance.md):
     # injected faults, quarantined torn checkpoints, graceful
     # preemption, and checkpoint auto-resume
