@@ -21,7 +21,13 @@ Knob: ``BIGDL_KERNELS`` (read at trace time):
 Every decision is emitted as a ``kernel/dispatch`` telemetry instant
 (op, backend, reason) at TRACE time — one instant per compilation, not
 per step — so PR 4's attribution can say which backend each module's
-HLO actually contains.  A leg that launches through
+HLO actually contains.  The reasons: ``forced:BIGDL_KERNELS=<mode>``,
+``unsupported-shape``, ``auto:tpu``, ``auto:off-tpu``,
+``auto:spmd-partitioned`` from :func:`choose_backend`; from an op that
+has one form and says so through :func:`note`, ``only-leg`` (the
+state-space scan) and ``whole-plane`` (an average pool whose window is
+the whole padded plane: a fused reduction in plain ``jnp`` in every
+mode, ``pool_pallas.avg_pool``).  A leg that launches through
 ``pallas_util.plane_call`` adds how it was launched
 (``planes_per_block``, ``grid``: :func:`launched`).  A small in-process
 ring (:func:`decisions`) records the same for tests and the micro-bench

@@ -45,7 +45,7 @@ def planes_per_block(planes, b: int) -> int:
     ``VMEM_BUDGET`` when every per-plane input and output (``planes``:
     [(per-plane shape, dtype), ...]) is held tile-rounded and twice, for
     the pipeline's two buffers.  A large plane (a 512x512 image, a
-    [C, HW] LRN slab) comes out at 1; a 7x7 head-pool plane at 256."""
+    [C, HW] LRN slab) comes out at 1; a 7x7 plane at 256."""
     per_plane = 2 * sum(_tiled_bytes(s, d) for s, d in planes)
     return max(1, min(b, VMEM_BUDGET // per_plane))
 
@@ -61,6 +61,10 @@ def plane_call(kernel, inputs, out_shapes, b, interpret: bool,
     neighbor blocks; many of them, so a small plane does not pay a grid
     step's fixed cost (~0.35 us) for 4 KB of transfer.  Where P comes
     out 1 the program is the one-plane-a-step launch it always was.
+    A plane stack is ``{2,1,0}`` with each plane tile-rounded, so a
+    caller whose planes are far smaller than a tile pays the layout
+    copies in and out as well: the average pool whose window is the
+    whole plane does not come here (``pool_pallas.avg_pool``).
 
     ``kernel`` sees refs of shape ``(P,) + plane`` and works on every
     plane of the block at once (leading axis).  ``inputs``: arrays whose

@@ -18,7 +18,11 @@ Two ops the autodiff path got subtly wrong and XLA lowers expensively:
   does).  The divisor map is pure geometry, computed in numpy at trace
   time (a separable outer product) and baked into the kernel as a
   constant — forward is one windowed-sum pass, backward one
-  residue-class scatter of ``gy / counts``.
+  residue-class scatter of ``gy / counts``.  A window that IS the
+  padded plane (a head pool) is no kernel: a float32 sum times the one
+  divisor and a broadcast back, plain ``jnp`` in every mode, which XLA
+  fuses into its neighbours; as a plane kernel a 98-byte 7x7 plane took
+  a 4 KB tile and two layout copies around the call (PERF.md, PR 41).
 
 Residue-class geometry (shared with ``ops/pooling_pallas.py``'s argmax
 kernel and the XLA reference leg): padded input positions split into
@@ -102,7 +106,7 @@ def pool_plane_supported(x, dims, strides) -> bool:
 # Pallas kernels: a ref is a block of P whole planes, [P, rows, cols],
 # and a body works on all P at once along the leading axis; the grid is
 # ceil(N*C / P), P chosen by ``pallas_util.plane_call`` from the plane
-# shape (256 for a 7x7 bf16 head-pool plane, 1 for a large one)
+# shape (256 for a 6x6 or 7x7 plane, 1 for a large one)
 # ---------------------------------------------------------------------------
 
 def _taps(xp, k2, s2, out2):
@@ -184,24 +188,9 @@ def _tie_bwd_kernel(xp_ref, yp_ref, gp_ref, dx_ref, *, k2, s2, l2, m2,
     dx_ref[...] = dxp[:, lo_h:lo_h + h, lo_w:lo_w + w]
 
 
-def _plane_sum(v):
-    """[P, rows, cols] -> [P, 1, 1] in float32, an axis at a time: a
-    reduction over both at once leaves Mosaic a 1-D value to reshape,
-    which it refuses."""
-    s = jnp.sum(v.astype(jnp.float32), axis=2, keepdims=True)
-    return jnp.sum(s, axis=1, keepdims=True)
-
-
 def _avg_fwd_kernel(xp_ref, inv_ref, y_ref, *, k2, s2, out2):
-    xp = xp_ref[...]
-    if xp.shape[1:] == tuple(k2):
-        # the window is the whole padded plane (a head pool): every tap
-        # below would be one element, so the same sum is taken as one
-        # reduction of the plane instead of kh*kw shifted adds
-        y_ref[...] = (_plane_sum(xp) * inv_ref[...]).astype(y_ref.dtype)
-        return
     s = None
-    for tap in _taps(xp, k2, s2, out2):
+    for tap in _taps(xp_ref[...], k2, s2, out2):
         s = tap if s is None else s + tap
     y_ref[...] = s * inv_ref[...]
 
@@ -212,15 +201,6 @@ def _avg_bwd_kernel(wp_ref, dx_ref, *, k2, s2, l2, j2, lo2, n2):
     (jh_max, jw_max), (lo_h, lo_w), (h, w) = j2, lo2, n2
     wp = wp_ref[...]
     p = wp.shape[0]
-    if (lh, lw) == (kh, kw) and (sh, sw) == (1, 1):
-        # the window is the whole padded plane: the extended grid holds
-        # its one weight and zeros, each shift below lands one tap on
-        # the weight, so every position reads it.  Taken as the plane's
-        # sum (exact: the rest is zero), which Mosaic can broadcast over
-        # rows and columns; a [P, 1, 1] slice it cannot
-        dx_ref[...] = jnp.broadcast_to(_plane_sum(wp), (p, h, w)) \
-            .astype(dx_ref.dtype)
-        return
     parts = []
     for rh in range(sh):
         cols = []
@@ -443,8 +423,8 @@ def _avg_fwd_pallas(x, dims, strides, pads, inv):
                           (lo_w, pw - lo_w - w)))
     kern = functools.partial(_avg_fwd_kernel, k2=k2, s2=s2,
                              out2=(oh, ow))
-    y = _plane_call(kern, [xp, inv[None]], (oh, ow), n * c, x.dtype,
-                    bcast=(1,))
+    y = _plane_call(kern, [xp, jnp.asarray(inv[0, 0], x.dtype)[None]],
+                    (oh, ow), n * c, x.dtype, bcast=(1,))
     return y.reshape(n, c, oh, ow)
 
 
@@ -478,14 +458,27 @@ def _avg_bwd_xla(wgt, x_shape, dims, strides, pads, dtype):
     return dx.astype(dtype)
 
 
+def _whole_plane_axes(shape, dims, strides, pads):
+    """The axes of a window that IS the padded extent of every axis it
+    touches (one output a plane); None where it slides along one."""
+    axes = []
+    for a, (n, k, s, p) in enumerate(zip(shape, dims, strides, pads)):
+        if k == n + sum(p):
+            axes.append(a)
+        elif (k, s) + tuple(p) != (1, 1, 0, 0):
+            return None
+    return tuple(axes) or None
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
 def avg_pool(x, dims, strides, pads, declared, count_include_pad: bool,
              divide: bool):
     """Torch-semantics average pooling (declared-vs-overflow divisors,
     ceil mode via the caller's asymmetric ``pads``) with exact custom
-    VJP; ``divide=False`` returns the plain window sum.  Any ndim on
-    the XLA leg, fused per-plane Pallas kernels for 4-D trailing-(H, W)
-    windows."""
+    VJP; ``divide=False`` returns the plain window sum.  A whole-plane
+    window is one float32 reduction in every mode; any other: any ndim
+    on the XLA leg, fused per-plane Pallas kernels for 4-D
+    trailing-(H, W) windows."""
     # divide is a nondiff_argnum: a static Python bool at trace time,
     # not a tracer — the branch is resolved per compilation
     if not divide:  # noqa: lint/tracer-branch
@@ -493,12 +486,17 @@ def avg_pool(x, dims, strides, pads, declared, count_include_pad: bool,
                                  dims, strides, pads)
     inv = _np_inv_counts(x.shape, tuple(dims), tuple(strides),
                          tuple(pads), tuple(declared), count_include_pad)
+    whole = _whole_plane_axes(x.shape, dims, strides, pads)
+    if whole:  # noqa: lint/tracer-branch (shapes: static)
+        # the padding adds zeros and there is one divisor
+        _dispatch.note("pool_avg.fwd", "xla", "whole-plane")
+        total = jnp.sum(x, axis=whole, keepdims=True,
+                        dtype=jnp.promote_types(x.dtype, jnp.float32))
+        return (total * inv.item()).astype(x.dtype)
     supported = pool_plane_supported(x, dims, strides) \
         and inv.shape[:2] == (1, 1)
     return _dispatch.dispatch(
-        "pool_avg.fwd",
-        lambda x, d, s, p, i: _avg_fwd_pallas(
-            x, d, s, p, jnp.asarray(i[0, 0], x.dtype)),
+        "pool_avg.fwd", _avg_fwd_pallas,
         lambda x, d, s, p, i: lax.reduce_window(
             x, jnp.zeros((), x.dtype), lax.add, d, s, p)
         * jnp.asarray(i, x.dtype),
@@ -517,20 +515,21 @@ def _avg_vjp_fwd(x, dims, strides, pads, declared, count_include_pad,
 def _avg_vjp_bwd(dims, strides, pads, declared, count_include_pad,
                  divide, res, gy):
     x_shape, x_dtype = res.shape[1:], res.dtype
-    if divide:
-        inv = _np_inv_counts(tuple(x_shape), tuple(dims), tuple(strides),
-                             tuple(pads), tuple(declared),
-                             count_include_pad)
-        wgt = gy * jnp.asarray(inv, gy.dtype)
-    else:
-        wgt = gy
-    dx = _dispatch.dispatch(
+    inv = _np_inv_counts(x_shape, tuple(dims), tuple(strides),
+                         tuple(pads), tuple(declared),
+                         count_include_pad) if divide else np.ones(())
+    if _whole_plane_axes(x_shape, dims, strides, pads):
+        # every position is in the one window: a broadcast
+        _dispatch.note("pool_avg.bwd", "xla", "whole-plane")
+        wgt = gy.astype(jnp.promote_types(gy.dtype, jnp.float32))
+        return (jnp.broadcast_to((wgt * inv.item()).astype(x_dtype),
+                                 x_shape),)
+    wgt = gy * jnp.asarray(inv, gy.dtype) if divide else gy
+    return (_dispatch.dispatch(
         "pool_avg.bwd", _avg_bwd_pallas, _avg_bwd_xla,
-        pool_plane_supported(jax.ShapeDtypeStruct(tuple(x_shape),
-                                                  x_dtype),
+        pool_plane_supported(jax.ShapeDtypeStruct(x_shape, x_dtype),
                              dims, strides),
-        wgt, x_shape, dims, strides, pads, x_dtype)
-    return (dx,)
+        wgt, x_shape, dims, strides, pads, x_dtype),)
 
 
 avg_pool.defvjp(_avg_vjp_fwd, _avg_vjp_bwd)

@@ -16,8 +16,6 @@ make every pytest worker fight over the library's lock.
 with ``as_tpu``.
 """
 
-import json
-import os
 import re
 
 import jax
@@ -27,18 +25,16 @@ import pytest
 from bigdl_tpu.ops import attention, dispatch
 from bigdl_tpu.ops.lrn_pallas import cross_map_lrn, within_channel_lrn
 from bigdl_tpu.ops.pool_pallas import avg_pool, maxpool_tie_split
-from test_kernels import _launches
+from test_kernels import WHOLE_PLANE, _assert_ragged_blocks, _launches
 
 pytestmark = pytest.mark.usefixtures(
     "described_compiles_stay_out_of_the_cache")
 
 
-def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1, as_traced=False):
+def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1):
     """Compiled text of ``op``'s value and VJP.  The cotangent is an
     ARGUMENT placed on the described device: a backward whose inputs do
-    not depend on such an argument is lowered for the CPU instead.
-    ``as_traced``: every instruction with its operands' shapes, which
-    is how a device trace names its events."""
+    not depend on such an argument is lowered for the CPU instead."""
     def fwd_bwd(*args):
         *xs, g = args
         y, vjp = jax.vjp(op, *xs)
@@ -47,15 +43,7 @@ def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1, as_traced=False):
     xs = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * n_in
     g = jax.ShapeDtypeStruct(jax.eval_shape(op, *xs).shape, dtype,
                              sharding=sharding)
-    compiled = jax.jit(fwd_bwd).lower(*xs, g).compile()
-    if not as_traced:
-        return compiled.as_text()
-    from jax._src.lib import xla_client
-
-    options = xla_client._xla.HloPrintOptions()
-    options.print_operand_shape = True
-    (module,) = compiled.runtime_executable().hlo_modules()
-    return module.to_string(options)
+    return jax.jit(fwd_bwd).lower(*xs, g).compile().as_text()
 
 
 def _backends(op_prefix):
@@ -93,63 +81,65 @@ def _pool_window(k, s, pad=((0, 0), (0, 0))):
     return (1, 1, k, k), (1, 1, s, s), ((0, 0), (0, 0)) + tuple(pad)
 
 
+def _same_pool(x):
+    """Inception-v1's 3x3/s1 "same" branch pool: a window that slides
+    over its plane, the average pool that stays a plane kernel."""
+    dims, strides, pads = _pool_window(3, 1, ((1, 1), (1, 1)))
+    return avg_pool(x, dims, strides, pads, pads, True, True)
+
+
 def test_avg_pool_stride1_compiles(one_chip, as_tpu):
-    """Inception-v1's 7x7/s1 head pool stays a Pallas kernel."""
-    dims, strides, pads = _pool_window(7, 1)
-    text = _fwd_bwd_text(
-        lambda x: avg_pool(x, dims, strides, pads, pads, True, True),
-        (32, 1024, 7, 7), jnp.bfloat16, one_chip)
+    """A 3x3/s1 branch pool on a 28x28 plane stays a Pallas kernel."""
+    text = _fwd_bwd_text(_same_pool, (32, 256, 28, 28), jnp.bfloat16,
+                         one_chip)
     assert _backends("pool_avg") == {
         ("pool_avg.fwd", "pallas"), ("pool_avg.bwd", "pallas")}
     assert "tpu_custom_call" in text
 
 
-def _head_pool(x):
+def _head(x, w):
+    """``relu -> 7x7 head pool -> product``, the pool between the
+    neighbours XLA may fuse it into."""
     dims, strides, pads = _pool_window(7, 1)
-    return avg_pool(x, dims, strides, pads, pads, True, True)
+    y = avg_pool(jax.nn.relu(x), dims, strides, pads, pads, True, True)
+    return y.reshape(y.shape[:2]) @ w
 
 
-@pytest.mark.parametrize("config,shape", [
-    ("inception_v1_imagenet", (256, 1024, 7, 7)),
-    ("resnet50_imagenet", (128, 2048, 7, 7)),
-])
-def test_head_pool_is_the_call_the_benchmark_reads(config, shape, one_chip,
-                                                   as_tpu):
-    """The one-chip cells find the head pool in a device trace by the
-    ``match`` patterns of their configuration, which name the custom
-    call's operand and result shapes.  A cell whose patterns match
-    nothing loses ``kernel.pallas_share`` and ``kernel.pallas_roofline``
-    and is refused (PR 25, ResNet-50, whose only Pallas kernel this
-    is): the compiled step must hold one instruction for each pattern,
-    launched a block of planes a grid step."""
-    text = _fwd_bwd_text(_head_pool, shape, jnp.bfloat16, one_chip,
-                         as_traced=True)
-    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
-                        "configs", config + ".json")
-    with open(path) as f:
-        patterns = {k["name"]: k["match"]
-                    for k in json.load(f)["pallas_kernels"]
-                    if k["kernel"] == "pool_avg"}
-    assert set(patterns) == {"pool_avg.fwd", "pool_avg.bwd"}
-    for name, pattern in patterns.items():
-        hits = [ln for ln in text.splitlines() if re.search(pattern, ln)]
-        assert len(hits) == 1, (name, hits)
-    launches = _launches("pool_avg")
-    assert set(launches) == {"pool_avg.fwd", "pool_avg.bwd"}
-    for launch in launches.values():
-        per_block = launch["planes_per_block"]
-        assert per_block > 1
-        assert launch["grid"] == (-(-262144 // per_block),)
+@pytest.mark.parametrize("shape", [(256, 1024, 7, 7), (128, 2048, 7, 7)],
+                         ids=["inception_v1", "resnet50"])
+def test_head_pool_adds_no_instruction_of_its_own(shape, one_chip, as_tpu):
+    """The one-chip CNN cells' head pool is a window that is the whole
+    plane: a reduction and a broadcast in plain ``jnp``, which XLA
+    keeps in its own ``{1,0,3,2}`` layout inside the neighbours'
+    fusions.  As a plane kernel it cost two layout copies, a ``pad`` to
+    ``[262144,13,13]``, a ``reduce`` over ``[262144,1,1]`` and two
+    calls at 4 KB a 98-byte plane (PR 41); none of those may come
+    back."""
+    def fwd_bwd(x, w, g):
+        y, vjp = jax.vjp(_head, x, w)
+        return y, vjp(g)
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((shape[1], 1000), jnp.bfloat16,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((shape[0], 1000), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(fwd_bwd).lower(x, w, g).compile().as_text()
+    assert set(dispatch.decisions()) == WHOLE_PLANE
+    assert not _launches("pool_avg")
+    for banned in ("tpu_custom_call", " pad(", "reduce-window"):
+        assert banned not in text, banned
+    for ln in text.splitlines():
+        assert not re.search(r"= \w+\[262144[,\]]", ln), ln
+        assert not re.search(r"= \w+\[[\d,]*7,7\]\S* copy\(", ln), ln
 
 
-def test_head_pool_compiles_with_a_ragged_last_block(one_chip, as_tpu):
-    """300 planes, 256 a grid step: the second block is 44 planes and
-    Mosaic has to take it."""
-    text = _fwd_bwd_text(_head_pool, (3, 100, 7, 7), jnp.bfloat16, one_chip)
+def test_pool_compiles_with_a_ragged_last_block(one_chip, as_tpu):
+    """300 planes of 6x6 under a 3x3/s1 window, 256 a grid step: the
+    second block is 44 planes and Mosaic has to take it."""
+    text = _fwd_bwd_text(_same_pool, (3, 100, 6, 6), jnp.bfloat16, one_chip)
     assert text.count("tpu_custom_call") >= 2
-    assert _launches("pool_avg") == {
-        "pool_avg.fwd": {"planes_per_block": 256, "grid": (2,)},
-        "pool_avg.bwd": {"planes_per_block": 256, "grid": (2,)}}
+    _assert_ragged_blocks(_launches("pool_avg"))
 
 
 def _pallas_grids(fn, *args):

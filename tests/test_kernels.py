@@ -251,7 +251,8 @@ def _launches(op_prefix):
 # 300 planes of at most 4 KB each way: 256 share a grid step (4 MB over
 # 2 buffers x 8 KB), and the second step's block is ragged, 44 of 256
 BLOCKED_AVG_CASES = [
-    # (shape, k, pads, declared): a window that IS the padded plane ...
+    # (shape, k, pads, declared): a window that IS the padded plane
+    # (one fused reduction and a broadcast, never a kernel) ...
     ((2, 150, 7, 7), (7, 7), ((0, 0), (0, 0)), ((0, 0), (0, 0))),
     ((3, 100, 5, 5), (7, 7), ((1, 1), (1, 1)), ((1, 1), (1, 1))),
     ((2, 150, 4, 6), (5, 8), ((1, 0), (1, 1)), ((1, 0), (1, 1))),
@@ -259,41 +260,132 @@ BLOCKED_AVG_CASES = [
     ((2, 150, 6, 6), (3, 3), ((1, 1), (1, 1)), ((1, 1), (1, 1))),
 ]
 
+WHOLE_PLANE = {("pool_avg.fwd", "xla", "whole-plane"),
+               ("pool_avg.bwd", "xla", "whole-plane")}
 
-@pytest.mark.parametrize("shape,k,p,declared", BLOCKED_AVG_CASES)
-@pytest.mark.parametrize("count_include_pad", [True, False])
-def test_blocked_avg_pool_parity(shape, k, p, declared, count_include_pad,
-                                 monkeypatch):
-    """Many planes a grid step, the last block ragged: value and VJP of
-    the blocked kernels against the XLA leg (``reduce_window`` and its
-    transpose), with and without the padding in the divisor."""
-    x = jnp.asarray(_rng(30).randn(*shape).astype(np.float32))
-    dims, strides, pads = _full(k, (1, 1), p)
-    y = _both_legs(lambda a: avg_pool(a, dims, strides, pads,
-                                      ((0, 0), (0, 0)) + declared,
-                                      count_include_pad, True), x,
-                   monkeypatch=monkeypatch)
-    if k != (3, 3):
-        assert y.shape[2:] == (1, 1)
-    launches = _launches("pool_avg")
+
+def _assert_ragged_blocks(launches):
+    """300 planes of a 3x3/s1 window on 6x6: 256 a forward grid step;
+    the backward's extended grid is wider (10x10 float32: two tiles of
+    8 rows), so fewer planes fit; both last blocks ragged."""
     assert launches["pool_avg.fwd"] == {"planes_per_block": 256,
                                         "grid": (2,)}
-    # the backward's extended grid is wider (13x13 float32 for a 7x7
-    # window: two tiles of 8 rows), so fewer planes fit; ragged as well
     per_block = launches["pool_avg.bwd"]["planes_per_block"]
     assert 1 < per_block <= 256 and 300 % per_block
     assert launches["pool_avg.bwd"]["grid"] == (-(-300 // per_block),)
 
 
+def _check_whole_plane(x, dims, strides, pads, declared, count_include_pad,
+                       monkeypatch, rtol=1e-5, atol=1e-6):
+    """Value and VJP of a whole-plane window in every kernel mode,
+    against a NumPy mean over the padded plane and against
+    ``reduce_window`` and its transpose; no mode launches a kernel."""
+    (lo_h, hi_h), (lo_w, hi_w) = pads[2:]
+    (_, dhi_h), (_, dhi_w) = declared[2:]
+    h, w = x.shape[2:]
+    # declared padding counts under count_include_pad, overflow never
+    count = (lo_h + h + dhi_h) * (lo_w + w + dhi_w) \
+        if count_include_pad else h * w
+    xs = np.asarray(x, np.float64)
+    want_y = xs.sum((2, 3), keepdims=True) / count
+    # a cotangent the input's dtype holds exactly: one rounding to judge
+    gy = np.asarray(jnp.asarray(_rng(1).randn(*want_y.shape), x.dtype),
+                    np.float64)
+    want_dx = np.broadcast_to(gy / count, xs.shape)
+    y_rw, vjp_rw = jax.vjp(
+        lambda a: jax.lax.reduce_window(a, 0.0, jax.lax.add, dims, strides,
+                                        pads) / count,
+        x.astype(jnp.float32))
+    dx_rw = vjp_rw(jnp.asarray(gy, jnp.float32))[0]
+    for mode in ("xla", "pallas", "auto"):
+        monkeypatch.setenv("BIGDL_KERNELS", mode)
+        dispatch.clear_decisions()
+        y, vjp = jax.vjp(jax.jit(
+            lambda a: avg_pool(a, dims, strides, pads, declared,
+                               count_include_pad, True)), x)
+        dx = vjp(jnp.asarray(gy, y.dtype))[0]
+        assert y.shape[2:] == (1, 1) and y.dtype == x.dtype
+        assert dx.shape == x.shape and dx.dtype == x.dtype
+        for got, want in ((y, want_y), (y, y_rw), (dx, want_dx),
+                          (dx, dx_rw)):
+            np.testing.assert_allclose(np.asarray(got, np.float64),
+                                       np.asarray(want, np.float64),
+                                       rtol=rtol, atol=atol)
+        assert set(dispatch.decisions()) == WHOLE_PLANE, mode
+        assert not _launches("pool_avg")
+
+
+@pytest.mark.parametrize("shape,k,p,declared", BLOCKED_AVG_CASES)
+@pytest.mark.parametrize("count_include_pad", [True, False])
+def test_blocked_avg_pool_parity(shape, k, p, declared, count_include_pad,
+                                 monkeypatch):
+    """The whole-plane rows: the one form, held to an independent mean
+    in every mode.  The sliding row: many planes a grid step, the last
+    block ragged, value and VJP of the blocked kernels against the XLA
+    leg, with and without the padding in the divisor."""
+    x = jnp.asarray(_rng(30).randn(*shape).astype(np.float32))
+    dims, strides, pads = _full(k, (1, 1), p)
+    declared = ((0, 0), (0, 0)) + declared
+    if k != (3, 3):
+        _check_whole_plane(x, dims, strides, pads, declared,
+                           count_include_pad, monkeypatch)
+        return
+    _both_legs(lambda a: avg_pool(a, dims, strides, pads, declared,
+                                  count_include_pad, True), x,
+               monkeypatch=monkeypatch)
+    _assert_ragged_blocks(_launches("pool_avg"))
+
+
 def test_blocked_avg_pool_parity_bf16(monkeypatch):
     """The benchmark's dtype: the whole-plane form accumulates in
-    float32, the XLA leg however it likes; both round to bfloat16."""
+    float32 and rounds once, to bfloat16, forward and backward."""
     x = jnp.asarray(_rng(31).randn(2, 150, 7, 7), jnp.bfloat16)
     dims, strides, pads = _full((7, 7), (1, 1), ((0, 0), (0, 0)))
-    _both_legs(lambda a: avg_pool(a, dims, strides, pads, pads, True, True),
-               x, rtol=2e-2, atol=2e-2, monkeypatch=monkeypatch)
-    assert all(l["planes_per_block"] == 256 and l["grid"] == (2,)
-               for l in _launches("pool_avg").values())
+    _check_whole_plane(x, dims, strides, pads, pads, True, monkeypatch,
+                       rtol=2 ** -8, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape,dims,strides,pads", [
+    # the CIFAR ResNet's 8x8 head (models/resnet.py)
+    ((4, 64, 8, 8), (1, 1, 8, 8), (1, 1, 1, 1), ((0, 0),) * 4),
+    # declared padding inside the one window
+    ((3, 100, 5, 5), (1, 1, 7, 7), (1, 1, 1, 1),
+     ((0, 0), (0, 0), (1, 1), (1, 1))),
+    # a channels-last head, strided by its own size
+    ((2, 7, 7, 96), (1, 7, 7, 1), (1, 7, 7, 1), ((0, 0),) * 4),
+], ids=["cifar_head", "padded", "nhwc"])
+def test_whole_plane_window_takes_the_form_everywhere(shape, dims, strides,
+                                                      pads, monkeypatch):
+    """The leg follows the shape alone: on the CPU, as a TPU decides
+    and inside a partitioned step (the four-chip cell), a window that
+    is the whole padded plane is the one reduction, said so."""
+    from jax.sharding import Mesh
+    from bigdl_tpu.ops import attention
+
+    x = _rng(34).randn(*shape).astype(np.float32)
+    axes = tuple(a for a, k in enumerate(dims) if k > 1)
+    count = np.prod([dims[a] for a in axes])
+    want = x.sum(axes, keepdims=True) / count
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    monkeypatch.delenv("BIGDL_KERNELS", raising=False)
+    for tpu in (False, True):
+        monkeypatch.setattr(attention, "is_tpu_device", lambda: tpu)
+        for over in (None, mesh):
+            dispatch.clear_decisions()
+
+            def fn(a):
+                with dispatch.spmd_partitioned(over):
+                    return avg_pool(a, dims, strides, pads, pads, True,
+                                    True)
+
+            y, vjp = jax.vjp(jax.jit(fn), jnp.asarray(x))
+            dx = vjp(jnp.ones_like(y))[0]
+            np.testing.assert_allclose(np.asarray(y), want, rtol=1e-5,
+                                       atol=1e-6)
+            np.testing.assert_allclose(np.asarray(dx),
+                                       np.full(shape, 1.0 / count),
+                                       rtol=1e-6)
+            assert set(dispatch.decisions()) == WHOLE_PLANE
 
 
 @pytest.mark.parametrize("name,shape,fn,planes,grid", [
@@ -336,7 +428,7 @@ def test_planes_per_block_reads_the_tiled_footprint():
     from bigdl_tpu.ops.pallas_util import VMEM_BUDGET, planes_per_block
 
     bf16, f32 = jnp.bfloat16, jnp.float32
-    # the head pool: a 7x7 (or 13x13) bf16 plane is one (16, 128) tile
+    # a small plane: 7x7 (or 13x13) in bf16 is one (16, 128) tile
     assert planes_per_block([((7, 7), bf16), ((1, 1), bf16)], 262144) == 256
     assert planes_per_block([((13, 13), bf16), ((7, 7), bf16)],
                             262144) == 256
@@ -523,8 +615,8 @@ def test_plane_launch_rides_on_the_dispatch_instant(tmp_path, monkeypatch):
     from bigdl_tpu import telemetry
     from bigdl_tpu.telemetry import schema
 
-    dims, strides, pads = _full((7, 7), (1, 1), ((0, 0), (0, 0)))
-    x = jnp.asarray(_rng(17).randn(2, 150, 7, 7).astype(np.float32))
+    dims, strides, pads = _full((3, 3), (1, 1), ((1, 1), (1, 1)))
+    x = jnp.asarray(_rng(17).randn(2, 150, 6, 6).astype(np.float32))
     telemetry.start_run(str(tmp_path))
     try:
         for mode in ("pallas", "xla"):
