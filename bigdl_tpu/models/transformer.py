@@ -25,10 +25,14 @@ convolution; or a Mamba-2 state-space mixer), and a dense gated or a
 routed sparse feed-forward per layer (softmax scores, or sigmoid scores
 with a bias that chooses; experts on the model's width or in a latent),
 either of which a layer may lack, every block under ``nn.Remat``, the
-head its own matrix or the embedding's.  It trains with the same
-criterion; the benchmark's ``laguna_s_2_1``, ``qwen3_next_80b_a3b``,
-``lfm2_24b_a2b`` and ``nemotron_3_super_120b_a12b`` configurations are
-such plans at published widths.
+head its own matrix or the embedding's.  Four scalar multipliers a model
+may publish are the plan's too (on the embedding's output, on the
+attention scores, on what each part adds to the residual stream, and a
+divisor of the logits), each doing nothing at its default.  It trains
+with the same criterion; the
+benchmark's ``laguna_s_2_1``, ``qwen3_next_80b_a3b``, ``lfm2_24b_a2b``,
+``nemotron_3_super_120b_a12b`` and ``granite_4_0_h_micro``
+configurations are such plans at published widths.
 """
 
 from __future__ import annotations
@@ -155,7 +159,17 @@ class DecoderPlan(NamedTuple):
     :class:`nn.Mamba2Mixer` of heads of ``ssm_head_dim``, ``ssm_groups``
     groups, a state of ``ssm_state`` and a convolution of ``ssm_conv``
     taps.  ``tie_embeddings``: the head projects with the embedding's own
-    matrix, one parameter read in two places."""
+    matrix, one parameter read in two places.
+
+    Four scalars, each of which multiplies nothing at its default:
+    ``embedding_scale`` the embedding's output is multiplied by;
+    ``attention_scale`` every attention layer's scores are multiplied by
+    before their softmax (None: ``1 / sqrt(head_dim)``);
+    ``residual_scale`` what a block's mixer and its feed-forward each add
+    to the residual stream is multiplied by; ``logit_scale`` the head's
+    logits are DIVIDED by before the log-softmax.  With
+    ``tie_embeddings`` the one matrix's gradient is the lookup's, scaled
+    by the first, plus the head's, scaled by one over the last."""
     vocab_size: int
     hidden_size: int
     head_dim: int
@@ -191,6 +205,10 @@ class DecoderPlan(NamedTuple):
     ssm_conv: int = 4
     expert_latent: Optional[int] = None
     expert_activation: str = "silu"
+    embedding_scale: float = 1.0
+    attention_scale: Optional[float] = None
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
 
 class VocabHead(Module):
@@ -199,11 +217,14 @@ class VocabHead(Module):
     the model's :class:`nn.LookupTable`, whose ``[vocab, embed]`` matrix
     is then the projection too (borrowed, not owned: the model has ONE
     such parameter, and its gradient is the embedding's plus the
-    head's)."""
+    head's).  ``logit_scale``: the logits are divided by it before the
+    log-softmax (a model's ``logits_scaling``); at 1 nothing is."""
 
     def __init__(self, embed_dim: int, vocab_size: int,
-                 tied_to: Optional[nn.LookupTable] = None):
+                 tied_to: Optional[nn.LookupTable] = None,
+                 logit_scale: float = 1.0):
         super().__init__()
+        self.logit_scale = logit_scale
         if tied_to is None:
             self.proj = nn.Linear(embed_dim, vocab_size, with_bias=False)
         else:
@@ -220,7 +241,10 @@ class VocabHead(Module):
                 input, self.embedding.weight.T.astype(input.dtype))
         else:
             logits = self.proj.forward(input)
-        return jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        logits = logits.astype(jnp.float32)
+        if self.logit_scale != 1.0:
+            logits = logits / self.logit_scale
+        return jax.nn.log_softmax(logits, axis=-1)
 
 
 def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
@@ -249,6 +273,8 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
     embedding = nn.LookupTable(plan.vocab_size, plan.hidden_size,
                                sparse=False if plan.tie_embeddings else None)
     model = nn.Sequential(embedding)
+    if plan.embedding_scale != 1.0:
+        model.add(nn.MulConstant(plan.embedding_scale))
     for layer in plan.layers:
         windowed = layer.attention == "window"
         if layer.attention == "none":
@@ -271,7 +297,8 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
                 window=plan.window if windowed else None,
                 rotary=plan.rotary_window if windowed else plan.rotary_full,
                 gate=plan.gate, backend=backend,
-                qk_norm=head_norm if plan.qk_norm else None)
+                qk_norm=head_norm if plan.qk_norm else None,
+                scale=plan.attention_scale)
         if layer.ffn == "none":
             ffn = None
         elif layer.ffn == "dense":
@@ -286,11 +313,13 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
                 activation=plan.expert_activation,
                 latent=plan.expert_latent)
         block = nn.DecoderBlock(plan.hidden_size, attn, ffn, eps=plan.eps,
-                                zero_centred=zero)
+                                zero_centred=zero,
+                                residual_scale=plan.residual_scale)
         model.add(nn.Remat(block) if remat else block)
     model.add(nn.RMSNorm(plan.hidden_size, plan.eps, zero_centred=zero))
     model.add(VocabHead(plan.hidden_size, plan.vocab_size,
-                        tied_to=embedding if plan.tie_embeddings else None))
+                        tied_to=embedding if plan.tie_embeddings else None,
+                        logit_scale=plan.logit_scale))
     return model
 
 

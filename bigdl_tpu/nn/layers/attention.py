@@ -298,6 +298,9 @@ class GroupedQueryAttention(Module):
     ``qk_norm``: a callable ``head_dim -> Module`` that makes the norm
     of one head's query and, called again, of one head's key (an
     ``RMSNorm``); both are applied before the rotation.
+    ``scale``: what a score ``q . k`` is multiplied by before the softmax;
+    None is ``1 / sqrt(head_dim)``, a number is the model's own (an
+    ``attention_multiplier``), handed to whichever leg runs.
 
     ``backend``: ``auto`` (the rule of ``ops.attention.
     select_attention_backend``: the Pallas flash kernels on a TPU from
@@ -305,15 +308,16 @@ class GroupedQueryAttention(Module):
     by the window and find kv heads by index; XLA's dense attention
     elsewhere), ``dense`` or ``flash``.  The decision is announced on a
     ``kernel/dispatch`` instant with ``window``, ``q_heads``,
-    ``kv_heads`` and, for the flash leg, ``blocks_visited`` of
-    ``blocks_total``.  No KV cache: this layer trains and scores; the
-    generation path still runs ``MultiHeadAttention``."""
+    ``kv_heads``, ``scale`` (null: the default) and, for the flash leg,
+    ``blocks_visited`` of ``blocks_total``.  No KV cache: this layer
+    trains and scores; the generation path still runs
+    ``MultiHeadAttention``."""
 
     def __init__(self, embed_dim: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, window: Optional[int] = None,
                  rotary: Optional[Rotary] = None,
                  gate: Optional[str] = None, backend: str = "auto",
-                 qk_norm=None):
+                 qk_norm=None, scale: Optional[float] = None):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads over "
@@ -323,7 +327,7 @@ class GroupedQueryAttention(Module):
         self.embed_dim, self.head_dim = embed_dim, head_dim
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.window, self.rotary, self.gate = window, rotary, gate
-        self.backend = backend
+        self.backend, self.scale = backend, scale
         q_width = 2 * head_dim if gate == "per_channel" else head_dim
         self.q_proj = Linear(embed_dim, num_heads * q_width, with_bias=False)
         self.k_proj = Linear(embed_dim, num_kv_heads * head_dim,
@@ -350,7 +354,7 @@ class GroupedQueryAttention(Module):
             backend, reason = select_attention_backend(s, s)
         facts = dict(window=self.window, q_heads=self.num_heads,
                      kv_heads=self.num_kv_heads, head_dim=self.head_dim,
-                     gate=self.gate, qk_norm=self.qk_norm)
+                     gate=self.gate, qk_norm=self.qk_norm, scale=self.scale)
         if backend == "flash":
             bq, bk, visited, total = flash_blocks(s, s, True, self.window)
             facts.update(block_q=bq, block_k=bk, blocks_visited=visited,
@@ -359,7 +363,8 @@ class GroupedQueryAttention(Module):
              **facts)
         attend = flash_attention if backend == "flash" \
             else dot_product_attention
-        return attend(q, k, v, causal=True, window=self.window)
+        return attend(q, k, v, causal=True, window=self.window,
+                      scale=self.scale)
 
     def update_output(self, input):
         b, s, _ = input.shape
@@ -406,17 +411,21 @@ class DecoderBlock(Module):
     (``RMSNorm``).  Either part may be ``None``: the block is then the
     ONE sub-layer it has, ``y = x + attn(norm1(x))`` or ``y = x +
     ffn(norm2(x))``, one norm and one residual add (the layers of a
-    decoder whose every layer is a mixer or a feed-forward alone)."""
+    decoder whose every layer is a mixer or a feed-forward alone).
+    ``residual_scale`` multiplies what each part adds, ``h = x +
+    residual_scale * attn(norm1(x))`` and the same for ``ffn`` (a model's
+    ``residual_multiplier``); at 1 nothing is multiplied."""
 
     def __init__(self, embed_dim: int, attn: Optional[Module],
                  ffn: Optional[Module], eps: float = 1e-6,
-                 zero_centred: bool = False):
+                 zero_centred: bool = False, residual_scale: float = 1.0):
         super().__init__()
         from bigdl_tpu.nn.layers.normalization import RMSNorm
 
         if attn is None and ffn is None:
             raise ValueError("a decoder block with neither a mixer nor a "
                              "feed-forward")
+        self.residual_scale = residual_scale
         if attn is not None:
             self.norm1 = RMSNorm(embed_dim, eps, zero_centred)
             self.attn = attn
@@ -427,10 +436,14 @@ class DecoderBlock(Module):
                                                    ("ffn", ffn))
                            if part is not None)
 
+    def _scaled(self, part):
+        return part if self.residual_scale == 1.0 \
+            else part * self.residual_scale
+
     def update_output(self, input):
         h = input
         if "attn" in self.parts:
-            h = h + self.attn.forward(self.norm1.forward(h))
+            h = h + self._scaled(self.attn.forward(self.norm1.forward(h)))
         if "ffn" in self.parts:
-            h = h + self.ffn.forward(self.norm2.forward(h))
+            h = h + self._scaled(self.ffn.forward(self.norm2.forward(h)))
         return h

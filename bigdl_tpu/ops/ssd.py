@@ -64,8 +64,10 @@ __all__ = ["ssd", "ssd_recurrent", "SCOPE", "CHUNK"]
 #: the ``jax.named_scope`` around the chunked scan
 SCOPE = "ssd"
 
-#: tokens a chunk: the size the layers run the scan at.  ``chunk=`` of
-#: :func:`ssd` is for the scan's own tests
+#: tokens a chunk: the size the layers run the scan at, and the one place
+#: it is set.  The chunk changes no answer, so neither a layer nor a plan
+#: names one (a ``mamba_chunk_size`` a model publishes is how ITS kernels
+#: tile the scan); ``chunk=`` of :func:`ssd` is for the scan's own tests
 CHUNK = 128
 
 

@@ -132,9 +132,10 @@ STREAM_NAMES = frozenset({
     # a leg adds how it was launched (plane kernels: planes_per_block,
     # grid; op=gated_delta_rule: leg, chunk, chunks, heads, key_dim,
     # value_dim and, on its Pallas leg, chunks_per_block, grid;
-    # op=attention: window, q_heads, kv_heads, head_dim and the flash
-    # leg's blocks; op=gated_short_conv: taps, channels, tokens; op=ssd:
-    # chunk, chunks, heads, head_dim, state, groups)
+    # op=attention: window, q_heads, kv_heads, head_dim, scale (null: 1
+    # over the root of head_dim) and the flash leg's blocks;
+    # op=gated_short_conv: taps, channels, tokens; op=ssd: chunk, chunks,
+    # heads, head_dim, state, groups)
     "kernel/dispatch",
     # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
     # instant per TRACE of a layer (experts, held, top_k, capacity

@@ -399,3 +399,76 @@ def test_routed_layer_combines_by_its_shapes(case, one_chip, monkeypatch):
     sums = [ln for ln in held if re.search(
         rf"= \w+\[{tokens},{d}\]\S* fusion\(.*bf16\[{rows},{d}\]", ln)]
     assert len(gathers) == 2 and len(sums) == 2
+
+
+def test_whole_mamba2_block_lowers_for_the_chip(one_chip, monkeypatch):
+    """A ``mamba`` layer of the ``granite_4_0_h_micro`` plan at its
+    published widths (hidden 2048; 64 heads of 64 on ONE group, state 128;
+    a gated MLP of 8192; what each part adds times 0.22), forward and
+    backward in bfloat16 at 8,192 positions, lowered for the chip and read
+    as it is handed to the compiler (PR 37): the scan said its own chunk
+    (``ops.ssd.CHUNK``; the published ``mamba_chunk_size`` is read by
+    nothing) and its one group, the chunked shapes the configuration's
+    patterns are written on are the program's (64 chunks of 128: the
+    float32 decay exponent ``[1,1,64,64,128,128]``, ``C B^T`` once for all
+    64 heads, the carried ``[1,1,64,64,128]`` state), and the residual
+    multiplier is in the text."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.nn import init
+    from bigdl_tpu.nn.module import functional_call, state_dict
+    from bigdl_tpu.ops import dispatch
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "granite_4_0_h_micro.json")) as fh:
+        conf = json.load(fh)
+    d, heads, p = conf["hidden_size"], conf["mamba_n_heads"], \
+        conf["mamba_d_head"]
+    groups, state = conf["mamba_n_groups"], conf["mamba_d_state"]
+    chunk, seq = conf["ssd_kernel_args"]["chunk"], conf["sequence_length"]
+    assert (d, heads, p, groups, state, chunk, seq) == (
+        2048, 64, 64, 1, 128, 128, 8192)
+    # the weights' values do not reach a lowering: zeros, not 300 MB of draws
+    monkeypatch.setattr(init.RandomUniform, "init",
+                        lambda self, shape, **_: np.zeros(shape, np.float32))
+    block = nn.DecoderBlock(
+        d, nn.Mamba2Mixer(d, heads, p, groups, state,
+                          taps=conf["mamba_d_conv"],
+                          eps=conf["rms_norm_eps"]),
+        nn.GatedMLP(d, conf["shared_intermediate_size"]),
+        eps=conf["rms_norm_eps"],
+        residual_scale=conf["residual_multiplier"])
+    buffers = state_dict(block, kind="buffer")
+
+    def loss(params, x):
+        y, _ = functional_call(block, {**params, **buffers}, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def shaped(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    params = {k: shaped(v.shape)
+              for k, v in state_dict(block, kind="param").items()}
+    assert params["attn.in_proj.weight"].shape == (8512, 2048)
+    assert params["attn.conv_weight"].shape == (4352, 4)
+    dispatch.clear_decisions()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, shaped((1, seq, d))).as_text(dialect="hlo")
+    (said,) = [s for s in dispatch.decisions() if s[0] == "ssd"]
+    assert said.launch == dict(chunk=chunk, chunks=64, heads=64, head_dim=64,
+                               state=128, groups=1)
+    for shape in ("f32[1,1,64,64,128,128]", "f32[1,1,64,128,128]",
+                  "f32[1,1,64,64,128]", "f32[64,1,1,64,64,128]",
+                  "bf16[1,8192,8512]", "bf16[1,8192,4352]"):
+        assert shape in text, shape
+    assert "f32[1,1,64,32,256,256]" not in text      # no chunk of 256
+    # 0.22 as bfloat16 holds it: the multiply is in the compute type
+    assert re.search(r"bf16\[\] constant\(0\.2197\)", text)
+    scan_match = conf["ssd_scan_match"]
+    for line in ("%f = f32[64,64,128]{2,1,0} fusion(f32[1,1,64,64,128]{4,3,2,"
+                 "1,0} %a), kind=kLoop",
+                 "%w = (s32[], f32[1,1,64,64,128]{4,3,2,1,0}, "
+                 "f32[64,1,1,64,64,128]{5,4,3,2,1,0}) while((s32[]) %t)"):
+        assert re.search(scan_match, line)
+    # a projection's product is not the scan's, whatever its other operand
+    assert not re.search(scan_match, "%p = bf16[1,8192,8512]{2,1,0} fusion("
+                         "bf16[8512,2048]{1,0} %w, bf16[8192,2048]{1,0} %x)")

@@ -48,16 +48,27 @@ def _scan_inputs(seed, dt_shift, b=1, s=40, h=4, p=8, g=2, n=8):
             f(b, s, g, n), 1.0 + 0.1 * f(h))
 
 
-SCANS = {"two-chunks-decays-near-one": (20, -4.0),
-         "three-chunks-padded-decays-near-zero": (16, 4.0),
-         "three-chunks-padded-decays-spread": (16, 0.0)}
+#: chunk, shift of ``dt``, and the shapes where they are not
+#: ``_scan_inputs``'s own; the last two are a whole mixer, 64 heads on ONE
+#: group, at the chunk the layers run (``CHUNK``: 300 tokens are three
+#: chunks, the last padded) and at the 256 a model publishes for its own
+#: kernels (two chunks); decays near 1, so a later chunk's output is
+#: mostly the state the earlier ones left
+SCANS = {"two-chunks-decays-near-one": (20, -4.0, {}),
+         "three-chunks-padded-decays-near-zero": (16, 4.0, {}),
+         "three-chunks-padded-decays-spread": (16, 0.0, {}),
+         "chunk-128-64-heads-one-group": (scan.CHUNK, -4.0, dict(
+             s=300, h=64, p=4, g=1, n=8)),
+         "chunk-256-64-heads-one-group": (256, -4.0, dict(
+             s=300, h=64, p=4, g=1, n=8))}
 
 
-@pytest.mark.parametrize("chunk,dt_shift", SCANS.values(), ids=SCANS)
-def test_chunked_scan_is_the_token_by_token_recurrence(chunk, dt_shift):
+@pytest.mark.parametrize("chunk,dt_shift,shape", SCANS.values(), ids=SCANS)
+def test_chunked_scan_is_the_token_by_token_recurrence(chunk, dt_shift,
+                                                       shape):
     """Values, the state after the last token, and the gradient by every
     input, at a length the chunk divides and at one it does not."""
-    args = _scan_inputs(1, dt_shift)
+    args = _scan_inputs(1, dt_shift, **shape)
     weigh = jnp.asarray(np.random.default_rng(2).standard_normal(
         args[0].shape), jnp.float32)
 
