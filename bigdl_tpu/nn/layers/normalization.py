@@ -186,9 +186,9 @@ class SpatialCrossMapLRN(Module):
         self.format = format
 
     def update_output(self, input):
-        # fused kernel-library path (ops/lrn_pallas.py): Pallas or the
-        # XLA banded-conv reference per BIGDL_KERNELS, exact custom VJP
-        # on either leg; NHWC runs the reference natively in its layout
+        # ops/lrn_pallas.py: the channel window as a banded C x C
+        # product in the input's own layout (NCHW or NHWC), exact custom
+        # VJP; one leg on every platform, no BIGDL_KERNELS choice
         from bigdl_tpu.ops.lrn_pallas import cross_map_lrn
 
         squeeze = input.ndim == 3

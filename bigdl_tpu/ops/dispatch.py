@@ -1,11 +1,11 @@
 """Kernel dispatch: one knob, per-op fallback, observable decisions.
 
-The ops library keeps TWO implementations of every fused op — a Pallas
-kernel (Mosaic-compiled on TPU, ``interpret=True`` elsewhere so the CPU
-tier-1 suite exercises the identical code path) and an XLA reference
-built from the same math.  Both sit UNDER the op's ``jax.custom_vjp``,
-so the analytically exact backward holds on either leg; this module
-decides which leg runs.
+The ops library keeps TWO implementations of a fused op that has a
+kernel — the Pallas kernel (Mosaic-compiled on TPU, ``interpret=True``
+elsewhere so the CPU tier-1 suite exercises the identical code path)
+and an XLA reference built from the same math.  Both sit UNDER the
+op's ``jax.custom_vjp``, so the analytically exact backward holds on
+either leg; this module decides which leg runs.
 
 Knob: ``BIGDL_KERNELS`` (read at trace time):
 
@@ -25,9 +25,11 @@ HLO actually contains.  The reasons: ``forced:BIGDL_KERNELS=<mode>``,
 ``unsupported-shape``, ``auto:tpu``, ``auto:off-tpu``,
 ``auto:spmd-partitioned`` from :func:`choose_backend`; from an op that
 has one form and says so through :func:`note`, ``only-leg`` (the
-state-space scan) and ``whole-plane`` (an average pool whose window is
-the whole padded plane: a fused reduction in plain ``jnp`` in every
-mode, ``pool_pallas.avg_pool``).  A leg that launches through
+state-space scan, the short convolution, the cross-map LRN's banded
+product: the knob does not reach them in any mode) and ``whole-plane``
+(an average pool whose window is the whole padded plane: a fused
+reduction in plain ``jnp`` in every mode, ``pool_pallas.avg_pool``).
+A leg that launches through
 ``pallas_util.plane_call`` adds how it was launched
 (``planes_per_block``, ``grid``: :func:`launched`).  A small in-process
 ring (:func:`decisions`) records the same for tests and the micro-bench

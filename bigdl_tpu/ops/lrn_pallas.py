@@ -1,20 +1,12 @@
-"""Fused LRN kernels (cross-map + within-channel) with exact VJPs.
+"""The two LRN ops (cross-map + within-channel) with exact VJPs.
 
-Round-5 motivation (BASELINE.md, round 5): inception sat at 0.25 MFU
-and its LRN layers lower to multi-op HLO chains — square, window-sum,
-scale, power, multiply — that XLA leaves as separate HBM-bound fusions
-(the channel window additionally fights TPU tiling: C is non-minor in
-NCHW activations).  Each op here is ONE Pallas pass per block: read x,
-square, unrolled shift-accumulate window sum, powf epilogue, write
-(y, denom) — and the backward is the hand-derived exact cotangent in a
-second fused pass, replacing an autodiff chain that re-materialized
-every intermediate.
-
-Math (both ops share the shape ``y = x * s^-beta``):
+Both ops share the shape ``y = x * s^-beta`` and carry a hand-derived
+exact cotangent under ``jax.custom_vjp`` in place of an autodiff chain
+that re-materialized every intermediate:
 
 - cross-map (``nn/SpatialCrossMapLRN.scala``):
   ``s_i = k + (a/n) * sum_{j in band(i)} x_j^2`` over a channel band of
-  ``n = size`` (odd) channels;
+  ``n = size`` channels;
   ``dx = g*s^-b - (2ab/n) * x * band^T(g*x*s^(-b-1))`` — for odd bands
   the transpose band IS the band.
 - within-channel (``nn/SpatialWithinChannelLRN.scala``):
@@ -24,12 +16,34 @@ Math (both ops share the shape ``y = x * s^-beta``):
   transpose window uses the swapped pads ``(hi, lo)`` (exact also for
   even windows).
 
-Both are registered as ``jax.custom_vjp`` with the backend (Pallas vs
-an XLA reference built from the same formulas) chosen per leg by
-``ops.dispatch`` — the VJP is exact on either leg, so the numeric-grad
-suite holds no matter how the knob is set.  Off-TPU the Pallas leg runs
-``interpret=True`` (same code path, pure jax ops — this is what the
-parity tests pin).
+**Cross-map has ONE leg**: the channel window as a banded ``C x C`` 1x1
+product on the MXU (``_band_apply``), in XLA's own layout for NCHW and
+NHWC alike, on every platform, on and off a mesh, whatever
+``BIGDL_KERNELS`` says; announced as ``kernel/dispatch
+op=lrn_cross_map.fwd|.bwd backend=xla reason=only-leg`` once a
+compilation.  Until PR 44 a Pallas kernel (square, unrolled
+shift-accumulate band sum, powf epilogue, one pass a ``[C + halo, HW
+tile]`` block) was the TPU's leg off a mesh.  It read 47% of its
+roofline and still lost, through its interface: every operand padded to
+``[N, C + 4, 3200]`` (one such copy forward, three backward), results
+sliced and reshaped back, and XLA made to leave the
+batch-and-channels-minor layout it keeps ``[256,192,56,56]`` in between
+two convolutions.  Inception-v1 at batch 256 on one TPU v5e, the same
+tree and machine, Pallas legs | ``BIGDL_KERNELS=xla`` (PERF.md section
+6, PR 41): device step 69.898 | 47.072 ms, 3,338.4 | 4,766.3 records/s,
+peak memory 7.94 | 5.75 GB; the head pool took 12.3 ms of that gap in
+PR 41 and this op the rest (PERF.md section 6, PR 44, kernel | banded
+product: 57.643 | 47.086 ms, 4,025 | 4,820 records/s; beside the four
+calls' 6.35 ms a step the kernel's six pads cost 3.44 and eight layout
+copies 4.96).  The four-chip cell, NHWC models and the CPU always ran
+the banded product.
+
+Within-channel keeps two legs under its ``custom_vjp``, a per-plane
+Pallas kernel (``pallas_util.plane_call``; ``interpret=True`` off the
+TPU, which is what the parity tests pin) and an XLA ``reduce_window``
+reference built from the same formulas, chosen per leg by
+``ops.dispatch``: no cell runs it and nothing has timed it (ROADMAP
+D3).
 """
 
 from __future__ import annotations
@@ -47,8 +61,8 @@ from bigdl_tpu.ops.pallas_util import (TPU_DTYPES as _TPU_DTYPES,
                                        VMEM_BUDGET as _VMEM_BUDGET,
                                        plane_call as _plane_call)
 
-__all__ = ["cross_map_lrn", "cross_map_lrn_supported",
-           "within_channel_lrn", "within_channel_lrn_supported"]
+__all__ = ["cross_map_lrn", "within_channel_lrn",
+           "within_channel_lrn_supported"]
 
 
 def _pow(s, p: float):
@@ -64,130 +78,8 @@ def _on_tpu_compiled() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# cross-map LRN: banded channel-window sum, layout [N, Cpad, HW-tile]
+# cross-map LRN: the channel window as a banded C x C product, one leg
 # ---------------------------------------------------------------------------
-
-def cross_map_lrn_supported(x, size: int, layout: str = "NCHW") -> bool:
-    """Structural gate for the Pallas leg: 4-D NCHW, odd band.  NHWC
-    stays on the XLA leg, which runs the banded conv NATIVELY in that
-    layout — repacking for the kernel would cost the exact full-tensor
-    relayout class this library exists to remove.  On real TPU
-    additionally require a Mosaic dtype and the block to fit VMEM."""
-    if x.ndim != 4 or size % 2 != 1 or size < 1 or layout != "NCHW":
-        return False
-    if _on_tpu_compiled():
-        if x.dtype not in _TPU_DTYPES:
-            return False
-        n, c, h, w = x.shape
-        f_pad = -(-(h * w) // 128) * 128
-        t = _pick_tile(f_pad, c + size - 1, jnp.dtype(x.dtype).itemsize)
-        if t is None:
-            return False
-    return True
-
-
-def _pick_tile(f_pad: int, cp: int, esz: int):
-    """Largest HW-tile whose fwd/bwd block stack fits the VMEM budget.
-    Mosaic wants a block's last dimension to be a multiple of 128 (or
-    the whole extent), so the candidates are the multiples of 128 that
-    divide ``f_pad`` (itself a multiple of 128); None when even one
-    128-lane tile does not fit."""
-    lanes = f_pad // 128
-    for k in range(lanes, 0, -1):
-        # ~5 live [Cp, T] planes: x, sq, running band sum, den, y
-        if lanes % k == 0 and 5 * cp * 128 * k * esz <= _VMEM_BUDGET:
-            return 128 * k
-    return None
-
-
-def _cml_fwd_kernel(xp_ref, y_ref, den_ref, *, c: int, size: int,
-                    half: int, alpha: float, beta: float, k: float):
-    xp = xp_ref[0]                      # [Cp, T]
-    sq = xp * xp
-    s = sq[0:c]
-    for d in range(1, size):
-        s = s + sq[d:d + c]
-    den = k + s * (alpha / size)
-    den_ref[0] = den
-    y_ref[0] = xp[half:half + c] * _pow(den, -beta)
-
-
-def _cml_bwd_kernel(xp_ref, gp_ref, denp_ref, dx_ref, *, c: int, size: int,
-                    half: int, alpha: float, beta: float):
-    xp = xp_ref[0]
-    gp = gp_ref[0]
-    denp = denp_ref[0]                  # halo channels carry 1.0
-    t = gp * xp * _pow(denp, -beta - 1.0)
-    ts = t[0:c]
-    for d in range(1, size):            # odd band: transpose == forward
-        ts = ts + t[d:d + c]
-    g = gp[half:half + c]
-    x = xp[half:half + c]
-    den = denp[half:half + c]
-    dx_ref[0] = g * _pow(den, -beta) \
-        - (2.0 * alpha * beta / size) * x * ts
-
-
-def _cml_pack(a, pad_val: float, half: int, f_pad: int):
-    """[N, C, H, W] -> [N, C + 2*half, f_pad] with channel halo."""
-    n, c, h, w = a.shape
-    flat = a.reshape(n, c, h * w)
-    return jnp.pad(flat, ((0, 0), (half, half), (0, f_pad - h * w)),
-                   constant_values=pad_val)
-
-
-def _cml_call(kernel, packed_inputs, out_shapes, n, f_pad, t):
-    from jax.experimental import pallas as pl
-
-    grid = (n, f_pad // t)
-    cp = packed_inputs[0].shape[1]
-    in_specs = [pl.BlockSpec((1, cp, t), lambda b, i: (b, 0, i))
-                for _ in packed_inputs]
-    out_specs = [pl.BlockSpec((1, s[1], t), lambda b, i: (b, 0, i))
-                 for s in out_shapes]
-    outs = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs,
-        out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
-        out_shape=[jax.ShapeDtypeStruct((n, s[1], f_pad), s[2])
-                   for s in out_shapes] if len(out_shapes) > 1
-        else jax.ShapeDtypeStruct(
-            (n, out_shapes[0][1], f_pad), out_shapes[0][2]),
-        interpret=_dispatch.use_interpret(),
-    )(*packed_inputs)
-    return outs
-
-
-def _cml_fwd_pallas(x, size, alpha, beta, k):
-    n, c, h, w = x.shape
-    half = (size - 1) // 2
-    f = h * w
-    f_pad = -(-f // 128) * 128
-    t = _pick_tile(f_pad, c + 2 * half, jnp.dtype(x.dtype).itemsize) \
-        or f_pad
-    xp = _cml_pack(x, 0.0, half, f_pad)
-    kern = functools.partial(_cml_fwd_kernel, c=c, size=size, half=half,
-                             alpha=alpha, beta=beta, k=k)
-    y, den = _cml_call(kern, [xp],
-                       [(n, c, x.dtype), (n, c, x.dtype)], n, f_pad, t)
-    return (y[:, :, :f].reshape(n, c, h, w),
-            den[:, :, :f].reshape(n, c, h, w))
-
-
-def _cml_bwd_pallas(x, den, g, size, alpha, beta):
-    n, c, h, w = x.shape
-    half = (size - 1) // 2
-    f = h * w
-    f_pad = -(-f // 128) * 128
-    t = _pick_tile(f_pad, c + 2 * half, jnp.dtype(x.dtype).itemsize) \
-        or f_pad
-    xp = _cml_pack(x, 0.0, half, f_pad)
-    gp = _cml_pack(g, 0.0, half, f_pad)
-    denp = _cml_pack(den, 1.0, half, f_pad)  # 1.0: powf stays finite
-    kern = functools.partial(_cml_bwd_kernel, c=c, size=size, half=half,
-                             alpha=alpha, beta=beta)
-    dx = _cml_call(kern, [xp, gp, denp], [(n, c, x.dtype)], n, f_pad, t)
-    return dx[:, :, :f].reshape(n, c, h, w)
-
 
 def _band_matrix(c: int, size: int, transpose: bool) -> np.ndarray:
     half = (size - 1) // 2
@@ -202,9 +94,9 @@ def _band_matrix(c: int, size: int, transpose: bool) -> np.ndarray:
 
 
 def _band_apply(v, size: int, transpose: bool, layout: str):
-    """Banded C x C matrix at every pixel as a 1x1 conv — it (and only
-    it) runs the channel window on the MXU, NATIVELY in either layout;
-    the XLA reference leg (see SpatialCrossMapLRN's original profile
+    """Banded C x C matrix at every pixel as a 1x1 conv: the channel
+    window on the MXU, NATIVELY in either layout (operands in ``v``'s
+    dtype, float32 sums; see SpatialCrossMapLRN's original profile
     note: reduce_window over the non-minor channel dim was ~10x
     slower)."""
     c_ax = 3 if layout == "NHWC" else 1
@@ -221,12 +113,23 @@ def _band_apply(v, size: int, transpose: bool, layout: str):
                                     dimension_numbers=dn)
 
 
-def _cml_fwd_xla(x, size, alpha, beta, k, layout="NCHW"):
+def _cml_note(leg: str, x, size: int, layout: str) -> None:
+    """ONE leg on every platform, on and off a mesh, in every
+    ``BIGDL_KERNELS`` mode: announced, not chosen (as ``ops/ssd.py``
+    and the short convolution announce theirs)."""
+    _dispatch.note(f"lrn_cross_map.{leg}", "xla", "only-leg",
+                   channels=x.shape[3 if layout == "NHWC" else 1],
+                   size=size, layout=layout)
+
+
+def _cml_fwd(x, size, alpha, beta, k, layout):
+    _cml_note("fwd", x, size, layout)
     den = k + _band_apply(x * x, size, False, layout) * (alpha / size)
     return x * _pow(den, -beta), den
 
 
-def _cml_bwd_xla(x, den, g, size, alpha, beta, layout="NCHW"):
+def _cml_bwd(x, den, g, size, alpha, beta, layout):
+    _cml_note("bwd", x, size, layout)
     t = g * x * _pow(den, -beta - 1.0)
     return g * _pow(den, -beta) \
         - (2.0 * alpha * beta / size) * x \
@@ -237,20 +140,10 @@ def _cml_bwd_xla(x, den, g, size, alpha, beta, layout="NCHW"):
 def cross_map_lrn(x, size: int, alpha: float, beta: float, k: float,
                   layout: str = "NCHW"):
     """AlexNet-style cross-channel LRN over NCHW/NHWC with exact custom
-    VJP; backend (fused Pallas kernel vs XLA banded-conv reference)
-    chosen by ``ops.dispatch`` — the NHWC reference runs in its native
-    layout (no relayout transposes)."""
+    VJP: the banded product in the input's own layout (no relayout
+    transposes), the one form on every platform."""
     y, _ = _cml_fwd(x, size, alpha, beta, k, layout)
     return y
-
-
-def _cml_fwd(x, size, alpha, beta, k, layout):
-    if layout == "NHWC":  # elementwise VJP math is layout-agnostic
-        return _cml_fwd_xla(x, size, alpha, beta, k, layout)
-    return _dispatch.dispatch(
-        "lrn_cross_map.fwd", _cml_fwd_pallas, _cml_fwd_xla,
-        cross_map_lrn_supported(x, size, layout), x, size, alpha, beta,
-        k)
 
 
 def _cml_vjp_fwd(x, size, alpha, beta, k, layout):
@@ -260,13 +153,7 @@ def _cml_vjp_fwd(x, size, alpha, beta, k, layout):
 
 def _cml_vjp_bwd(size, alpha, beta, k, layout, res, g):
     x, den = res
-    if layout == "NHWC":
-        return (_cml_bwd_xla(x, den, g, size, alpha, beta, layout),)
-    dx = _dispatch.dispatch(
-        "lrn_cross_map.bwd", _cml_bwd_pallas, _cml_bwd_xla,
-        cross_map_lrn_supported(x, size, layout), x, den, g, size,
-        alpha, beta)
-    return (dx,)
+    return (_cml_bwd(x, den, g, size, alpha, beta, layout),)
 
 
 cross_map_lrn.defvjp(_cml_vjp_fwd, _cml_vjp_bwd)

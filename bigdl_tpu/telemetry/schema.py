@@ -135,7 +135,8 @@ STREAM_NAMES = frozenset({
     # op=attention: window, q_heads, kv_heads, head_dim, scale (null: 1
     # over the root of head_dim) and the flash leg's blocks;
     # op=gated_short_conv: taps, channels, tokens; op=ssd: chunk, chunks,
-    # heads, head_dim, state, groups)
+    # heads, head_dim, state, groups; op=lrn_cross_map.fwd|.bwd:
+    # channels, size, layout)
     "kernel/dispatch",
     # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
     # instant per TRACE of a layer (experts, held, top_k, capacity

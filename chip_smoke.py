@@ -14,9 +14,10 @@ benchmark's first cell), with random weights and synthetic data made from
 - **serve**  — what ``cli serve --bf16`` does: ``serving.serve_model``
   with AOT-warmed buckets, then ``POST /v1/predict`` over real HTTP on
   an ephemeral port, ``/status`` read back, a drained stop;
-- **kernels** — ``flash_attention`` and ``cross_map_lrn`` forward and
-  backward on the chip against their XLA legs, and the
-  ``kernel/dispatch`` decisions of the train phase.
+- **kernels** — ``flash_attention`` forward and backward on the chip
+  against its XLA leg, ``cross_map_lrn`` (one leg, the banded product)
+  against its definition in float32, and the ``kernel/dispatch``
+  decisions of the train phase.
 
 ``python chip_smoke.py --chips 4`` runs ONLY the data-parallel path:
 ``optim.DistriOptimizer`` (``cli train --distributed``) over the
@@ -47,8 +48,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 CLASSES = 1000
 IMAGE = (3, 224, 224)
 
-#: |pallas - xla| <= TOL * max|xla|, per dtype: bf16 keeps 8 bits of
-#: mantissa (eps 2^-8) and both legs round intermediates differently;
+#: |got - want| <= TOL * max|want|, per dtype: bf16 keeps 8 bits of
+#: mantissa (eps 2^-8) and two forms round intermediates differently;
 #: f32 differs by the transcendental and MXU pass order only
 KERNEL_TOL = {"bfloat16": 4e-2, "float32": 2e-3}
 
@@ -344,6 +345,7 @@ def kernels_phase(seed: int, platform: str, train_decisions,
     import jax.numpy as jnp
     import numpy as np
 
+    import bigdl_tpu.nn as nn
     from bigdl_tpu.ops import dispatch
     from bigdl_tpu.ops.attention import (dot_product_attention,
                                          flash_attention)
@@ -373,27 +375,28 @@ def kernels_phase(seed: int, platform: str, train_decisions,
                          "dtype": "bfloat16", "max_err": round(err, 5)})
 
         lrn = lambda x: cross_map_lrn(x, 5, 1e-4, 0.75, 1.0)  # noqa: E731
+        only_leg = {("lrn_cross_map.fwd", "xla", "only-leg"),
+                    ("lrn_cross_map.bwd", "xla", "only-leg")}
+
+        def lrn_definition(x):
+            """The layer's rank-5 path on ``[N, C, 1, H, W]``: a
+            ``reduce_window`` sum over the channel window, backward by
+            autodiff; given and returned in float32."""
+            return nn.SpatialCrossMapLRN(5, 1e-4, 0.75, 1.0).update_output(
+                x[:, :, None])[:, :, 0]
+
         for shape in lrn_shapes:
             for dtype in (jnp.bfloat16, jnp.float32):
                 x, g = draw(shape, dtype), draw(shape, dtype)
-                legs = {}
-                for mode in ("xla", "auto"):
-                    # the knob is read at TRACE time: a fresh jit per
-                    # leg, and the ring shows which leg each one took
-                    os.environ["BIGDL_KERNELS"] = mode
-                    dispatch.clear_decisions()
-                    try:
-                        legs[mode] = _value_and_vjp(lrn)(x, g)
-                    finally:
-                        del os.environ["BIGDL_KERNELS"]
-                    took = {(op, b) for op, b, _ in dispatch.decisions()}
-                    want_b = "xla" if mode == "xla" else "pallas"
-                    check(took == {("lrn_cross_map.fwd", want_b),
-                                   ("lrn_cross_map.bwd", want_b)},
-                          f"cross_map_lrn {mode} leg took {took}")
-                on_platform(legs["auto"], platform, "cross_map_lrn")
+                dispatch.clear_decisions()
+                got = _value_and_vjp(lrn)(x, g)
+                took = set(dispatch.decisions())
+                check(took == only_leg, f"cross_map_lrn took {took}")
+                on_platform(got, platform, "cross_map_lrn")
+                want = _value_and_vjp(lrn_definition)(
+                    x.astype(jnp.float32), g.astype(jnp.float32))
                 name = jnp.dtype(dtype).name
-                err = _max_err(legs["auto"], legs["xla"])
+                err = _max_err(got, want)
                 check(err <= KERNEL_TOL[name],
                       f"cross_map_lrn {shape} {name}: {err}")
                 rows.append({"op": "cross_map_lrn", "shape": list(shape),
@@ -404,10 +407,9 @@ def kernels_phase(seed: int, platform: str, train_decisions,
         reasons = {r for _, _, r in train_decisions}
         check("auto:off-tpu" not in reasons,
               f"the train step dispatched off the TPU: {train_decisions}")
-        for site in ("lrn_cross_map.fwd", "lrn_cross_map.bwd"):
-            took = {b for op, b, _ in train_decisions if op == site}
-            check(took == {"pallas"},
-                  f"{site} took {took or 'no decision'} in the train step")
+        took = {d for d in train_decisions
+                if d[0].startswith("lrn_cross_map")}
+        check(took == only_leg, f"the train step's LRN sites took {took}")
         out.update(tolerance=KERNEL_TOL, parity=rows,
                    train_dispatch=[list(d) for d in train_decisions],
                    default_device=str(jax.devices()[0]))

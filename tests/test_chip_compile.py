@@ -25,7 +25,8 @@ import pytest
 from bigdl_tpu.ops import attention, dispatch
 from bigdl_tpu.ops.lrn_pallas import cross_map_lrn, within_channel_lrn
 from bigdl_tpu.ops.pool_pallas import avg_pool, maxpool_tie_split
-from test_kernels import WHOLE_PLANE, _assert_ragged_blocks, _launches
+from test_kernels import (ONLY_LEG, WHOLE_PLANE, _assert_ragged_blocks,
+                          _launches)
 
 pytestmark = pytest.mark.usefixtures(
     "described_compiles_stay_out_of_the_cache")
@@ -63,12 +64,47 @@ def test_flash_attention_compiles(shape, one_chip, as_tpu):
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("shape", [(32, 64, 56, 56), (32, 192, 56, 56)])
 def test_cross_map_lrn_compiles(shape, dtype, one_chip, as_tpu):
-    """The two LRN sites of Inception-v1 (models/inception.py)."""
+    """The two LRN sites of Inception-v1 (models/inception.py): on one
+    chip too the banded product, no Mosaic kernel (PR 44)."""
     text = _fwd_bwd_text(lambda x: cross_map_lrn(x, 5, 1e-4, 0.75, 1.0),
                          shape, dtype, one_chip)
-    assert _backends("lrn_cross_map") == {
-        ("lrn_cross_map.fwd", "pallas"), ("lrn_cross_map.bwd", "pallas")}
-    assert "tpu_custom_call" in text
+    assert set(dispatch.decisions()) == ONLY_LEG
+    assert "tpu_custom_call" not in text
+    assert " convolution(" in text
+
+
+def test_inception_stem_holds_no_kernel_and_no_packed_plane(one_chip,
+                                                            as_tpu):
+    """Inception-v1 from conv1 to conv2/norm2, forward and backward in
+    bf16: both LRN sites between their convolutions.  As a kernel each
+    site wanted ``[N, C + 4, 3200]`` operands (56 x 56 padded to 25
+    lane tiles), copied in and out of XLA's own layout; none of that may
+    come back."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.models.inception import build_inception_v1
+    from bigdl_tpu.nn.module import functional_call, state_dict
+
+    stem = nn.Sequential(*build_inception_v1(1000).get(0).layers[:9])
+    assert all(isinstance(stem.get(i), nn.SpatialCrossMapLRN)
+               for i in (3, 8))
+
+    def shaped(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    state = jax.tree.map(lambda a: shaped(a.shape), state_dict(stem))
+
+    def fwd_bwd(state, x, g):
+        y, vjp = jax.vjp(lambda s, a: functional_call(stem, s, a)[0],
+                         state, x)
+        return y, vjp(g)
+
+    text = jax.jit(fwd_bwd).lower(state, shaped((32, 3, 224, 224)),
+                                  shaped((32, 192, 56, 56))).compile().as_text()
+    assert {(d, d.launch["channels"]) for d in dispatch.decisions()
+            if d[0].startswith("lrn_cross_map")} \
+        == {(d, c) for d in ONLY_LEG for c in (64, 192)}
+    assert "tpu_custom_call" not in text
+    assert not re.search(r"\[\d+,\d+,3200\]", text)
 
 
 def test_within_channel_lrn_compiles(one_chip, as_tpu):
@@ -167,19 +203,9 @@ def _pallas_grids(fn, *args):
 
 
 def test_large_planes_keep_one_plane_a_grid_step(as_tpu):
-    """What the block rule must leave alone.  Cross-map LRN has its own
-    launcher (a [C + halo, HW tile] slab a grid step over (N, tiles)),
-    so no plane launch rides on its decisions; and a plane stack whose
-    planes are large (within-channel LRN on 384x384 images) comes out of
-    the shared launcher at one plane a step, grid (N*C,), as before."""
-    x = jax.ShapeDtypeStruct((32, 192, 56, 56), jnp.bfloat16)
-    grids = _pallas_grids(lambda a: cross_map_lrn(a, 5, 1e-4, 0.75, 1.0), x)
-    assert [g for g, _ in grids] == [(32, 5), (32, 5)]
-    assert {b for _, blocks in grids for b in blocks} \
-        == {(1, 196, 640), (1, 192, 640)}
-    assert _launches("lrn_cross_map") == {"lrn_cross_map.fwd": {},
-                                          "lrn_cross_map.bwd": {}}
-
+    """What the block rule must leave alone: a plane stack whose planes
+    are large (within-channel LRN on 384x384 images) comes out of the
+    shared launcher at one plane a step, grid (N*C,), as before."""
     x = jax.ShapeDtypeStruct((2, 3, 384, 384), jnp.float32)
     grids = _pallas_grids(lambda a: within_channel_lrn(a, 5, 1e-4, 0.75), x)
     assert [g for g, _ in grids] == [(6,), (6,)]
@@ -219,7 +245,8 @@ def test_partitioned_step_takes_xla_leg(topo, as_tpu):
     """On the four-chip data mesh XLA partitions the step, and the TPU
     compiler refuses a Mosaic kernel there ("cannot be automatically
     partitioned"): inside ``spmd_partitioned`` — the scope TrainStep and
-    EvalStep trace under — ``auto`` must take the XLA leg and say so."""
+    EvalStep trace under — ``auto`` must take the XLA leg and say so
+    (a two-legged op: within-channel LRN)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -228,7 +255,7 @@ def test_partitioned_step_takes_xla_leg(topo, as_tpu):
 
     def fwd_bwd(x, g):
         with dispatch.spmd_partitioned(mesh):
-            y, vjp = jax.vjp(lambda a: cross_map_lrn(a, 5, 1e-4, 0.75, 1.0),
+            y, vjp = jax.vjp(lambda a: within_channel_lrn(a, 5, 1e-4, 0.75),
                              x)
             return y, vjp(g)
 

@@ -1,11 +1,14 @@
 """Kernel-library parity + dispatch tests (bigdl_tpu/ops/).
 
-Every fused op keeps two legs under one ``jax.custom_vjp`` — the Pallas
-kernel (interpret mode on this CPU suite: the IDENTICAL code path that
-Mosaic compiles on TPU) and the XLA reference.  Parity must hold on
-forward values AND the hand-derived VJP cotangents, across odd shapes,
-dtypes, and ceil/asymmetric-padding edges; ``tests/test_numeric_grads.py``
-separately pins both legs against finite differences.
+A fused op with a kernel keeps two legs under one ``jax.custom_vjp`` —
+the Pallas kernel (interpret mode on this CPU suite: the IDENTICAL code
+path that Mosaic compiles on TPU) and the XLA reference.  Parity must
+hold on forward values AND the hand-derived VJP cotangents, across odd
+shapes, dtypes, and ceil/asymmetric-padding edges;
+``tests/test_numeric_grads.py`` separately pins both legs against
+finite differences.  The cross-map LRN has ONE leg (the banded product,
+in every mode): it is held to its definition instead, a channel-window
+sum in float32 with autodiff's backward.
 
 The dispatch layer's contract is pinned here too: ``BIGDL_KERNELS=xla``
 bypasses Pallas EVERYWHERE (the process-wide kill switch), ``pallas``
@@ -61,35 +64,115 @@ def _both_legs(fn, x, seed=1, rtol=1e-5, atol=1e-6, monkeypatch=None):
 # parity: LRN family
 # ---------------------------------------------------------------------------
 
+ONLY_LEG = {("lrn_cross_map.fwd", "xla", "only-leg"),
+            ("lrn_cross_map.bwd", "xla", "only-leg")}
+
+
+def _lrn_definition(x, size, alpha, beta, k, c_ax=1):
+    """The cross-map LRN as ``SpatialCrossMapLRN``'s rank-5 path has it:
+    a ``lax.reduce_window`` sum of squares over the channel window, in
+    float32; its backward is autodiff's."""
+    x = x.astype(jnp.float32)
+    half = (size - 1) // 2
+    dims, pads = [1] * x.ndim, [(0, 0)] * x.ndim
+    dims[c_ax], pads[c_ax] = size, (half, size - 1 - half)
+    window_sum = jax.lax.reduce_window(x * x, 0.0, jax.lax.add, tuple(dims),
+                                       (1,) * x.ndim, pads)
+    return x * jnp.power(k + window_sum * (alpha / size), -beta)
+
+
+def _cross_map_against_definition(x, size, alpha, beta, k, seed=1,
+                                  rtol=1e-5, atol=1e-6):
+    """Value and VJP of the op's one leg against the definition's."""
+    dispatch.clear_decisions()
+    y, vjp = jax.vjp(jax.jit(
+        lambda a: cross_map_lrn(a, size, alpha, beta, k)), x)
+    y0, vjp0 = jax.vjp(jax.jit(
+        lambda a: _lrn_definition(a, size, alpha, beta, k)), x)
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y0),
+                               rtol=rtol, atol=atol)
+    gy = jnp.asarray(_rng(seed).randn(*y.shape).astype(np.float32), y.dtype)
+    np.testing.assert_allclose(
+        np.asarray(vjp(gy)[0], np.float32),
+        np.asarray(vjp0(gy.astype(jnp.float32))[0], np.float32),
+        rtol=rtol, atol=atol)
+    assert set(dispatch.decisions()) == ONLY_LEG
+
+
 @pytest.mark.parametrize("shape,size", [
     ((2, 7, 5, 5), 5),      # band wider than half the channels
     ((1, 3, 4, 4), 3),      # tiny channel count
     ((2, 16, 7, 9), 5),     # non-square odd spatial
-    # Inception-v1's conv2/norm2 plane: 56*56 pads to 3200 lanes and the
-    # block does not fit VMEM whole, so the HW axis is TILED (5 x 640)
-    ((1, 192, 56, 56), 5),
+    ((1, 192, 56, 56), 5),  # Inception-v1's conv2/norm2 plane
 ])
-def test_cross_map_lrn_parity(shape, size, monkeypatch):
+def test_cross_map_lrn_parity(shape, size):
     x = jnp.asarray(_rng().randn(*shape).astype(np.float32))
-    _both_legs(lambda a: cross_map_lrn(a, size, 1e-4, 0.75, 1.0), x,
-               monkeypatch=monkeypatch)
+    _cross_map_against_definition(x, size, 1e-4, 0.75, 1.0)
 
 
-@pytest.mark.parametrize("f_pad,cp,esz", [
-    (3200, 196, 4),     # used to halve 3200 -> 1600 -> 800: not x128
-    (3200, 196, 2), (3200, 68, 4), (12544, 68, 4), (128, 1024, 4)])
-def test_lrn_tile_is_a_lane_multiple_that_divides_the_plane(f_pad, cp, esz):
-    """Mosaic refuses a block whose last dimension is neither a multiple
-    of 128 nor the whole extent (tests/test_chip_compile.py asks the
-    real compiler; this pins the chooser's arithmetic)."""
-    from bigdl_tpu.ops.lrn_pallas import _VMEM_BUDGET, _pick_tile
+@pytest.mark.parametrize("shape,size", [
+    ((2, 64, 56, 56), 5),   # pool1/norm1, cut to batch 2
+    ((2, 192, 56, 56), 5),  # conv2/norm2, cut to batch 2
+    ((2, 3, 5, 5), 7),      # a band wider than the channels
+])
+def test_cross_map_lrn_is_one_path_for_both_layouts(shape, size):
+    """NCHW and NHWC are one code path (the banded product in the
+    input's own layout): values and gradients agree through a transpose
+    of the data, and each layout announces itself."""
+    to_last, to_first = (0, 2, 3, 1), (0, 3, 1, 2)
+    x = jnp.asarray(_rng(30).randn(*shape).astype(np.float32))
+    gy = jnp.asarray(_rng(31).randn(*shape).astype(np.float32))
+    dispatch.clear_decisions()
+    y_c, vjp_c = jax.vjp(jax.jit(
+        lambda a: cross_map_lrn(a, size, 1e-4, 0.75, 1.0, "NCHW")), x)
+    y_l, vjp_l = jax.vjp(jax.jit(
+        lambda a: cross_map_lrn(a, size, 1e-4, 0.75, 1.0, "NHWC")),
+        jnp.transpose(x, to_last))
+    np.testing.assert_allclose(
+        np.asarray(y_c), np.asarray(jnp.transpose(y_l, to_first)),
+        rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(vjp_c(gy)[0]),
+        np.asarray(jnp.transpose(vjp_l(jnp.transpose(gy, to_last))[0],
+                                 to_first)), rtol=1e-5, atol=1e-6)
+    assert set(dispatch.decisions()) == ONLY_LEG
+    assert {(d.launch["layout"], d.launch["channels"], d.launch["size"])
+            for d in dispatch.decisions()} \
+        == {("NCHW", shape[1], size), ("NHWC", shape[1], size)}
 
-    t = _pick_tile(f_pad, cp, esz)
-    assert t % 128 == 0 and f_pad % t == 0
-    assert 5 * cp * t * esz <= _VMEM_BUDGET
-    # and it is the LARGEST such tile
-    assert not any(f_pad % u == 0 and 5 * cp * u * esz <= _VMEM_BUDGET
-                   for u in range(t + 128, f_pad + 1, 128))
+
+@pytest.mark.parametrize("mode", ["auto", "pallas", "xla"])
+def test_kernel_mode_does_not_reach_cross_map_lrn(mode, tmp_path,
+                                                  monkeypatch):
+    """``BIGDL_KERNELS`` chooses nothing for this op, ``pallas``
+    included: the decision is ``xla`` / ``only-leg``, nothing raises,
+    the traced program holds no ``pallas_call``, and the run log's
+    instants say which site (channels, size, layout)."""
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.telemetry import schema
+
+    monkeypatch.setenv("BIGDL_KERNELS", mode)
+    dispatch.clear_decisions()
+    x = jnp.asarray(_rng(32).randn(1, 6, 5, 5).astype(np.float32))
+
+    def fwd_bwd(a):
+        y, vjp = jax.vjp(lambda b: cross_map_lrn(b, 3, 1e-4, 0.75, 1.0), a)
+        return vjp(y)
+
+    telemetry.start_run(str(tmp_path))
+    try:
+        jaxpr = jax.make_jaxpr(fwd_bwd)(x)
+    finally:
+        telemetry.end_run()
+    assert "pallas_call" not in str(jaxpr)
+    assert set(dispatch.decisions()) == ONLY_LEG
+    events, errors = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
+    assert not errors and not schema.validate_events(events)
+    said = [e for e in events if e.get("name") == "kernel/dispatch"]
+    assert [(e["op"], e["backend"], e["reason"], e["channels"], e["size"],
+             e["layout"]) for e in said] == [
+        ("lrn_cross_map.fwd", "xla", "only-leg", 6, 3, "NCHW"),
+        ("lrn_cross_map.bwd", "xla", "only-leg", 6, 3, "NCHW")]
 
 
 def test_strided_pool_leaves_pallas_on_tpu_only(monkeypatch):
@@ -126,10 +209,9 @@ def test_partitioned_step_takes_xla_leg_on_tpu(monkeypatch):
             assert dispatch.choose_backend("op", True)[0] == "pallas"
 
 
-def test_cross_map_lrn_general_beta_and_k(monkeypatch):
+def test_cross_map_lrn_general_beta_and_k():
     x = jnp.asarray(_rng(3).randn(1, 5, 6, 6).astype(np.float32))
-    _both_legs(lambda a: cross_map_lrn(a, 3, 0.001, 0.5, 2.0), x,
-               monkeypatch=monkeypatch)
+    _cross_map_against_definition(x, 3, 0.001, 0.5, 2.0)
 
 
 @pytest.mark.parametrize("shape,size", [
@@ -143,12 +225,13 @@ def test_within_channel_lrn_parity(shape, size, monkeypatch):
                monkeypatch=monkeypatch)
 
 
-def test_lrn_bf16_parity(monkeypatch):
-    """The bench dtype: both legs agree within bf16 slack."""
+def test_lrn_bf16_parity():
+    """The bench dtype: the leg agrees with the float32 definition
+    within bf16 slack."""
     x = jnp.asarray(_rng(2).randn(2, 8, 8, 8).astype(np.float32),
                     jnp.bfloat16)
-    _both_legs(lambda a: cross_map_lrn(a, 5, 1e-4, 0.75, 1.0), x,
-               rtol=2e-2, atol=2e-2, monkeypatch=monkeypatch)
+    _cross_map_against_definition(x, 5, 1e-4, 0.75, 1.0,
+                                  rtol=2e-2, atol=2e-2)
 
 
 # ---------------------------------------------------------------------------
@@ -467,8 +550,8 @@ def test_tie_split_conserves_gradient_mass(monkeypatch):
 def test_cross_map_lrn_rank5_and_nhwc(monkeypatch):
     """Rank-5 inputs keep the generic reduce_window reference (review
     r6 finding: the op-routing rewrite briefly dropped it) and NHWC
-    matches NCHW through the native-layout reference leg — with the
-    exact VJP, no relayout transposes."""
+    matches NCHW through the banded product in its native layout — with
+    the exact VJP, no relayout transposes."""
     import bigdl_tpu.nn as nn
 
     layer = nn.SpatialCrossMapLRN(3, 0.001, 0.75)
@@ -564,12 +647,13 @@ def test_pallas_mode_forces_kernels(monkeypatch):
     monkeypatch.setenv("BIGDL_KERNELS", "pallas")
     dispatch.clear_decisions()
     x = jnp.asarray(_rng(13).randn(1, 4, 5, 5).astype(np.float32))
-    y, vjp = jax.vjp(lambda a: cross_map_lrn(a, 3, 1e-4, 0.75, 1.0), x)
+    y, vjp = jax.vjp(jax.jit(
+        lambda a: within_channel_lrn(a, 3, 1e-4, 0.75)), x)
     vjp(jnp.ones_like(y))
     recs = [r for r in dispatch.decisions()
-            if r[0].startswith("lrn_cross_map")]
+            if r[0].startswith("lrn_within_channel")]
     assert {op for op, _, _ in recs} \
-        == {"lrn_cross_map.fwd", "lrn_cross_map.bwd"}
+        == {"lrn_within_channel.fwd", "lrn_within_channel.bwd"}
     assert all(b == "pallas" for _, b, _ in recs)
 
 
@@ -596,7 +680,7 @@ def test_dispatch_emits_telemetry_instant(tmp_path, monkeypatch):
     telemetry.start_run(str(tmp_path))
     try:
         x = jnp.asarray(_rng(15).randn(1, 3, 5, 5).astype(np.float32))
-        cross_map_lrn(x, 3, 1e-4, 0.75, 1.0)
+        within_channel_lrn(x, 3, 1e-4, 0.75)
     finally:
         telemetry.end_run()
     logs = list(tmp_path.glob("*.jsonl"))
@@ -604,7 +688,7 @@ def test_dispatch_emits_telemetry_instant(tmp_path, monkeypatch):
     events, errors = schema.read_events(str(logs[0]))
     assert not errors
     inst = [e for e in events if e.get("name") == "kernel/dispatch"]
-    assert inst and inst[0]["op"] == "lrn_cross_map.fwd" \
+    assert inst and inst[0]["op"] == "lrn_within_channel.fwd" \
         and inst[0]["backend"] == "xla"
     assert not schema.validate_events(events)
 
