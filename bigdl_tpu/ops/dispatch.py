@@ -25,8 +25,8 @@ HLO actually contains.  The reasons: ``forced:BIGDL_KERNELS=<mode>``,
 ``unsupported-shape``, ``auto:tpu``, ``auto:off-tpu``,
 ``auto:spmd-partitioned`` from :func:`choose_backend`; from an op that
 has one form and says so through :func:`note`, ``only-leg`` (the
-state-space scan, the short convolution, the cross-map LRN's banded
-product: the knob does not reach them in any mode) and ``whole-plane``
+short convolution, the cross-map LRN's banded product: the knob does not
+reach them in any mode) and ``whole-plane``
 (an average pool whose window is the whole padded plane: a fused
 reduction in plain ``jnp`` in every mode, ``pool_pallas.avg_pool``).
 A leg that launches through

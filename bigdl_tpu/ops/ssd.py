@@ -30,34 +30,70 @@ skip weight a head.  Two forms stand here:
   of it stacked, and ``exp(L_t) S C_t`` is one more batched product.
   Every exponent is of a number that is not positive.  Matrix products
   take their operands in ``x``'s dtype and accumulate in float32; the
-  decays and the carried state are float32.  The backward is autodiff's:
-  the scan keeps the state that enters each chunk.
+  decays and the carried state are float32.  The scan's backward is
+  autodiff's: it keeps the state that enters each chunk.
 
 Shapes: ``x`` ``[batch, seq, heads, head_dim]``, ``dt`` ``[batch, seq,
 heads]``, ``A``, ``D`` ``[heads]``, ``B``, ``C`` ``[batch, seq, groups,
 state]``; the result is ``x``'s shape and dtype.  A length the chunk does
 not divide is padded with tokens that change nothing (``dt = 0``).
 
-ONE leg computes it, XLA's, on every platform: nothing is chosen, and
-the PR that brings a Pallas leg for the chunk-local products brings
-``ops/dispatch.choose_backend`` with it (the layer calls :func:`ssd` and
-will not know).  The leg is announced on a ``kernel/dispatch`` instant
-(``op=ssd``, ``backend="xla"``, ``reason="only-leg"``, ``chunk``,
-``chunks``, ``heads``, ``head_dim``,
-``state``, ``groups``), once a compilation, and the scan's operations lie
+TWO legs compute what is local to the chunks; the scan over the chunks
+(:func:`_carry`: ``S' = exp(L_Q) S + S_own``, no product) is one
+``lax.scan`` for both and its backward is autodiff's.  It stays XLA's
+``while`` because the benchmark counts a call by it; a state carried in
+VMEM across the chunks is a later step (ROADMAP S8g).
+``ops/dispatch.choose_backend`` picks, from what the trace can see:
+
+- XLA's (:func:`_chunked`): batched products for all chunks at once
+  through HBM (``C B^T``, the decayed ``Q x Q`` matrix times it, ``dt x``,
+  the own and entering states), operands transposed to ``[batch, groups,
+  heads a group, chunks, Q, *]`` and ``y`` transposed back; autodiff's
+  backward.  Off the TPU, inside ``dispatch.spmd_partitioned`` (a Mosaic
+  kernel cannot be partitioned over a mesh), under ``BIGDL_KERNELS=xla``,
+  and at a shape the kernels do not take.
+- Pallas's (:func:`_scan_pallas`, one ``jax.custom_vjp`` around the
+  carry), on a TPU when ``chunk`` is :data:`CHUNK`, ``head_dim`` is whole
+  sublane tiles (a multiple of 16), ``state`` whole lane tiles (a multiple
+  of 128) and x, B, C share a dtype Mosaic compiles.  Four Mosaic calls a
+  scan, a grid step one chunk of :data:`HEAD_BLOCK` heads of one group,
+  operands in the layout the mixer's activations have on the TPU (the
+  sequence minor: ``x`` as ``[batch, heads * head_dim, seq]``; the
+  transposes in front are bitcasts there): before the carry ``S_own`` and
+  ``exp(L_Q)``; after it ``y`` from the entering states; in the backward
+  first the entering states' cotangent, then, after the carry's backward,
+  ``dx``, ``d dt``, ``dA``, ``dB``, ``dC``, ``dD`` in one call.  ``C B^T``,
+  the masked exponent, the ``Q x Q`` decay, its product with the scores
+  and ``dt x`` live in VMEM only; the backward computes them again from
+  ``x``, ``dt``, ``B``, ``C`` and the entering states the carry kept, and
+  keeps nothing ``Q x Q``.  Same mathematics, rounded where the XLA leg
+  rounds; the running sums are products with a triangle of ones at
+  ``Precision.HIGHEST``.  What still crosses HBM are the float32 own and
+  entering states, ``[chunks, batch, groups, heads a group, head_dim,
+  state]``, the carry's operand and result.
+
+The decision is announced on a ``kernel/dispatch`` instant (``op=ssd``,
+``backend``, ``reason``, ``chunk``, ``chunks``, ``heads``, ``head_dim``,
+``state``, ``groups`` and, for the Pallas leg, ``head_block`` and
+``grid``), once a compilation, and the scan's operations lie
 under the ``jax.named_scope`` :data:`SCOPE`: an XLA dump and the
 profiler's op metadata carry it.  (The names of a device trace's events
 do not, so the benchmark finds the scan's events by the shapes only it
-has.)
+has; each Mosaic call reads or writes a stack of states, which those
+patterns name.)
 """
 
 from __future__ import annotations
 
 from typing import Tuple
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ssd", "ssd_recurrent", "SCOPE", "CHUNK"]
 
@@ -104,17 +140,25 @@ def _carry(decay, own) -> Tuple[jax.Array, jax.Array]:
                     (decay, own))
 
 
+def _whole_chunks(chunk: int, *arrays):
+    """``[batch, seq, ...]`` arrays padded to whole chunks with tokens that
+    change nothing (``dt = 0``), and how many chunks that is."""
+    s = arrays[0].shape[1]
+    c = -(-s // chunk)
+    pad = c * chunk - s
+    if pad:
+        arrays = tuple(
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in arrays)
+    return (c,) + tuple(arrays)
+
+
 def _chunked(x, dt, A, B, C, D, chunk: int) -> Tuple[jax.Array, jax.Array]:
     f32 = jnp.float32
     b, s, h, p = x.shape
     g, n = B.shape[2:]
     r, dtype = h // g, x.dtype
-    c = -(-s // chunk)
-    pad = c * chunk - s
-    if pad:
-        x, dt, B, C = (
-            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            for a in (x, dt, B, C))
+    c, x, dt, B, C = _whole_chunks(chunk, x, dt, B, C)
     dt = dt.astype(f32)
     # [batch, groups, heads a group, chunks, Q, *]; B and C have no head
     xc = x.reshape(b, c, chunk, g, r, p).transpose(0, 3, 4, 1, 2, 5)
@@ -149,21 +193,423 @@ def _chunked(x, dt, A, B, C, D, chunk: int) -> Tuple[jax.Array, jax.Array]:
     return y[:, :s].astype(dtype), state.reshape(b, h, p, n)
 
 
+
+
+# -- the Pallas leg: a chunk's products in VMEM -----------------------------------
+#
+# The kernels see the sequence on the LANES: ``x`` as ``[batch, heads *
+# head_dim, seq]``, ``dt`` as ``[batch, heads, seq]``, ``B`` and ``C`` as
+# ``[batch, groups * state, seq]``.  That is the layout XLA keeps the
+# mixer's activations in on the TPU (``[1, 8192, 4096]`` with the sequence
+# minor: the transposes in front of the calls are bitcasts there, and a
+# copy where a program holds them the other way).  A grid step takes one
+# chunk's ``Q`` columns of ``hb`` heads of one group: a head is ``head_dim``
+# whole sublane rows, so what has no ``Q x Q`` in it (the states' products,
+# ``dt x``, the skip) is done for the block's heads at once, and only the
+# decayed product goes head by head.  ``dt`` comes as the chunk's ``[heads,
+# Q]`` and a one-hot product picks the block's rows (and puts gradients
+# back), exactly.
+
+LANES = 128
+
+#: heads a grid step of the Pallas leg takes (fewer when a group holds
+#: fewer)
+HEAD_BLOCK = 16
+
+#: what an exponent below the diagonal is set to BEFORE the exponential
+_MASKED = -1e30
+
+_NN = ((1,), (0,))      # a b
+_NT = ((1,), (1,))      # a b^T
+_TN = ((0,), (0,))      # a^T b
+
+
+def _dot(a, b, form=_NN, exact=False):
+    """A product on the MXU, float32 out; ``exact`` for float32 operands
+    whose digits all count (running sums, one-hot selections)."""
+    return lax.dot_general(
+        a, b, (form, ((), ())), preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST if exact else None)
+
+
+def _iota(shape, axis):
+    return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _head_block(r: int) -> int:
+    """Heads of a group a grid step takes: the largest divisor of ``r`` up
+    to :data:`HEAD_BLOCK`."""
+    return max(d for d in range(1, HEAD_BLOCK + 1) if r % d == 0)
+
+
+def _block_sums(dt_ref, a_ref, hb: int):
+    """Of the step's ``hb`` heads: the one-hot ``[hb, heads]`` that picks
+    them, ``L_t`` as rows ``[hb, Q]`` and as columns ``[Q, hb]`` (the SAME
+    sums: ``L_t - L_t`` has to be 0) and ``dt`` as rows."""
+    f32 = jnp.float32
+    dt = dt_ref[...]                                         # [heads, Q]
+    h, q = dt.shape
+    pick = (_iota((hb, h), 1)
+            == pl.program_id(2) * hb + _iota((hb, h), 0)).astype(f32)
+    rows, cols = _iota((q, q), 0), _iota((q, q), 1)
+    total = _dot(_dot(pick, dt * a_ref[...], exact=True),
+                 (rows <= cols).astype(f32), exact=True)     # sum over s <= t
+    return (pick, total,
+            _dot((rows == cols).astype(f32), total, _NT, True),
+            _dot(pick, dt, exact=True))
+
+
+def _picked(col_ref, pick):
+    """``[heads, 1]`` -> the block's ``[hb, 1]``."""
+    col = jnp.broadcast_to(col_ref[...], (pick.shape[1], LANES))
+    return _dot(pick, col, exact=True)[:, :1]
+
+
+def _put_back(col, pick):
+    """The block's ``[hb, 1]`` -> ``[heads, 1]``, zero elsewhere."""
+    return _dot(pick, jnp.broadcast_to(col, (pick.shape[0], LANES)), _TN,
+                True)[:, :1]
+
+
+def _a_head(rows, p: int):
+    """``[hb, Q or 1]`` a head -> ``[hb * p, Q or 1]``: a head's row over
+    its ``p`` sublane rows."""
+    hb, q = rows.shape
+    return jnp.broadcast_to(rows[:, None], (hb, p, q)).reshape(hb * p, q)
+
+
+def _states(ref):
+    """A ``[hb, head_dim, state]`` block as ``[hb * head_dim, state]``."""
+    hb, p, n = ref.shape
+    return ref[...].reshape(hb * p, n)
+
+
+def _fade(upto, total, total_c, j):
+    """``exp(L_t - L_s)`` of head ``j``, ``[Q_s, Q_t]``, 0 where ``s > t``:
+    the exponent is masked BEFORE the exponential."""
+    return jnp.exp(jnp.where(upto, total[j:j + 1] - total_c[:, j:j + 1],
+                             _MASKED))
+
+
+def _own_kernel(x_ref, dt_ref, a_ref, b_ref, own_ref, decay_ref, *, hb):
+    """Before the carry: ``S_own = (exp(L_Q - L_s) dt_s x_s)^T B_s`` of
+    every head of the block, and ``exp(L_Q)``."""
+    f32, dtype = jnp.float32, x_ref.dtype
+    pick, total, _, dt = _block_sums(dt_ref, a_ref, hb)
+    p, q = x_ref.shape[0] // hb, total.shape[1]
+    last = total[:, q - 1:q]
+    xdt = (x_ref[...].astype(f32) * _a_head(dt, p)).astype(dtype)
+    kept = (xdt.astype(f32)
+            * _a_head(jnp.exp(last - total), p)).astype(dtype)
+    own_ref[...] = _dot(kept, b_ref[...], _NT).reshape(own_ref.shape)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        decay_ref[...] = jnp.zeros_like(decay_ref)
+
+    decay_ref[...] += _put_back(jnp.exp(last), pick)
+
+
+def _out_kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, s_ref, y_ref, *,
+                hb):
+    """After the carry: ``y_t = sum_{s<=t} exp(L_t - L_s)(C_t . B_s) dt_s
+    x_s + exp(L_t) S C_t + D x_t``."""
+    f32, dtype = jnp.float32, x_ref.dtype
+    pick, total, total_c, dt = _block_sums(dt_ref, a_ref, hb)
+    p, q = x_ref.shape[0] // hb, total.shape[1]
+    upto = _iota((q, q), 0) <= _iota((q, q), 1)              # s <= t
+    cm = c_ref[...]
+    scores = _dot(b_ref[...], cm, _TN)                       # B_s . C_t
+    x = x_ref[...].astype(f32)
+    xdt = (x * _a_head(dt, p)).astype(dtype)
+    y = _a_head(_picked(d_ref, pick), p) * x + _a_head(jnp.exp(total), p) \
+        * _dot(_states(s_ref).astype(dtype), cm)
+    for j in range(hb):
+        head = slice(j * p, (j + 1) * p)
+        weights = (_fade(upto, total, total_c, j) * scores).astype(dtype)
+        y_ref[head] = (y[head] + _dot(xdt[head], weights)).astype(dtype)
+
+
+def _entering_bwd_kernel(dy_ref, dt_ref, a_ref, c_ref, ds_ref, *, hb):
+    """The entering states' cotangent, ``(exp(L_t) dy_t)^T C_t``: what the
+    carry's backward needs first."""
+    f32, dtype = jnp.float32, dy_ref.dtype
+    _, total, _, _ = _block_sums(dt_ref, a_ref, hb)
+    grow = _a_head(jnp.exp(total), dy_ref.shape[0] // hb)
+    ds_ref[...] = _dot((dy_ref[...].astype(f32) * grow).astype(dtype),
+                       c_ref[...], _NT).reshape(ds_ref.shape)
+
+
+def _local_bwd_kernel(x_ref, dy_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
+                      s_ref, down_ref, ddecay_ref,
+                      dx_ref, ddt_ref, db_ref, dc_ref, dhead_ref,
+                      db_acc, dc_acc, *, hb, per_group):
+    """Everything else of the backward, the chunk's internals computed
+    again: ``dx``, ``d dt``, ``dB``, ``dC`` and, summed over the whole
+    grid, ``dA`` and ``dD`` (columns 0 and 1 of ``dhead``)."""
+    f32, dtype = jnp.float32, x_ref.dtype
+    block = pl.program_id(2)
+    pick, total, total_c, dt = _block_sums(dt_ref, a_ref, hb)
+    p, q = x_ref.shape[0] // hb, total.shape[1]
+    rows, cols = _iota((q, q), 0), _iota((q, q), 1)
+    upto = rows <= cols
+    bm, cm = b_ref[...], c_ref[...]
+    scores = _dot(bm, cm, _TN)
+    last = total[:, q - 1:q]
+    grow, shrink = jnp.exp(total), jnp.exp(last - total)
+    skip = _picked(d_ref, pick)
+    x, dy_low = x_ref[...].astype(f32), dy_ref[...]
+    dy = dy_low.astype(f32)
+    dt_p, grow_p, shrink_p = (_a_head(v, p) for v in (dt, grow, shrink))
+    xdt = (x * dt_p).astype(dtype)
+    kept = (xdt.astype(f32) * shrink_p).astype(dtype)
+    state = _states(s_ref).astype(dtype)
+    d_own = _states(down_ref).astype(dtype)
+    # y's part from the entering state, exp(L_t) (S C_t)
+    d_c = _dot(state, (dy * grow_p).astype(dtype), _TN)      # [state, Q]
+    d_grow = dy * _dot(state, cm) * grow_p
+    # the chunk's own state, (exp(L_Q - L_s) dt_s x_s)^T B_s
+    d_b = _dot(d_own, kept, _TN)
+    d_kept = _dot(d_own, bm)
+    d_xdt_own = d_kept * shrink_p
+    d_shrink = d_kept * xdt.astype(f32) * shrink_p
+    straight, skipped = dy * x, _a_head(skip, p) * dy
+    sub, lane = _iota((hb, 1), 0), _iota((1, hb), 1)
+
+    def a_row(v, j):                      # a head's [p, Q] -> its row of [hb, Q]
+        return jnp.where(sub == j, jnp.sum(v, axis=0, keepdims=True), 0)
+
+    d_scores = jnp.zeros((q, q), f32)
+    d_total = jnp.zeros((hb, q), f32)     # d L_t a head, the rows' part
+    d_total_c = jnp.zeros((q, hb), f32)   # the columns' part
+    d_shrunk = jnp.zeros((hb, q), f32)    # d exp(L_Q - L_s) exp(L_Q - L_s)
+    d_dt = jnp.zeros((hb, q), f32)        # through dt_s x_s
+    d_skip = jnp.zeros((hb, q), f32)
+    for j in range(hb):
+        head = slice(j * p, (j + 1) * p)
+        fade = _fade(upto, total, total_c, j)
+        weights = fade * scores
+        d_w = _dot(xdt[head], dy_low[head], _TN)             # [Q_s, Q_t]
+        d_xdt = d_xdt_own[head] + _dot(dy_low[head], weights.astype(dtype),
+                                       _NT)
+        d_scores = d_scores + d_w * fade
+        d_gap = d_w * weights                                # 0 where s > t
+        d_total = d_total + a_row(d_gap, j) + a_row(d_grow[head], j)
+        d_total_c = d_total_c - jnp.where(
+            lane == j, jnp.sum(d_gap, axis=1, keepdims=True), 0)
+        d_shrunk = d_shrunk + a_row(d_shrink[head], j)
+        d_dt = d_dt + a_row(d_xdt * x[head], j)
+        d_skip = d_skip + a_row(straight[head], j)
+        dx_ref[head] = (d_xdt * dt_p[head] + skipped[head]).astype(dtype)
+    low = d_scores.astype(dtype)
+    d_b = d_b + _dot(cm, low, _NT)
+    d_c = d_c + _dot(bm, low)
+    # d L_t: rows, columns (the identity transposes them, exactly), and
+    # L_Q's own on the chunk's last token; d (dt_s A): its sum over t >= s
+    d_last = jnp.sum(d_shrunk, axis=1, keepdims=True) \
+        + _picked(ddecay_ref, pick) * jnp.exp(last)
+    d_total = d_total - d_shrunk \
+        + _dot(d_total_c, (rows == cols).astype(f32), _TN, True) \
+        + jnp.where(_iota((1, q), 1) == q - 1, d_last, 0)
+    d_rate = _dot(pick, _dot(d_total, (rows >= cols).astype(f32),
+                             exact=True), _TN, True)         # [heads, Q]
+
+    @pl.when(block == 0)
+    def _():
+        ddt_ref[...] = jnp.zeros_like(ddt_ref)
+
+    ddt_ref[...] += d_rate * a_ref[...] + _dot(pick, d_dt, _TN, True)
+
+    @pl.when((block == 0) & (pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        dhead_ref[...] = jnp.zeros_like(dhead_ref)
+
+    which = _iota((1, LANES), 1)
+    dhead_ref[...] += jnp.where(
+        which == 0, jnp.sum(d_rate * dt_ref[...], axis=1, keepdims=True), 0) \
+        + jnp.where(which == 1, _put_back(
+            jnp.sum(d_skip, axis=1, keepdims=True), pick), 0)
+
+    # B and C are a group's: summed over its blocks of heads
+    @pl.when(block % per_group == 0)
+    def _():
+        db_acc[...] = jnp.zeros_like(db_acc)
+        dc_acc[...] = jnp.zeros_like(dc_acc)
+
+    db_acc[...] += d_b
+    dc_acc[...] += d_c
+
+    @pl.when(block % per_group == per_group - 1)
+    def _():
+        db_ref[...] = db_acc[...].astype(db_ref.dtype)
+        dc_ref[...] = dc_acc[...].astype(dc_ref.dtype)
+
+
+class _Launch:
+    """Block specs and shapes of one scan's four calls: grid ``(batch,
+    chunks, blocks of heads)``, a group's blocks next to each other.  An
+    operand is a ``(block spec, shape, dtype)``."""
+
+    def __init__(self, b, c, h, p, g, n, dtype, hb, interpret):
+        f32 = jnp.float32
+        r, q = h // g, CHUNK
+        per = r // hb
+        self.hb, self.per_group, self.grid = hb, per, (b, c, h // hb)
+        self.interpret, self.calls = interpret, {}
+
+        def operand(block, index, shape, dtype):
+            return pl.BlockSpec(block, index), shape, dtype
+
+        # x, y and their cotangents, the sequence minor
+        self.wide = operand((None, hb * p, q), lambda i, m, j: (i, j, m),
+                            (b, h * p, c * q), dtype)
+        # dt and its cotangent
+        self.steps = operand((None, h, q), lambda i, m, j: (i, 0, m),
+                             (b, h, c * q), f32)
+        # A, D; dA and dD are columns 0 and 1 of [heads, 128]
+        self.head = operand((h, 1), lambda i, m, j: (0, 0), (h, 1), f32)
+        self.heads = operand((h, LANES), lambda i, m, j: (0, 0), (h, LANES),
+                             f32)
+        # B, C and their cotangents
+        self.proj = operand((None, n, q), lambda i, m, j: (i, j // per, m),
+                            (b, g * n, c * q), dtype)
+        # states, float32, as the carry stacks them
+        self.states = operand(
+            (None, None, None, hb, p, n),
+            lambda i, m, j: (m, i, j // per, j % per, 0, 0),
+            (c, b, g, r, p, n), f32)
+        # exp(L_Q) and its cotangent
+        self.decay = operand((None, None, h, 1), lambda i, m, j: (m, i, 0, 0),
+                             (c, b, h, 1), f32)
+        self.acc = pltpu.VMEM((n, q), f32)
+
+    def call(self, kernel, ins, outs, scratch=(), **static):
+        """The call as ONE jitted function a kernel: every layer of a model
+        (and a forward computed again) traces and lowers a kernel's body
+        once, not once a call (0.2 s each, 54 calls a step in a cell)."""
+        if kernel not in self.calls:
+            self.calls[kernel] = jax.jit(pl.pallas_call(
+                functools.partial(kernel, hb=self.hb, **static),
+                grid=self.grid, in_specs=[o[0] for o in ins],
+                out_specs=[o[0] for o in outs],
+                out_shape=[jax.ShapeDtypeStruct(*o[1:]) for o in outs],
+                scratch_shapes=list(scratch),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("arbitrary",) * 3),
+                interpret=self.interpret))
+        return self.calls[kernel]
+
+
+_launch = functools.lru_cache(maxsize=None)(_Launch)
+
+
+def _minor(a):
+    """``[batch, seq, ...]`` -> ``[batch, ..., seq]``."""
+    return a.reshape(a.shape[:2] + (-1,)).transpose(0, 2, 1)
+
+
+def _major(a, like):
+    """:func:`_minor` undone, to ``like``'s shape."""
+    return a.transpose(0, 2, 1).reshape(like.shape)
+
+
+def _operands(x, dt, a, bm, cm, d):
+    """The custom VJP's arguments as the kernels take them, and how the
+    kernels are launched on them."""
+    from bigdl_tpu.ops.dispatch import use_interpret
+
+    b, s, h, p = x.shape
+    g, n = bm.shape[2:]
+    return _launch(b, s // CHUNK, h, p, g, n, x.dtype, _head_block(h // g),
+                   use_interpret()), (
+        _minor(x), _minor(dt), a.reshape(h, 1), _minor(bm), _minor(cm),
+        d.reshape(h, 1))
+
+
+def _scan_pallas_fwd(x, dt, a, bm, cm, d):
+    k, (xm, dtm, a_col, bmm, cmm, d_col) = _operands(x, dt, a, bm, cm, d)
+    own, decay = k.call(_own_kernel, [k.wide, k.steps, k.head, k.proj],
+                        [k.states, k.decay])(xm, dtm, a_col, bmm)
+    # the part that runs in order stays XLA's, its backward autodiff's
+    (state, entering), carry_bwd = jax.vjp(
+        _carry, decay.reshape(own.shape[:4]), own)
+    y, = k.call(_out_kernel, [k.wide, k.steps, k.head, k.head, k.proj,
+                              k.proj, k.states], [k.wide])(
+        xm, dtm, a_col, d_col, bmm, cmm, entering)
+    return (_major(y, x), state), (x, dt, a, bm, cm, d, entering, carry_bwd)
+
+
+@jax.custom_vjp
+def _scan_pallas(x, dt, a, bm, cm, d):
+    """The chunked scan on whole chunks of :data:`CHUNK`, the chunk-local
+    work as four Mosaic calls: :func:`ssd`'s arguments, ``dt``, ``a`` and
+    ``d`` float32 -> ``y`` as ``x`` and the last state ``[batch, groups,
+    heads a group, head_dim, state]``."""
+    return _scan_pallas_fwd(x, dt, a, bm, cm, d)[0]
+
+
+def _scan_pallas_bwd(kept, cotangents):
+    x, dt, a, bm, cm, d, entering, carry_bwd = kept
+    dy, d_state = cotangents
+    k, (xm, dtm, a_col, bmm, cmm, d_col) = _operands(x, dt, a, bm, cm, d)
+    dym = _minor(dy)
+    d_entering, = k.call(_entering_bwd_kernel,
+                         [k.wide, k.steps, k.head, k.proj], [k.states])(
+        dym, dtm, a_col, cmm)
+    d_decay, d_own = carry_bwd((d_state, d_entering))
+    dx, d_dt, d_b, d_c, d_head = k.call(
+        _local_bwd_kernel,
+        [k.wide, k.wide, k.steps, k.head, k.head, k.proj, k.proj, k.states,
+         k.states, k.decay],
+        [k.wide, k.steps, k.proj, k.proj, k.heads], [k.acc, k.acc],
+        per_group=k.per_group)(
+        xm, dym, dtm, a_col, d_col, bmm, cmm, entering, d_own,
+        d_decay.reshape(k.decay[1]))
+    return (_major(dx, x), _major(d_dt, dt), d_head[:, 0], _major(d_b, bm),
+            _major(d_c, cm), d_head[:, 1])
+
+
+_scan_pallas.defvjp(_scan_pallas_fwd, _scan_pallas_bwd)
+
+
+def _chunked_pallas(x, dt, A, B, C, D) -> Tuple[jax.Array, jax.Array]:
+    f32 = jnp.float32
+    b, s, h, p = x.shape
+    _, x, dt, B, C = _whole_chunks(CHUNK, x, dt, B, C)
+    y, state = _scan_pallas(x, dt.astype(f32), A.astype(f32), B, C,
+                            D.astype(f32))
+    return y[:, :s], state.reshape(b, h, p, B.shape[-1])
+
+
 def ssd(x, dt, A, B, C, D, chunk: int = CHUNK, return_state: bool = False):
     """The chunked form (module docstring); ``return_state`` also hands
     out the float32 state after the last token, ``[batch, heads,
     head_dim, state]``."""
-    from bigdl_tpu.ops.dispatch import note
+    from bigdl_tpu.ops.dispatch import dispatch, launched
+    from bigdl_tpu.ops.pallas_util import mosaic_dtype
 
     b, s, h, p = x.shape
     g, n = B.shape[2:]
     if h % g:
         raise ValueError(f"{h} heads over {g} groups")
     chunk = min(chunk, s)
-    # ONE leg on every platform, as the short convolution's: announced,
-    # not chosen (a second leg brings ``choose_backend`` with it)
-    note("ssd", "xla", "only-leg", chunk=chunk, chunks=-(-s // chunk),
-         heads=h, head_dim=p, state=n, groups=g)
+    chunks = -(-s // chunk)
+    hb = _head_block(h // g)
+    said = dict(chunk=chunk, chunks=chunks, heads=h, head_dim=p, state=n,
+                groups=g)
+    # shapes and dtype only: a head is whole sublane tiles, a state whole
+    # lane tiles
+    supported = chunk == CHUNK and p % 16 == 0 and n % LANES == 0 \
+        and x.dtype == B.dtype == C.dtype and mosaic_dtype(x.dtype)
+
+    def kernels():
+        launched(**said, head_block=hb, grid=(b, chunks, h // hb))
+        return _chunked_pallas(x, dt, A, B, C, D)
+
+    def whole():
+        launched(**said)
+        return _chunked(x, dt, A, B, C, D, chunk)
+
     with jax.named_scope(SCOPE):
-        y, state = _chunked(x, dt, A, B, C, D, chunk)
+        y, state = dispatch("ssd", kernels, whole, bool(supported))
     return (y, state) if return_state else y

@@ -135,8 +135,8 @@ STREAM_NAMES = frozenset({
     # op=attention: window, q_heads, kv_heads, head_dim, scale (null: 1
     # over the root of head_dim) and the flash leg's blocks;
     # op=gated_short_conv: taps, channels, tokens; op=ssd: chunk, chunks,
-    # heads, head_dim, state, groups; op=lrn_cross_map.fwd|.bwd:
-    # channels, size, layout)
+    # heads, head_dim, state, groups and, on its Pallas leg, head_block,
+    # grid; op=lrn_cross_map.fwd|.bwd: channels, size, layout)
     "kernel/dispatch",
     # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
     # instant per TRACE of a layer (experts, held, top_k, capacity
@@ -181,7 +181,7 @@ STREAM_NAMES = frozenset({
     # and the largest state norm over the heads after the last token
     # (counters, as above; the scan's own trace-time decision is a
     # kernel/dispatch instant with op=ssd: chunk, chunks, heads,
-    # head_dim, state, groups)
+    # head_dim, state, groups and, on its Pallas leg, head_block, grid)
     "ssm/decay_mean", "ssm/dt_mean", "ssm/state_norm_max",
     # fault tolerance (bigdl_tpu/faults.py + docs/fault_tolerance.md):
     # injected faults, quarantined torn checkpoints, graceful

@@ -401,22 +401,14 @@ def test_routed_layer_combines_by_its_shapes(case, one_chip, monkeypatch):
     assert len(gathers) == 2 and len(sums) == 2
 
 
-def test_whole_mamba2_block_lowers_for_the_chip(one_chip, monkeypatch):
+def _granite_mamba_block(one_chip, monkeypatch):
     """A ``mamba`` layer of the ``granite_4_0_h_micro`` plan at its
-    published widths (hidden 2048; 64 heads of 64 on ONE group, state 128;
-    a gated MLP of 8192; what each part adds times 0.22), forward and
-    backward in bfloat16 at 8,192 positions, lowered for the chip and read
-    as it is handed to the compiler (PR 37): the scan said its own chunk
-    (``ops.ssd.CHUNK``; the published ``mamba_chunk_size`` is read by
-    nothing) and its one group, the chunked shapes the configuration's
-    patterns are written on are the program's (64 chunks of 128: the
-    float32 decay exponent ``[1,1,64,64,128,128]``, ``C B^T`` once for all
-    64 heads, the carried ``[1,1,64,64,128]`` state), and the residual
-    multiplier is in the text."""
+    published widths, as ``(configuration, gradient of a loss by the
+    parameters and the input, their shapes in bfloat16 at 8,192
+    positions)``."""
     import bigdl_tpu.nn as nn
     from bigdl_tpu.nn import init
     from bigdl_tpu.nn.module import functional_call, state_dict
-    from bigdl_tpu.ops import dispatch
 
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "granite_4_0_h_micro.json")) as fh:
@@ -450,9 +442,28 @@ def test_whole_mamba2_block_lowers_for_the_chip(one_chip, monkeypatch):
               for k, v in state_dict(block, kind="param").items()}
     assert params["attn.in_proj.weight"].shape == (8512, 2048)
     assert params["attn.conv_weight"].shape == (4352, 4)
+    return conf, jax.jit(jax.grad(loss, argnums=(0, 1))), (
+        params, shaped((1, seq, d)))
+
+
+def test_whole_mamba2_block_lowers_for_the_chip(one_chip, monkeypatch):
+    """A ``mamba`` layer of the ``granite_4_0_h_micro`` plan at its
+    published widths (hidden 2048; 64 heads of 64 on ONE group, state 128;
+    a gated MLP of 8192; what each part adds times 0.22), forward and
+    backward in bfloat16 at 8,192 positions, lowered for the chip and read
+    as it is handed to the compiler (PR 37): the scan said its own chunk
+    (``ops.ssd.CHUNK``; the published ``mamba_chunk_size`` is read by
+    nothing) and its one group, the chunked shapes the configuration's
+    patterns are written on are the program's (64 chunks of 128: the
+    float32 decay exponent ``[1,1,64,64,128,128]``, ``C B^T`` once for all
+    64 heads, the carried ``[1,1,64,64,128]`` state), and the residual
+    multiplier is in the text."""
+    from bigdl_tpu.ops import dispatch
+
+    conf, grad, shapes = _granite_mamba_block(one_chip, monkeypatch)
+    chunk = conf["ssd_kernel_args"]["chunk"]
     dispatch.clear_decisions()
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, shaped((1, seq, d))).as_text(dialect="hlo")
+    text = grad.lower(*shapes).as_text(dialect="hlo")
     (said,) = [s for s in dispatch.decisions() if s[0] == "ssd"]
     assert said.launch == dict(chunk=chunk, chunks=64, heads=64, head_dim=64,
                                state=128, groups=1)
@@ -472,3 +483,79 @@ def test_whole_mamba2_block_lowers_for_the_chip(one_chip, monkeypatch):
     # a projection's product is not the scan's, whatever its other operand
     assert not re.search(scan_match, "%p = bf16[1,8192,8512]{2,1,0} fusion("
                          "bf16[8512,2048]{1,0} %w, bf16[8192,2048]{1,0} %x)")
+
+
+def _scan_events(text, conf):
+    """Of a compiled text: its Mosaic calls, and the names of the
+    ``ssd_kernels`` that count each ``while``."""
+    lines = text.splitlines()
+    calls = [ln for ln in lines if "tpu_custom_call" in ln]
+    whiles = [sorted(k["counts"] for k in conf["ssd_kernels"]
+                     if re.search(k["match"], ln))
+              for ln in lines if " while(" in ln]
+    return calls, sorted(w for w in whiles if w)
+
+
+SCAN_CELLS = {"granite-block": "granite_4_0_h_micro",
+              "nemotron-scan": "nemotron_3_super_120b_a12b"}
+
+
+@pytest.mark.parametrize("config", SCAN_CELLS.values(), ids=SCAN_CELLS)
+def test_ssd_kernels_compile_and_are_read_as_the_scan(config, one_chip,
+                                                      as_tpu, monkeypatch):
+    """The scan on a TPU's own leg: the chunk-local work is four Mosaic
+    calls around the carry's two ``while``s, in the whole Granite block
+    (64 heads on one group) and in a scan of the Nemotron cell's shape (32
+    heads on 2 groups).  The benchmark's reading stays in place: every
+    call is named by the configuration's ``ssd_scan_match`` and
+    ``ssd_match`` (each holds a ``[chunks, batch, groups, heads a group,
+    head_dim, state]`` stack of states), ``ssd_kernels`` counts a forward
+    and a backward ``while`` and tells them apart, and no ``Q x Q`` decay
+    of all chunks is left in the text."""
+    from bigdl_tpu.ops import dispatch, ssd
+
+    if config == "granite_4_0_h_micro":
+        conf, grad, shapes = _granite_mamba_block(one_chip, monkeypatch)
+    else:
+        with open(os.path.join(ROOT, "benchmark", "configs",
+                               config + ".json")) as fh:
+            conf = json.load(fh)
+
+        def shaped(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        def loss(*args):
+            y, state = ssd.ssd(*args, return_state=True)
+            return jnp.sum(y.astype(jnp.float32) ** 2) + jnp.sum(state)
+
+        f32 = jnp.float32
+        grad = jax.jit(jax.grad(loss, argnums=tuple(range(6))))
+        shapes = (shaped(1, 8192, 32, 64), shaped(1, 8192, 32, dtype=f32),
+                  shaped(32, dtype=f32), shaped(1, 8192, 2, 128),
+                  shaped(1, 8192, 2, 128), shaped(32, dtype=f32))
+    args = conf["ssd_kernel_args"]
+    heads, groups = args["heads"], args["groups"]
+    dispatch.clear_decisions()
+    text = _as_traced(grad.lower(*shapes).compile())
+    (said,) = [s for s in dispatch.decisions() if s[0] == "ssd"]
+    assert tuple(said) == ("ssd", "pallas", "auto:tpu")
+    assert ssd.CHUNK == args["chunk"] == 128
+    assert said.launch == dict(
+        chunk=128, chunks=64, heads=heads, head_dim=64, state=128,
+        groups=groups, head_block=ssd.HEAD_BLOCK,
+        grid=(1, 64, heads // ssd.HEAD_BLOCK))
+    calls, whiles = _scan_events(text, conf)
+    # the own states, the output, the entering states' cotangent, the rest
+    assert len(calls) == 4
+    for pattern in (conf["ssd_scan_match"], conf["ssd_match"]):
+        assert all(re.search(pattern, ln.strip()) for ln in calls)
+    assert whiles == [["scan"], ["scan", "scan_bwd"]]
+    r = heads // groups
+    assert f"f32[1,{groups},{r},64,128,128]" not in text
+    assert not re.search(r"f32\[[\d,]*128,128,128\]", text)
+    # the other state-space cell's patterns do not claim these calls
+    other = next(c for c in SCAN_CELLS.values() if c != config)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           other + ".json")) as fh:
+        theirs = json.load(fh)["ssd_scan_match"]
+    assert not [ln for ln in calls if re.search(theirs, ln.strip())]

@@ -174,8 +174,8 @@ def test_granite_hybrid_plan_trains_through_local_optimizer_and_is_traced(
     scans = [e for e in legs if e["op"] == "ssd"]
     assert scans and {(e["backend"], e["reason"], e["chunk"], e["chunks"],
                        e["heads"], e["head_dim"], e["state"], e["groups"])
-                      for e in scans} == {("xla", "only-leg", 128, 2, 4, 8,
-                                           16, 1)}
+                      for e in scans} == {("xla", "unsupported-shape", 128, 2,
+                                           4, 8, 16, 1)}
     attn = [e for e in legs if e["op"] == "attention"]
     assert {(e["q_heads"], e["kv_heads"], e["head_dim"], e["scale"])
             for e in attn} == {(4, 2, 16, 0.03125)}

@@ -91,13 +91,24 @@ def test_chunked_scan_is_the_token_by_token_recurrence(chunk, dt_shift,
             np.abs(want).max()))
 
 
-def test_a_carry_zeroed_on_purpose_is_seen(monkeypatch):
+#: a shape the Pallas leg takes: the cells' heads of 64 and state of 128
+KERNEL_SHAPE = dict(p=64, n=128)
+
+
+@pytest.mark.parametrize("leg", ["xla", "pallas"])
+def test_a_carry_zeroed_on_purpose_is_seen(leg, monkeypatch):
     """With decays near 1 most of an output comes from earlier chunks: a
     scan that forgets the state between chunks is far from the
-    recurrence, so the comparison above would refuse it."""
-    args = _scan_inputs(3, -4.0)
+    recurrence, so the comparison above would refuse it.  On both legs:
+    the carry is the one ``_carry`` between the Pallas leg's calls too."""
+    monkeypatch.setenv("BIGDL_KERNELS", leg)
+    chunk, shape = (8, {}) if leg == "xla" else (
+        scan.CHUNK, dict(s=384, h=2, g=1, **KERNEL_SHAPE))
+    args = _scan_inputs(3, -4.0, **shape)
     want = compiled(scan.ssd_recurrent, *args)
-    good = compiled(functools.partial(scan.ssd, chunk=8), *args)
+    dispatch.clear_decisions()
+    good = compiled(functools.partial(scan.ssd, chunk=chunk), *args)
+    assert [d[1] for d in dispatch.decisions() if d[0] == "ssd"] == [leg]
     np.testing.assert_allclose(good, want, rtol=2e-4, atol=2e-5)
 
     real = scan._carry
@@ -107,26 +118,129 @@ def test_a_carry_zeroed_on_purpose_is_seen(monkeypatch):
         return state, jnp.zeros_like(entering)
 
     monkeypatch.setattr(scan, "_carry", forgetful)
-    bad = compiled(functools.partial(scan.ssd, chunk=8), *args)
+    bad = compiled(functools.partial(scan.ssd, chunk=chunk), *args)
     gap = np.abs(np.asarray(bad) - np.asarray(want)).max()
     assert gap > 0.1 * np.abs(np.asarray(want)).max()
 
 
-def test_the_scan_announces_its_one_leg_and_refuses_odd_groups():
-    dispatch.clear_decisions()
-    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
-        (2, 300, 6, 8), (2, 300, 6), (6,), (2, 300, 3, 16), (2, 300, 3, 16),
-        (6,))]
-    out = jax.eval_shape(scan.ssd, *shapes)
+#: dtype, what it is compared with, shift of ``dt``, the kernel's head
+#: block (None: its own), shape.  float32 against the definition, bfloat16
+#: against the XLA leg (the same roundings in the same places); one group
+#: and two; 300 tokens are three chunks, the last padded; heads a group
+#: over TWO grid steps, so that ``dB``, ``dC``, ``d dt``, ``dA`` and ``dD``
+#: are summed over blocks
+KERNEL_SCANS = {
+    "float32-one-group-padded": (
+        jnp.float32, "recurrent", -4.0, None, dict(s=300, h=2, g=1)),
+    "float32-two-groups-two-records": (
+        jnp.float32, "recurrent", 0.0, None, dict(b=2, s=256, h=4, g=2)),
+    "float32-two-blocks-a-group": (
+        jnp.float32, "recurrent", -2.0, 2, dict(s=256, h=4, g=1)),
+    "bfloat16-two-groups": (
+        jnp.bfloat16, "xla", -2.0, None, dict(s=384, h=4, g=2)),
+    "bfloat16-one-group-padded": (
+        jnp.bfloat16, "xla", -4.0, None, dict(s=300, h=4, g=1))}
+
+
+@pytest.mark.parametrize("dtype,against,dt_shift,head_block,shape",
+                         KERNEL_SCANS.values(), ids=KERNEL_SCANS)
+def test_pallas_leg_is_the_scan(dtype, against, dt_shift, head_block, shape,
+                                monkeypatch):
+    """The interpreted kernels around the carry: values, the state after
+    the last token and the gradient by ``x``, ``dt``, ``A``, ``B``, ``C``
+    and ``D``."""
+    if head_block:
+        monkeypatch.setattr(scan, "HEAD_BLOCK", head_block)
+    args = _scan_inputs(5, dt_shift, **shape, **KERNEL_SHAPE)
+    args = tuple(a.astype(dtype) if i in (0, 3, 4) else a
+                 for i, a in enumerate(args))
+    weigh = jnp.asarray(np.random.default_rng(6).standard_normal(
+        args[0].shape), jnp.float32)
+
+    def both(fn, leg):
+        def loss(*a):
+            y, state = fn(*a, return_state=True)
+            return (jnp.sum(y.astype(jnp.float32) * weigh) + jnp.sum(state),
+                    (y, state))
+        monkeypatch.setenv("BIGDL_KERNELS", leg)
+        dispatch.clear_decisions()
+        out = compiled(jax.value_and_grad(loss, argnums=tuple(range(6)),
+                                          has_aux=True), *args)
+        return out, [d for d in dispatch.decisions() if d[0] == "ssd"]
+
+    ((_, (y, state)), grads), (said,) = both(scan.ssd, "pallas")
+    assert tuple(said) == ("ssd", "pallas", "forced:BIGDL_KERNELS=pallas")
+    heads, groups = shape["h"], shape["g"]
+    block = head_block or min(heads // groups, scan.HEAD_BLOCK)
+    assert said.launch["head_block"] == block
+    assert said.launch["grid"] == (shape.get("b", 1), -(-shape["s"] // 128),
+                                   heads // block)
+    ((_, (y0, state0)), grads0), _ = both(
+        scan.ssd_recurrent if against == "recurrent" else
+        functools.partial(scan.ssd, chunk=scan.CHUNK), "xla")
+    assert y.dtype == dtype and state.dtype == jnp.float32
+    # bfloat16: a rounding of y is 2^-8 of it, and the legs round the same
+    # operands, not bit for bit the same sums
+    rtol = 2e-4 if dtype == jnp.float32 else 2e-2
+    close = functools.partial(np.testing.assert_allclose, rtol=rtol)
+    f32 = functools.partial(np.asarray, dtype=np.float32)
+    close(f32(y), f32(y0), atol=rtol * 0.1 * float(np.abs(f32(y0)).max()))
+    close(state, state0, atol=rtol * 0.1 * float(np.abs(state0).max()))
+    for got, want in zip(grads, grads0):
+        close(f32(got), f32(want),
+              atol=(2e-4 if dtype == jnp.float32 else 2e-2)
+              * float(np.abs(f32(want)).max()))
+
+
+def test_the_scan_announces_the_chosen_leg_and_refuses_odd_groups(
+        monkeypatch):
+    """Off the TPU ``auto`` keeps the XLA leg; ``pallas`` takes the kernels
+    where the shape allows (chunk 128, a head of whole sublane tiles, a
+    state of whole lane tiles) and says how they are launched."""
+    def said_for(*shapes):
+        dispatch.clear_decisions()
+        # a function of its own a call: a trace that is found again in the
+        # cache announces nothing, and the knob is read at trace time
+        out = jax.eval_shape(lambda *a: scan.ssd(*a), *(
+            jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes))
+        (said,) = [d for d in dispatch.decisions() if d[0] == "ssd"]
+        return out, said
+
+    small = ((2, 300, 6, 8), (2, 300, 6), (6,), (2, 300, 3, 16),
+             (2, 300, 3, 16), (6,))
+    wide = ((2, 300, 12, 64), (2, 300, 12), (12,), (2, 300, 2, 128),
+            (2, 300, 2, 128), (12,))
+    facts = dict(chunk=128, chunks=3, heads=6, head_dim=8, state=16, groups=3)
+    monkeypatch.delenv("BIGDL_KERNELS", raising=False)
+    out, said = said_for(*small)
     assert out.shape == (2, 300, 6, 8)
-    (said,) = [d for d in dispatch.decisions() if d[0] == "ssd"]
-    assert tuple(said) == ("ssd", "xla", "only-leg")
-    assert said.launch == dict(chunk=128, chunks=3, heads=6, head_dim=8,
-                               state=16, groups=3)
+    assert tuple(said) == ("ssd", "xla", "unsupported-shape")
+    assert said.launch == facts
+    _, said = said_for(*wide)
+    assert tuple(said) == ("ssd", "xla", "auto:off-tpu")
+    wide_facts = dict(facts, heads=12, head_dim=64, state=128, groups=2)
+    assert said.launch == wide_facts
+    monkeypatch.setenv("BIGDL_KERNELS", "pallas")
+    _, said = said_for(*small)
+    assert tuple(said) == ("ssd", "xla", "unsupported-shape")
+    out, said = said_for(*wide)
+    assert out.shape == (2, 300, 12, 64)
+    assert tuple(said) == ("ssd", "pallas", "forced:BIGDL_KERNELS=pallas")
+    assert said.launch == dict(wide_facts, head_block=6, grid=(2, 3, 2))
+    # a state of half a lane tile; a short sequence is one chunk of its
+    # own length
+    narrow = wide[:3] + ((2, 300, 2, 64),) * 2 + wide[5:]
+    assert tuple(said_for(*narrow)[1]) == ("ssd", "xla", "unsupported-shape")
+    short = tuple((2, 100) + s[2:] if len(s) > 1 else s for s in wide)
+    assert tuple(said_for(*short)[1]) == ("ssd", "xla", "unsupported-shape")
+    monkeypatch.setenv("BIGDL_KERNELS", "xla")
+    assert tuple(said_for(*wide)[1]) == ("ssd", "xla",
+                                         "forced:BIGDL_KERNELS=xla")
     assert scan.CHUNK == 128
-    shapes[3] = shapes[4] = jax.ShapeDtypeStruct((2, 300, 4, 16), jnp.float32)
     with pytest.raises(ValueError, match="6 heads over 4 groups"):
-        jax.eval_shape(scan.ssd, *shapes)
+        jax.eval_shape(scan.ssd, *(
+            jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+                small[:3] + ((2, 300, 4, 16),) * 2 + small[5:])))
 
 
 # -- the mixer and its norm -----------------------------------------------------------
@@ -556,8 +670,8 @@ def test_nemotron_h_plan_trains_through_local_optimizer_and_is_traced(
     scans = [e for e in legs if e["op"] == "ssd"]
     assert scans and {(e["backend"], e["reason"], e["chunk"], e["chunks"],
                        e["heads"], e["head_dim"], e["state"], e["groups"])
-                      for e in scans} == {("xla", "only-leg", 128, 2, 4, 8,
-                                           16, 2)}
+                      for e in scans} == {("xla", "unsupported-shape", 128, 2,
+                                           4, 8, 16, 2)}
     attn = [e for e in legs if e["op"] == "attention"]
     assert {(e["q_heads"], e["kv_heads"], e["head_dim"], e["gate"],
              e["qk_norm"]) for e in attn} == {(4, 1, 16, None, False)}
