@@ -186,10 +186,10 @@ class SpatialCrossMapLRN(Module):
         self.format = format
 
     def update_output(self, input):
-        # ops/lrn_pallas.py: the channel window as a banded C x C
+        # ops/lrn.py: the channel window as a banded C x C
         # product in the input's own layout (NCHW or NHWC), exact custom
         # VJP; one leg on every platform, no BIGDL_KERNELS choice
-        from bigdl_tpu.ops.lrn_pallas import cross_map_lrn
+        from bigdl_tpu.ops.lrn import cross_map_lrn
 
         squeeze = input.ndim == 3
         x = input[None] if squeeze else input
@@ -197,7 +197,7 @@ class SpatialCrossMapLRN(Module):
             out = cross_map_lrn(x, self.size, self.alpha, self.beta,
                                 self.k, self.format)
             return out[0] if squeeze else out
-        # rank > 4: generic channel-window reference (no fused kernel)
+        # rank > 4: generic channel-window reference, autodiff's backward
         c_ax = x.ndim - 1 if self.format == "NHWC" else 1
         half = (self.size - 1) // 2
         dims, strides, pads = [1] * x.ndim, [1] * x.ndim, [(0, 0)] * x.ndim
@@ -227,7 +227,7 @@ class SpatialWithinChannelLRN(Module):
         self.size, self.alpha, self.beta = size, alpha, beta
 
     def update_output(self, input):
-        from bigdl_tpu.ops.lrn_pallas import within_channel_lrn
+        from bigdl_tpu.ops.lrn import within_channel_lrn
 
         if input.ndim == 3:
             return within_channel_lrn(input[None], self.size, self.alpha,
@@ -235,7 +235,7 @@ class SpatialWithinChannelLRN(Module):
         if input.ndim == 4:
             return within_channel_lrn(input, self.size, self.alpha,
                                       self.beta)
-        # rank > 4: reference path (no fused kernel / exact VJP)
+        # rank > 4: reference path, autodiff's backward
         half = (self.size - 1) // 2
         dims, strides, pads = [1] * input.ndim, [1] * input.ndim, [(0, 0)] * input.ndim
         for ax in (input.ndim - 2, input.ndim - 1):
@@ -259,7 +259,7 @@ class SpatialSubtractiveNormalization(Module):
         self.register_buffer("kernel", k / k.sum())
 
     def update_output(self, input):
-        from bigdl_tpu.ops.norm_pallas import subtractive_norm
+        from bigdl_tpu.ops.norm import subtractive_norm
 
         squeeze = input.ndim == 3
         x = input[None] if squeeze else input
@@ -283,7 +283,7 @@ class SpatialDivisiveNormalization(Module):
         self.threshold, self.thresval = threshold, thresval
 
     def update_output(self, input):
-        from bigdl_tpu.ops.norm_pallas import divisive_norm
+        from bigdl_tpu.ops.norm import divisive_norm
 
         squeeze = input.ndim == 3
         x = input[None] if squeeze else input

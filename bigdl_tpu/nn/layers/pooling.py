@@ -63,12 +63,13 @@ class _PoolBase(Module):
     """Shared window plumbing over the trailing spatial axes."""
 
     ceil_mode = False
-    #: XLA's select-and-scatter backward (first-argmax ties, bit-parity
-    #: with the reference) benches FASTER on TPU v5e than the unrolled
-    #: tie-split VJP (4,853 vs 3,494 img/s on the Inception-v1 train
-    #: step) — the claim that select-and-scatter dominated the step was
-    #: an attribution error in the round-2 profile.  tie_split() opts
-    #: into the equal-split gradient (residue-class gather backward).
+    #: which maximum of a window with tied maxima takes its cotangent:
+    #: False, the first in (kh, kw) order takes all of it (the
+    #: reference's loop, ``nn/NNPrimitive.scala:594-972``; what
+    #: ``reduce_window``'s select-and-scatter backward does); True, the
+    #: tied positions share it equally (``ops/pool.py
+    #: maxpool_tie_split``: gradient mass is conserved either way, but
+    #: only this one is symmetric in the ties).
     tie_split = False
 
     def torch_ties(self):
@@ -112,29 +113,17 @@ class _PoolBase(Module):
             taps *= d
         if self.tie_split and taps <= self._TIE_SPLIT_MAX_TAPS \
                 and jnp.issubdtype(x.dtype, jnp.floating):
-            # ops/pool_pallas.py: exact equal-tie-split custom VJP,
-            # fused Pallas backward on supported 4-D planes
-            from bigdl_tpu.ops.pool_pallas import maxpool_tie_split
+            # ops/pool.py: exact equal-tie-split custom VJP
+            from bigdl_tpu.ops.pool import maxpool_tie_split
             return maxpool_tie_split(x, dims, strides, tuple(pads))
-        if not self.tie_split:
-            from bigdl_tpu.ops.pooling_pallas import (
-                maxpool_argmax, pallas_pool_supported)
-            if pallas_pool_supported(x, dims, strides, pads):
-                # Pallas argmax-index kernel: same first-argmax tie
-                # semantics as select-and-scatter, but the backward
-                # scatters from a saved int8 tap index instead of
-                # re-reading x and y (round-5 profile: the re-read was
-                # ~28% of the Inception-v1 step)
-                return maxpool_argmax(x, dims, strides, tuple(pads))
         return lax.reduce_window(x, _max_init(x.dtype), lax.max, dims, strides, pads)
 
     def _avg(self, x, count_include_pad: bool, divide: bool = True):
         dims, strides, pads, declared = self._window(x)
-        # ops/pool_pallas.py: the Torch divisor map (declared padding
-        # counts, ceil-overflow never does) is a trace-time numpy
-        # constant there, the window sum a fused kernel, and the
-        # backward the exact linear transpose
-        from bigdl_tpu.ops.pool_pallas import avg_pool
+        # ops/pool.py: the Torch divisor map (declared padding counts,
+        # ceil-overflow never does) is a trace-time numpy constant
+        # there, and the backward the exact linear transpose
+        from bigdl_tpu.ops.pool import avg_pool
         return avg_pool(x, dims, strides, tuple(pads), tuple(declared),
                         count_include_pad, divide)
 

@@ -1,11 +1,13 @@
 """Kernel dispatch: one knob, per-op fallback, observable decisions.
 
-The ops library keeps TWO implementations of a fused op that has a
-kernel — the Pallas kernel (Mosaic-compiled on TPU, ``interpret=True``
-elsewhere so the CPU tier-1 suite exercises the identical code path)
-and an XLA reference built from the same math.  Both sit UNDER the
-op's ``jax.custom_vjp``, so the analytically exact backward holds on
-either leg; this module decides which leg runs.
+Three ops keep TWO implementations, both live: flash attention (through
+its router, ``ops/attention.py select_attention_backend``), the gated
+delta rule (``ops/delta_rule.py``) and the state-space scan
+(``ops/ssd.py``).  Each has a Pallas kernel (Mosaic-compiled on a TPU
+off a mesh, ``interpret=True`` elsewhere so the CPU tier-1 suite
+exercises the identical code path) and an XLA form of the same math (the
+CPU, a partitioned step, a shape the kernel does not take), both UNDER
+the op's ``jax.custom_vjp``; this module decides which runs.
 
 Knob: ``BIGDL_KERNELS`` (read at trace time):
 
@@ -16,7 +18,7 @@ Knob: ``BIGDL_KERNELS`` (read at trace time):
   suite never silently drops onto the (slow) Pallas interpreter.
 - ``pallas`` — Pallas whenever the shape is structurally supported;
   off-TPU this means interpret mode (the parity tests' setting).
-- ``xla`` — the reference leg everywhere, a process-wide kill switch.
+- ``xla`` — the XLA form everywhere, a process-wide kill switch.
 
 Every decision is emitted as a ``kernel/dispatch`` telemetry instant
 (op, backend, reason) at TRACE time — one instant per compilation, not
@@ -25,15 +27,13 @@ HLO actually contains.  The reasons: ``forced:BIGDL_KERNELS=<mode>``,
 ``unsupported-shape``, ``auto:tpu``, ``auto:off-tpu``,
 ``auto:spmd-partitioned`` from :func:`choose_backend`; from an op that
 has one form and says so through :func:`note`, ``only-leg`` (the
-short convolution, the cross-map LRN's banded product: the knob does not
-reach them in any mode) and ``whole-plane``
-(an average pool whose window is the whole padded plane: a fused
-reduction in plain ``jnp`` in every mode, ``pool_pallas.avg_pool``).
-A leg that launches through
-``pallas_util.plane_call`` adds how it was launched
-(``planes_per_block``, ``grid``: :func:`launched`).  A small in-process
-ring (:func:`decisions`) records the same for tests and the micro-bench
-harness.
+short convolution, both LRNs, the Torch-legacy normalisations'
+smoothing, the tie-split max pool and the average pool: the knob does
+not reach them in any mode) and ``whole-plane`` (an average pool whose
+window is the whole padded plane: a fused reduction, ``pool.avg_pool``).
+A leg adds what it was launched with through :func:`launched` (``ssd``:
+its chunking, ``head_block`` and ``grid``).  A small in-process ring
+(:func:`decisions`) records the same for tests.
 
 Caveat: the knob is read when a function is traced.  A jit-cached
 executable does not re-dispatch when the env changes; tests flip the
@@ -59,9 +59,9 @@ MODES = ("auto", "pallas", "xla")
 
 class Decision(tuple):
     """One ``(op, backend, reason)`` triple — it unpacks, compares and
-    sorts as that — which also carries, in ``launch``, what the leg's
-    launcher said of itself (``plane_call``: ``planes_per_block`` and
-    ``grid``; empty for a leg that reports nothing)."""
+    sorts as that — which also carries, in ``launch``, what the leg
+    said of itself (``ssd``: ``head_block`` and ``grid``; empty for a
+    leg that reports nothing)."""
 
     launch: Dict[str, object]
 
@@ -148,18 +148,18 @@ def auto_pallas() -> Tuple[bool, str]:
 
 def note(op: str, backend: str, reason: str, **launch) -> None:
     """Record + emit one dispatch decision (shared by :func:`dispatch`
-    and call sites with bespoke selection logic, e.g. the argmax pool
-    and the attention auto-backend)."""
+    and call sites with one leg or selection logic of their own, e.g.
+    the attention auto-backend)."""
     _DECISIONS.append(Decision(op, backend, reason, **launch))
     telemetry.instant("kernel/dispatch", op=op, backend=backend,
                       reason=reason, **launch)
 
 
 def launched(**facts) -> None:
-    """A launcher's word on the leg being dispatched, at trace time:
-    ``plane_call`` reports ``planes_per_block`` and ``grid`` here, and
-    they ride on that leg's decision.  Outside :func:`dispatch` (a
-    kernel called bare) there is no decision to ride on."""
+    """A leg's word on how it was launched, at trace time: ``ssd``
+    reports its chunking, ``head_block`` and ``grid`` here, and they
+    ride on that leg's decision.  Outside :func:`dispatch` (a kernel
+    called bare) there is no decision to ride on."""
     holder = _LAUNCH.get()
     if holder is not None:
         holder.update(facts)
@@ -169,7 +169,7 @@ def dispatch(op: str, pallas_fn: Callable, xla_fn: Callable,
              supported: bool, *args, **kwargs):
     """Run ``pallas_fn`` or ``xla_fn`` per :func:`choose_backend`,
     recording the decision — once the leg is traced, so that it holds
-    what the leg's launcher :func:`launched`.  Called at trace time
+    what the leg :func:`launched`.  Called at trace time
     inside the op's custom-vjp forward/backward rules."""
     backend, reason = choose_backend(op, supported)
     fn = pallas_fn if backend == "pallas" else xla_fn

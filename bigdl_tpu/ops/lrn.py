@@ -38,12 +38,9 @@ calls' 6.35 ms a step the kernel's six pads cost 3.44 and eight layout
 copies 4.96).  The four-chip cell, NHWC models and the CPU always ran
 the banded product.
 
-Within-channel keeps two legs under its ``custom_vjp``, a per-plane
-Pallas kernel (``pallas_util.plane_call``; ``interpret=True`` off the
-TPU, which is what the parity tests pin) and an XLA ``reduce_window``
-reference built from the same formulas, chosen per leg by
-``ops.dispatch``: no cell runs it and nothing has timed it (ROADMAP
-D3).
+Within-channel has one leg too, ``lax.reduce_window`` sums under the
+formulas above, announced the same way (``op=lrn_within_channel.fwd|
+.bwd``): no cell runs it.
 """
 
 from __future__ import annotations
@@ -57,12 +54,8 @@ import numpy as np
 from jax import lax
 
 from bigdl_tpu.ops import dispatch as _dispatch
-from bigdl_tpu.ops.pallas_util import (TPU_DTYPES as _TPU_DTYPES,
-                                       VMEM_BUDGET as _VMEM_BUDGET,
-                                       plane_call as _plane_call)
 
-__all__ = ["cross_map_lrn", "within_channel_lrn",
-           "within_channel_lrn_supported"]
+__all__ = ["cross_map_lrn", "within_channel_lrn"]
 
 
 def _pow(s, p: float):
@@ -71,10 +64,6 @@ def _pow(s, p: float):
     if p == -0.5:
         return lax.rsqrt(s)
     return jnp.exp(p * jnp.log(s))
-
-
-def _on_tpu_compiled() -> bool:
-    return not _dispatch.use_interpret()
 
 
 # ---------------------------------------------------------------------------
@@ -160,80 +149,8 @@ cross_map_lrn.defvjp(_cml_vjp_fwd, _cml_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
-# within-channel LRN: spatial-window sum, layout [N*C, Hpad, Wpad]
+# within-channel LRN: spatial-window sums over NCHW
 # ---------------------------------------------------------------------------
-
-def within_channel_lrn_supported(x, size: int) -> bool:
-    if x.ndim != 4 or size < 1:
-        return False
-    if _on_tpu_compiled():
-        if x.dtype not in _TPU_DTYPES:
-            return False
-        h, w = x.shape[2], x.shape[3]
-        hp, wp = h + size - 1, w + size - 1
-        # ~4 live [Hp, Wp] planes per block (x, sq, accumulator, out)
-        if 4 * hp * wp * jnp.dtype(x.dtype).itemsize > _VMEM_BUDGET:
-            return False
-    return True
-
-
-def _wcl_fwd_kernel(xp_ref, y_ref, sc_ref, *, h: int, w: int, size: int,
-                    lo: int, alpha: float, beta: float):
-    xp = xp_ref[...]                    # [P, Hp, Wp]: a block of planes
-    sq = xp * xp
-    ws = None
-    for dh in range(size):
-        for dw in range(size):
-            tap = sq[:, dh:dh + h, dw:dw + w]
-            ws = tap if ws is None else ws + tap
-    scale = 1.0 + ws * (alpha / (size * size))
-    sc_ref[...] = scale
-    y_ref[...] = xp[:, lo:lo + h, lo:lo + w] * _pow(scale, -beta)
-
-
-def _wcl_bwd_kernel(tp_ref, x_ref, g_ref, sc_ref, dx_ref, *, h: int,
-                    w: int, size: int, alpha: float, beta: float):
-    tp = tp_ref[...]                    # transpose-padded t, [P, Hp, Wp]
-    ts = None
-    for dh in range(size):
-        for dw in range(size):
-            tap = tp[:, dh:dh + h, dw:dw + w]
-            ts = tap if ts is None else ts + tap
-    g = g_ref[...]
-    x = x_ref[...]
-    scale = sc_ref[...]
-    dx_ref[...] = g * _pow(scale, -beta) \
-        - (2.0 * alpha * beta / (size * size)) * x * ts
-
-
-def _wcl_fwd_pallas(x, size, alpha, beta):
-    n, c, h, w = x.shape
-    lo, hi = (size - 1) // 2, size - 1 - (size - 1) // 2
-    planes = x.reshape(n * c, h, w)
-    xp = jnp.pad(planes, ((0, 0), (lo, hi), (lo, hi)))
-    kern = functools.partial(_wcl_fwd_kernel, h=h, w=w, size=size, lo=lo,
-                             alpha=alpha, beta=beta)
-    y, scale = _plane_call(kern, [xp],
-                           [((h, w), x.dtype), ((h, w), x.dtype)], n * c,
-                           _dispatch.use_interpret())
-    return y.reshape(n, c, h, w), scale.reshape(n, c, h, w)
-
-
-def _wcl_bwd_pallas(x, scale, g, size, alpha, beta):
-    n, c, h, w = x.shape
-    lo, hi = (size - 1) // 2, size - 1 - (size - 1) // 2
-    t = (g * x * _pow(scale, -beta - 1.0)).reshape(n * c, h, w)
-    # TRANSPOSE pads (hi, lo): position m gathers windows o with
-    # m in [o-lo, o+hi]  <=>  o in [m-hi, m+lo]
-    tp = jnp.pad(t, ((0, 0), (hi, lo), (hi, lo)))
-    flat = lambda a: a.reshape(n * c, h, w)  # noqa: E731
-    kern = functools.partial(_wcl_bwd_kernel, h=h, w=w, size=size,
-                             alpha=alpha, beta=beta)
-    dx = _plane_call(kern, [tp, flat(x), flat(g), flat(scale)],
-                     [((h, w), x.dtype)], n * c,
-                     _dispatch.use_interpret())
-    return dx.reshape(n, c, h, w)
-
 
 def _win_sum(v, size: int, pads: Tuple[int, int]):
     dims = (1, 1, size, size)
@@ -242,13 +159,15 @@ def _win_sum(v, size: int, pads: Tuple[int, int]):
                              (1, 1, 1, 1), p)
 
 
-def _wcl_fwd_xla(x, size, alpha, beta):
+def _wcl_fwd(x, size, alpha, beta):
+    _dispatch.note("lrn_within_channel.fwd", "xla", "only-leg")
     lo, hi = (size - 1) // 2, size - 1 - (size - 1) // 2
     scale = 1.0 + _win_sum(x * x, size, (lo, hi)) * (alpha / (size * size))
     return x * _pow(scale, -beta), scale
 
 
-def _wcl_bwd_xla(x, scale, g, size, alpha, beta):
+def _wcl_bwd(x, scale, g, size, alpha, beta):
+    _dispatch.note("lrn_within_channel.bwd", "xla", "only-leg")
     lo, hi = (size - 1) // 2, size - 1 - (size - 1) // 2
     t = g * x * _pow(scale, -beta - 1.0)
     ts = _win_sum(t, size, (hi, lo))
@@ -263,12 +182,6 @@ def within_channel_lrn(x, size: int, alpha: float, beta: float):
     return y
 
 
-def _wcl_fwd(x, size, alpha, beta):
-    return _dispatch.dispatch(
-        "lrn_within_channel.fwd", _wcl_fwd_pallas, _wcl_fwd_xla,
-        within_channel_lrn_supported(x, size), x, size, alpha, beta)
-
-
 def _wcl_vjp_fwd(x, size, alpha, beta):
     y, scale = _wcl_fwd(x, size, alpha, beta)
     return y, (x, scale)
@@ -276,11 +189,7 @@ def _wcl_vjp_fwd(x, size, alpha, beta):
 
 def _wcl_vjp_bwd(size, alpha, beta, res, g):
     x, scale = res
-    dx = _dispatch.dispatch(
-        "lrn_within_channel.bwd", _wcl_bwd_pallas, _wcl_bwd_xla,
-        within_channel_lrn_supported(x, size), x, scale, g, size, alpha,
-        beta)
-    return (dx,)
+    return (_wcl_bwd(x, scale, g, size, alpha, beta),)
 
 
 within_channel_lrn.defvjp(_wcl_vjp_fwd, _wcl_vjp_bwd)

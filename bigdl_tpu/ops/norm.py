@@ -2,16 +2,11 @@
 VJPs.
 
 These Torch-legacy ops are built around a kernel-weighted spatial
-smoothing of a channel-reduced map.  The reference (and the previous
-layer implementation) expresses the smoothing as a 1-channel depthwise
-``lax.conv`` — on TPU that is the WORST conv shape there is: a single
-input/output channel leaves the 128x128 MXU >99% idle and the op runs
-as serialized HBM-bound window traffic.  The smoothing is really a
-``kh*kw``-tap shift-accumulate on the VPU, which is exactly what the
-Pallas kernel here does (whole padded planes per block, unrolled static
-shifts, one write).  Channel reduction, division and thresholding stay
-in XLA — they are elementwise/small reductions XLA fuses into the
-adjacent kernels already.
+smoothing of a channel-reduced map, written as the reference writes it:
+a 1-channel ``lax.conv`` (``smooth2d``; a poor shape for the MXU, but
+no registry model builds one of these layers and no cell times them).
+Channel reduction, division and thresholding are elementwise/small
+reductions XLA fuses into their neighbours.
 
 VJP derivations (g = upstream cotangent, C = channel count):
 
@@ -35,7 +30,8 @@ VJP derivations (g = upstream cotangent, C = channel count):
 
 The smoothing kernel is a module BUFFER, never trained — its cotangent
 is defined as zero (``lax.stop_gradient`` semantics), matching the
-framework's buffer contract.  Backend per leg via ``ops.dispatch``.
+framework's buffer contract.  One leg, announced to ``ops.dispatch`` as
+``norm_smooth.fwd|.bwd backend=xla reason=only-leg``.
 """
 
 from __future__ import annotations
@@ -47,12 +43,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from bigdl_tpu.ops import dispatch as _dispatch
-from bigdl_tpu.ops.pallas_util import (TPU_DTYPES as _TPU_DTYPES,
-                                       VMEM_BUDGET as _VMEM_BUDGET,
-                                       plane_call as _plane_call)
 
-__all__ = ["smooth2d", "smooth2d_supported", "subtractive_norm",
-           "divisive_norm", "contrastive_norm"]
+__all__ = ["smooth2d", "subtractive_norm", "divisive_norm",
+           "contrastive_norm"]
 
 
 def _fwd_pads(kh: int, kw: int):
@@ -64,46 +57,13 @@ def _transpose_pads(kh: int, kw: int):
     return (ahi, alo), (bhi, blo)
 
 
-def smooth2d_supported(stack, kernel) -> bool:
-    """Pallas-leg gate for the smoothing kernel: [B, H, W] stack, 2-D
-    kernel; on real TPU additionally a Mosaic dtype + VMEM fit."""
-    if stack.ndim != 3 or kernel.ndim != 2:
-        return False
-    if not _dispatch.use_interpret():
-        if stack.dtype not in _TPU_DTYPES:
-            return False
-        hp = stack.shape[1] + kernel.shape[0] - 1
-        wp = stack.shape[2] + kernel.shape[1] - 1
-        if 3 * hp * wp * jnp.dtype(stack.dtype).itemsize > _VMEM_BUDGET:
-            return False
-    return True
-
-
-def _smooth_kernel(vp_ref, w_ref, out_ref, *, h: int, w: int, kh: int,
-                   kw: int, flip: bool):
-    vp = vp_ref[...]                    # [P, Hp, Wp] padded planes
-    acc = None
-    for i in range(kh):
-        for j in range(kw):
-            wt = w_ref[kh - 1 - i, kw - 1 - j] if flip else w_ref[i, j]
-            tap = vp[:, i:i + h, j:j + w] * wt
-            acc = tap if acc is None else acc + tap
-    out_ref[...] = acc
-
-
-def _smooth_pallas(stack, kernel, pads, flip: bool):
-    b, h, w = stack.shape
-    kh, kw = kernel.shape
-    (alo, ahi), (blo, bhi) = pads
-    vp = jnp.pad(stack, ((0, 0), (alo, ahi), (blo, bhi)))
-    kern = functools.partial(_smooth_kernel, h=h, w=w, kh=kh, kw=kw,
-                             flip=flip)
-    return _plane_call(kern, [vp, kernel.astype(stack.dtype)],
-                       [((h, w), stack.dtype)], b,
-                       _dispatch.use_interpret(), bcast=(1,))
-
-
-def _smooth_xla(stack, kernel, pads, flip: bool):
+def smooth2d(stack, kernel, pads, flip: bool = False):
+    """Kernel-weighted window sum over a [B, H, W] plane stack (the
+    shared primitive under all three normalizations; ``flip=True`` with
+    swapped pads is the exact transpose).  NOT differentiable on its
+    own — always called inside a custom-vjp fwd/bwd rule."""
+    _dispatch.note("norm_smooth.bwd" if flip else "norm_smooth.fwd", "xla",
+                   "only-leg")
     k = kernel[::-1, ::-1] if flip else kernel
     v = stack[:, None]                  # [B, 1, H, W]
     w4 = k.astype(stack.dtype)[None, None]
@@ -112,17 +72,6 @@ def _smooth_xla(stack, kernel, pads, flip: bool):
     out = lax.conv_general_dilated(v, w4, (1, 1), pads,
                                    dimension_numbers=dn)
     return out[:, 0]
-
-
-def smooth2d(stack, kernel, pads, flip: bool = False):
-    """Kernel-weighted window sum over a [B, H, W] plane stack (the
-    shared primitive under all three normalizations; ``flip=True`` with
-    swapped pads is the exact transpose).  NOT differentiable on its
-    own — always called inside a custom-vjp fwd/bwd rule."""
-    op = "norm_smooth.bwd" if flip else "norm_smooth.fwd"
-    return _dispatch.dispatch(
-        op, _smooth_pallas, _smooth_xla,
-        smooth2d_supported(stack, kernel), stack, kernel, pads, flip)
 
 
 def _coef(kernel, h: int, w: int, dtype):

@@ -129,9 +129,9 @@ STREAM_NAMES = frozenset({
     # kernel dispatch (bigdl_tpu/ops/dispatch.py): one instant per
     # TRACE-time backend decision — op, backend (pallas|xla), reason —
     # so attribution can name which backend each module compiled to;
-    # a leg adds how it was launched (plane kernels: planes_per_block,
-    # grid; op=gated_delta_rule: leg, chunk, chunks, heads, key_dim,
-    # value_dim and, on its Pallas leg, chunks_per_block, grid;
+    # a leg adds how it was launched (op=gated_delta_rule: leg, chunk,
+    # chunks, heads, key_dim, value_dim and, on its Pallas leg,
+    # chunks_per_block, grid;
     # op=attention: window, q_heads, kv_heads, head_dim, scale (null: 1
     # over the root of head_dim) and the flash leg's blocks;
     # op=gated_short_conv: taps, channels, tokens; op=ssd: chunk, chunks,

@@ -67,7 +67,6 @@ time inside jitted-program construction):
 |-----------------------|----------|
 | BIGDL_FLASH_BLOCK_Q/K | ops.attention flash block sizes (default 1024/512 — round-5 hardware sweep) |
 | BIGDL_FLASH_MIN_SEQ   | ops.attention auto-backend threshold (default 512; dense below) |
-| BIGDL_POOL_KERNEL     | ops.pooling_pallas argmax-index pool (off/auto/on/interpret; auto=off — see BASELINE.md postmortem) |
 | BIGDL_COMPILE_CACHE   | Engine.enable_compile_cache persistent XLA executable cache dir (0 = off; JAX_COMPILATION_CACHE_DIR, when set, wins; default <checkout>/.jax_cache) |
 | BIGDL_COMPILE_CACHE_MIN_S | Engine.enable_compile_cache min compile seconds for an entry to persist (default 0.1) |
 | BIGDL_COORDINATOR_TIMEOUT | Engine._init_distributed bounded jax.distributed join (s, default 300; 0 = unbounded) |

@@ -1,4 +1,5 @@
-"""The kernels of the main path, compiled for a described TPU v5e.
+"""The kernels of the main path, and the CNN cells' ops that are none,
+compiled for a described TPU v5e.
 
 No chip is attached here: the installed TPU compiler lowers for a
 ``v5e:2x2`` topology that is described, not present, and raises what
@@ -23,10 +24,9 @@ import jax.numpy as jnp
 import pytest
 
 from bigdl_tpu.ops import attention, dispatch
-from bigdl_tpu.ops.lrn_pallas import cross_map_lrn, within_channel_lrn
-from bigdl_tpu.ops.pool_pallas import avg_pool, maxpool_tie_split
-from test_kernels import (ONLY_LEG, WHOLE_PLANE, _assert_ragged_blocks,
-                          _launches)
+from bigdl_tpu.ops.lrn import cross_map_lrn
+from bigdl_tpu.ops.pool import avg_pool
+from test_kernels import ONLY_LEG, WHOLE_PLANE, _only_leg
 
 pytestmark = pytest.mark.usefixtures(
     "described_compiles_stay_out_of_the_cache")
@@ -45,11 +45,6 @@ def _fwd_bwd_text(op, shape, dtype, sharding, n_in=1):
     g = jax.ShapeDtypeStruct(jax.eval_shape(op, *xs).shape, dtype,
                              sharding=sharding)
     return jax.jit(fwd_bwd).lower(*xs, g).compile().as_text()
-
-
-def _backends(op_prefix):
-    return {(op, b) for op, b, _ in dispatch.decisions()
-            if op.startswith(op_prefix)}
 
 
 @pytest.mark.parametrize("shape", [(8, 8, 512, 64), (2, 8, 4096, 64)])
@@ -107,37 +102,12 @@ def test_inception_stem_holds_no_kernel_and_no_packed_plane(one_chip,
     assert not re.search(r"\[\d+,\d+,3200\]", text)
 
 
-def test_within_channel_lrn_compiles(one_chip, as_tpu):
-    text = _fwd_bwd_text(lambda x: within_channel_lrn(x, 5, 1e-4, 0.75),
-                         (8, 32, 32, 32), jnp.float32, one_chip)
-    assert "tpu_custom_call" in text
-
-
-def _pool_window(k, s, pad=((0, 0), (0, 0))):
-    return (1, 1, k, k), (1, 1, s, s), ((0, 0), (0, 0)) + tuple(pad)
-
-
-def _same_pool(x):
-    """Inception-v1's 3x3/s1 "same" branch pool: a window that slides
-    over its plane, the average pool that stays a plane kernel."""
-    dims, strides, pads = _pool_window(3, 1, ((1, 1), (1, 1)))
-    return avg_pool(x, dims, strides, pads, pads, True, True)
-
-
-def test_avg_pool_stride1_compiles(one_chip, as_tpu):
-    """A 3x3/s1 branch pool on a 28x28 plane stays a Pallas kernel."""
-    text = _fwd_bwd_text(_same_pool, (32, 256, 28, 28), jnp.bfloat16,
-                         one_chip)
-    assert _backends("pool_avg") == {
-        ("pool_avg.fwd", "pallas"), ("pool_avg.bwd", "pallas")}
-    assert "tpu_custom_call" in text
-
-
 def _head(x, w):
     """``relu -> 7x7 head pool -> product``, the pool between the
     neighbours XLA may fuse it into."""
-    dims, strides, pads = _pool_window(7, 1)
-    y = avg_pool(jax.nn.relu(x), dims, strides, pads, pads, True, True)
+    pads = ((0, 0),) * 4
+    y = avg_pool(jax.nn.relu(x), (1, 1, 7, 7), (1, 1, 1, 1), pads, pads,
+                 True, True)
     return y.reshape(y.shape[:2]) @ w
 
 
@@ -162,7 +132,6 @@ def test_head_pool_adds_no_instruction_of_its_own(shape, one_chip, as_tpu):
                              sharding=one_chip)
     text = jax.jit(fwd_bwd).lower(x, w, g).compile().as_text()
     assert set(dispatch.decisions()) == WHOLE_PLANE
-    assert not _launches("pool_avg")
     for banned in ("tpu_custom_call", " pad(", "reduce-window"):
         assert banned not in text, banned
     for ln in text.splitlines():
@@ -170,75 +139,37 @@ def test_head_pool_adds_no_instruction_of_its_own(shape, one_chip, as_tpu):
         assert not re.search(r"= \w+\[[\d,]*7,7\]\S* copy\(", ln), ln
 
 
-def test_pool_compiles_with_a_ragged_last_block(one_chip, as_tpu):
-    """300 planes of 6x6 under a 3x3/s1 window, 256 a grid step: the
-    second block is 44 planes and Mosaic has to take it."""
-    text = _fwd_bwd_text(_same_pool, (3, 100, 6, 6), jnp.bfloat16, one_chip)
-    assert text.count("tpu_custom_call") >= 2
-    _assert_ragged_blocks(_launches("pool_avg"))
+@pytest.mark.parametrize("site", ["aux_head", "branch_pool"])
+def test_sliding_average_pools_lower_without_a_kernel(site, one_chip,
+                                                      as_tpu):
+    """What a TPU off a mesh compiled as plane kernels until PR 46, now
+    the one XLA form: an auxiliary head of Inception-v1 (its 5x5/s3
+    ceil-mode pool to the classifier) and a 3x3/s1 "same" branch pool,
+    forward and backward in bf16.  Lowered text only, no compile."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.models.inception import _aux_head
+    from bigdl_tpu.nn.module import functional_call, state_dict
 
+    block, shape = {
+        "aux_head": (_aux_head(512, "loss1", 1000).evaluate(),
+                     (32, 512, 14, 14)),
+        "branch_pool": (nn.Sequential(
+            nn.SpatialAveragePooling(3, 3, 1, 1, 1, 1)), (32, 256, 28, 28)),
+    }[site]
 
-def _pallas_grids(fn, *args):
-    """[(grid, [block shapes])] of every ``pallas_call`` traced in
-    ``fn``'s value and VJP."""
-    found = []
+    def shaped(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                gm = eqn.params["grid_mapping"]
-                found.append((tuple(gm.grid),
-                              [tuple(getattr(d, "block_size", d)
-                                     for d in b.block_shape)
-                               for b in gm.block_mappings]))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
+    def fwd_bwd(state, x):
+        y, vjp = jax.vjp(lambda s, a: functional_call(block, s, a)[0],
+                         state, x)
+        return y, vjp(y)
 
-    def fwd_bwd(x):
-        y, vjp = jax.vjp(fn, x)
-        return vjp(y)
-
-    walk(jax.make_jaxpr(fwd_bwd)(*args).jaxpr)
-    return found
-
-
-def test_large_planes_keep_one_plane_a_grid_step(as_tpu):
-    """What the block rule must leave alone: a plane stack whose planes
-    are large (within-channel LRN on 384x384 images) comes out of the
-    shared launcher at one plane a step, grid (N*C,), as before."""
-    x = jax.ShapeDtypeStruct((2, 3, 384, 384), jnp.float32)
-    grids = _pallas_grids(lambda a: within_channel_lrn(a, 5, 1e-4, 0.75), x)
-    assert [g for g, _ in grids] == [(6,), (6,)]
-    assert {b[0] for _, blocks in grids for b in blocks} == {1}
-    assert _launches("lrn_within_channel") == {
-        "lrn_within_channel.fwd": {"planes_per_block": 1, "grid": (6,)},
-        "lrn_within_channel.bwd": {"planes_per_block": 1, "grid": (6,)}}
-
-
-@pytest.mark.parametrize("name,shape,make_op", [
-    # the aux heads' 5x5/s3 ceil-mode pool (14 -> 4: one overflow row)
-    ("pool_avg", (32, 512, 14, 14), lambda: (
-        lambda x: avg_pool(x, *_pool_window(5, 3, ((0, 1), (0, 1))),
-                           ((0, 0),) * 4, True, True))),
-    # the stem's 3x3/s2 ceil-mode max pool under split_ties()
-    ("pool_tie_split", (32, 64, 112, 112), lambda: (
-        lambda x: maxpool_tie_split(
-            x, *_pool_window(3, 2, ((0, 1), (0, 1)))))),
-])
-def test_strided_pool_compiles_or_takes_xla(name, shape, make_op,
-                                            one_chip, as_tpu):
-    """Mosaic refuses a strided vector slice, so on a TPU ``auto`` must
-    either compile the strided pool or route it to the XLA leg with the
-    decision recorded — never hand the chip a kernel it rejects."""
-    text = _fwd_bwd_text(make_op(), shape, jnp.bfloat16, one_chip)
-    took = {b for _, b in _backends(name)}
-    assert took, "no dispatch decision recorded"
-    if "pallas" in took:
-        assert "tpu_custom_call" in text
-    else:
-        reasons = {r for op, _, r in dispatch.decisions()
-                   if op.startswith(name)}
-        assert reasons == {"unsupported-shape"}
+    state = jax.tree.map(lambda a: shaped(a.shape), state_dict(block))
+    text = jax.jit(fwd_bwd).lower(state, shaped(shape)).as_text()
+    assert set(dispatch.decisions()) == _only_leg("pool_avg")
+    assert "reduce_window" in text
+    assert "tpu_custom_call" not in text
 
 
 def test_partitioned_step_takes_xla_leg(topo, as_tpu):
@@ -246,21 +177,31 @@ def test_partitioned_step_takes_xla_leg(topo, as_tpu):
     compiler refuses a Mosaic kernel there ("cannot be automatically
     partitioned"): inside ``spmd_partitioned`` — the scope TrainStep and
     EvalStep trace under — ``auto`` must take the XLA leg and say so
-    (a two-legged op: within-channel LRN)."""
+    (a two-legged op: the state-space scan, at shapes its kernels
+    take)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from bigdl_tpu.ops.ssd import ssd
+
     mesh = Mesh(np.array(topo.devices), ("data",))
-    batch = NamedSharding(mesh, P("data"))
 
-    def fwd_bwd(x, g):
+    def shaped(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    def fwd_bwd(x, dt, a, b, c, d):
         with dispatch.spmd_partitioned(mesh):
-            y, vjp = jax.vjp(lambda a: within_channel_lrn(a, 5, 1e-4, 0.75),
-                             x)
-            return y, vjp(g)
+            y, vjp = jax.vjp(lambda x: ssd(x, dt, a, b, c, d), x)
+            return y, vjp(y)
 
-    x = jax.ShapeDtypeStruct((32, 64, 56, 56), jnp.bfloat16, sharding=batch)
-    text = jax.jit(fwd_bwd).lower(x, x).compile().as_text()
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    heads = shaped((16,), f32)
+    proj = shaped((4, 256, 1, 128), bf16, "data")
+    text = jax.jit(fwd_bwd).lower(
+        shaped((4, 256, 16, 64), bf16, "data"),
+        shaped((4, 256, 16), f32, "data"), heads, proj, proj,
+        heads).compile().as_text()
     assert "tpu_custom_call" not in text
-    assert {(b, r) for _, b, r in dispatch.decisions()} == {
-        ("xla", "auto:spmd-partitioned")}
+    assert [tuple(d) for d in dispatch.decisions()] == [
+        ("ssd", "xla", "auto:spmd-partitioned")]

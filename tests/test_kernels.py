@@ -1,71 +1,78 @@
-"""Kernel-library parity + dispatch tests (bigdl_tpu/ops/).
+"""Ops-library tests (bigdl_tpu/ops/): every op against its definition,
+and the dispatch contract.
 
-A fused op with a kernel keeps two legs under one ``jax.custom_vjp`` —
-the Pallas kernel (interpret mode on this CPU suite: the IDENTICAL code
-path that Mosaic compiles on TPU) and the XLA reference.  Parity must
-hold on forward values AND the hand-derived VJP cotangents, across odd
-shapes, dtypes, and ceil/asymmetric-padding edges;
-``tests/test_numeric_grads.py`` separately pins both legs against
-finite differences.  The cross-map LRN has ONE leg (the banded product,
-in every mode): it is held to its definition instead, a channel-window
-sum in float32 with autodiff's backward.
+The plane family (both LRNs, the three Torch-legacy normalisations, the
+tie-split max pool, the average pool) has ONE leg each, plain ``jnp``
+under a ``jax.custom_vjp`` with a hand-derived backward.  Each is held
+to its definition: the same math written the obvious way in float32
+with autodiff's backward, across odd shapes, dtypes and
+ceil/asymmetric-padding edges; ``tests/test_numeric_grads.py``
+separately pins the backwards against finite differences.
 
-The dispatch layer's contract is pinned here too: ``BIGDL_KERNELS=xla``
-bypasses Pallas EVERYWHERE (the process-wide kill switch), ``pallas``
-forces the kernels, a typo'd value raises instead of silently
-defaulting, and every decision lands in the decision ring + the
-``kernel/dispatch`` telemetry stream.
+The dispatch layer's contract is pinned here too, on a specimen op of
+two trivial legs: ``BIGDL_KERNELS=xla`` bypasses Pallas EVERYWHERE (the
+process-wide kill switch), ``pallas`` forces the kernel leg, a typo'd
+value raises instead of silently defaulting, every decision lands in
+the decision ring + the ``kernel/dispatch`` telemetry stream, and no
+mode reaches an op of the plane family.  The three ops that do have a
+kernel are tested where their layers are (``test_attention.py``,
+``test_linear_attention.py``, ``test_ssm.py``).
 """
+
+import itertools
+import pathlib
+import re
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from bigdl_tpu.ops import dispatch
-from bigdl_tpu.ops.lrn_pallas import cross_map_lrn, within_channel_lrn
-from bigdl_tpu.ops.norm_pallas import (contrastive_norm, divisive_norm,
-                                       subtractive_norm)
-from bigdl_tpu.ops.pool_pallas import avg_pool, maxpool_tie_split
+from bigdl_tpu.ops.lrn import cross_map_lrn, within_channel_lrn
+from bigdl_tpu.ops.norm import (contrastive_norm, divisive_norm,
+                                subtractive_norm)
+from bigdl_tpu.ops.pool import avg_pool, maxpool_tie_split
+
+BF16_TOL = dict(rtol=2e-2, atol=2e-2)
 
 
 def _rng(seed=0):
     return np.random.RandomState(seed)
 
 
-def _both_legs(fn, x, seed=1, rtol=1e-5, atol=1e-6, monkeypatch=None):
-    """Run fn's value+VJP on both dispatch legs and assert parity."""
-    outs = {}
-    for mode in ("xla", "pallas"):
-        monkeypatch.setenv("BIGDL_KERNELS", mode)
-        dispatch.clear_decisions()
-        # compiled (run eagerly, a Pallas kernel in interpret mode
-        # dispatches every primitive of its body as its own program),
-        # through a function of this leg's own so that no trace of the
-        # other leg can be found in a cache
-        y, vjp = jax.vjp(jax.jit(lambda a: fn(a)), x)
-        assert dispatch.decisions(), f"{mode} leg was not traced"
-        outs[mode] = (y, vjp)
-    y1, vjp1 = outs["xla"]
-    y2, vjp2 = outs["pallas"]
-    np.testing.assert_allclose(np.asarray(y1, np.float32),
-                               np.asarray(y2, np.float32),
+def _only_leg(*ops):
+    return {(f"{op}.{leg}", "xla", "only-leg")
+            for op in ops for leg in ("fwd", "bwd")}
+
+
+def _against_definition(fn, definition, x, seed=1, rtol=1e-5, atol=1e-6):
+    """Value and VJP of an op's one leg against its definition's, which
+    is given ``x`` in float32 and differentiated by autodiff; returns
+    the decisions the op's trace announced."""
+    dispatch.clear_decisions()
+    y, vjp = jax.vjp(jax.jit(fn), x)
+    said = set(dispatch.decisions())
+    y0, vjp0 = jax.vjp(jax.jit(definition), x.astype(jnp.float32))
+    assert y.dtype == x.dtype and y.shape == y0.shape
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y0),
                                rtol=rtol, atol=atol)
-    gy = jnp.asarray(_rng(seed).randn(*y1.shape).astype(np.float32),
-                     y1.dtype)
-    np.testing.assert_allclose(np.asarray(vjp1(gy)[0], np.float32),
-                               np.asarray(vjp2(gy)[0], np.float32),
-                               rtol=rtol, atol=atol)
-    return y1
+    gy = jnp.asarray(_rng(seed).randn(*y.shape).astype(np.float32), y.dtype)
+    dx = vjp(gy)[0]
+    assert dx.dtype == x.dtype and dx.shape == x.shape
+    np.testing.assert_allclose(
+        np.asarray(dx, np.float32),
+        np.asarray(vjp0(gy.astype(jnp.float32))[0]), rtol=rtol, atol=atol)
+    return said | set(dispatch.decisions())
 
 
 # ---------------------------------------------------------------------------
-# parity: LRN family
+# the LRN family
 # ---------------------------------------------------------------------------
 
-ONLY_LEG = {("lrn_cross_map.fwd", "xla", "only-leg"),
-            ("lrn_cross_map.bwd", "xla", "only-leg")}
+ONLY_LEG = _only_leg("lrn_cross_map")
 
 
 def _lrn_definition(x, size, alpha, beta, k, c_ax=1):
@@ -76,27 +83,16 @@ def _lrn_definition(x, size, alpha, beta, k, c_ax=1):
     half = (size - 1) // 2
     dims, pads = [1] * x.ndim, [(0, 0)] * x.ndim
     dims[c_ax], pads[c_ax] = size, (half, size - 1 - half)
-    window_sum = jax.lax.reduce_window(x * x, 0.0, jax.lax.add, tuple(dims),
-                                       (1,) * x.ndim, pads)
+    window_sum = lax.reduce_window(x * x, 0.0, lax.add, tuple(dims),
+                                   (1,) * x.ndim, pads)
     return x * jnp.power(k + window_sum * (alpha / size), -beta)
 
 
-def _cross_map_against_definition(x, size, alpha, beta, k, seed=1,
-                                  rtol=1e-5, atol=1e-6):
-    """Value and VJP of the op's one leg against the definition's."""
-    dispatch.clear_decisions()
-    y, vjp = jax.vjp(jax.jit(
-        lambda a: cross_map_lrn(a, size, alpha, beta, k)), x)
-    y0, vjp0 = jax.vjp(jax.jit(
-        lambda a: _lrn_definition(a, size, alpha, beta, k)), x)
-    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y0),
-                               rtol=rtol, atol=atol)
-    gy = jnp.asarray(_rng(seed).randn(*y.shape).astype(np.float32), y.dtype)
-    np.testing.assert_allclose(
-        np.asarray(vjp(gy)[0], np.float32),
-        np.asarray(vjp0(gy.astype(jnp.float32))[0], np.float32),
-        rtol=rtol, atol=atol)
-    assert set(dispatch.decisions()) == ONLY_LEG
+def _cross_map_against_definition(x, size, alpha, beta, k, **tol):
+    assert _against_definition(
+        lambda a: cross_map_lrn(a, size, alpha, beta, k),
+        lambda a: _lrn_definition(a, size, alpha, beta, k), x,
+        **tol) == ONLY_LEG
 
 
 @pytest.mark.parametrize("shape,size", [
@@ -175,20 +171,6 @@ def test_kernel_mode_does_not_reach_cross_map_lrn(mode, tmp_path,
         ("lrn_cross_map.bwd", "xla", "only-leg", 6, 3, "NCHW")]
 
 
-def test_strided_pool_leaves_pallas_on_tpu_only(monkeypatch):
-    """Mosaic has no strided vector slice: on a TPU the plane-pool gate
-    turns a strided window to the XLA leg; the interpreter keeps it."""
-    from bigdl_tpu.ops import attention
-    from bigdl_tpu.ops.pool_pallas import pool_plane_supported
-
-    x = jax.ShapeDtypeStruct((2, 4, 14, 14), jnp.float32)
-    s1, s3 = (1, 1, 1, 1), (1, 1, 3, 3)
-    assert pool_plane_supported(x, (1, 1, 5, 5), s3)
-    monkeypatch.setattr(attention, "is_tpu_device", lambda: True)
-    assert not pool_plane_supported(x, (1, 1, 5, 5), s3)
-    assert pool_plane_supported(x, (1, 1, 5, 5), s1)
-
-
 def test_partitioned_step_takes_xla_leg_on_tpu(monkeypatch):
     """Inside ``spmd_partitioned`` over >1 device ``auto`` never picks a
     Mosaic kernel (it cannot be partitioned); a 1-device mesh, or no
@@ -214,15 +196,32 @@ def test_cross_map_lrn_general_beta_and_k():
     _cross_map_against_definition(x, 3, 0.001, 0.5, 2.0)
 
 
-@pytest.mark.parametrize("shape,size", [
-    ((2, 4, 6, 6), 3),
-    ((1, 2, 7, 5), 4),      # EVEN window: asymmetric (lo, hi) pads
-    ((2, 3, 9, 9), 5),
-])
-def test_within_channel_lrn_parity(shape, size, monkeypatch):
-    x = jnp.asarray(_rng(1).randn(*shape).astype(np.float32))
-    _both_legs(lambda a: within_channel_lrn(a, size, 0.01, 0.75), x,
-               monkeypatch=monkeypatch)
+def _wcl_definition(x, size, alpha, beta):
+    """``nn/SpatialWithinChannelLRN.scala``: a ``reduce_window`` mean
+    of squares over the ``size x size`` window, Torch pads
+    ``(half, size - 1 - half)``."""
+    half = (size - 1) // 2
+    pad = (half, size - 1 - half)
+    window_sum = lax.reduce_window(
+        x * x, 0.0, lax.add, (1, 1, size, size), (1, 1, 1, 1),
+        ((0, 0), (0, 0), pad, pad))
+    return x * jnp.power(1.0 + window_sum * (alpha / (size * size)), -beta)
+
+
+@pytest.mark.parametrize("shape,size,dtype", [
+    ((2, 4, 6, 6), 3, jnp.float32),
+    ((1, 2, 7, 5), 4, jnp.float32),     # EVEN window: asymmetric pads
+    ((2, 3, 9, 9), 5, jnp.float32),
+    ((2, 150, 5, 5), 5, jnp.float32),   # a window as wide as the plane
+    ((2, 8, 8, 8), 4, jnp.bfloat16),    # the cells' dtype
+], ids=["3", "even4", "5", "plane_wide", "bf16"])
+def test_within_channel_lrn_parity(shape, size, dtype):
+    x = jnp.asarray(_rng(1).randn(*shape).astype(np.float32), dtype)
+    tol = BF16_TOL if dtype == jnp.bfloat16 else {}
+    assert _against_definition(
+        lambda a: within_channel_lrn(a, size, 0.01, 0.75),
+        lambda a: _wcl_definition(a, size, 0.01, 0.75), x,
+        **tol) == _only_leg("lrn_within_channel")
 
 
 def test_lrn_bf16_parity():
@@ -230,12 +229,11 @@ def test_lrn_bf16_parity():
     within bf16 slack."""
     x = jnp.asarray(_rng(2).randn(2, 8, 8, 8).astype(np.float32),
                     jnp.bfloat16)
-    _cross_map_against_definition(x, 5, 1e-4, 0.75, 1.0,
-                                  rtol=2e-2, atol=2e-2)
+    _cross_map_against_definition(x, 5, 1e-4, 0.75, 1.0, **BF16_TOL)
 
 
 # ---------------------------------------------------------------------------
-# parity: subtractive / divisive / contrastive
+# subtractive / divisive / contrastive
 # ---------------------------------------------------------------------------
 
 def _gauss(k):
@@ -244,47 +242,73 @@ def _gauss(k):
     return jnp.asarray(_gaussian_kernel(k))
 
 
-@pytest.mark.parametrize("shape,ksize", [
-    ((2, 4, 7, 7), 9),      # default 9x9 gaussian, kernel > image half
-    ((1, 3, 12, 10), 5),
-    ((2, 1, 6, 6), 4),      # EVEN kernel: asymmetric SAME pads
+def _smooth_definition(v, kernel):
+    """Kernel-weighted window sum of ``[N, H, W]`` maps under Torch's
+    "same" pads ``(k // 2, (k - 1) // 2)``: a one-channel correlation."""
+    kh, kw = kernel.shape
+    out = lax.conv_general_dilated(
+        v[:, None], kernel[None, None].astype(v.dtype), (1, 1),
+        ((kh // 2, (kh - 1) // 2), (kw // 2, (kw - 1) // 2)),
+        dimension_numbers=("NCHW", "OIHW", "NCHW"))
+    return out[:, 0]
+
+
+def _sub_definition(x, kernel):
+    """``nn/SpatialSubtractiveNormalization.scala``: less the smoothed
+    channel mean over the kernel's mass inside the image."""
+    coef = _smooth_definition(jnp.ones((1,) + x.shape[2:], x.dtype), kernel)
+    return x - (_smooth_definition(jnp.mean(x, 1), kernel) / coef)[:, None]
+
+
+def _div_definition(x, kernel, threshold=1e-4, thresval=1e-4):
+    """``nn/SpatialDivisiveNormalization.scala``: over the smoothed
+    local deviation, no less than its mean over the image, thresholded."""
+    coef = _smooth_definition(jnp.ones((1,) + x.shape[2:], x.dtype), kernel)
+    sigma = jnp.sqrt(jnp.clip(
+        _smooth_definition(jnp.mean(x * x, 1), kernel) / coef, 0.0))
+    e = jnp.maximum(sigma, jnp.mean(sigma, (1, 2), keepdims=True))
+    return x / jnp.where(e < threshold, thresval, e)[:, None]
+
+
+NORMS = {
+    "subtractive": (subtractive_norm, _sub_definition),
+    "divisive": (divisive_norm, _div_definition),
+    "contrastive": (contrastive_norm,
+                    lambda x, k: _div_definition(_sub_definition(x, k), k)),
+}
+
+
+@pytest.mark.parametrize("norm,shape,ksize,dtype", [
+    ("subtractive", (2, 4, 7, 7), 9, jnp.float32),   # kernel > image half
+    ("subtractive", (1, 3, 12, 10), 5, jnp.float32),
+    ("subtractive", (2, 1, 6, 6), 4, jnp.float32),   # EVEN kernel
+    ("divisive", (2, 4, 7, 7), 9, jnp.float32),
+    ("divisive", (1, 2, 9, 11), 5, jnp.float32),
+    ("contrastive", (2, 4, 7, 7), 9, jnp.float32),
+    ("subtractive", (300, 2, 6, 6), 3, jnp.float32),  # many small planes
+    ("subtractive", (2, 4, 8, 8), 5, jnp.bfloat16),   # the cells' dtype
 ])
-def test_subtractive_norm_parity(shape, ksize, monkeypatch):
-    x = jnp.asarray(_rng(4).randn(*shape).astype(np.float32))
-    _both_legs(lambda a: subtractive_norm(a, _gauss(ksize)), x,
-               monkeypatch=monkeypatch)
+def test_norm_parity(norm, shape, ksize, dtype):
+    op, definition = NORMS[norm]
+    x = jnp.asarray(_rng(4).randn(*shape).astype(np.float32), dtype)
+    tol = BF16_TOL if dtype == jnp.bfloat16 else dict(rtol=1e-4, atol=1e-5)
+    assert _against_definition(
+        lambda a: op(a, _gauss(ksize)),
+        lambda a: definition(a, _gauss(ksize)), x,
+        **tol) == _only_leg("norm_smooth")
 
 
-@pytest.mark.parametrize("shape,ksize", [
-    ((2, 4, 7, 7), 9),
-    ((1, 2, 9, 11), 5),
-])
-def test_divisive_norm_parity(shape, ksize, monkeypatch):
-    x = jnp.asarray(_rng(5).randn(*shape).astype(np.float32))
-    _both_legs(lambda a: divisive_norm(a, _gauss(ksize)), x,
-               monkeypatch=monkeypatch)
-
-
-def test_contrastive_norm_parity(monkeypatch):
-    x = jnp.asarray(_rng(6).randn(2, 4, 7, 7).astype(np.float32))
-    _both_legs(lambda a: contrastive_norm(a, _gauss(9)), x,
-               monkeypatch=monkeypatch)
-
-
-def test_smoothing_kernel_gets_zero_cotangent(monkeypatch):
+def test_smoothing_kernel_gets_zero_cotangent():
     """The smoothing kernel is a BUFFER (never trained): its cotangent
-    is zero by contract on both legs."""
+    is zero by contract."""
     x = jnp.asarray(_rng(7).randn(1, 2, 5, 5).astype(np.float32))
-    k = _gauss(3)
-    for mode in ("xla", "pallas"):
-        monkeypatch.setenv("BIGDL_KERNELS", mode)
-        _, vjp = jax.vjp(lambda a, w: subtractive_norm(a, w), x, k)
-        _, dk = vjp(jnp.ones((1, 2, 5, 5), jnp.float32))
-        assert float(jnp.max(jnp.abs(dk))) == 0.0
+    _, vjp = jax.vjp(jax.jit(subtractive_norm), x, _gauss(3))
+    _, dk = vjp(jnp.ones((1, 2, 5, 5), jnp.float32))
+    assert not np.asarray(dk).any()
 
 
 # ---------------------------------------------------------------------------
-# parity: pooling (tie-split + Torch-divisor average)
+# pooling (tie-split + Torch-divisor average)
 # ---------------------------------------------------------------------------
 
 def _full(k, s, p):
@@ -301,131 +325,110 @@ POOL_CASES = [
 ]
 
 
+def _window_taps(x, dims, strides, pads, fill):
+    """Every tap of every window, gathered explicitly from the padded
+    input: ``[taps, *out_shape]``."""
+    xp = jnp.pad(x, pads, constant_values=fill)
+    out = [(n - k) // s + 1 for n, k, s in zip(xp.shape, dims, strides)]
+    return jnp.stack([
+        lax.slice(xp, off, [o + (n - 1) * s + 1
+                            for o, n, s in zip(off, out, strides)], strides)
+        for off in itertools.product(*[range(k) for k in dims])])
+
+
+def _maxpool_definition(x, dims, strides, pads):
+    """The largest tap of each window of the ``-inf``-padded input;
+    autodiff's backward of ``jnp.max`` splits ties equally."""
+    return jnp.max(_window_taps(x, dims, strides, pads, -jnp.inf), axis=0)
+
+
 @pytest.mark.parametrize("shape,k,s,p", POOL_CASES)
 @pytest.mark.parametrize("tie_heavy", [False, True])
-def test_maxpool_tie_split_parity(shape, k, s, p, tie_heavy, monkeypatch):
+def test_maxpool_tie_split_parity(shape, k, s, p, tie_heavy):
     x = _rng(8).randn(*shape).astype(np.float32)
     if tie_heavy:  # quantize to force equal maxima inside windows
         x = np.round(x * 2.0) / 2.0
     dims, strides, pads = _full(k, s, p)
-    _both_legs(lambda a: maxpool_tie_split(a, dims, strides, pads),
-               jnp.asarray(x), monkeypatch=monkeypatch)
+    assert _against_definition(
+        lambda a: maxpool_tie_split(a, dims, strides, pads),
+        lambda a: _maxpool_definition(a, dims, strides, pads),
+        jnp.asarray(x)) == _only_leg("pool_tie_split")
 
 
-@pytest.mark.parametrize("shape,k,s,p", POOL_CASES)
-@pytest.mark.parametrize("count_include_pad", [True, False])
-def test_avg_pool_parity(shape, k, s, p, count_include_pad, monkeypatch):
-    x = jnp.asarray(_rng(9).randn(*shape).astype(np.float32))
-    dims, strides, pads = _full(k, s, p)
-    # declared padding below the ceil-overflow hi — the Torch divisor
-    # subtlety the op must reproduce on both legs
-    declared = ((0, 0), (0, 0)) \
-        + tuple((lo, min(hi, lo)) for lo, hi in p)
-    _both_legs(lambda a: avg_pool(a, dims, strides, pads, declared,
-                                  count_include_pad, True), x,
-               monkeypatch=monkeypatch)
+def _np_divisors(shape, dims, strides, pads, declared, count_include_pad):
+    """Torch's divisor of each window, counted tap by tap: a tap counts
+    if it lies on the data or, under ``count_include_pad``, on DECLARED
+    padding; padding that ceil mode added past it never counts
+    (``SpatialAveragePooling.scala:133-135``)."""
+    counted = [(0, lo + n + dhi) if count_include_pad else (lo, lo + n)
+               for n, (lo, _), (_, dhi) in zip(shape, pads, declared)]
+    out = [(lo + n + hi - k) // s + 1
+           for n, k, s, (lo, hi) in zip(shape, dims, strides, pads)]
+    counts = np.zeros(out)
+    for o in np.ndindex(*out):
+        for tap in np.ndindex(*dims):
+            at = [oi * s + t for oi, s, t in zip(o, strides, tap)]
+            counts[o] += all(a <= q < b for q, (a, b) in zip(at, counted))
+    return np.maximum(counts, 1.0)
 
 
-def _launches(op_prefix):
-    return {r[0]: r.launch for r in dispatch.decisions()
-            if r[0].startswith(op_prefix) and r[1] == "pallas"}
+def _avgpool_definition(x, dims, strides, pads, declared, count_include_pad):
+    """A ``reduce_window`` sum over the zero-padded input, divided by
+    the counted divisors; autodiff's backward."""
+    total = lax.reduce_window(x, 0.0, lax.add, dims, strides, pads)
+    return total / jnp.asarray(_np_divisors(
+        x.shape, dims, strides, pads, declared, count_include_pad), x.dtype)
 
-
-# 300 planes of at most 4 KB each way: 256 share a grid step (4 MB over
-# 2 buffers x 8 KB), and the second step's block is ragged, 44 of 256
-BLOCKED_AVG_CASES = [
-    # (shape, k, pads, declared): a window that IS the padded plane
-    # (one fused reduction and a broadcast, never a kernel) ...
-    ((2, 150, 7, 7), (7, 7), ((0, 0), (0, 0)), ((0, 0), (0, 0))),
-    ((3, 100, 5, 5), (7, 7), ((1, 1), (1, 1)), ((1, 1), (1, 1))),
-    ((2, 150, 4, 6), (5, 8), ((1, 0), (1, 1)), ((1, 0), (1, 1))),
-    # ... and one that slides over it, 3x3/s1 "same" padding
-    ((2, 150, 6, 6), (3, 3), ((1, 1), (1, 1)), ((1, 1), (1, 1))),
-]
 
 WHOLE_PLANE = {("pool_avg.fwd", "xla", "whole-plane"),
                ("pool_avg.bwd", "xla", "whole-plane")}
 
-
-def _assert_ragged_blocks(launches):
-    """300 planes of a 3x3/s1 window on 6x6: 256 a forward grid step;
-    the backward's extended grid is wider (10x10 float32: two tiles of
-    8 rows), so fewer planes fit; both last blocks ragged."""
-    assert launches["pool_avg.fwd"] == {"planes_per_block": 256,
-                                        "grid": (2,)}
-    per_block = launches["pool_avg.bwd"]["planes_per_block"]
-    assert 1 < per_block <= 256 and 300 % per_block
-    assert launches["pool_avg.bwd"]["grid"] == (-(-300 // per_block),)
-
-
-def _check_whole_plane(x, dims, strides, pads, declared, count_include_pad,
-                       monkeypatch, rtol=1e-5, atol=1e-6):
-    """Value and VJP of a whole-plane window in every kernel mode,
-    against a NumPy mean over the padded plane and against
-    ``reduce_window`` and its transpose; no mode launches a kernel."""
-    (lo_h, hi_h), (lo_w, hi_w) = pads[2:]
-    (_, dhi_h), (_, dhi_w) = declared[2:]
-    h, w = x.shape[2:]
-    # declared padding counts under count_include_pad, overflow never
-    count = (lo_h + h + dhi_h) * (lo_w + w + dhi_w) \
-        if count_include_pad else h * w
-    xs = np.asarray(x, np.float64)
-    want_y = xs.sum((2, 3), keepdims=True) / count
-    # a cotangent the input's dtype holds exactly: one rounding to judge
-    gy = np.asarray(jnp.asarray(_rng(1).randn(*want_y.shape), x.dtype),
-                    np.float64)
-    want_dx = np.broadcast_to(gy / count, xs.shape)
-    y_rw, vjp_rw = jax.vjp(
-        lambda a: jax.lax.reduce_window(a, 0.0, jax.lax.add, dims, strides,
-                                        pads) / count,
-        x.astype(jnp.float32))
-    dx_rw = vjp_rw(jnp.asarray(gy, jnp.float32))[0]
-    for mode in ("xla", "pallas", "auto"):
-        monkeypatch.setenv("BIGDL_KERNELS", mode)
-        dispatch.clear_decisions()
-        y, vjp = jax.vjp(jax.jit(
-            lambda a: avg_pool(a, dims, strides, pads, declared,
-                               count_include_pad, True)), x)
-        dx = vjp(jnp.asarray(gy, y.dtype))[0]
-        assert y.shape[2:] == (1, 1) and y.dtype == x.dtype
-        assert dx.shape == x.shape and dx.dtype == x.dtype
-        for got, want in ((y, want_y), (y, y_rw), (dx, want_dx),
-                          (dx, dx_rw)):
-            np.testing.assert_allclose(np.asarray(got, np.float64),
-                                       np.asarray(want, np.float64),
-                                       rtol=rtol, atol=atol)
-        assert set(dispatch.decisions()) == WHOLE_PLANE, mode
-        assert not _launches("pool_avg")
+F32, BF16 = jnp.float32, jnp.bfloat16
+AVG_CASES = [
+    # (shape, k, s, pads, declared, dtype, whole plane?)
+    # declared padding below the ceil-overflow hi: the Torch divisor
+    # subtlety the op must reproduce
+    *[(shape, k, s, p, tuple((lo, min(hi, lo)) for lo, hi in p), F32, False)
+      for shape, k, s, p in POOL_CASES],
+    # many small planes: a window that IS the padded plane (one fused
+    # reduction and a broadcast) ...
+    ((2, 150, 7, 7), (7, 7), (1, 1), ((0, 0), (0, 0)), ((0, 0), (0, 0)),
+     F32, True),
+    ((3, 100, 5, 5), (7, 7), (1, 1), ((1, 1), (1, 1)), ((1, 1), (1, 1)),
+     F32, True),
+    ((2, 150, 4, 6), (5, 8), (1, 1), ((1, 0), (1, 1)), ((1, 0), (1, 1)),
+     F32, True),
+    # ... and one that slides over it, 3x3/s1 "same" padding
+    ((2, 150, 6, 6), (3, 3), (1, 1), ((1, 1), (1, 1)), ((1, 1), (1, 1)),
+     F32, False),
+]
+AVG_BF16_CASES = [
+    # the benchmark's dtype: the whole-plane form accumulates in float32
+    # and rounds once; the sliding form sums in the input's dtype
+    ((2, 150, 7, 7), (7, 7), (1, 1), ((0, 0), (0, 0)), ((0, 0), (0, 0)),
+     BF16, True),
+    ((2, 16, 14, 14), (3, 3), (1, 1), ((1, 1), (1, 1)), ((1, 1), (1, 1)),
+     BF16, False),          # the Inception branches' pool
+    ((2, 16, 14, 14), (5, 5), (3, 3), ((0, 1), (0, 1)), ((0, 0), (0, 0)),
+     BF16, False),          # the auxiliary heads' pool, one overflow row
+]
 
 
-@pytest.mark.parametrize("shape,k,p,declared", BLOCKED_AVG_CASES)
-@pytest.mark.parametrize("count_include_pad", [True, False])
-def test_blocked_avg_pool_parity(shape, k, p, declared, count_include_pad,
-                                 monkeypatch):
-    """The whole-plane rows: the one form, held to an independent mean
-    in every mode.  The sliding row: many planes a grid step, the last
-    block ragged, value and VJP of the blocked kernels against the XLA
-    leg, with and without the padding in the divisor."""
-    x = jnp.asarray(_rng(30).randn(*shape).astype(np.float32))
-    dims, strides, pads = _full(k, (1, 1), p)
+@pytest.mark.parametrize("shape,k,s,p,declared,dtype,whole,include_pad", [
+    *[c + (ip,) for c in AVG_CASES for ip in (True, False)],
+    *[c + (True,) for c in AVG_BF16_CASES]])
+def test_avg_pool_parity(shape, k, s, p, declared, dtype, whole,
+                         include_pad):
+    x = jnp.asarray(_rng(9).randn(*shape).astype(np.float32), dtype)
+    dims, strides, pads = _full(k, s, p)
     declared = ((0, 0), (0, 0)) + declared
-    if k != (3, 3):
-        _check_whole_plane(x, dims, strides, pads, declared,
-                           count_include_pad, monkeypatch)
-        return
-    _both_legs(lambda a: avg_pool(a, dims, strides, pads, declared,
-                                  count_include_pad, True), x,
-               monkeypatch=monkeypatch)
-    _assert_ragged_blocks(_launches("pool_avg"))
-
-
-def test_blocked_avg_pool_parity_bf16(monkeypatch):
-    """The benchmark's dtype: the whole-plane form accumulates in
-    float32 and rounds once, to bfloat16, forward and backward."""
-    x = jnp.asarray(_rng(31).randn(2, 150, 7, 7), jnp.bfloat16)
-    dims, strides, pads = _full((7, 7), (1, 1), ((0, 0), (0, 0)))
-    _check_whole_plane(x, dims, strides, pads, pads, True, monkeypatch,
-                       rtol=2 ** -8, atol=1e-6)
+    tol = BF16_TOL if dtype == BF16 else {}
+    said = _against_definition(
+        lambda a: avg_pool(a, dims, strides, pads, declared, include_pad,
+                           True),
+        lambda a: _avgpool_definition(a, dims, strides, pads, declared,
+                                      include_pad), x, **tol)
+    assert said == (WHOLE_PLANE if whole else _only_leg("pool_avg"))
 
 
 @pytest.mark.parametrize("shape,dims,strides,pads", [
@@ -439,9 +442,9 @@ def test_blocked_avg_pool_parity_bf16(monkeypatch):
 ], ids=["cifar_head", "padded", "nhwc"])
 def test_whole_plane_window_takes_the_form_everywhere(shape, dims, strides,
                                                       pads, monkeypatch):
-    """The leg follows the shape alone: on the CPU, as a TPU decides
-    and inside a partitioned step (the four-chip cell), a window that
-    is the whole padded plane is the one reduction, said so."""
+    """The form follows the shape alone: on the CPU, on a TPU and inside
+    a partitioned step (the four-chip cell), a window that is the whole
+    padded plane is the one reduction, said so."""
     from jax.sharding import Mesh
     from bigdl_tpu.ops import attention
 
@@ -471,80 +474,19 @@ def test_whole_plane_window_takes_the_form_everywhere(shape, dims, strides,
             assert set(dispatch.decisions()) == WHOLE_PLANE
 
 
-@pytest.mark.parametrize("name,shape,fn,planes,grid", [
-    # backward: three 10x10 extended planes in (8 KB each in float32),
-    # 6x6 out (4 KB)
-    ("pool_tie_split", (2, 150, 6, 6),
-     lambda a: maxpool_tie_split(
-         a, *_full((3, 3), (1, 1), ((1, 1), (1, 1)))), 73, (5,)),
-    # backward: 8 KB (9x9 padded) + 3 x 4 KB in, 4 KB out
-    ("lrn_within_channel", (2, 150, 5, 5),
-     lambda a: within_channel_lrn(a, 5, 0.01, 0.75), 85, (4,)),
-])
-def test_blocked_launch_keeps_other_plane_kernels(name, shape, fn, planes,
-                                                  grid, monkeypatch):
-    """The launcher's other kernels on a ragged grid of plane blocks:
-    same values as the XLA leg, as at one plane a step."""
-    x = _rng(32).randn(*shape).astype(np.float32)
-    if name == "pool_tie_split":
-        x = np.round(x * 2.0) / 2.0     # ties inside windows
-    _both_legs(fn, jnp.asarray(x), monkeypatch=monkeypatch)
-    bwd = _launches(name)[name + ".bwd"]
-    assert bwd == {"planes_per_block": planes, "grid": grid}
-
-
-def test_blocked_smoothing_parity(monkeypatch):
-    """``norm_pallas``'s smoothing stack is one plane a record: 300
-    records of 6x6 share grid steps, 256 and a ragged 44."""
-    x = jnp.asarray(_rng(33).randn(300, 2, 6, 6).astype(np.float32))
-    _both_legs(lambda a: subtractive_norm(a, _gauss(3)), x, rtol=1e-4,
-               atol=1e-5, monkeypatch=monkeypatch)
-    # (``_coef``'s single plane of ones is a launch of its own)
-    assert {"planes_per_block": 256, "grid": (2,)} in [
-        r.launch for r in dispatch.decisions()
-        if r[0] == "norm_smooth.fwd"]
-
-
-def test_planes_per_block_reads_the_tiled_footprint():
-    """P is the VMEM budget over the tile-rounded, double-buffered
-    planes: small planes share a step, a large one keeps its own."""
-    from bigdl_tpu.ops.pallas_util import VMEM_BUDGET, planes_per_block
-
-    bf16, f32 = jnp.bfloat16, jnp.float32
-    # a small plane: 7x7 (or 13x13) in bf16 is one (16, 128) tile
-    assert planes_per_block([((7, 7), bf16), ((1, 1), bf16)], 262144) == 256
-    assert planes_per_block([((13, 13), bf16), ((7, 7), bf16)],
-                            262144) == 256
-    # 17 rows of float32 are three (8, 128) tiles, 12 KB
-    assert planes_per_block([((17, 130), f32)], 10 ** 6) \
-        == VMEM_BUDGET // (2 * 3 * 8 * 256 * 4)
-    # never more planes than there are
-    assert planes_per_block([((7, 7), bf16), ((1, 1), bf16)], 6) == 6
-    # cross-map LRN's [C + halo, HW tile] slabs, were they launched
-    # here, and a 384x384 image: one a step
-    assert planes_per_block([((196, 3200), bf16)] + [((192, 3200), bf16)] * 2,
-                            256) == 1
-    assert planes_per_block([((388, 388), f32)] + [((384, 384), f32)] * 2,
-                            64) == 1
-
-
-def test_tie_split_conserves_gradient_mass(monkeypatch):
+def test_tie_split_conserves_gradient_mass():
     """Equal-split semantics: summed input gradient == summed output
-    gradient regardless of ties (mass conservation), on both legs."""
+    gradient regardless of ties (mass conservation)."""
     x = jnp.asarray(np.ones((1, 1, 4, 4), np.float32))  # ALL ties
     dims, strides, pads = _full((2, 2), (2, 2), ((0, 0), (0, 0)))
-    for mode in ("xla", "pallas"):
-        monkeypatch.setenv("BIGDL_KERNELS", mode)
-        _, vjp = jax.vjp(
-            lambda a: maxpool_tie_split(a, dims, strides, pads), x)
-        gy = jnp.asarray(_rng(10).randn(1, 1, 2, 2).astype(np.float32))
-        (dx,) = vjp(gy)
-        np.testing.assert_allclose(float(jnp.sum(dx)),
-                                   float(jnp.sum(gy)), rtol=1e-6)
-        # each of the 4 tied positions gets exactly a quarter
-        np.testing.assert_allclose(np.asarray(dx)[0, 0, :2, :2],
-                                   np.asarray(gy)[0, 0, 0, 0] / 4.0,
-                                   rtol=1e-6)
+    _, vjp = jax.vjp(
+        jax.jit(lambda a: maxpool_tie_split(a, dims, strides, pads)), x)
+    gy = _rng(10).randn(1, 1, 2, 2).astype(np.float32)
+    dx = np.asarray(vjp(jnp.asarray(gy))[0])
+    np.testing.assert_allclose(dx.sum(), gy.sum(), rtol=1e-6)
+    # each of the 4 tied positions gets exactly a quarter
+    np.testing.assert_allclose(dx[0, 0, :2, :2], gy[0, 0, 0, 0] / 4.0,
+                               rtol=1e-6)
 
 
 def test_cross_map_lrn_rank5_and_nhwc(monkeypatch):
@@ -579,24 +521,43 @@ def test_cross_map_lrn_rank5_and_nhwc(monkeypatch):
     assert "transpose" not in hlo
 
 
-def test_pool_nonstandard_rank_uses_xla_leg(monkeypatch):
-    """5-D volumetric windows have no Pallas kernel — the op must fall
-    back (and record it) rather than fail."""
-    monkeypatch.setenv("BIGDL_KERNELS", "pallas")
-    dispatch.clear_decisions()
-    x = jnp.asarray(_rng(11).randn(1, 2, 4, 6, 6).astype(np.float32))
-    d5, s5, p5 = (1, 1, 2, 2, 2), (1, 1, 2, 2, 2), ((0, 0),) * 5
-    y, vjp = jax.vjp(lambda a: maxpool_tie_split(a, d5, s5, p5), x)
-    vjp(jnp.ones_like(y))
-    recs = [r for r in dispatch.decisions()
-            if r[0].startswith("pool_tie_split")]
-    assert recs and all(b == "xla" and reason == "unsupported-shape"
-                        for _, b, reason in recs)
+def test_pool_nonstandard_rank_uses_xla_leg():
+    """The pools take any rank (temporal and volumetric pooling): a
+    rank-3 and a rank-5 window against the definitions."""
+    x3 = jnp.asarray(np.round(_rng(11).randn(2, 9, 4) * 2.0) / 2.0,
+                     jnp.float32)
+    d3, s3, p3 = (1, 3, 1), (1, 2, 1), ((0, 0), (1, 1), (0, 0))
+    assert _against_definition(
+        lambda a: maxpool_tie_split(a, d3, s3, p3),
+        lambda a: _maxpool_definition(a, d3, s3, p3),
+        x3) == _only_leg("pool_tie_split")
+    x5 = jnp.asarray(_rng(11).randn(1, 2, 4, 6, 6).astype(np.float32))
+    d5, s5 = (1, 1, 2, 3, 3), (1, 1, 2, 2, 2)
+    p5 = ((0, 0), (0, 0), (0, 0), (1, 0), (1, 0))
+    assert _against_definition(
+        lambda a: maxpool_tie_split(a, d5, s5, p5),
+        lambda a: _maxpool_definition(a, d5, s5, p5),
+        x5) == _only_leg("pool_tie_split")
+    assert _against_definition(
+        lambda a: avg_pool(a, d5, s5, p5, p5, False, True),
+        lambda a: _avgpool_definition(a, d5, s5, p5, p5, False),
+        x5) == _only_leg("pool_avg")
 
 
 # ---------------------------------------------------------------------------
 # dispatch contract
 # ---------------------------------------------------------------------------
+
+def _specimen(x, supported=True):
+    """A two-legged op as ``dispatch.dispatch`` sees one: the kernel leg
+    says how it was launched, the XLA leg says nothing."""
+    def kernel(a):
+        dispatch.launched(grid=(2,))
+        return a + 1.0
+
+    return dispatch.dispatch("specimen", kernel, lambda a: a + 1.0,
+                             supported, x)
+
 
 def test_bad_kernel_mode_raises(monkeypatch):
     monkeypatch.setenv("BIGDL_KERNELS", "palas")
@@ -604,57 +565,59 @@ def test_bad_kernel_mode_raises(monkeypatch):
         dispatch.kernel_mode()
 
 
-def test_xla_mode_bypasses_pallas_everywhere(monkeypatch):
-    """BIGDL_KERNELS=xla is the process-wide kill switch: drive every
-    kernel-library layer fwd+bwd and assert not one Pallas decision."""
+@pytest.mark.parametrize("mode", ["auto", "pallas", "xla"])
+def test_kernel_mode_does_not_reach_the_plane_family(mode, monkeypatch):
+    """No mode chooses anything for a one-legged op, ``pallas``
+    included: every layer of the family traces to a program without a
+    ``pallas_call`` and says ``xla`` / ``only-leg``."""
     import bigdl_tpu.nn as nn
-    from bigdl_tpu.ops.pooling_pallas import pallas_pool_supported
-    from bigdl_tpu.utils.rng import RNG
+
+    monkeypatch.setenv("BIGDL_KERNELS", mode)
+    dispatch.clear_decisions()
+    x = jnp.asarray(_rng(12).randn(2, 4, 9, 9).astype(np.float32))
+    for layer in (
+            nn.SpatialCrossMapLRN(5, 1e-4, 0.75),
+            nn.SpatialWithinChannelLRN(3, 0.01, 0.75),
+            nn.SpatialSubtractiveNormalization(4),
+            nn.SpatialDivisiveNormalization(4),
+            nn.SpatialContrastiveNormalization(4),
+            nn.SpatialMaxPooling(3, 3, 2, 2, 1, 1).split_ties(),
+            nn.SpatialAveragePooling(3, 3, 2, 2, 1, 1, ceil_mode=True),
+            nn.SpatialAveragePooling(3, 3, 1, 1, 1, 1)):
+        layer.evaluate()
+
+        def fwd_bwd(a):
+            y, vjp = jax.vjp(layer.update_output, a)
+            return vjp(y)
+
+        assert "pallas_call" not in str(jax.make_jaxpr(fwd_bwd)(x))
+    assert set(dispatch.decisions()) == _only_leg(
+        "lrn_cross_map", "lrn_within_channel", "norm_smooth",
+        "pool_tie_split", "pool_avg")
+
+
+def test_xla_mode_bypasses_pallas_everywhere(monkeypatch):
+    """BIGDL_KERNELS=xla is the process-wide kill switch: a supported
+    two-legged op and the attention router take the XLA form."""
+    from bigdl_tpu.ops.attention import select_attention_backend
 
     monkeypatch.setenv("BIGDL_KERNELS", "xla")
     dispatch.clear_decisions()
-    RNG.set_seed(0)
-    layers = [
-        nn.SpatialCrossMapLRN(5, 1e-4, 0.75),
-        nn.SpatialWithinChannelLRN(3, 0.01, 0.75),
-        nn.SpatialSubtractiveNormalization(4),
-        nn.SpatialDivisiveNormalization(4),
-        nn.SpatialContrastiveNormalization(4),
-        nn.SpatialMaxPooling(3, 3, 2, 2, 1, 1).split_ties(),
-        nn.SpatialAveragePooling(3, 3, 2, 2, 1, 1, ceil_mode=True),
-    ]
-    x = jnp.asarray(_rng(12).randn(2, 4, 9, 9).astype(np.float32))
-    for layer in layers:
-        layer.evaluate()
-        y, vjp = jax.vjp(jax.jit(layer.update_output), x)
-        vjp(jnp.ones_like(y))
-    recs = dispatch.decisions()
-    assert recs, "kernel-library layers must record dispatch decisions"
-    assert all(b == "xla" for _, b, _ in recs), \
-        [r for r in recs if r[1] != "xla"]
-    # the argmax-pool support gate honors the same switch: supported
-    # under its own opt-in, vetoed the moment BIGDL_KERNELS=xla
-    xb = jnp.zeros((2, 4, 8, 8), jnp.bfloat16)
-    dims, strides, pads = _full((2, 2), (2, 2), ((0, 0), (0, 0)))
-    monkeypatch.setenv("BIGDL_POOL_KERNEL", "interpret")
-    monkeypatch.setenv("BIGDL_KERNELS", "auto")
-    assert pallas_pool_supported(xb, dims, strides, pads)
-    monkeypatch.setenv("BIGDL_KERNELS", "xla")
-    assert not pallas_pool_supported(xb, dims, strides, pads)
+    jax.jit(lambda a: _specimen(a))(jnp.ones((3,), jnp.float32))
+    assert dispatch.decisions() == [
+        ("specimen", "xla", "forced:BIGDL_KERNELS=xla")]
+    assert select_attention_backend(4096, 4096)[0] == "dense"
 
 
 def test_pallas_mode_forces_kernels(monkeypatch):
     monkeypatch.setenv("BIGDL_KERNELS", "pallas")
     dispatch.clear_decisions()
-    x = jnp.asarray(_rng(13).randn(1, 4, 5, 5).astype(np.float32))
-    y, vjp = jax.vjp(jax.jit(
-        lambda a: within_channel_lrn(a, 3, 1e-4, 0.75)), x)
-    vjp(jnp.ones_like(y))
-    recs = [r for r in dispatch.decisions()
-            if r[0].startswith("lrn_within_channel")]
-    assert {op for op, _, _ in recs} \
-        == {"lrn_within_channel.fwd", "lrn_within_channel.bwd"}
-    assert all(b == "pallas" for _, b, _ in recs)
+    x = jnp.ones((3,), jnp.float32)
+    jax.jit(lambda a: _specimen(a))(x)
+    jax.jit(lambda a: _specimen(a, supported=False))(x)
+    assert dispatch.decisions() == [
+        ("specimen", "pallas", "forced:BIGDL_KERNELS=pallas"),
+        ("specimen", "xla", "unsupported-shape")]
 
 
 def test_auto_mode_off_tpu_prefers_xla(monkeypatch):
@@ -662,12 +625,8 @@ def test_auto_mode_off_tpu_prefers_xla(monkeypatch):
     the Pallas leg is still reachable via the explicit knob above."""
     monkeypatch.setenv("BIGDL_KERNELS", "auto")
     dispatch.clear_decisions()
-    x = jnp.asarray(_rng(14).randn(1, 4, 5, 5).astype(np.float32))
-    within_channel_lrn(x, 3, 0.01, 0.75)
-    recs = [r for r in dispatch.decisions()
-            if r[0] == "lrn_within_channel.fwd"]
-    assert recs and recs[-1][1] == "xla" \
-        and recs[-1][2] == "auto:off-tpu"
+    _specimen(jnp.ones((3,), jnp.float32))
+    assert dispatch.decisions() == [("specimen", "xla", "auto:off-tpu")]
 
 
 def test_dispatch_emits_telemetry_instant(tmp_path, monkeypatch):
@@ -679,8 +638,7 @@ def test_dispatch_emits_telemetry_instant(tmp_path, monkeypatch):
     monkeypatch.setenv("BIGDL_KERNELS", "xla")
     telemetry.start_run(str(tmp_path))
     try:
-        x = jnp.asarray(_rng(15).randn(1, 3, 5, 5).astype(np.float32))
-        within_channel_lrn(x, 3, 1e-4, 0.75)
+        _specimen(jnp.ones((3,), jnp.float32))
     finally:
         telemetry.end_run()
     logs = list(tmp_path.glob("*.jsonl"))
@@ -688,34 +646,48 @@ def test_dispatch_emits_telemetry_instant(tmp_path, monkeypatch):
     events, errors = schema.read_events(str(logs[0]))
     assert not errors
     inst = [e for e in events if e.get("name") == "kernel/dispatch"]
-    assert inst and inst[0]["op"] == "lrn_within_channel.fwd" \
+    assert inst and inst[0]["op"] == "specimen" \
         and inst[0]["backend"] == "xla"
     assert not schema.validate_events(events)
 
 
-def test_plane_launch_rides_on_the_dispatch_instant(tmp_path, monkeypatch):
-    """A ``plane_call`` kernel's decision says how it was launched, in
-    the run log's instant as in the ring; an XLA leg says nothing."""
+def test_launch_facts_ride_on_the_dispatch_instant(tmp_path, monkeypatch):
+    """What a leg says through ``dispatch.launched`` is on its decision,
+    in the run log's instant as in the ring; a leg that says nothing
+    adds nothing."""
     from bigdl_tpu import telemetry
     from bigdl_tpu.telemetry import schema
 
-    dims, strides, pads = _full((3, 3), (1, 1), ((1, 1), (1, 1)))
-    x = jnp.asarray(_rng(17).randn(2, 150, 6, 6).astype(np.float32))
+    x = jnp.ones((3,), jnp.float32)
+    dispatch.clear_decisions()
     telemetry.start_run(str(tmp_path))
     try:
         for mode in ("pallas", "xla"):
             monkeypatch.setenv("BIGDL_KERNELS", mode)
-            jax.jit(lambda a: avg_pool(a, dims, strides, pads, pads, True,
-                                       True))(x)
+            jax.jit(lambda a: _specimen(a))(x)
     finally:
         telemetry.end_run()
+    assert [d.launch for d in dispatch.decisions()] == [{"grid": (2,)}, {}]
     events, errors = schema.read_events(str(next(tmp_path.glob("*.jsonl"))))
     assert not errors and not schema.validate_events(events)
     by_backend = {e["backend"]: e for e in events
                   if e.get("name") == "kernel/dispatch"}
-    assert by_backend["pallas"]["planes_per_block"] == 256
     assert list(by_backend["pallas"]["grid"]) == [2]
-    assert "planes_per_block" not in by_backend["xla"]
+    assert "grid" not in by_backend["xla"]
+
+
+def test_pallas_is_imported_only_where_a_cell_times_it():
+    """A kernel comes with a benchmark cell that runs it and a roofline
+    metric that reads it (docs/kernels.md): under ``bigdl_tpu/`` only
+    the three modules whose kernels have both import Pallas.  Read off
+    the sources, nothing is imported."""
+    root = pathlib.Path(dispatch.__file__).resolve().parents[1]
+    imports = re.compile(
+        r"^\s*(from\s+jax\.experimental(\.pallas\b|\s+import\s+.*\bpallas\b)"
+        r"|import\s+jax\.experimental\.pallas\b)", re.M)
+    found = {str(p.relative_to(root)) for p in root.rglob("*.py")
+             if imports.search(p.read_text())}
+    assert found == {"ops/attention.py", "ops/delta_rule.py", "ops/ssd.py"}
 
 
 def test_attention_routing_shares_predicate(monkeypatch):

@@ -10,6 +10,7 @@ import torch
 import torch.nn.functional as F
 
 import bigdl_tpu.nn as nn
+from test_max_pool_ties import _pow2_grads
 
 
 def _cmp(ours, theirs, rtol=1e-4, atol=1e-5):
@@ -162,6 +163,53 @@ def test_avg_pooling_pad_count_exclude():
     x = np.random.randn(1, 1, 7, 7).astype(np.float32)
     ref = F.avg_pool2d(torch.tensor(x), 3, 2, 1, count_include_pad=False)
     _cmp(layer.forward(jnp.asarray(x)), ref.numpy())
+
+
+CELL_POOLS = {
+    # the pool geometries the benchmark's CNN cells run, at small
+    # channel counts: (layer, torch's, input plane)
+    "stem_3x3_s2_ceil": (
+        lambda: nn.SpatialMaxPooling(3, 3, 2, 2).ceil(),
+        lambda x: F.max_pool2d(x, 3, 2, 0, ceil_mode=True), 16),
+    "branch_3x3_s1_pad1": (
+        lambda: nn.SpatialMaxPooling(3, 3, 1, 1, 1, 1).ceil(),
+        lambda x: F.max_pool2d(x, 3, 1, 1, ceil_mode=True), 14),
+    "resnet_3x3_s2_pad1": (
+        lambda: nn.SpatialMaxPooling(3, 3, 2, 2, 1, 1),
+        lambda x: F.max_pool2d(x, 3, 2, 1), 16),
+    "aux_head_5x5_s3_avg": (
+        lambda: nn.SpatialAveragePooling(5, 5, 3, 3).ceil(),
+        lambda x: F.avg_pool2d(x, 5, 3, 0, ceil_mode=True), 14),
+    "head_7x7_avg": (
+        lambda: nn.SpatialAveragePooling(7, 7, 1, 1),
+        lambda x: F.avg_pool2d(x, 7, 1), 7),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("site", CELL_POOLS)
+def test_cell_pool_geometries_match_torch(site, dtype):
+    """Value and input gradient of each pool a CNN cell runs, in the
+    cells' dtype too; torch computes in float32 on the same (bf16-grid)
+    numbers and a cotangent of powers of two, so a max pool must agree
+    bitwise, first-argmax ties included."""
+    build, ref, plane = CELL_POOLS[site]
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(np.round(rng.normal(size=(2, 8, plane, plane)) * 4) / 4,
+                    dtype)
+    y, vjp = jax.vjp(jax.jit(build().update_output), x)
+    tx = torch.tensor(np.asarray(x, np.float32), requires_grad=True)
+    ty = ref(tx)
+    gy = _pow2_grads(rng, y.shape, dtype)
+    ty.backward(torch.tensor(np.asarray(gy, np.float32)))
+    exact = "avg" not in site
+    tol = dict(rtol=0, atol=0) if exact \
+        else dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
+        else dict(rtol=1e-5, atol=1e-6)
+    assert y.dtype == dtype
+    _cmp(np.asarray(y, np.float32), ty.detach().numpy(), **tol)
+    _cmp(np.asarray(vjp(gy)[0], np.float32), tx.grad.numpy(), **tol)
 
 
 def test_volumetric_max_pooling():

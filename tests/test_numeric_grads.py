@@ -5,12 +5,9 @@ layer's backward against either Torch or a numeric differentiator).
 differences, so custom-VJP layers and composite normalizations get a
 backward check even where no framework oracle exists.
 
-Every case runs under BOTH kernel-dispatch legs (``BIGDL_KERNELS=xla``
-and ``=pallas``): each of these layers routes through a
-``bigdl_tpu.ops`` custom-VJP op whose hand-derived exact cotangent must
-hold whether the backend is the XLA reference or the Pallas kernel (in
-interpret mode on the CPU suite — the identical code path that Mosaic
-compiles on TPU)."""
+Each of these layers routes through a ``bigdl_tpu.ops`` custom-VJP op
+with a hand-derived cotangent (``ops/lrn.py``, ``ops/norm.py``,
+``ops/pool.py``: one form each, on every platform)."""
 
 import numpy as np
 import jax
@@ -30,9 +27,7 @@ def _layer_fn(layer):
     def fn(x):
         return layer.update_output(x)
 
-    # compiled: check_grads evaluates fn and its VJP several times, and
-    # run eagerly a Pallas kernel in interpret mode dispatches every
-    # primitive of its body as a program of its own, each time
+    # compiled: check_grads evaluates fn and its VJP several times
     return jax.jit(fn)
 
 
@@ -49,8 +44,6 @@ CASES = [
     # custom-VJP paths
     ("maxpool_tie_split", lambda: nn.SpatialMaxPooling(3, 3, 2, 2, 1, 1)
      .split_ties(), (2, 3, 9, 9)),
-    # one leg since PR 44: the knob does not reach it, so both modes
-    # check the same banded product
     ("lrn_banded_conv", lambda: nn.SpatialCrossMapLRN(5, 0.0001, 0.75),
      (2, 7, 5, 5)),
     # ceil-mode average pooling (asymmetric declared-vs-overflow padding
@@ -58,14 +51,34 @@ CASES = [
     ("ceil_avg_pool", lambda: nn.SpatialAveragePooling(3, 3, 2, 2, 1, 1,
                                                        ceil_mode=True),
      (2, 3, 9, 9)),
+    # the Inception branches' 3x3/s1 "same" pool: overlapping windows
+    ("same_avg_pool", lambda: nn.SpatialAveragePooling(3, 3, 1, 1, 1, 1),
+     (2, 3, 6, 6)),
+    # padding left out of the divisor, with a ceil-overflow row
+    ("ceil_avg_pool_data_only", lambda: nn.SpatialAveragePooling(
+        3, 3, 2, 2, 1, 1, ceil_mode=True, count_include_pad=False),
+     (2, 3, 8, 8)),
+    # floor mode stops short of the last row and column: no window
+    # reaches them and their gradient is zero
+    ("floor_avg_pool_short", lambda: nn.SpatialAveragePooling(3, 3, 2, 2),
+     (1, 2, 8, 8)),
+    ("maxpool_tie_split_overlap", lambda: nn.SpatialMaxPooling(
+        3, 3, 1, 1, 1, 1).split_ties(), (1, 2, 6, 6)),
+    # EVEN window: asymmetric (lo, hi) pads, swapped in the transpose
+    ("within_channel_lrn_even", lambda: nn.SpatialWithinChannelLRN(
+        4, 0.01, 0.75), (1, 2, 7, 5)),
+    ("lrn_banded_conv_nhwc", lambda: nn.SpatialCrossMapLRN(
+        5, 0.0001, 0.75, format="NHWC"), (2, 5, 5, 7)),
+    ("volumetric_avg_pool", lambda: nn.VolumetricAveragePooling(
+        2, 3, 3, 2, 2, 2, 0, 1, 1), (1, 2, 4, 7, 7)),
+    ("temporal_maxpool_tie_split", lambda: nn.TemporalMaxPooling(3, 2)
+     .split_ties(), (2, 9, 4)),
 ]
 
 
-@pytest.mark.parametrize("kernels", ["xla", "pallas"])
 @pytest.mark.parametrize("case", CASES, ids=lambda c: c[0])
-def test_vjp_matches_finite_differences(case, kernels, monkeypatch):
+def test_vjp_matches_finite_differences(case):
     name, build, shape = case
-    monkeypatch.setenv("BIGDL_KERNELS", kernels)
     RNG.set_seed(0)
     # finite differences need f64 — scoped, so the rest of the suite
     # keeps the default f32 world

@@ -59,13 +59,12 @@ PINNED_MODULES = [
     # fit estimator, and OOM forensics — device OOMs revert to a bare
     # RESOURCE_EXHAUSTED with no resident-buffer evidence
     "bigdl_tpu/telemetry/memory.py",
-    # the kernel library (PR 6): losing any of these silently reverts
+    # the ops library (PR 6): losing any of these silently reverts
     # hot paths to unfused XLA chains and wrong-by-autodiff VJPs
     "bigdl_tpu/ops/dispatch.py",
-    "bigdl_tpu/ops/lrn_pallas.py",
-    "bigdl_tpu/ops/norm_pallas.py",
-    "bigdl_tpu/ops/pool_pallas.py",
-    "bigdl_tpu/ops/pooling_pallas.py",
+    "bigdl_tpu/ops/lrn.py",
+    "bigdl_tpu/ops/norm.py",
+    "bigdl_tpu/ops/pool.py",
     "bigdl_tpu/ops/attention.py",
     # the serving layer (ISSUE 8): losing any of these silently reverts
     # online inference to per-call EvalStep rebuilds (a compile per
