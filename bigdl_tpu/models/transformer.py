@@ -20,19 +20,26 @@ attention for sequences larger than one chip holds.
 (:class:`DecoderPlan`): RMS normalisation, rotary positions, a mixer
 whose kind differs by layer (grouped-query attention, full or within a
 window, with a gate a head or a channel and optionally normed queries
-and keys; gated delta-rule linear attention; a double-gated short
-convolution; or a Mamba-2 state-space mixer), and a dense gated or a
+and keys; latent attention, whose queries, keys and values come through
+low-rank latents and whose rotary key is one head shared by all; gated
+delta-rule linear attention; a double-gated short convolution; or a
+Mamba-2 state-space mixer), and a dense gated or a
 routed sparse feed-forward per layer (softmax scores, or sigmoid scores
 with a bias that chooses; experts on the model's width or in a latent),
 either of which a layer may lack, every block under ``nn.Remat``, the
 head its own matrix or the embedding's.  Four scalar multipliers a model
 may publish are the plan's too (on the embedding's output, on the
 attention scores, on what each part adds to the residual stream, and a
-divisor of the logits), each doing nothing at its default.  It trains
-with the same criterion; the
+divisor of the logits), each doing nothing at its default, and so is the
+residual path: one stream and a ``+`` by default, or ``residual_streams``
+streams that every block reads, writes and mixes through an
+``nn.HyperConnection`` a part (manifold-constrained hyper-connections:
+the mixing matrix made doubly stochastic by Sinkhorn's iterations), with
+the embedding copied into the streams and their sum before the final
+norm.  It trains with the same criterion; the
 benchmark's ``laguna_s_2_1``, ``qwen3_next_80b_a3b``, ``lfm2_24b_a2b``,
-``nemotron_3_super_120b_a12b`` and ``granite_4_0_h_micro``
-configurations are such plans at published widths.
+``nemotron_3_super_120b_a12b``, ``granite_4_0_h_micro`` and
+``xing4_0_29b_a4b`` configurations are such plans at published widths.
 """
 
 from __future__ import annotations
@@ -114,19 +121,23 @@ def build_transformer_lm(vocab_size: int, num_layers: int = 4,
 
 
 #: the kinds of mixer and of feed-forward a :class:`LayerPlan` may name
-ATTENTION_KINDS = ("full", "window", "linear", "conv", "ssm", "none")
+ATTENTION_KINDS = ("full", "window", "latent", "linear", "conv", "ssm",
+                   "none")
 FFN_KINDS = ("dense", "sparse", "none")
 
 
 class LayerPlan(NamedTuple):
     """One decoder layer: ``attention`` is the kind of its MIXER,
-    ``"full"``, ``"window"``, ``"linear"``, ``"conv"`` or ``"ssm"`` (the
-    field keeps the name it had when every mixer was an attention; a
-    ``"conv"`` layer is an :class:`nn.GatedShortConv`, an ``"ssm"`` layer
-    an :class:`nn.Mamba2Mixer`, and neither attends to anything),
-    ``heads`` its query heads (of a linear layer: its value heads; of an
-    ``"ssm"`` layer: its state-space heads; a ``"conv"`` layer has none
-    and ignores it), ``ffn`` ``"dense"`` or ``"sparse"``.  ``"none"`` in
+    ``"full"``, ``"window"``, ``"latent"``, ``"linear"``, ``"conv"`` or
+    ``"ssm"`` (the field keeps the name it had when every mixer was an
+    attention; a ``"latent"`` layer is an :class:`nn.LatentAttention`, a
+    ``"conv"`` layer an :class:`nn.GatedShortConv`, an ``"ssm"`` layer
+    an :class:`nn.Mamba2Mixer`, and the last two attend to nothing),
+    ``heads`` its query heads (of a ``"latent"`` layer: its heads, each
+    with keys and values of its own out of the shared latent, so the
+    plan's ``kv_heads`` is not read; of a linear layer: its value heads;
+    of an ``"ssm"`` layer: its state-space heads; a ``"conv"`` layer has
+    none and ignores it), ``ffn`` ``"dense"`` or ``"sparse"``.  ``"none"`` in
     either place: the layer lacks that part and is the other alone, one
     norm and one residual add (both ``"none"`` is refused)."""
     attention: str
@@ -159,7 +170,20 @@ class DecoderPlan(NamedTuple):
     :class:`nn.Mamba2Mixer` of heads of ``ssm_head_dim``, ``ssm_groups``
     groups, a state of ``ssm_state`` and a convolution of ``ssm_conv``
     taps.  ``tie_embeddings``: the head projects with the embedding's own
-    matrix, one parameter read in two places.
+    matrix, one parameter read in two places.  A ``"latent"`` layer is an
+    :class:`nn.LatentAttention` whose queries come through a latent of
+    ``q_rank`` and whose keys and values through one of ``kv_rank``; a
+    head's query and key are ``head_dim`` (the part without positions)
+    plus ``rope_dim`` (rotated by ``rotary_full``, which is then a rotary
+    of ``rope_dim`` dimensions; the key's is one head shared by all)
+    wide, its value ``value_dim``.
+
+    ``residual_streams`` > 1: manifold-constrained hyper-connections.
+    The embedding is copied into that many streams, every block's parts
+    sit inside an :class:`nn.HyperConnection` each (``sinkhorn_iters``
+    iterations, ``residual_clamp`` on the mixing logits, ``residual_eps``
+    in the streams' norm) and the final norm reads the streams' sum.  At
+    1 none of it is built.
 
     Four scalars, each of which multiplies nothing at its default:
     ``embedding_scale`` the embedding's output is multiplied by;
@@ -209,6 +233,14 @@ class DecoderPlan(NamedTuple):
     attention_scale: Optional[float] = None
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    q_rank: int = 0
+    kv_rank: int = 0
+    rope_dim: int = 0
+    value_dim: int = 0
+    residual_streams: int = 1
+    sinkhorn_iters: int = 20
+    residual_clamp: float = 30.0
+    residual_eps: float = 1e-6
 
 
 class VocabHead(Module):
@@ -275,6 +307,9 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
     model = nn.Sequential(embedding)
     if plan.embedding_scale != 1.0:
         model.add(nn.MulConstant(plan.embedding_scale))
+    streams = plan.residual_streams
+    if streams > 1:
+        model.add(nn.StreamExpand(streams))
     for layer in plan.layers:
         windowed = layer.attention == "window"
         if layer.attention == "none":
@@ -286,6 +321,12 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
                 eps=plan.eps)
         elif layer.attention == "conv":
             attn = nn.GatedShortConv(plan.hidden_size, taps=plan.conv_taps)
+        elif layer.attention == "latent":
+            attn = nn.LatentAttention(
+                plan.hidden_size, layer.heads, plan.head_dim, plan.rope_dim,
+                plan.value_dim, plan.q_rank, plan.kv_rank,
+                rotary=plan.rotary_full, scale=plan.attention_scale,
+                eps=plan.eps, backend=backend)
         elif layer.attention == "linear":
             attn = nn.GatedDeltaNet(
                 plan.hidden_size, plan.linear_key_heads, layer.heads,
@@ -312,10 +353,15 @@ def build_decoder_lm(plan: DecoderPlan, remat: bool = True,
                 select_bias=plan.router_bias,
                 activation=plan.expert_activation,
                 latent=plan.expert_latent)
-        block = nn.DecoderBlock(plan.hidden_size, attn, ffn, eps=plan.eps,
-                                zero_centred=zero,
-                                residual_scale=plan.residual_scale)
+        block = nn.DecoderBlock(
+            plan.hidden_size, attn, ffn, eps=plan.eps, zero_centred=zero,
+            residual_scale=plan.residual_scale, streams=streams,
+            sinkhorn_iters=plan.sinkhorn_iters,
+            residual_clamp=plan.residual_clamp,
+            residual_eps=plan.residual_eps)
         model.add(nn.Remat(block) if remat else block)
+    if streams > 1:
+        model.add(nn.StreamSum(streams))
     model.add(nn.RMSNorm(plan.hidden_size, plan.eps, zero_centred=zero))
     model.add(VocabHead(plan.hidden_size, plan.vocab_size,
                         tied_to=embedding if plan.tie_embeddings else None,

@@ -18,6 +18,7 @@ from bigdl_tpu.nn.layers.shape import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.container_ext import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.rnn import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.attention import *  # noqa: F401,F403
+from bigdl_tpu.nn.layers.hyper_connection import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.linear_attention import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.short_conv import *  # noqa: F401,F403
 from bigdl_tpu.nn.layers.ssm import *  # noqa: F401,F403

@@ -21,7 +21,14 @@ from bigdl_tpu.nn.layers.linear import Linear
 from bigdl_tpu.nn.module import Module, Parameter
 
 __all__ = ["LayerNorm", "MultiHeadAttention", "TransformerBlock",
-           "Rotary", "GroupedQueryAttention", "DecoderBlock"]
+           "Rotary", "GroupedQueryAttention", "LatentAttention",
+           "DecoderBlock"]
+
+
+#: the ``jax.named_scope`` around a latent-attention layer's work between
+#: its first product and its output projection (the compiled step's
+#: ``op_name`` metadata carries it, a device trace's events do not)
+LATENT_SCOPE = "mla"
 
 
 def generation_cache_context():
@@ -279,6 +286,30 @@ class Rotary(NamedTuple):
         return out.astype(x.dtype)
 
 
+def _attend_causal(op, backend, q, k, v, **facts):
+    """Causal attention of [B, H, S, D] arrays on the leg ``backend`` names
+    (``auto``: ``ops.attention.select_attention_backend``'s rule),
+    announced as ``kernel/dispatch op=<op>`` with the layer's ``facts``
+    (its ``scale``, and its ``window`` if it has one, are the call's too)
+    and, for the flash leg, its blocks."""
+    from bigdl_tpu.ops.attention import (dot_product_attention,
+                                         flash_attention, flash_blocks,
+                                         select_attention_backend)
+    from bigdl_tpu.ops.dispatch import note
+
+    s, window = q.shape[2], facts.get("window")
+    reason = "layer:backend"
+    if backend == "auto":
+        backend, reason = select_attention_backend(s, s)
+    if backend == "flash":
+        bq, bk, visited, total = flash_blocks(s, s, True, window)
+        facts.update(block_q=bq, block_k=bk, blocks_visited=visited,
+                     blocks_total=total)
+    note(op, "pallas" if backend == "flash" else "xla", reason, **facts)
+    attend = flash_attention if backend == "flash" else dot_product_attention
+    return attend(q, k, v, causal=True, window=window, scale=facts["scale"])
+
+
 class GroupedQueryAttention(Module):
     """Causal self-attention over [batch, seq, embed] with fewer key/
     value heads than query heads (Ainslie et al. 2023): query head h
@@ -343,28 +374,10 @@ class GroupedQueryAttention(Module):
                                with_bias=False)
 
     def _attend(self, q, k, v):
-        from bigdl_tpu.ops.attention import (dot_product_attention,
-                                             flash_attention, flash_blocks,
-                                             select_attention_backend)
-        from bigdl_tpu.ops.dispatch import note
-
-        s = q.shape[2]
-        backend, reason = self.backend, "layer:backend"
-        if backend == "auto":
-            backend, reason = select_attention_backend(s, s)
-        facts = dict(window=self.window, q_heads=self.num_heads,
-                     kv_heads=self.num_kv_heads, head_dim=self.head_dim,
-                     gate=self.gate, qk_norm=self.qk_norm, scale=self.scale)
-        if backend == "flash":
-            bq, bk, visited, total = flash_blocks(s, s, True, self.window)
-            facts.update(block_q=bq, block_k=bk, blocks_visited=visited,
-                         blocks_total=total)
-        note("attention", "pallas" if backend == "flash" else "xla", reason,
-             **facts)
-        attend = flash_attention if backend == "flash" \
-            else dot_product_attention
-        return attend(q, k, v, causal=True, window=self.window,
-                      scale=self.scale)
+        return _attend_causal(
+            "attention", self.backend, q, k, v, window=self.window, q_heads=self.num_heads,
+            kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+            gate=self.gate, qk_norm=self.qk_norm, scale=self.scale)
 
     def update_output(self, input):
         b, s, _ = input.shape
@@ -402,34 +415,154 @@ class GroupedQueryAttention(Module):
                 f"{self.num_heads}/{self.num_kv_heads}, window={self.window})")
 
 
+class LatentAttention(Module):
+    """Causal multi-head latent attention over [batch, seq, embed]
+    (DeepSeek-V2, arXiv:2405.04434): queries, keys and values all come
+    through low-rank latents, and the rotary part of every head's key is
+    ONE shared head.  ``u`` the layer's input, ``Norm`` an ``RMSNorm``
+    (``eps``), ``H`` heads, no bias:
+
+    - ``c_q = Norm_q(u W_qa)`` (``q_rank``); ``[q_nope, q_rope] =
+      split_heads(c_q W_qb, H x [head_dim, rope_dim])``;
+    - ``[c_kv, k_rope] = split(u W_kva, [kv_rank, rope_dim])``; ``c_kv <-
+      Norm_kv(c_kv)``; ``[k_nope, v] = split_heads(c_kv W_kvb, H x
+      [head_dim, value_dim])``;
+    - ``q_h = [q_nope_h, rope(q_rope_h)]``, ``k_h = [k_nope_h,
+      rope(k_rope)]``: the one rotated key under every head (``rotary``:
+      a :class:`Rotary` of ``rope_dim`` dimensions, or None);
+    - ``s_ij = scale q_i . k_j`` for ``j <= i`` (``scale`` None: ``1 /
+      sqrt(head_dim + rope_dim)``; a model whose YaRN carries an
+      ``mscale`` hands in its own), softmax in float32, ``o_h = sum_j
+      p_ij v_j`` of ``value_dim``; ``concat_h(o_h) W_o``.
+
+    Queries and keys are ``head_dim + rope_dim`` wide and values
+    ``value_dim``: either leg (``backend`` as
+    :class:`GroupedQueryAttention`'s) takes ``v`` of a width of its own.
+    ``u W_qa`` and ``u W_kva`` are one product.  The decision is announced
+    on a ``kernel/dispatch`` instant ``op=latent_attention`` with
+    ``heads``, ``qk_dim``, ``rope_dim``, ``value_dim``, ``q_rank``,
+    ``kv_rank``, ``scale`` and, for the flash leg, its blocks.  No latent
+    cache: this layer trains and scores."""
+
+    def __init__(self, embed_dim: int, num_heads: int, head_dim: int,
+                 rope_dim: int, value_dim: int, q_rank: int, kv_rank: int,
+                 rotary: Optional[Rotary] = None,
+                 scale: Optional[float] = None, eps: float = 1e-6,
+                 backend: str = "auto"):
+        super().__init__()
+        from bigdl_tpu.nn.layers.normalization import RMSNorm
+
+        if rotary is not None and rotary.dims != rope_dim:
+            raise ValueError(f"a rotary of {rotary.dims} dimensions on a "
+                             f"rotary key of {rope_dim}")
+        self.embed_dim, self.num_heads = embed_dim, num_heads
+        self.head_dim, self.rope_dim, self.value_dim = \
+            head_dim, rope_dim, value_dim
+        self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.rotary, self.backend = rotary, backend
+        self.scale = 1.0 / math.sqrt(head_dim + rope_dim) \
+            if scale is None else scale
+        self.q_a = Linear(embed_dim, q_rank, with_bias=False)
+        self.q_norm = RMSNorm(q_rank, eps)
+        self.q_b = Linear(q_rank, num_heads * (head_dim + rope_dim),
+                          with_bias=False)
+        self.kv_a = Linear(embed_dim, kv_rank + rope_dim, with_bias=False)
+        self.kv_norm = RMSNorm(kv_rank, eps)
+        self.kv_b = Linear(kv_rank, num_heads * (head_dim + value_dim),
+                           with_bias=False)
+        self.out_proj = Linear(num_heads * value_dim, embed_dim,
+                               with_bias=False)
+
+    def _attend(self, q, k, v):
+        return _attend_causal(
+            "latent_attention", self.backend, q, k, v, heads=self.num_heads, qk_dim=q.shape[-1], rope_dim=self.rope_dim,
+            value_dim=self.value_dim, q_rank=self.q_rank,
+            kv_rank=self.kv_rank, scale=self.scale)
+
+    def update_output(self, input):
+        b, s, _ = input.shape
+        h, d, r, dv = (self.num_heads, self.head_dim, self.rope_dim,
+                       self.value_dim)
+        # one product for both latents of the same input
+        fused = jnp.dot(input, jnp.concatenate(
+            [self.q_a.weight, self.kv_a.weight], axis=0).T.astype(
+                input.dtype))
+        with jax.named_scope(LATENT_SCOPE):
+            c_q, c_kv, k_rope = jnp.split(
+                fused, [self.q_rank, self.q_rank + self.kv_rank], axis=-1)
+            q = self.q_b.forward(self.q_norm.forward(c_q)).reshape(
+                b, s, h, d + r)
+            kv = self.kv_b.forward(self.kv_norm.forward(c_kv)).reshape(
+                b, s, h, d + dv)
+            q_rope, k_rope = q[..., d:], k_rope.reshape(b, s, 1, r)
+            if self.rotary is not None:
+                q_rope = self.rotary.apply(q_rope)
+                k_rope = self.rotary.apply(k_rope)
+            q = jnp.concatenate([q[..., :d], q_rope], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :d], jnp.broadcast_to(k_rope, (b, s, h, r))],
+                axis=-1)
+            out = self._attend(q.transpose(0, 2, 1, 3),
+                               k.transpose(0, 2, 1, 3),
+                               kv[..., d:].transpose(0, 2, 1, 3))
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
+        return self.out_proj.forward(out)
+
+    def __repr__(self):
+        return (f"LatentAttention({self.embed_dim}, heads={self.num_heads}x"
+                f"({self.head_dim}+{self.rope_dim})/{self.value_dim}, ranks="
+                f"{self.q_rank}/{self.kv_rank})")
+
+
 class DecoderBlock(Module):
     """Pre-norm decoder block with RMS normalisation: ``h = x +
     attn(norm1(x))``, ``y = h + ffn(norm2(h))``.  ``attn`` and ``ffn`` are
     modules over [batch, seq, embed] (a :class:`GroupedQueryAttention`, a
-    ``GatedDeltaNet``, a ``GatedShortConv`` or a ``Mamba2Mixer``; a
-    ``GatedMLP`` or a ``RoutedExperts``); ``zero_centred`` is the norms'
+    :class:`LatentAttention`, a ``GatedDeltaNet``, a ``GatedShortConv`` or
+    a ``Mamba2Mixer``; a ``GatedMLP`` or a ``RoutedExperts``);
+    ``zero_centred`` is the norms'
     (``RMSNorm``).  Either part may be ``None``: the block is then the
     ONE sub-layer it has, ``y = x + attn(norm1(x))`` or ``y = x +
     ffn(norm2(x))``, one norm and one residual add (the layers of a
     decoder whose every layer is a mixer or a feed-forward alone).
     ``residual_scale`` multiplies what each part adds, ``h = x +
     residual_scale * attn(norm1(x))`` and the same for ``ffn`` (a model's
-    ``residual_multiplier``); at 1 nothing is multiplied."""
+    ``residual_multiplier``); at 1 nothing is multiplied.
+
+    ``streams`` > 1: the block carries that many residual streams
+    ([batch, seq, streams * embed]) and each part it has sits inside an
+    ``nn.HyperConnection`` (``hc_attn``, ``hc_ffn``; built with
+    ``sinkhorn_iters``, ``residual_clamp``, ``residual_eps``), which reads
+    the part's input from the streams and writes its output back to them
+    in place of the ``+``; at 1 none is built and the block is the one
+    above."""
 
     def __init__(self, embed_dim: int, attn: Optional[Module],
                  ffn: Optional[Module], eps: float = 1e-6,
-                 zero_centred: bool = False, residual_scale: float = 1.0):
+                 zero_centred: bool = False, residual_scale: float = 1.0,
+                 streams: int = 1, sinkhorn_iters: int = 20,
+                 residual_clamp: float = 30.0, residual_eps: float = 1e-6):
         super().__init__()
+        from bigdl_tpu.nn.layers.hyper_connection import HyperConnection
         from bigdl_tpu.nn.layers.normalization import RMSNorm
 
         if attn is None and ffn is None:
             raise ValueError("a decoder block with neither a mixer nor a "
                              "feed-forward")
-        self.residual_scale = residual_scale
+        self.residual_scale, self.streams = residual_scale, streams
+
+        def path():
+            return HyperConnection(embed_dim, streams, sinkhorn_iters,
+                                   residual_clamp, residual_eps)
+
         if attn is not None:
+            if streams > 1:
+                self.hc_attn = path()
             self.norm1 = RMSNorm(embed_dim, eps, zero_centred)
             self.attn = attn
         if ffn is not None:
+            if streams > 1:
+                self.hc_ffn = path()
             self.norm2 = RMSNorm(embed_dim, eps, zero_centred)
             self.ffn = ffn
         self.parts = tuple(name for name, part in (("attn", attn),
@@ -440,8 +573,19 @@ class DecoderBlock(Module):
         return part if self.residual_scale == 1.0 \
             else part * self.residual_scale
 
+    def _around(self, path, norm, part, x):
+        """One part inside its hyper-connection, on the streams ``x``."""
+        u, mix = path.forward(x)
+        return path.merge(x, self._scaled(part.forward(norm.forward(u))), mix)
+
     def update_output(self, input):
         h = input
+        if self.streams > 1:
+            if "attn" in self.parts:
+                h = self._around(self.hc_attn, self.norm1, self.attn, h)
+            if "ffn" in self.parts:
+                h = self._around(self.hc_ffn, self.norm2, self.ffn, h)
+            return h
         if "attn" in self.parts:
             h = h + self._scaled(self.attn.forward(self.norm1.forward(h)))
         if "ffn" in self.parts:

@@ -129,6 +129,14 @@ class Remat(Container):
             return out, [after[n] for n in names]
 
         policy = _keep_named if self._policy is None else self._policy
+        streams = getattr(inner, "streams", 1)
+        if streams > 1:
+            # the block's input is kept whatever the policy, and here it
+            # is ``streams`` times a one-stream block's
+            telemetry.instant(
+                "remat/keep", kept="residual_streams", streams=streams,
+                shape=list(input.shape), dtype=str(input.dtype),
+                bytes=input.size * input.dtype.itemsize)
         out, buffers = jax.checkpoint(run, policy=policy)(input)
         load_state_dict(inner, dict(zip(names, buffers)), strict=False)
         return out

@@ -64,7 +64,8 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     for query position i, the keys j with ``i - j < window``.  ``k``/``v``
     may carry fewer heads than ``q`` (grouped-query attention): query
     head h reads kv head ``h // (H / G)``, found by a reshape of ``q``,
-    never by repeating ``k``."""
+    never by repeating ``k``.  ``v`` may be of another width than ``q``
+    and ``k``: the result is as wide as ``v``."""
     if window is not None and not causal:
         raise ValueError("window attention is causal: pass causal=True")
     b, h, sq, d = q.shape
@@ -96,7 +97,7 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     valid = jnp.max(s, axis=-1, keepdims=True) > _NEG_INF / 2
     p = jnp.where(valid, p, 0.0)
     out = jnp.einsum(pv, p.astype(v.dtype), v)
-    return out.reshape(b, h, sq, d)
+    return out.reshape(b, h, sq, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -512,12 +513,12 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
-    g, sk = k.shape[1], k.shape[2]
+    g, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
     geom = _geometry(q, k, causal, window, block_q, block_k)
     bq, bk = geom.bq, geom.bk
     qr = q.reshape(b * h, sq, d)
     kr = k.reshape(b * g, sk, d)
-    vr = v.reshape(b * g, sk, d)
+    vr = v.reshape(b * g, sk, dv)
 
     def q_map(bh, qi, j):
         return bh, qi, 0
@@ -532,24 +533,24 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bk, d), k_map),
-            pl.BlockSpec((1, bk, d), k_map),
+            pl.BlockSpec((1, bk, dv), k_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bq, dv), q_map),
             pl.BlockSpec((1, bq, 1), q_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
+    return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
 def _flash_bwd_impl(q, k, v, out, lse, do, scale, causal,
@@ -558,14 +559,14 @@ def _flash_bwd_impl(q, k, v, out, lse, do, scale, causal,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
-    g, sk = k.shape[1], k.shape[2]
+    g, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
     geom = _geometry(q, k, causal, window, block_q, block_k)
     bq, bk = geom.bq, geom.bk
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     qr = q.reshape(b * h, sq, d)
     kr = k.reshape(b * g, sk, d)
-    vr = v.reshape(b * g, sk, d)
-    dor = do.reshape(b * h, sq, d)
+    vr = v.reshape(b * g, sk, dv)
+    dor = do.reshape(b * h, sq, dv)
     lser = lse.reshape(b * h, sq, 1)
     deltar = delta.reshape(b * h, sq, 1)
 
@@ -582,8 +583,8 @@ def _flash_bwd_impl(q, k, v, out, lse, do, scale, causal,
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bk, d), k_map),
-            pl.BlockSpec((1, bk, d), k_map),
-            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, dv), k_map),
+            pl.BlockSpec((1, bq, dv), q_map),
             pl.BlockSpec((1, bq, 1), q_map),
             pl.BlockSpec((1, bq, 1), q_map),
         ],
@@ -600,33 +601,33 @@ def _flash_bwd_impl(q, k, v, out, lse, do, scale, causal,
         lo, hi = geom.q_bounds(ki)
         return geom.q_head(bg, r), _mn(lo + j, hi), 0
 
-    dk, dv = pl.pallas_call(
+    dk, dvals = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, geom=geom),
         grid=(b * g, geom.nk, geom.group, geom.q_visits()),
         in_specs=[
             pl.BlockSpec((1, bq, d), qrow_map),
             pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bq, d), qrow_map),
+            pl.BlockSpec((1, bk, dv), kv_map),
+            pl.BlockSpec((1, bq, dv), qrow_map),
             pl.BlockSpec((1, bq, 1), qrow_map),
             pl.BlockSpec((1, bq, 1), qrow_map),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, dv), kv_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * g, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * g, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * g, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, g, sk, d),
-            dv.reshape(b, g, sk, d))
+            dvals.reshape(b, g, sk, dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -695,12 +696,15 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None):
-    """Flash attention (Pallas TPU kernel).  ``q`` [B, H, S, D], ``k``/
-    ``v`` [B, G, S, D] with G dividing H (grouped-query attention: the
-    block index map finds a query head's kv head, nothing is repeated);
-    out like ``q``.  ``window`` (with ``causal``) keeps keys j with
-    ``i - j < window`` and bounds the key-block loop from below as
-    causality bounds it from above.
+    """Flash attention (Pallas TPU kernel).  ``q`` [B, H, S, D], ``k``
+    [B, G, S, D] and ``v`` [B, G, S, Dv] with G dividing H (grouped-query
+    attention: the block index map finds a query head's kv head, nothing
+    is repeated); out [B, H, S, Dv].  ``v`` has a width of its own: the
+    output, its cotangent, ``dv`` and the forward's accumulator follow
+    ``v``; ``dq`` and ``dk`` follow ``q`` (latent attention runs heads of
+    192 over values of 128 as they are).  ``window`` (with ``causal``)
+    keeps keys j with ``i - j < window`` and bounds the key-block loop
+    from below as causality bounds it from above.
 
     O(S) memory: softmax is computed online per q block over streamed k/v
     blocks; backward recomputes p from the saved logsumexp (no S x S

@@ -136,7 +136,9 @@ STREAM_NAMES = frozenset({
     # over the root of head_dim) and the flash leg's blocks;
     # op=gated_short_conv: taps, channels, tokens; op=ssd: chunk, chunks,
     # heads, head_dim, state, groups and, on its Pallas leg, head_block,
-    # grid; op=lrn_cross_map.fwd|.bwd: channels, size, layout)
+    # grid; op=lrn_cross_map.fwd|.bwd: channels, size, layout;
+    # op=latent_attention: heads, qk_dim, rope_dim, value_dim, q_rank,
+    # kv_rank, scale and the flash leg's blocks)
     "kernel/dispatch",
     # routed experts (bigdl_tpu/nn/layers/moe.py RoutedExperts): one
     # instant per TRACE of a layer (experts, held, top_k, capacity
@@ -183,6 +185,17 @@ STREAM_NAMES = frozenset({
     # kernel/dispatch instant with op=ssd: chunk, chunks, heads,
     # head_dim, state, groups and, on its Pallas leg, head_block, grid)
     "ssm/decay_mean", "ssm/dt_mean", "ssm/state_norm_max",
+    # multi-stream residual path (bigdl_tpu/nn/layers/hyper_connection.py
+    # HyperConnection): one instant per TRACE of a path (streams,
+    # sinkhorn_iters, clamp, eps, embed_dim, dtype of the streams), and
+    # per step and path the largest distance of a column sum of the
+    # mixing matrix from 1 (Sinkhorn's own error after its iterations),
+    # the mean off-diagonal entry of that matrix, and the means of the
+    # read and the write weights (counters, as above; a block under
+    # nn.Remat says the streams it keeps as its input on a remat/keep
+    # instant, kept=residual_streams)
+    "residual/mhc", "mhc/col_err_max", "mhc/res_offdiag_mean",
+    "mhc/pre_mean", "mhc/post_mean",
     # fault tolerance (bigdl_tpu/faults.py + docs/fault_tolerance.md):
     # injected faults, quarantined torn checkpoints, graceful
     # preemption, and checkpoint auto-resume

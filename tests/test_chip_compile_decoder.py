@@ -17,6 +17,7 @@ import json
 import math
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -559,3 +560,137 @@ def test_ssd_kernels_compile_and_are_read_as_the_scan(config, one_chip,
                            other + ".json")) as fh:
         theirs = json.load(fh)["ssd_scan_match"]
     assert not [ln for ln in calls if re.search(theirs, ln.strip())]
+
+
+# -- the flash kernels' value width, and the plan that needs it ---------------
+
+def _masked_sha(text):
+    """sha256 of a lowered text with its Mosaic payload bodies masked
+    (they carry the line numbers of the kernel's call stack)."""
+    import hashlib
+
+    masked = re.sub(r'\\22body\\22: \\22.*?\\22', "", text)
+    return hashlib.sha256(masked.encode()).hexdigest()
+
+
+def _gqa_block_text(one_chip):
+    """A one-stream decoder block of grouped-query attention (12 heads on
+    4 kv heads of 128, a window of 512, rotary, a per-head gate) and a
+    gated MLP, forward and backward in bfloat16 at 2,048 positions,
+    lowered for the chip."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.nn.module import functional_call, state_dict
+
+    attn = nn.GroupedQueryAttention(512, 12, 4, 128, window=512,
+                                    rotary=nn.Rotary(64), gate="per_head")
+    block = nn.DecoderBlock(512, attn, nn.GatedMLP(512, 1024))
+
+    def loss(params, x):
+        y, _ = functional_call(block, params, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def shaped(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    params = {k: shaped(v.shape)
+              for k, v in state_dict(block, kind="param").items()}
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, shaped((1, 2048, 512))).as_text()
+
+
+#: ``_masked_sha(_gqa_block_text(...))`` on the tree BEFORE the kernels
+#: learned a value width of their own (commit b82440d, PR 46), made by
+#: importing this function over that tree's ``bigdl_tpu``
+GQA_BLOCK_BEFORE = "76a293969ba45a53d822c23db4815df7f2626f4678b6907d83ac0bba3f1f529c"
+
+
+def test_at_equal_widths_a_grouped_query_block_lowers_as_it_did(one_chip,
+                                                                as_tpu):
+    """The kernels take ``v`` of a width of its own; where it is ``q``'s,
+    nothing of a block's lowered program moved: every operand type, block
+    shape, grid and scratch of the three calls, and everything around
+    them, byte for byte outside the Mosaic payloads."""
+    text = _gqa_block_text(one_chip)
+    assert text.count("tpu_custom_call") == 3
+    assert _masked_sha(text) == GQA_BLOCK_BEFORE
+
+
+def test_xing4_expert_layer_lowers_for_the_chip_with_two_widths(
+        one_chip, as_tpu, monkeypatch):
+    """An expert layer of the ``xing4_0_29b_a4b`` plan at its published
+    widths (hidden 3584 in four streams; 32 latent-attention heads of 128 +
+    64 rotary over values of 128 out of latents of 768 and 512; 8 of 64
+    experts of 1,024 and a shared one), forward and backward in bfloat16
+    at 8,192 positions, lowered for the chip: the three flash calls hold
+    queries and keys of 192 and values of 128 as they are (nothing is
+    padded to 256), the layer said so, the routed layer runs a prefix of
+    16,384 rows and scatter-adds it, and each of the two residual paths
+    said its four streams and twenty iterations."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.nn import init
+    from bigdl_tpu.nn.module import functional_call, state_dict
+    from bigdl_tpu.ops import dispatch
+
+    sys.path.insert(0, ROOT)
+    from benchmark.models import xing4
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "xing4_0_29b_a4b.json")) as fh:
+        conf = json.load(fh)
+    said = []
+    monkeypatch.setattr(telemetry, "instant",
+                        lambda name, **attrs: said.append((name, attrs)))
+    # the values do not reach a lowering: zeros, not 500 MB of draws
+    monkeypatch.setattr(init.RandomUniform, "init",
+                        lambda self, shape, **_: np.zeros(shape, np.float32))
+    # the family's own plan, cut to ONE expert layer and a toy vocabulary
+    model = xing4.build(dict(conf, num_hidden_layers=1, first_layer=2,
+                             vocab_size=128))
+    assert isinstance(model.layers[1], nn.StreamExpand)
+    assert isinstance(model.layers[3], nn.StreamSum)
+    block = model.layers[2]                              # under nn.Remat
+    assert isinstance(block, nn.Remat)
+    buffers = state_dict(block, kind="buffer")
+
+    def loss(params, x):
+        y, _ = functional_call(block, {**params, **buffers}, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def shaped(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    params = {k: shaped(v.shape)
+              for k, v in state_dict(block, kind="param").items()}
+    assert sum(math.prod(v.shape) for v in params.values()) == 128426358
+    dispatch.clear_decisions()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, shaped((1, 8192, 4 * 3584))).as_text(dialect="hlo")
+    (leg,) = [s for s in dispatch.decisions() if s[0] == "latent_attention"]
+    assert tuple(leg) == ("latent_attention", "pallas", "auto:tpu")
+    assert leg.launch == dict(
+        heads=32, qk_dim=192, rope_dim=64, value_dim=128, q_rank=768,
+        kv_rank=512, scale=pytest.approx(192 ** -0.5 * (0.1 * math.log(64)
+                                                        + 1) ** 2),
+        block_q=1024, block_k=512, blocks_visited=72, blocks_total=128)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3                   # the forward is not run again
+    assert not [ln for ln in calls if "[32,8192,256]" in ln]
+    # out and logsumexp follow v; dq follows q; dk follows q and dv follows v
+    results = sorted(re.sub(r"\{[\d,]*\}", "", ln.split(" custom-call(")[0]
+                            .split(" = ")[1]) for ln in calls)
+    assert results == ["(bf16[32,8192,128], f32[32,8192,1])",
+                       "(bf16[32,8192,192], bf16[32,8192,128])",
+                       "bf16[32,8192,192]"]
+    (route,) = [a for name, a in said if name == "moe/route"]
+    assert (route["capacity"], route["worst"], route["combine"],
+            route["score"], route["select_bias"], route["shared"]) == (
+        16384, 32768, "scatter_add", "sigmoid", True, True)
+    paths = [a for name, a in said if name == "residual/mhc"]
+    assert len(paths) >= 2 and {(a["streams"], a["sinkhorn_iters"],
+                                 a["clamp"], a["dtype"]) for a in paths} == {
+        (4, 20, 30.0, "bfloat16")}
+    (kept,) = {(a["streams"], tuple(a["shape"]), a["bytes"])
+               for name, a in said if name == "remat/keep"
+               and a["kept"] == "residual_streams"}
+    assert kept == (4, (1, 8192, 14336), 8192 * 14336 * 2)

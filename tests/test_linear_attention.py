@@ -380,7 +380,7 @@ def test_the_builder_checks_a_plan_before_it_builds_a_module(monkeypatch):
                         lambda *a, **k: built.append(a))
     bad = list(plan.layers) + [models.LayerPlan("mamba", 4, "dense")]
     with pytest.raises(ValueError, match="layer 4.*'mamba'.*full, window, "
-                                         "linear"):
+                                         "latent, linear"):
         models.build_decoder_lm(plan._replace(layers=bad))
     bad = list(plan.layers) + [models.LayerPlan("full", 4, "soft")]
     with pytest.raises(ValueError, match="'soft'.*dense, sparse"):

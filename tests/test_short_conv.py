@@ -415,7 +415,7 @@ def test_lfm2_plan_loss_and_every_gradient_match_the_reference(over, family):
 def test_the_builder_names_the_conv_kind_and_leaves_other_plans_alone():
     plan = models.tiny_decoder_plan(64)
     bad = list(plan.layers) + [models.LayerPlan("mamba", 4, "dense")]
-    with pytest.raises(ValueError, match="full, window, linear, conv"):
+    with pytest.raises(ValueError, match="full, window, latent, linear, conv"):
         models.build_decoder_lm(plan._replace(layers=bad))
     assert (plan.conv_taps, plan.router_score, plan.router_bias,
             plan.tie_embeddings) == (3, "softmax", False, False)
