@@ -25,62 +25,73 @@ skip weight a head.  Two forms stand here:
   so everything but ``S`` is batched products over all chunks at once
   (``C B^T`` once a group; the decay matrix ``exp(L_t - L_s)``, masked
   BEFORE the exponential, a head), and what is left to do in order is a
-  ``lax.scan`` over the chunks that carries ``S`` and does no product:
-  ``S' = exp(L_Q) S + S_own``.  The states that enter the chunks come out
-  of it stacked, and ``exp(L_t) S C_t`` is one more batched product.
-  Every exponent is of a number that is not positive.  Matrix products
-  take their operands in ``x``'s dtype and accumulate in float32; the
-  decays and the carried state are float32.  The scan's backward is
-  autodiff's: it keeps the state that enters each chunk.
+  walk over the chunks that carries ``S`` and does no product: ``S' =
+  exp(L_Q) S + S_own``.  The states that enter the chunks are kept for
+  the backward, and ``exp(L_t) S C_t`` is one more product.  Every
+  exponent is of a number that is not positive.  Matrix products take
+  their operands in ``x``'s dtype and accumulate in float32; the decays
+  and the carried state are float32.
 
 Shapes: ``x`` ``[batch, seq, heads, head_dim]``, ``dt`` ``[batch, seq,
 heads]``, ``A``, ``D`` ``[heads]``, ``B``, ``C`` ``[batch, seq, groups,
 state]``; the result is ``x``'s shape and dtype.  A length the chunk does
 not divide is padded with tokens that change nothing (``dt = 0``).
 
-TWO legs compute what is local to the chunks; the scan over the chunks
-(:func:`_carry`: ``S' = exp(L_Q) S + S_own``, no product) is one
-``lax.scan`` for both and its backward is autodiff's.  It stays XLA's
-``while`` because the benchmark counts a call by it; a state carried in
-VMEM across the chunks is a later step (ROADMAP S8g).
-``ops/dispatch.choose_backend`` picks, from what the trace can see:
+TWO legs, and where the state lives between chunks is what tells them
+apart.  ``ops/dispatch.choose_backend`` picks, from what the trace can
+see:
 
 - XLA's (:func:`_chunked`): batched products for all chunks at once
   through HBM (``C B^T``, the decayed ``Q x Q`` matrix times it, ``dt x``,
   the own and entering states), operands transposed to ``[batch, groups,
-  heads a group, chunks, Q, *]`` and ``y`` transposed back; autodiff's
-  backward.  Off the TPU, inside ``dispatch.spmd_partitioned`` (a Mosaic
-  kernel cannot be partitioned over a mesh), under ``BIGDL_KERNELS=xla``,
-  and at a shape the kernels do not take.
-- Pallas's (:func:`_scan_pallas`, one ``jax.custom_vjp`` around the
-  carry), on a TPU when ``chunk`` is :data:`CHUNK`, ``head_dim`` is whole
-  sublane tiles (a multiple of 16), ``state`` whole lane tiles (a multiple
-  of 128) and x, B, C share a dtype Mosaic compiles.  Four Mosaic calls a
-  scan, a grid step one chunk of :data:`HEAD_BLOCK` heads of one group,
-  operands in the layout the mixer's activations have on the TPU (the
-  sequence minor: ``x`` as ``[batch, heads * head_dim, seq]``; the
-  transposes in front are bitcasts there): before the carry ``S_own`` and
-  ``exp(L_Q)``; after it ``y`` from the entering states; in the backward
-  first the entering states' cotangent, then, after the carry's backward,
-  ``dx``, ``d dt``, ``dA``, ``dB``, ``dC``, ``dD`` in one call.  ``C B^T``,
-  the masked exponent, the ``Q x Q`` decay, its product with the scores
-  and ``dt x`` live in VMEM only; the backward computes them again from
-  ``x``, ``dt``, ``B``, ``C`` and the entering states the carry kept, and
-  keeps nothing ``Q x Q``.  Same mathematics, rounded where the XLA leg
+  heads a group, chunks, Q, *]`` and ``y`` transposed back, and between
+  them :func:`_carry`, one ``lax.scan`` over the chunks (``S' = exp(L_Q) S
+  + S_own``, no product; XLA's ``while``); autodiff's backward.  Off the
+  TPU, inside ``dispatch.spmd_partitioned`` (a Mosaic kernel cannot be
+  partitioned over a mesh), under ``BIGDL_KERNELS=xla``, and at a shape
+  the kernels do not take.
+- Pallas's (:func:`_scan_pallas`, one ``jax.custom_vjp``), on a TPU when
+  ``chunk`` is :data:`CHUNK`, ``head_dim`` is whole sublane tiles (a
+  multiple of 16), ``state`` whole lane tiles (a multiple of 128), x, B, C
+  share a dtype Mosaic compiles and a record's states, ``heads * head_dim
+  * state`` float32, fit :data:`STATE_BUDGET` of VMEM.  ONE Mosaic call a
+  direction walks the chunks IN ORDER with the state in VMEM: a grid step
+  is one chunk of :data:`HEAD_BLOCK` heads of one group, the grid
+  ``(batch, chunks, blocks of heads)`` runs one step after another with
+  the last axis fastest, and :func:`_advance` is the carry, the same
+  float32 expression as :func:`_carry`'s step.  Operands come in the
+  layout the mixer's activations have on the TPU (the sequence minor:
+  ``x`` as ``[batch, heads * head_dim, seq]``; the transposes in front are
+  bitcasts there).  The forward (:func:`_fwd_kernel`) keeps every block's
+  state of a record in its last-state result, resident from the record's
+  first chunk (where it is zeroed) to its last; a step writes the state
+  that enters to the stack the backward needs, computes ``y`` from it,
+  then the chunk's own state and the state that leaves.  The backward
+  (:func:`_bwd_kernel`) takes the chunks from the last to the first
+  through its index maps, with the cotangent of the state that leaves a
+  chunk in a VMEM scratch started from the last state's cotangent; a step
+  reads the chunk's entering state from the stack, gives ``dx``, ``d dt``,
+  ``dA``, ``dB``, ``dC``, ``dD`` with the chunk's internals computed
+  again, and steps the cotangent by the same :func:`_advance`.  ``C B^T``,
+  the masked exponent, the ``Q x Q`` decay, its product with the scores,
+  ``dt x``, a chunk's own state and ``exp(L_Q)`` live in VMEM only, and
+  nothing ``Q x Q`` is kept.  Same mathematics, rounded where the XLA leg
   rounds; the running sums are products with a triangle of ones at
-  ``Precision.HIGHEST``.  What still crosses HBM are the float32 own and
-  entering states, ``[chunks, batch, groups, heads a group, head_dim,
-  state]``, the carry's operand and result.
+  ``Precision.HIGHEST``.  The one state stack that crosses HBM is the
+  float32 entering states, ``[chunks, batch, groups, heads a group,
+  head_dim, state]``: written once by the forward, read once by the
+  backward.
 
 The decision is announced on a ``kernel/dispatch`` instant (``op=ssd``,
 ``backend``, ``reason``, ``chunk``, ``chunks``, ``heads``, ``head_dim``,
-``state``, ``groups`` and, for the Pallas leg, ``head_block`` and
-``grid``), once a compilation, and the scan's operations lie
-under the ``jax.named_scope`` :data:`SCOPE`: an XLA dump and the
-profiler's op metadata carry it.  (The names of a device trace's events
-do not, so the benchmark finds the scan's events by the shapes only it
-has; each Mosaic call reads or writes a stack of states, which those
-patterns name.)
+``state``, ``groups`` and, for the Pallas leg, ``head_block``, ``grid``,
+``carry="vmem"``, ``calls=2`` and ``state_bytes``), once a compilation,
+and the scan's operations lie under the ``jax.named_scope`` :data:`SCOPE`:
+an XLA dump and the profiler's op metadata carry it.  (The names of a
+device trace's events do not, so the benchmark finds the scan's events by
+the shapes only it has; both Mosaic calls hold the stack of entering
+states, which those patterns name.  With no ``while`` left, the benchmark's
+two scan rooflines, which count a call by it, read nothing on this leg.)
 """
 
 from __future__ import annotations
@@ -216,6 +227,15 @@ LANES = 128
 #: fewer)
 HEAD_BLOCK = 16
 
+#: the VMEM a scan's carried state may take, ``heads * head_dim * state``
+#: float32 (2 MB and 1 MB in the two cells that run the leg): the forward
+#: holds a record's states twice (a result's two buffers), the backward
+#: three times (the last state's cotangent and the scratch it starts),
+#: inside the scoped VMEM a Mosaic call has by default (at 4 MiB, 128 heads
+#: of 64 on a state of 128, both calls compile for a v5e); a larger state
+#: takes the XLA leg
+STATE_BUDGET = 4 << 20
+
 #: what an exponent below the diagonal is set to BEFORE the exponential
 _MASKED = -1e30
 
@@ -278,10 +298,18 @@ def _a_head(rows, p: int):
     return jnp.broadcast_to(rows[:, None], (hb, p, q)).reshape(hb * p, q)
 
 
-def _states(ref):
-    """A ``[hb, head_dim, state]`` block as ``[hb * head_dim, state]``."""
-    hb, p, n = ref.shape
-    return ref[...].reshape(hb * p, n)
+def _states(ref, at=Ellipsis):
+    """A ``[hb, head_dim, state]`` block (``ref[at]``) as ``[hb * head_dim,
+    state]``."""
+    hb, p, n = (block := ref[at]).shape
+    return block.reshape(hb * p, n)
+
+
+def _block_of(per_group: int, hb: int):
+    """Where the grid step's block of heads lies in a record's ``[groups,
+    heads a group, head_dim, state]`` states."""
+    block = pl.program_id(2)
+    return block // per_group, pl.ds(block % per_group * hb, hb)
 
 
 def _fade(upto, total, total_c, j):
@@ -291,64 +319,70 @@ def _fade(upto, total, total_c, j):
                              _MASKED))
 
 
-def _own_kernel(x_ref, dt_ref, a_ref, b_ref, own_ref, decay_ref, *, hb):
-    """Before the carry: ``S_own = (exp(L_Q - L_s) dt_s x_s)^T B_s`` of
-    every head of the block, and ``exp(L_Q)``."""
-    f32, dtype = jnp.float32, x_ref.dtype
-    pick, total, _, dt = _block_sums(dt_ref, a_ref, hb)
-    p, q = x_ref.shape[0] // hb, total.shape[1]
-    last = total[:, q - 1:q]
-    xdt = (x_ref[...].astype(f32) * _a_head(dt, p)).astype(dtype)
-    kept = (xdt.astype(f32)
-            * _a_head(jnp.exp(last - total), p)).astype(dtype)
-    own_ref[...] = _dot(kept, b_ref[...], _NT).reshape(own_ref.shape)
+def _advance(state, decay, own):
+    """The carry where the state lives, in VMEM: ``S' = exp(L_Q) S +
+    S_own`` in float32, the expression of :func:`_carry`'s step in its
+    order.  The backward walks the chunks the other way with the same
+    step: ``dS = exp(L_Q) dS' + (exp(L_t) dy_t)^T C_t``."""
+    return state * decay + own
 
-    @pl.when(pl.program_id(2) == 0)
+
+def _fwd_kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
+                y_ref, enter_ref, state_ref, *, hb, per_group):
+    """A chunk of a block of heads, the chunks of a record in order:
+    ``y_t = sum_{s<=t} exp(L_t - L_s)(C_t . B_s) dt_s x_s + exp(L_t) S C_t
+    + D x_t`` from the state ``S`` that enters, which goes to the stack
+    the backward reads, then ``S' = exp(L_Q) S + (exp(L_Q - L_s) dt_s
+    x_s)^T B_s``.  ``state_ref`` holds every block's state of the record
+    and stays in VMEM from the record's first chunk to its last."""
+    f32, dtype = jnp.float32, x_ref.dtype
+    mine = _block_of(per_group, hb)
+
+    @pl.when(pl.program_id(1) == 0)
     def _():
-        decay_ref[...] = jnp.zeros_like(decay_ref)
+        state_ref[mine] = jnp.zeros(enter_ref.shape, f32)
 
-    decay_ref[...] += _put_back(jnp.exp(last), pick)
-
-
-def _out_kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, s_ref, y_ref, *,
-                hb):
-    """After the carry: ``y_t = sum_{s<=t} exp(L_t - L_s)(C_t . B_s) dt_s
-    x_s + exp(L_t) S C_t + D x_t``."""
-    f32, dtype = jnp.float32, x_ref.dtype
     pick, total, total_c, dt = _block_sums(dt_ref, a_ref, hb)
     p, q = x_ref.shape[0] // hb, total.shape[1]
     upto = _iota((q, q), 0) <= _iota((q, q), 1)              # s <= t
-    cm = c_ref[...]
-    scores = _dot(b_ref[...], cm, _TN)                       # B_s . C_t
+    bm, cm = b_ref[...], c_ref[...]
+    scores = _dot(bm, cm, _TN)                               # B_s . C_t
+    entering = _states(state_ref, mine)
+    enter_ref[...] = entering.reshape(enter_ref.shape)
     x = x_ref[...].astype(f32)
     xdt = (x * _a_head(dt, p)).astype(dtype)
     y = _a_head(_picked(d_ref, pick), p) * x + _a_head(jnp.exp(total), p) \
-        * _dot(_states(s_ref).astype(dtype), cm)
+        * _dot(entering.astype(dtype), cm)
     for j in range(hb):
         head = slice(j * p, (j + 1) * p)
         weights = (_fade(upto, total, total_c, j) * scores).astype(dtype)
         y_ref[head] = (y[head] + _dot(xdt[head], weights)).astype(dtype)
+    last = total[:, q - 1:q]
+    kept = (xdt.astype(f32)
+            * _a_head(jnp.exp(last - total), p)).astype(dtype)
+    state_ref[mine] = _advance(
+        entering, _a_head(jnp.exp(last), p),
+        _dot(kept, bm, _NT)).reshape(enter_ref.shape)
 
 
-def _entering_bwd_kernel(dy_ref, dt_ref, a_ref, c_ref, ds_ref, *, hb):
-    """The entering states' cotangent, ``(exp(L_t) dy_t)^T C_t``: what the
-    carry's backward needs first."""
-    f32, dtype = jnp.float32, dy_ref.dtype
-    _, total, _, _ = _block_sums(dt_ref, a_ref, hb)
-    grow = _a_head(jnp.exp(total), dy_ref.shape[0] // hb)
-    ds_ref[...] = _dot((dy_ref[...].astype(f32) * grow).astype(dtype),
-                       c_ref[...], _NT).reshape(ds_ref.shape)
-
-
-def _local_bwd_kernel(x_ref, dy_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
-                      s_ref, down_ref, ddecay_ref,
-                      dx_ref, ddt_ref, db_ref, dc_ref, dhead_ref,
-                      db_acc, dc_acc, *, hb, per_group):
-    """Everything else of the backward, the chunk's internals computed
-    again: ``dx``, ``d dt``, ``dB``, ``dC`` and, summed over the whole
-    grid, ``dA`` and ``dD`` (columns 0 and 1 of ``dhead``)."""
+def _bwd_kernel(x_ref, dy_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, s_ref,
+                dlast_ref, dx_ref, ddt_ref, db_ref, dc_ref, dhead_ref,
+                db_acc, dc_acc, ds_acc, *, hb, per_group):
+    """The backward of a chunk of a block of heads, the chunks of a record
+    from the LAST to the first (the index maps turn the grid's chunk
+    axis), the chunk's internals computed again: ``dx``, ``d dt``, ``dB``,
+    ``dC`` and, summed over the whole grid, ``dA`` and ``dD`` (columns 0
+    and 1 of ``dhead``).  ``ds_acc`` holds, for every block of the record,
+    the cotangent of the state that LEAVES the chunk: the last state's at
+    the record's last chunk, and from there :func:`_advance`'s."""
     f32, dtype = jnp.float32, x_ref.dtype
     block = pl.program_id(2)
+    mine = _block_of(per_group, hb)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        ds_acc[mine] = dlast_ref[mine]
+
     pick, total, total_c, dt = _block_sums(dt_ref, a_ref, hb)
     p, q = x_ref.shape[0] // hb, total.shape[1]
     rows, cols = _iota((q, q), 0), _iota((q, q), 1)
@@ -363,10 +397,17 @@ def _local_bwd_kernel(x_ref, dy_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
     dt_p, grow_p, shrink_p = (_a_head(v, p) for v in (dt, grow, shrink))
     xdt = (x * dt_p).astype(dtype)
     kept = (xdt.astype(f32) * shrink_p).astype(dtype)
-    state = _states(s_ref).astype(dtype)
-    d_own = _states(down_ref).astype(dtype)
+    entering, leaving = _states(s_ref), _states(ds_acc, mine)
+    state, d_own = entering.astype(dtype), leaving.astype(dtype)
+    # the carry, S' = exp(L_Q) S + S_own: d exp(L_Q) a head, and the
+    # entering state's cotangent with y's part, (exp(L_t) dy_t)^T C_t
+    d_decay = jnp.sum(jnp.sum((leaving * entering).reshape(hb, p, -1),
+                              axis=1), axis=1, keepdims=True)
+    grown = (dy * grow_p).astype(dtype)
+    ds_acc[mine] = _advance(leaving, _a_head(jnp.exp(last), p),
+                            _dot(grown, cm, _NT)).reshape(s_ref.shape)
     # y's part from the entering state, exp(L_t) (S C_t)
-    d_c = _dot(state, (dy * grow_p).astype(dtype), _TN)      # [state, Q]
+    d_c = _dot(state, grown, _TN)                            # [state, Q]
     d_grow = dy * _dot(state, cm) * grow_p
     # the chunk's own state, (exp(L_Q - L_s) dt_s x_s)^T B_s
     d_b = _dot(d_own, kept, _TN)
@@ -407,7 +448,7 @@ def _local_bwd_kernel(x_ref, dy_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
     # d L_t: rows, columns (the identity transposes them, exactly), and
     # L_Q's own on the chunk's last token; d (dt_s A): its sum over t >= s
     d_last = jnp.sum(d_shrunk, axis=1, keepdims=True) \
-        + _picked(ddecay_ref, pick) * jnp.exp(last)
+        + d_decay * jnp.exp(last)
     d_total = d_total - d_shrunk \
         + _dot(d_total_c, (rows == cols).astype(f32), _TN, True) \
         + jnp.where(_iota((1, q), 1) == q - 1, d_last, 0)
@@ -446,50 +487,34 @@ def _local_bwd_kernel(x_ref, dy_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
 
 
 class _Launch:
-    """Block specs and shapes of one scan's four calls: grid ``(batch,
-    chunks, blocks of heads)``, a group's blocks next to each other.  An
-    operand is a ``(block spec, shape, dtype)``."""
+    """Block specs and shapes of one scan's two calls: grid ``(batch,
+    chunks, blocks of heads)``, a group's blocks next to each other, the
+    last axis fastest and every axis ``"arbitrary"``: the steps run one
+    after another in the grid's order, which is what carries the state (a
+    ``"parallel"`` chunk axis would break the carry in silence).  An
+    operand is a ``(block spec, shape, dtype)``; ``ascending`` holds the
+    forward's, ``descending`` the backward's, whose grid step ``m`` is
+    chunk ``chunks - 1 - m``."""
 
     def __init__(self, b, c, h, p, g, n, dtype, hb, interpret):
-        f32 = jnp.float32
-        r, q = h // g, CHUNK
-        per = r // hb
-        self.hb, self.per_group, self.grid = hb, per, (b, c, h // hb)
+        r = h // g
+        self.hb, self.per_group, self.grid = hb, r // hb, (b, c, h // hb)
         self.interpret, self.calls = interpret, {}
+        shape = (b, c, h, p, g, n, dtype, hb)
+        self.ascending = _Operands(lambda m: m, *shape)
+        self.descending = _Operands(lambda m: c - 1 - m, *shape)
+        self.acc = pltpu.VMEM((n, CHUNK), jnp.float32)
+        # every block's state of a record
+        self.carried = pltpu.VMEM((g, r, p, n), jnp.float32)
 
-        def operand(block, index, shape, dtype):
-            return pl.BlockSpec(block, index), shape, dtype
-
-        # x, y and their cotangents, the sequence minor
-        self.wide = operand((None, hb * p, q), lambda i, m, j: (i, j, m),
-                            (b, h * p, c * q), dtype)
-        # dt and its cotangent
-        self.steps = operand((None, h, q), lambda i, m, j: (i, 0, m),
-                             (b, h, c * q), f32)
-        # A, D; dA and dD are columns 0 and 1 of [heads, 128]
-        self.head = operand((h, 1), lambda i, m, j: (0, 0), (h, 1), f32)
-        self.heads = operand((h, LANES), lambda i, m, j: (0, 0), (h, LANES),
-                             f32)
-        # B, C and their cotangents
-        self.proj = operand((None, n, q), lambda i, m, j: (i, j // per, m),
-                            (b, g * n, c * q), dtype)
-        # states, float32, as the carry stacks them
-        self.states = operand(
-            (None, None, None, hb, p, n),
-            lambda i, m, j: (m, i, j // per, j % per, 0, 0),
-            (c, b, g, r, p, n), f32)
-        # exp(L_Q) and its cotangent
-        self.decay = operand((None, None, h, 1), lambda i, m, j: (m, i, 0, 0),
-                             (c, b, h, 1), f32)
-        self.acc = pltpu.VMEM((n, q), f32)
-
-    def call(self, kernel, ins, outs, scratch=(), **static):
+    def call(self, kernel, ins, outs, scratch=()):
         """The call as ONE jitted function a kernel: every layer of a model
         (and a forward computed again) traces and lowers a kernel's body
-        once, not once a call (0.2 s each, 54 calls a step in a cell)."""
+        once, not once a call (0.2 s each, 27 calls a step in a cell)."""
         if kernel not in self.calls:
             self.calls[kernel] = jax.jit(pl.pallas_call(
-                functools.partial(kernel, hb=self.hb, **static),
+                functools.partial(kernel, hb=self.hb,
+                                  per_group=self.per_group),
                 grid=self.grid, in_specs=[o[0] for o in ins],
                 out_specs=[o[0] for o in outs],
                 out_shape=[jax.ShapeDtypeStruct(*o[1:]) for o in outs],
@@ -498,6 +523,44 @@ class _Launch:
                     dimension_semantics=("arbitrary",) * 3),
                 interpret=self.interpret))
         return self.calls[kernel]
+
+
+class _Operands:
+    """A direction's operands; ``at(m)`` is the chunk of grid step ``m``."""
+
+    def __init__(self, at, b, c, h, p, g, n, dtype, hb):
+        f32 = jnp.float32
+        r, q = h // g, CHUNK
+        per = r // hb
+
+        def operand(block, index, shape, dtype):
+            return pl.BlockSpec(block, index), shape, dtype
+
+        # x, y and their cotangents, the sequence minor
+        self.wide = operand((None, hb * p, q), lambda i, m, j: (i, j, at(m)),
+                            (b, h * p, c * q), dtype)
+        # dt and its cotangent
+        self.steps = operand((None, h, q), lambda i, m, j: (i, 0, at(m)),
+                             (b, h, c * q), f32)
+        # A, D; dA and dD are columns 0 and 1 of [heads, 128]
+        self.head = operand((h, 1), lambda i, m, j: (0, 0), (h, 1), f32)
+        self.heads = operand((h, LANES), lambda i, m, j: (0, 0), (h, LANES),
+                             f32)
+        # B, C and their cotangents
+        self.proj = operand((None, n, q),
+                            lambda i, m, j: (i, j // per, at(m)),
+                            (b, g * n, c * q), dtype)
+        # the states that enter the chunks, float32, stacked as the XLA
+        # leg's carry stacks them
+        self.states = operand(
+            (None, None, None, hb, p, n),
+            lambda i, m, j: (at(m), i, j // per, j % per, 0, 0),
+            (c, b, g, r, p, n), f32)
+        # the state after a record's last token and its cotangent: the
+        # record's, in VMEM from its first grid step to its last
+        self.last = operand((None, g, r, p, n),
+                            lambda i, m, j: (i, 0, 0, 0, 0), (b, g, r, p, n),
+                            f32)
 
 
 _launch = functools.lru_cache(maxsize=None)(_Launch)
@@ -528,43 +591,34 @@ def _operands(x, dt, a, bm, cm, d):
 
 def _scan_pallas_fwd(x, dt, a, bm, cm, d):
     k, (xm, dtm, a_col, bmm, cmm, d_col) = _operands(x, dt, a, bm, cm, d)
-    own, decay = k.call(_own_kernel, [k.wide, k.steps, k.head, k.proj],
-                        [k.states, k.decay])(xm, dtm, a_col, bmm)
-    # the part that runs in order stays XLA's, its backward autodiff's
-    (state, entering), carry_bwd = jax.vjp(
-        _carry, decay.reshape(own.shape[:4]), own)
-    y, = k.call(_out_kernel, [k.wide, k.steps, k.head, k.head, k.proj,
-                              k.proj, k.states], [k.wide])(
-        xm, dtm, a_col, d_col, bmm, cmm, entering)
-    return (_major(y, x), state), (x, dt, a, bm, cm, d, entering, carry_bwd)
+    o = k.ascending
+    y, entering, state = k.call(
+        _fwd_kernel, [o.wide, o.steps, o.head, o.head, o.proj, o.proj],
+        [o.wide, o.states, o.last])(xm, dtm, a_col, d_col, bmm, cmm)
+    return (_major(y, x), state), (x, dt, a, bm, cm, d, entering)
 
 
 @jax.custom_vjp
 def _scan_pallas(x, dt, a, bm, cm, d):
-    """The chunked scan on whole chunks of :data:`CHUNK`, the chunk-local
-    work as four Mosaic calls: :func:`ssd`'s arguments, ``dt``, ``a`` and
-    ``d`` float32 -> ``y`` as ``x`` and the last state ``[batch, groups,
-    heads a group, head_dim, state]``."""
+    """The chunked scan on whole chunks of :data:`CHUNK`, one Mosaic call a
+    direction with the state in VMEM: :func:`ssd`'s arguments, ``dt``,
+    ``a`` and ``d`` float32 -> ``y`` as ``x`` and the last state ``[batch,
+    groups, heads a group, head_dim, state]``."""
     return _scan_pallas_fwd(x, dt, a, bm, cm, d)[0]
 
 
 def _scan_pallas_bwd(kept, cotangents):
-    x, dt, a, bm, cm, d, entering, carry_bwd = kept
+    x, dt, a, bm, cm, d, entering = kept
     dy, d_state = cotangents
     k, (xm, dtm, a_col, bmm, cmm, d_col) = _operands(x, dt, a, bm, cm, d)
-    dym = _minor(dy)
-    d_entering, = k.call(_entering_bwd_kernel,
-                         [k.wide, k.steps, k.head, k.proj], [k.states])(
-        dym, dtm, a_col, cmm)
-    d_decay, d_own = carry_bwd((d_state, d_entering))
+    o = k.descending
     dx, d_dt, d_b, d_c, d_head = k.call(
-        _local_bwd_kernel,
-        [k.wide, k.wide, k.steps, k.head, k.head, k.proj, k.proj, k.states,
-         k.states, k.decay],
-        [k.wide, k.steps, k.proj, k.proj, k.heads], [k.acc, k.acc],
-        per_group=k.per_group)(
-        xm, dym, dtm, a_col, d_col, bmm, cmm, entering, d_own,
-        d_decay.reshape(k.decay[1]))
+        _bwd_kernel,
+        [o.wide, o.wide, o.steps, o.head, o.head, o.proj, o.proj, o.states,
+         o.last],
+        [o.wide, o.steps, o.proj, o.proj, o.heads],
+        [k.acc, k.acc, k.carried])(
+        xm, _minor(dy), dtm, a_col, d_col, bmm, cmm, entering, d_state)
     return (_major(dx, x), _major(d_dt, dt), d_head[:, 0], _major(d_b, bm),
             _major(d_c, cm), d_head[:, 1])
 
@@ -597,13 +651,16 @@ def ssd(x, dt, A, B, C, D, chunk: int = CHUNK, return_state: bool = False):
     hb = _head_block(h // g)
     said = dict(chunk=chunk, chunks=chunks, heads=h, head_dim=p, state=n,
                 groups=g)
+    state_bytes = h * p * n * 4
     # shapes and dtype only: a head is whole sublane tiles, a state whole
-    # lane tiles
+    # lane tiles, a record's states fit their share of VMEM
     supported = chunk == CHUNK and p % 16 == 0 and n % LANES == 0 \
-        and x.dtype == B.dtype == C.dtype and mosaic_dtype(x.dtype)
+        and x.dtype == B.dtype == C.dtype and mosaic_dtype(x.dtype) \
+        and state_bytes <= STATE_BUDGET
 
     def kernels():
-        launched(**said, head_block=hb, grid=(b, chunks, h // hb))
+        launched(**said, head_block=hb, grid=(b, chunks, h // hb),
+                 carry="vmem", calls=2, state_bytes=state_bytes)
         return _chunked_pallas(x, dt, A, B, C, D)
 
     def whole():

@@ -486,13 +486,13 @@ def test_whole_mamba2_block_lowers_for_the_chip(one_chip, monkeypatch):
                          "bf16[8512,2048]{1,0} %w, bf16[8192,2048]{1,0} %x)")
 
 
-def _scan_events(text, conf):
-    """Of a compiled text: its Mosaic calls, and the names of the
-    ``ssd_kernels`` that count each ``while``."""
+def _scan_events(text, confs):
+    """Of a compiled text: its Mosaic calls, and of its ``while``s the
+    ``ssd_kernels`` names of any of ``confs`` that would count it."""
     lines = text.splitlines()
     calls = [ln for ln in lines if "tpu_custom_call" in ln]
-    whiles = [sorted(k["counts"] for k in conf["ssd_kernels"]
-                     if re.search(k["match"], ln))
+    whiles = [sorted(k["counts"] for conf in confs
+                     for k in conf["ssd_kernels"] if re.search(k["match"], ln))
               for ln in lines if " while(" in ln]
     return calls, sorted(w for w in whiles if w)
 
@@ -504,24 +504,30 @@ SCAN_CELLS = {"granite-block": "granite_4_0_h_micro",
 @pytest.mark.parametrize("config", SCAN_CELLS.values(), ids=SCAN_CELLS)
 def test_ssd_kernels_compile_and_are_read_as_the_scan(config, one_chip,
                                                       as_tpu, monkeypatch):
-    """The scan on a TPU's own leg: the chunk-local work is four Mosaic
-    calls around the carry's two ``while``s, in the whole Granite block
-    (64 heads on one group) and in a scan of the Nemotron cell's shape (32
-    heads on 2 groups).  The benchmark's reading stays in place: every
-    call is named by the configuration's ``ssd_scan_match`` and
-    ``ssd_match`` (each holds a ``[chunks, batch, groups, heads a group,
-    head_dim, state]`` stack of states), ``ssd_kernels`` counts a forward
-    and a backward ``while`` and tells them apart, and no ``Q x Q`` decay
-    of all chunks is left in the text."""
+    """The scan on a TPU's own leg: ONE Mosaic call a direction that walks
+    the chunks in order with the state in VMEM (Mosaic takes the resident
+    state, the scratch and the backward's reversed index maps), in the
+    whole Granite block (64 heads on one group) and in a scan of the
+    Nemotron cell's shape (32 heads on 2 groups).  No ``while`` of the
+    scan is left for either configuration's ``ssd_kernels`` to count (the
+    two scan rooflines read nothing until a ``benchmark`` PR re-points
+    them), nothing fills a stack of states with zeros, and the shares keep
+    reading: both calls are named by the configuration's ``ssd_scan_match``
+    and ``ssd_match`` (each holds the ``[chunks, batch, groups, heads a
+    group, head_dim, state]`` stack of entering states) and by neither
+    pattern of the other cell; no ``Q x Q`` decay of all chunks is in the
+    text."""
     from bigdl_tpu.ops import dispatch, ssd
 
-    if config == "granite_4_0_h_micro":
-        conf, grad, shapes = _granite_mamba_block(one_chip, monkeypatch)
-    else:
+    confs = {}
+    for name in SCAN_CELLS.values():
         with open(os.path.join(ROOT, "benchmark", "configs",
-                               config + ".json")) as fh:
-            conf = json.load(fh)
-
+                               name + ".json")) as fh:
+            confs[name] = json.load(fh)
+    conf = confs[config]
+    if config == "granite_4_0_h_micro":
+        _, grad, shapes = _granite_mamba_block(one_chip, monkeypatch)
+    else:
         def shaped(*shape, dtype=jnp.bfloat16):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -544,22 +550,25 @@ def test_ssd_kernels_compile_and_are_read_as_the_scan(config, one_chip,
     assert said.launch == dict(
         chunk=128, chunks=64, heads=heads, head_dim=64, state=128,
         groups=groups, head_block=ssd.HEAD_BLOCK,
-        grid=(1, 64, heads // ssd.HEAD_BLOCK))
-    calls, whiles = _scan_events(text, conf)
-    # the own states, the output, the entering states' cotangent, the rest
-    assert len(calls) == 4
+        grid=(1, 64, heads // ssd.HEAD_BLOCK),
+        carry="vmem", calls=2, state_bytes=heads * 64 * 128 * 4)
+    assert said.launch["state_bytes"] <= ssd.STATE_BUDGET
+    calls, whiles = _scan_events(text, confs.values())
+    # a forward and a backward, and nothing of the scan between them
+    assert len(calls) == 2
+    assert whiles == []
+    r = heads // groups
+    stack = rf"f32\[64,1,{groups},{r},64,128\]"
+    assert all(re.search(stack, ln) for ln in calls)
+    assert not re.search(stack + r"\S* broadcast\(", text)
     for pattern in (conf["ssd_scan_match"], conf["ssd_match"]):
         assert all(re.search(pattern, ln.strip()) for ln in calls)
-    assert whiles == [["scan"], ["scan", "scan_bwd"]]
-    r = heads // groups
     assert f"f32[1,{groups},{r},64,128,128]" not in text
     assert not re.search(r"f32\[[\d,]*128,128,128\]", text)
     # the other state-space cell's patterns do not claim these calls
-    other = next(c for c in SCAN_CELLS.values() if c != config)
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           other + ".json")) as fh:
-        theirs = json.load(fh)["ssd_scan_match"]
-    assert not [ln for ln in calls if re.search(theirs, ln.strip())]
+    theirs = next(c for name, c in confs.items() if name != config)
+    for pattern in (theirs["ssd_scan_match"], theirs["ssd_match"]):
+        assert not [ln for ln in calls if re.search(pattern, ln.strip())]
 
 
 # -- the flash kernels' value width, and the plan that needs it ---------------

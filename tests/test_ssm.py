@@ -100,7 +100,8 @@ def test_a_carry_zeroed_on_purpose_is_seen(leg, monkeypatch):
     """With decays near 1 most of an output comes from earlier chunks: a
     scan that forgets the state between chunks is far from the
     recurrence, so the comparison above would refuse it.  On both legs:
-    the carry is the one ``_carry`` between the Pallas leg's calls too."""
+    the XLA leg's carry is ``_carry``, the Pallas leg's the ``_advance``
+    its kernels step the state in VMEM by."""
     monkeypatch.setenv("BIGDL_KERNELS", leg)
     chunk, shape = (8, {}) if leg == "xla" else (
         scan.CHUNK, dict(s=384, h=2, g=1, **KERNEL_SHAPE))
@@ -118,17 +119,30 @@ def test_a_carry_zeroed_on_purpose_is_seen(leg, monkeypatch):
         return state, jnp.zeros_like(entering)
 
     monkeypatch.setattr(scan, "_carry", forgetful)
+    monkeypatch.setattr(scan, "_advance",
+                        lambda state, decay, own: jnp.zeros_like(own))
+    # a kernel is traced once a shape: not the sound one again, and the
+    # forgetful one for nobody else
+    monkeypatch.setattr(scan, "_launch", functools.lru_cache(maxsize=None)(
+        scan._Launch))
     bad = compiled(functools.partial(scan.ssd, chunk=chunk), *args)
     gap = np.abs(np.asarray(bad) - np.asarray(want)).max()
     assert gap > 0.1 * np.abs(np.asarray(want)).max()
 
 
 #: dtype, what it is compared with, shift of ``dt``, the kernel's head
-#: block (None: its own), shape.  float32 against the definition, bfloat16
+#: block (None: its own), shape, and the weight of ``y`` in the loss (1
+#: where a case names none).  float32 against the definition, bfloat16
 #: against the XLA leg (the same roundings in the same places); one group
 #: and two; 300 tokens are three chunks, the last padded; heads a group
 #: over TWO grid steps, so that ``dB``, ``dC``, ``d dt``, ``dA`` and ``dD``
-#: are summed over blocks
+#: are summed over blocks.  From "two-records-of-three-chunks" on, what
+#: only a state carried in VMEM can get wrong: a second record has to
+#: start from zero and from ITS last state's cotangent (decays near 1, so
+#: what the first record left would be seen); two blocks of heads over
+#: five and six chunks keep a state and a cotangent each, walked forward
+#: and back; with ``y`` out of the loss every gradient comes through the
+#: last state's cotangent and the carry's backward alone
 KERNEL_SCANS = {
     "float32-one-group-padded": (
         jnp.float32, "recurrent", -4.0, None, dict(s=300, h=2, g=1)),
@@ -139,29 +153,44 @@ KERNEL_SCANS = {
     "bfloat16-two-groups": (
         jnp.bfloat16, "xla", -2.0, None, dict(s=384, h=4, g=2)),
     "bfloat16-one-group-padded": (
-        jnp.bfloat16, "xla", -4.0, None, dict(s=300, h=4, g=1))}
+        jnp.bfloat16, "xla", -4.0, None, dict(s=300, h=4, g=1)),
+    "float32-two-records-of-three-chunks-decays-near-one": (
+        jnp.float32, "recurrent", -4.0, None, dict(b=2, s=384, h=2, g=1)),
+    "float32-five-chunks-two-blocks-a-group": (
+        jnp.float32, "recurrent", -3.0, 2, dict(s=640, h=4, g=1)),
+    "float32-last-state-alone-in-the-loss": (
+        jnp.float32, "recurrent", -3.0, 2, dict(b=2, s=384, h=4, g=2), 0.0),
+    "bfloat16-two-records-six-chunks-two-blocks-a-group": (
+        jnp.bfloat16, "xla", -4.0, 2, dict(b=2, s=700, h=4, g=1))}
 
 
-@pytest.mark.parametrize("dtype,against,dt_shift,head_block,shape",
-                         KERNEL_SCANS.values(), ids=KERNEL_SCANS)
+@pytest.mark.parametrize(
+    "dtype,against,dt_shift,head_block,shape,y_weight",
+    [(case + (1.0,))[:6] for case in KERNEL_SCANS.values()],
+    ids=KERNEL_SCANS)
 def test_pallas_leg_is_the_scan(dtype, against, dt_shift, head_block, shape,
-                                monkeypatch):
-    """The interpreted kernels around the carry: values, the state after
-    the last token and the gradient by ``x``, ``dt``, ``A``, ``B``, ``C``
-    and ``D``."""
+                                y_weight, monkeypatch):
+    """The interpreted kernels, the state carried in VMEM from chunk to
+    chunk: values, the state after the last token and the gradient by
+    ``x``, ``dt``, ``A``, ``B``, ``C`` and ``D``."""
     if head_block:
         monkeypatch.setattr(scan, "HEAD_BLOCK", head_block)
     args = _scan_inputs(5, dt_shift, **shape, **KERNEL_SHAPE)
     args = tuple(a.astype(dtype) if i in (0, 3, 4) else a
                  for i, a in enumerate(args))
-    weigh = jnp.asarray(np.random.default_rng(6).standard_normal(
-        args[0].shape), jnp.float32)
+    rng = np.random.default_rng(6)
+    weigh = y_weight * jnp.asarray(rng.standard_normal(args[0].shape),
+                                   jnp.float32)
+    heads, groups = shape["h"], shape["g"]
+    weigh_state = jnp.asarray(rng.standard_normal(
+        (shape.get("b", 1), heads, KERNEL_SHAPE["p"], KERNEL_SHAPE["n"])),
+        jnp.float32)
 
     def both(fn, leg):
         def loss(*a):
             y, state = fn(*a, return_state=True)
-            return (jnp.sum(y.astype(jnp.float32) * weigh) + jnp.sum(state),
-                    (y, state))
+            return (jnp.sum(y.astype(jnp.float32) * weigh)
+                    + jnp.sum(state * weigh_state), (y, state))
         monkeypatch.setenv("BIGDL_KERNELS", leg)
         dispatch.clear_decisions()
         out = compiled(jax.value_and_grad(loss, argnums=tuple(range(6)),
@@ -170,7 +199,6 @@ def test_pallas_leg_is_the_scan(dtype, against, dt_shift, head_block, shape,
 
     ((_, (y, state)), grads), (said,) = both(scan.ssd, "pallas")
     assert tuple(said) == ("ssd", "pallas", "forced:BIGDL_KERNELS=pallas")
-    heads, groups = shape["h"], shape["g"]
     block = head_block or min(heads // groups, scan.HEAD_BLOCK)
     assert said.launch["head_block"] == block
     assert said.launch["grid"] == (shape.get("b", 1), -(-shape["s"] // 128),
@@ -226,7 +254,17 @@ def test_the_scan_announces_the_chosen_leg_and_refuses_odd_groups(
     out, said = said_for(*wide)
     assert out.shape == (2, 300, 12, 64)
     assert tuple(said) == ("ssd", "pallas", "forced:BIGDL_KERNELS=pallas")
-    assert said.launch == dict(wide_facts, head_block=6, grid=(2, 3, 2))
+    assert said.launch == dict(wide_facts, head_block=6, grid=(2, 3, 2),
+                               carry="vmem", calls=2,
+                               state_bytes=12 * 64 * 128 * 4)
+    # a record's states are carried in VMEM: more of them than their
+    # budget there go the other way
+    assert 64 * 64 * 128 * 4 * 2 == scan.STATE_BUDGET
+    for heads, leg in ((128, "pallas"), (144, "xla")):
+        big = tuple((2, 300, heads) + s[3:] if len(s) > 2 and s[2] == 12
+                    else (heads,) if s == (12,) else s for s in wide)
+        _, said = said_for(*big)
+        assert said[1] == leg and said.launch["heads"] == heads
     # a state of half a lane tile; a short sequence is one chunk of its
     # own length
     narrow = wide[:3] + ((2, 300, 2, 64),) * 2 + wide[5:]
